@@ -43,14 +43,17 @@ class TestBenchtrend:
         ones yield points. Schema drift in a future bench round fails
         HERE, in tier-1, instead of silently emptying the gate."""
         files = bt.bench_files(REPO)
-        assert len(files) >= 10
+        # the five CPU-VM trajectories; the old driver wrapper records
+        # (BENCH_r0N / MULTICHIP_r0N) are gone and must not come back
+        assert len(files) >= 5
+        assert not [f.name for f in files if "_r0" in f.name]
         by_file: dict[str, int] = {}
         for f in files:
             pts = bt.parse_file(f, f.name)    # must not raise
             by_file[f.name] = len(pts)
         for name in ("BENCH_asr.json", "BENCH_compile.json",
                      "BENCH_coord.json", "BENCH_delivery.json",
-                     "MULTICHIP.json", "BENCH_r02.json"):
+                     "MULTICHIP.json"):
             assert by_file.get(name, 0) >= 1, (name, by_file)
         assert sum(by_file.values()) >= 40
 
@@ -95,7 +98,7 @@ class TestBenchtrend:
                    "timestamp": "2026-02-01T00:00:00Z"}
         bad_fb = {"metric": "fix_device_realtime_x", "value": 1.0,
                   "gate": "tpu_only",
-                  "fallback_reason": "tunnel_dead_probe_timeout",
+                  "fallback_reason": "tpu_body_timed_out",
                   "timestamp": "2026-03-01T00:00:00Z"}
         (root / "BENCH_fix.json").write_text(
             json.dumps(base + [bad_cpu, bad_fb]))

@@ -165,17 +165,39 @@ def test_fused_resize_plane_matches_xla_directly():
         assert got.shape == (2, 3, dh, dw) and got.dtype == np.uint8
 
 
-def test_use_pallas_policy():
+def test_use_pallas_policy(monkeypatch):
+    from vlog_tpu import config
     from vlog_tpu.ops import pallas_ladder as pal
 
     assert pal.use_pallas("0") is False
     assert pal.use_pallas("off") is False
-    # the probe runs the real (interpreted) kernel; it must be healthy
-    # on this VM or the whole fused plane silently disappears
-    assert pal.pallas_available() is True
+    # "1" means the kernel, with no probe standing between the knob and
+    # the plane: a refusal must surface where the kernel is traced
     assert pal.use_pallas("1") is True
-    # auto never fuses off-TPU: interpret mode is a correctness vehicle
+    assert not hasattr(pal, "pallas_available")
+    # auto never fuses: Mosaic refuses the kernel as written on a TPU
+    # (PR 21) and interpret mode is a correctness vehicle, not a path
     assert pal.use_pallas("auto") is False
+    monkeypatch.setattr(config, "PALLAS", "auto")
+    assert pal.use_pallas() is False
+    monkeypatch.setattr(config, "PALLAS", "1")
+    assert pal.use_pallas() is True
+
+
+def test_fused_plane_never_falls_back(monkeypatch):
+    """A lowering failure raises out of ``fused_resize_plane``; there is
+    no XLA path behind it (what ``VLOG_PALLAS=1`` on a TPU relies on)."""
+    from vlog_tpu.ops import pallas_ladder as pal
+    from vlog_tpu.ops.resize import resample_matrix
+
+    def refuse(*_a, **_k):
+        raise NotImplementedError("Unsupported cast: uint8 -> float32")
+
+    monkeypatch.setattr(pal.pl, "pallas_call", refuse)
+    x = np.zeros((1, 32, 48), np.uint8)
+    with pytest.raises(NotImplementedError, match="Unsupported cast"):
+        pal.fused_resize_plane(x, jnp.asarray(resample_matrix(32, 16)),
+                               jnp.asarray(resample_matrix(48, 24)))
 
 
 def test_block_rows_exact_divisor():
@@ -379,27 +401,32 @@ def _restore_jax_cache_config():
 
 
 def test_compile_cache_policy(tmp_path, monkeypatch):
-    from vlog_tpu import config
+    """Placement is decided outside the program: jax's own
+    JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; unset on
+    CPU there is no cache (host-ISA AOT entries do not port)."""
     from vlog_tpu.parallel import compile_cache as cc
 
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
     try:
-        # CPU + no explicit dir: disabled (host-ISA AOT entries do not
-        # port across machines)
         cc.reset_for_tests()
-        monkeypatch.setattr(config, "COMPILE_CACHE_DIR", "")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert cc.ensure_compile_cache() is None
-        # explicit dir: armed on ANY platform, idempotent
+        assert updates == []
         cc.reset_for_tests()
-        monkeypatch.setattr(config, "COMPILE_CACHE_DIR",
-                            str(tmp_path / "xla"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "xla"))
         armed = cc.ensure_compile_cache()
         assert armed == str(tmp_path / "xla")
-        assert Path(armed).is_dir()
         assert cc.ensure_compile_cache() == armed    # second call: no-op
-        assert jax.config.jax_compilation_cache_dir == armed
+        # reported, not set: jax read the variable itself at start-up;
+        # the only thing set in code is the persistence floor
+        assert updates == [
+            ("jax_persistent_cache_min_compile_time_secs", 0.0)]
+        assert not (tmp_path / "xla").exists()       # jax creates it
     finally:
         cc.reset_for_tests()
-        _restore_jax_cache_config()
 
 
 def test_compile_meter_counts_backend_compiles():
@@ -420,15 +447,21 @@ def test_compile_cache_serves_warm_recompiles(tmp_path, monkeypatch):
     """In-process warm-vs-cold: after jax.clear_caches() the second
     compile of the same program is a persistent-cache HIT, which skips
     the backend compile — the meter (which counts only backend
-    compiles) must see (almost) nothing."""
-    from vlog_tpu import config
+    compiles) must see (almost) nothing. The test plays the operator:
+    it places the cache the way JAX_COMPILATION_CACHE_DIR would have at
+    start-up; ensure_compile_cache must leave that placement alone."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
     from vlog_tpu.parallel import compile_cache as cc
 
     try:
         cc.reset_for_tests()
-        monkeypatch.setattr(config, "COMPILE_CACHE_DIR",
-                            str(tmp_path / "xla"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "xla"))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "xla"))
+        jcc.reset_cache()
         assert cc.ensure_compile_cache() == str(tmp_path / "xla")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
 
         def f(x):
             return jnp.sin(x) * 3.0 + jnp.cos(x) @ jnp.ones((512, 512))
@@ -476,11 +509,11 @@ _WARM_COLD_CHILD = textwrap.dedent("""\
 @pytest.mark.slow
 def test_compile_cache_bench_record(tmp_path):
     """The acceptance gate, measured the way production restarts hit it:
-    two fresh processes sharing one VLOG_COMPILE_CACHE_DIR. Warm-start
-    metered compile_s must be <= 0.2x cold; the pair is appended as a
-    labeled BENCH_compile.json record."""
+    two fresh processes sharing one JAX_COMPILATION_CACHE_DIR (jax's
+    own variable). Warm-start metered compile_s must be <= 0.2x cold;
+    the pair is appended as a labeled BENCH_compile.json record."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               VLOG_COMPILE_CACHE_DIR=str(tmp_path / "xla"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
     runs = []
     for _ in range(2):
         r = subprocess.run([sys.executable, "-c", _WARM_COLD_CHILD],
@@ -603,11 +636,13 @@ def test_raw_speed_knobs_parsed_and_documented():
     from vlog_tpu import config
     from vlog_tpu.analysis import registry as reg
 
-    reg.assert_knobs(("VLOG_PALLAS", "VLOG_WHISPER_QUANT",
-                      "VLOG_COMPILE_CACHE_DIR"))
+    reg.assert_knobs(("VLOG_PALLAS", "VLOG_WHISPER_QUANT"))
     assert isinstance(config.PALLAS, str)
     assert isinstance(config.WHISPER_QUANT, str)
-    assert isinstance(config.COMPILE_CACHE_DIR, str)
+    # the compile cache is placed by jax's own variable, not a knob
+    assert not hasattr(config, "COMPILE_CACHE_DIR")
+    assert "VLOG_COMPILE_CACHE_DIR" not in (
+        Path(__file__).parent.parent / "README.md").read_text()
 
 
 def _fixture_pkg(tmp_path, files):

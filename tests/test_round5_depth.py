@@ -813,12 +813,15 @@ def test_bench_merge_entropy_derives_coloc():
          "chain_fps": 45.0}, ent)
     assert out["coloc_bound"] == "device"
     assert out["coloc_e2e_estimate_x"] == 1.5
-    # cpu fallback must NOT claim a co-located figure
+    # no device throughput in the record: nothing to derive from
     out = bench._merge_entropy(
-        {"metric": "720p_chain_ladder_device_realtime_x_cpu_fallback",
-         "chain_fps": 1.0}, ent)
+        {"metric": "4k_6rung_chain_ladder_device_realtime_x"}, ent)
     assert "coloc_e2e_estimate_x" not in out
     assert out["entropy_mode"] == "cabac"          # entropy still merged
+    # the orchestrator has no CPU body to fall back to any more
+    assert not hasattr(bench, "run_smoke")
+    assert not hasattr(bench, "run_probe")
+    assert "cpu_fallback" not in Path(bench.__file__).read_text()
     # garbage entropy line is ignored
     out = bench._merge_entropy(dict(rec), "not json")
     assert "coloc_e2e_estimate_x" not in out

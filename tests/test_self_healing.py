@@ -245,6 +245,102 @@ def test_device_fault_refunds_attempt_budget_with_bound(run, db, tmp_path):
 
 
 # --------------------------------------------------------------------------
+# A device-classified error that repeats on healthy devices is the job's
+# (PR 21: what a compile-time HBM OOM of the ladder program looks like)
+# --------------------------------------------------------------------------
+
+# the shape jax 0.9.0 raises on a TPU v5 lite when a program's buffers
+# exceed HBM (chip run, PR 21); the class name classifies as a device
+# fault, the failure is a property of the program
+_HBM_OOM = ("RESOURCE_EXHAUSTED: Allocation (size={size}) would exceed "
+            "memory (size=17179869184) :: #allocation5 [shape = "
+            "'f32[100000,100000]{{0,1:T(8,128)}}', space=hbm, size = "
+            "0x{addr:x}, tag = 'output of multiply_add_fusion@{{}}']")
+
+
+class JaxRuntimeError(RuntimeError):
+    """Same NAME as jax.errors.JaxRuntimeError (faults matches names)."""
+
+
+class TestRepeatFaultDetector:
+    def test_first_sight_is_hardware_repeat_on_healthy_is_the_job(self):
+        probed = []
+        det = faults.RepeatFaultDetector(
+            probe=lambda d: probed.append(d) or True)
+        exc = JaxRuntimeError(_HBM_OOM.format(size=40038400000, addr=255))
+        assert faults.is_device_fault(exc)
+        assert det.repeats_on_healthy_devices(7, exc, ("d0", "d1")) is False
+        assert probed == []          # nothing to compare with yet
+        # same failure, different volatile numbers
+        again = JaxRuntimeError(_HBM_OOM.format(size=40038400001, addr=4095))
+        assert det.repeats_on_healthy_devices(7, again, ("d0", "d1")) is True
+        assert probed == ["d0", "d1"]
+
+    def test_other_job_or_other_error_is_not_a_repeat(self):
+        det = faults.RepeatFaultDetector(probe=lambda d: True)
+        exc = JaxRuntimeError(_HBM_OOM.format(size=1, addr=1))
+        assert det.repeats_on_healthy_devices(1, exc, ("d",)) is False
+        assert det.repeats_on_healthy_devices(2, exc, ("d",)) is False
+        other = JaxRuntimeError("INTERNAL: device halted")
+        assert det.repeats_on_healthy_devices(1, other, ("d",)) is False
+        # ...and the error that replaced it is what a repeat compares to
+        assert det.repeats_on_healthy_devices(1, exc, ("d",)) is False
+
+    def test_failing_or_raising_probe_keeps_the_hardware_suspect(self):
+        exc = JaxRuntimeError("INTERNAL: device halted")
+        det = faults.RepeatFaultDetector(probe=lambda d: False)
+        det.repeats_on_healthy_devices(1, exc, ("d",))
+        assert det.repeats_on_healthy_devices(1, exc, ("d",)) is False
+
+        def boom(_d):
+            raise RuntimeError("probe could not allocate")
+
+        det = faults.RepeatFaultDetector(probe=boom)
+        det.repeats_on_healthy_devices(1, exc, ("d",))
+        assert det.repeats_on_healthy_devices(1, exc, ("d",)) is False
+
+    def test_memory_is_bounded(self):
+        det = faults.RepeatFaultDetector(probe=lambda d: True, capacity=4)
+        exc = JaxRuntimeError("INTERNAL: device halted")
+        for job in range(10):
+            det.repeats_on_healthy_devices(job, exc, ())
+        assert len(det._seen) == 4
+
+
+def test_repeated_device_error_fails_the_job_permanently(run, db, tmp_path,
+                                                         monkeypatch):
+    """Daemon loop on one healthy (virtual) device set: the first
+    device-classified failure is refunded as ``device_fault``; the SAME
+    failure on the retry, with the real put/reduce/pull probe passing,
+    dead-letters the job as ``permanent`` — a waiter gets the error
+    after two attempts instead of a refund loop."""
+    src = make_y4m(tmp_path / "src.y4m", n_frames=4, width=64, height=48,
+                   fps=24)
+    v = run(vids.create_video(db, "Too big", source_path=str(src)))
+    jid = run(claims.enqueue_job(db, v["id"]))
+
+    def too_big(*_a, **_k):
+        raise JaxRuntimeError(_HBM_OOM.format(size=40038400000, addr=255))
+
+    monkeypatch.setattr("vlog_tpu.worker.pipeline.process_video", too_big)
+    daemon = make_daemon(db, tmp_path)
+
+    assert run(daemon.poll_once()) is True
+    row = run(db.fetch_one("SELECT * FROM jobs WHERE id=:id", {"id": jid}))
+    assert row["failed_at"] is None and row["attempt"] == 0   # refunded
+    assert run(daemon.poll_once()) is True
+    row = run(db.fetch_one("SELECT * FROM jobs WHERE id=:id", {"id": jid}))
+    assert row["failed_at"] is not None
+    assert "not a hardware fault" in row["error"]
+    history = run(claims.get_failure_history(db, jid))
+    assert [h["failure_class"] for h in history] == ["device_fault",
+                                                     "permanent"]
+    video = run(vids.get_video(db, v["id"]))
+    assert video["status"] == "failed"
+    assert run(daemon.poll_once()) is False      # nothing left to claim
+
+
+# --------------------------------------------------------------------------
 # The full chaos loop: fault mid-job -> quarantine -> renegotiate ->
 # refund-requeue -> byte-identical retry (ISSUE 7 acceptance)
 # --------------------------------------------------------------------------

@@ -1,15 +1,12 @@
 """meshshim: every shard_map call site goes through parallel/mesh.
 
-``parallel/mesh.py::shard_map`` is the single version shim over jax's
-shard_map API (jax >= 0.4.35 renamed ``check_rep`` to ``check_vma`` and
-moved the function out of ``jax.experimental``); every sharded program
-in the tree — the 1-D ladder programs, the per-column programs of the
-2-D (data × rung) grid, the dryrun harness — builds on it. A raw
-``jax.shard_map`` / ``jax.experimental.shard_map`` import anywhere else
-re-introduces the exact breakage the shim exists to absorb: the call
-site works on the pinned jax and silently fails (or flips replication
-checking) on the next upgrade, and it bypasses the shim's fixed
-``check_vma=False`` contract the byte-identity tests depend on.
+``parallel/mesh.py::shard_map`` is the tree's one spelling of
+``jax.shard_map``; every sharded program in the tree — the 1-D ladder
+programs, the per-column programs of the 2-D (data × rung) grid, the
+dryrun harness — imports it from there. One import site means the next
+jax that moves or renames the function (as 0.4.35 did: out of
+``jax.experimental``, ``check_rep`` to ``check_vma``) is absorbed in
+one line instead of at every program builder.
 
 Rule: outside ``parallel/mesh.py``, no module may
 
@@ -33,7 +30,7 @@ from vlog_tpu.analysis.core import Finding, Module, dotted_name
 
 RULE = "meshshim"
 
-_SHIM = "parallel/mesh.py (the version shim)"
+_SHIM = "parallel/mesh.py (the one import site)"
 _RAW_MODULES = frozenset({
     "jax.experimental.shard_map",
 })

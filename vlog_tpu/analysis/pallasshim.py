@@ -1,15 +1,14 @@
 """pallasshim: Pallas kernel code stays inside ops/pallas_ladder.py.
 
 ``ops/pallas_ladder.py`` is the tree's single Pallas surface: it owns the
-guarded ``jax.experimental.pallas`` import, the interpret-mode fallback,
-the VMEM budget check, the one-shot availability probe, and the
-byte-identity contract with the XLA resize path. Program builders select
-a *plane* via :func:`~vlog_tpu.ops.pallas_ladder.ladder_resize` — they
-never see ``pallas_call``. A raw pallas import anywhere else leaks
-kernel code past those guards: the call site compiles on TPU but
-explodes under ``JAX_PLATFORMS=cpu`` (no interpret fallback), dodges the
-probe's process-wide disable, and silently forks the byte-identity
-contract the tier-1 matrix asserts.
+``jax.experimental.pallas`` import, the interpret-mode switch for
+non-TPU backends, and the byte-identity contract with the XLA resize
+path. Program builders select a *plane* via
+:func:`~vlog_tpu.ops.pallas_ladder.ladder_resize` — they never see
+``pallas_call``. A raw pallas import anywhere else leaks kernel code
+past that module: the call site explodes under ``JAX_PLATFORMS=cpu`` (no
+interpret switch), ignores ``VLOG_PALLAS``, and silently forks the
+byte-identity contract the tier-1 matrix asserts.
 
 Rule: outside ``ops/pallas_ladder.py``, no module may
 

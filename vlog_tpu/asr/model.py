@@ -273,8 +273,11 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     return logits, cache
 
 
-def init_random_params(cfg: WhisperConfig, seed: int = 0) -> Params:
-    """Random small-scale params in the HF naming scheme (tests only)."""
+def random_state_dict(cfg: WhisperConfig, seed: int = 0
+                      ) -> dict[str, np.ndarray]:
+    """Seeded random weights for ``cfg`` as a host state dict in the HF
+    naming scheme — what ``asr/load.py`` reads back from
+    ``model.safetensors`` (tests, and chip_smoke.py's checkpoint)."""
     rng = np.random.default_rng(seed)
     p: dict[str, np.ndarray] = {}
 
@@ -315,4 +318,10 @@ def init_random_params(cfg: WhisperConfig, seed: int = 0) -> Params:
             w(f"{n}.fc2.weight", d, ffn)
             w(f"{n}.fc2.bias", d)
             ln(f"{n}.final_layer_norm")
-    return {k: jnp.asarray(v) for k, v in p.items()}
+    return p
+
+
+def init_random_params(cfg: WhisperConfig, seed: int = 0) -> Params:
+    """:func:`random_state_dict` as device params (tests only)."""
+    return {k: jnp.asarray(v)
+            for k, v in random_state_dict(cfg, seed).items()}

@@ -15,6 +15,7 @@ from vlog_tpu.backends.base import (  # noqa: F401
     get_backend,
     plan_rung_geometry,
     register_backend,
+    require_accelerator,
     select_backend,
 )
 from vlog_tpu.backends.source import (  # noqa: F401

@@ -135,6 +135,9 @@ class RunResult:
     # bounded-loss accounting preemption-tolerant workers assert on
     # (vlog_resume_segments_skipped_total)
     resumed_segments: int = 0
+    # the (data x rung) grid label the ladder program dispatched over
+    # ("1x1" single chip); "" where a codec path does not report it
+    mesh_shape: str = ""
 
 
 # progress_cb(frames_done, frames_total, message)
@@ -190,28 +193,53 @@ def select_backend(preference: str | None = None) -> Backend:
     registered backend reports TPU devices, then anything. The choice is
     cached per process — probing instantiates backends (and may open
     accelerators), which must happen once, not per job.
+
+    A backend whose ``detect()`` raises is skipped only while another
+    registered backend answers; when none does, the last error
+    propagates — the caller asked for an accelerator and must hear why
+    there is none, not get a backend that never detected anything.
     """
     global _SELECTED
     if preference:
         return get_backend(preference)
     if _SELECTED is not None:
         return _SELECTED
+    if not _REGISTRY:
+        raise RuntimeError("no backends registered")
     best = None
+    error: Exception | None = None
     for name in _REGISTRY:
         b = get_backend(name)
         try:
             caps = b.detect()
-        except Exception:       # noqa: BLE001 — a broken backend is
-            continue            # skipped, not fatal to selection
+        except Exception as exc:  # noqa: BLE001 — re-raised below unless
+            error = exc           # another backend answers
+            continue
         if caps.device_kind == "tpu":
             _SELECTED = b
             return b
         if best is None:
             best = b
     if best is None:
-        raise RuntimeError("no backends registered (or none detectable)")
+        # registry non-empty and nothing answered: every detect() raised
+        raise error
     _SELECTED = best
     return best
+
+
+def require_accelerator(backend: Backend, accelerator: str) -> Capabilities:
+    """What ``backend`` found, once it matches what the worker is about
+    to advertise. A worker registered as ``tpu`` claims TPU-gated jobs;
+    on a backend that resolved to anything else those jobs would run on
+    XLA:CPU and complete, so the entry points refuse to start instead."""
+    caps = backend.detect()
+    if accelerator == "tpu" and caps.device_kind != "tpu":
+        raise SystemExit(
+            f"refusing to start: --accelerator tpu but backend "
+            f"{caps.backend!r} found {caps.device_count} "
+            f"{caps.device_kind!r} device(s) and no TPU; start with "
+            "--accelerator cpu to run without one")
+    return caps
 
 
 def plan_rung_geometry(src_w: int, src_h: int, rung: config.QualityRung,

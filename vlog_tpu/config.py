@@ -658,17 +658,11 @@ ENTROPY_THREADS: int = _env_int(
 TPU_MESH_SPEC: str = _env_str("VLOG_TPU_MESH", "data:-1")
 # Fused Pallas ladder kernel (ops/pallas_ladder.py): resize + quantize +
 # uint8 cast in one VMEM pass per rung instead of three XLA dispatches.
-# "auto" fuses on real TPU only (falling back to XLA per-rung when the
-# working set exceeds VMEM, or process-wide if the probe kernel fails);
-# "1" forces the kernel wherever it probes healthy (interpreted on CPU —
-# the byte-identity test vehicle); "0" pins the classic XLA path.
+# "auto" and "0" are the XLA path on every platform; "1" asks for the
+# kernel — interpreted on CPU (the byte-identity test vehicle), and on a
+# TPU a Mosaic refusal raises (as of PR 21 Mosaic refuses the kernel as
+# written; see the module docstring and ROADMAP Speed item 5).
 PALLAS: str = _env_str("VLOG_PALLAS", "auto")
-# Persistent XLA compile cache directory (parallel/compile_cache.py).
-# Empty = default BASE_DIR/xla_cache, enabled on TPU platforms only
-# (CPU AOT entries bake host ISA). Setting it explicitly enables the
-# cache on ANY platform with a zero min-compile-time floor — every
-# program persists, which is what the warm-vs-cold gate measures.
-COMPILE_CACHE_DIR: str = _env_str("VLOG_COMPILE_CACHE_DIR", "")
 # Mesh job slots (parallel/scheduler.py): the process's devices partition
 # into this many equal-width slots so the scheduler can admit that many
 # queued jobs onto the mesh CONCURRENTLY (e.g. 2 on a v5e-8 = two

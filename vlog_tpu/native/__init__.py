@@ -5,4 +5,8 @@ must do per frame (CAVLC slice coding, NAL escaping). See build.py for
 the on-demand toolchain story.
 """
 
-from vlog_tpu.native.build import NativeBuildError, get_lib  # noqa: F401
+from vlog_tpu.native.build import (  # noqa: F401
+    NativeBuildError,
+    get_lib,
+    require_lib,
+)

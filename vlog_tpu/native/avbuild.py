@@ -37,21 +37,23 @@ class VtAvInfo(ctypes.Structure):
 
 def _compile() -> Path:
     _BUILD.mkdir(exist_ok=True)
+    from vlog_tpu.native.build import inputs_digest, publish, stamp_matches
+
     srcs = [_DIR / "avshim.c", _DIR / "av1enc.c"]
     so = _BUILD / "libvtav.so"
-    if so.exists() and all(so.stat().st_mtime >= s.stat().st_mtime
-                           for s in srcs):
+    cc = os.environ.get("CC", "gcc")
+    digest = inputs_digest([*srcs, Path(__file__)], cc)   # flags live here
+    if stamp_matches(so, digest):
         return so
     pid = os.getpid()
     tmp_so = _BUILD / f"libvtav.{pid}.so.tmp"
-    cc = os.environ.get("CC", "gcc")
     cmd = [cc, "-O2", "-fPIC", "-shared", *map(str, srcs), "-o",
            str(tmp_so),
            "-lavformat", "-lavcodec", "-lavutil", "-lswscale"]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(f"avshim build failed: {proc.stderr[:1000]}")
-    os.replace(tmp_so, so)
+    publish(tmp_so, so, digest)
     return so
 
 

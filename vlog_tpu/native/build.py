@@ -1,17 +1,25 @@
 """Build + load the native entropy-coding library (ctypes).
 
 Compiled on demand with the system toolchain into
-``vlog_tpu/native/_build/`` and cached by source mtime. No pip/pybind11
-required (environment constraint); pure C ABI via ctypes. All entry
-points release the GIL for the duration of the call (ctypes semantics),
-so the worker's per-frame thread pool scales across cores.
+``vlog_tpu/native/_build/`` and reused only while a stamp file beside
+the library records the sha256 of the exact inputs it was built from
+(:func:`inputs_digest`) — ``_build/`` is git-ignored but travels with
+a copied tree, and a library built from other sources must never be
+loaded in place of the committed ones. No pip/pybind11 required
+(environment constraint); pure C ABI via ctypes. All entry points
+release the GIL for the duration of the call (ctypes semantics), so
+the worker's per-frame thread pool scales across cores.
 
-Disable with VLOG_NATIVE=0 (callers fall back to the Python coders).
+``VLOG_NATIVE=0`` selects the Python coders on purpose. With it unset a
+failed build is an error on the backend path (:func:`require_lib`); the
+per-slice helpers still treat a missing library as "use Python", which
+is what the bit-identity tests of the two coders rely on.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,6 +36,36 @@ class NativeBuildError(RuntimeError):
     pass
 
 
+def inputs_digest(paths: list[Path], *extra: str) -> str:
+    """sha256 over the named files' names and bytes (plus ``extra``
+    strings such as the compiler command) — the build stamp."""
+    h = hashlib.sha256()
+    for p in paths:
+        h.update(p.name.encode() + b"\0" + p.read_bytes() + b"\0")
+    for e in extra:
+        h.update(e.encode() + b"\0")
+    return h.hexdigest()
+
+
+def stamp_matches(so: Path, digest: str) -> bool:
+    """Was ``so`` built from exactly the inputs ``digest`` names?"""
+    stamp = so.with_suffix(".stamp")
+    return (so.exists() and stamp.exists()
+            and stamp.read_text().strip() == digest)
+
+
+def publish(tmp_so: Path, so: Path, digest: str) -> None:
+    """Atomically install a fresh build and then its stamp. A crash
+    between the two leaves a library without a matching stamp, which
+    the next process rebuilds."""
+    stamp = so.with_suffix(".stamp")
+    stamp.unlink(missing_ok=True)
+    os.replace(tmp_so, so)
+    tmp_stamp = stamp.with_suffix(f".stamp.{os.getpid()}.tmp")
+    tmp_stamp.write_text(digest + "\n")
+    os.replace(tmp_stamp, stamp)
+
+
 def _compile() -> Path:
     _BUILD.mkdir(exist_ok=True)
     src = _DIR / "cavlc.c"
@@ -39,15 +77,17 @@ def _compile() -> Path:
     from vlog_tpu.codecs.h264 import cabac_ctx_tables, cavlc_tables
     from vlog_tpu.codecs.hevc import tables as hevc_tables
 
-    stamp_inputs = [src, jpeg_src, hevc_src, h264c_src, engine_hdr,
-                    _DIR / "gen_tables.py",
-                    _DIR / "gen_hevc_tables.py",
-                    _DIR / "gen_h264_cabac_tables.py",
-                    Path(cavlc_tables.__file__),   # real inputs of the
-                    Path(hevc_tables.__file__),    # generators
-                    Path(cabac_ctx_tables.__file__)]
-    if so.exists() and all(so.stat().st_mtime >= p.stat().st_mtime
-                           for p in stamp_inputs):
+    cc = os.environ.get("CC", "g++")
+    digest = inputs_digest(
+        [src, jpeg_src, hevc_src, h264c_src, engine_hdr,
+         _DIR / "gen_tables.py",
+         _DIR / "gen_hevc_tables.py",
+         _DIR / "gen_h264_cabac_tables.py",
+         Path(cavlc_tables.__file__),   # real inputs of the
+         Path(hevc_tables.__file__),    # generators
+         Path(cabac_ctx_tables.__file__),
+         Path(__file__)], cc)           # the compiler flags live here
+    if stamp_matches(so, digest):
         return so
     from vlog_tpu.native.gen_tables import generate
 
@@ -66,27 +106,49 @@ def _compile() -> Path:
     h264c_inc = _BUILD / f"h264_cabac_tables.{pid}.inc"
     h264c_inc.write_text(gen_h264_hdr())
     tmp_so = _BUILD / f"libvtnative.{pid}.so.tmp"
-    cc = os.environ.get("CC", "g++")
     cmd = [cc, "-O3", "-fPIC", "-shared", "-x", "c++",
            f"-DVT_TABLES_INC=\"{inc.name}\"",
            f"-DVT_HEVC_TABLES_INC=\"{hevc_inc.name}\"",
            f"-DVT_H264_CABAC_INC=\"{h264c_inc.name}\"",
            str(src), str(jpeg_src), str(hevc_src), str(h264c_src),
            "-I", str(_BUILD), "-I", str(_DIR), "-o", str(tmp_so)]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+    except OSError as exc:          # no compiler on PATH
+        raise NativeBuildError(f"native build failed: {exc}") from exc
     if proc.returncode != 0:
         raise NativeBuildError(f"native build failed: {proc.stderr[:2000]}")
-    os.replace(tmp_so, so)
+    publish(tmp_so, so, digest)
     inc.rename(_BUILD / "cavlc_tables.inc")        # for reference/debugging
     hevc_inc.rename(_BUILD / "hevc_tables.inc")
     h264c_inc.rename(_BUILD / "h264_cabac_tables.inc")
     return so
 
 
+_ERROR: Exception | None = None
+
+
+def native_disabled() -> bool:
+    """``VLOG_NATIVE=0``: the operator asked for the Python coders."""
+    return os.environ.get("VLOG_NATIVE", "1") in ("0", "false", "no")
+
+
+def require_lib() -> ctypes.CDLL | None:
+    """:func:`get_lib` for the production path: None only when
+    ``VLOG_NATIVE=0`` asked for the Python coders; a build or load
+    failure raises instead of quietly running ~100x slower."""
+    lib = get_lib()
+    if lib is None and not native_disabled():
+        raise NativeBuildError(
+            "native entropy coders unavailable and VLOG_NATIVE is not 0: "
+            f"{_ERROR}") from _ERROR
+    return lib
+
+
 def get_lib() -> ctypes.CDLL | None:
     """The loaded library, or None (build failure / disabled)."""
-    global _LIB, _TRIED
-    if os.environ.get("VLOG_NATIVE", "1") in ("0", "false", "no"):
+    global _LIB, _TRIED, _ERROR
+    if native_disabled():
         return None
     with _LOCK:
         if _TRIED:
@@ -95,7 +157,8 @@ def get_lib() -> ctypes.CDLL | None:
         try:
             so = _compile()
             lib = ctypes.CDLL(str(so))
-        except (NativeBuildError, OSError):
+        except (NativeBuildError, OSError) as exc:
+            _ERROR = exc
             _LIB = None
             return None
         i8 = ctypes.POINTER(ctypes.c_uint8)

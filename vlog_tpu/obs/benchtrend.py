@@ -18,16 +18,18 @@ the gate):
 - one legacy unlabeled first record in BENCH_delivery.json
   ({metric, hot_cache_rps, cold_origin_rps, ...});
 - runner wrappers (``{n, cmd, rc, tail, parsed?}`` /
-  ``{n_devices, rc, ok, skipped, tail}``) — BENCH_r0N.json,
-  MULTICHIP_r0N.json — whose ``parsed`` record and any JSON lines
-  embedded in ``tail`` are recovered.
+  ``{n_devices, rc, ok, skipped, tail}``), the shape a driver that
+  captures a bench command's output writes — none is committed at
+  HEAD — whose ``parsed`` record and any JSON lines embedded in
+  ``tail`` are recovered.
 
 Gating rules:
 
 - records labeled ``gate: tpu_only`` count only when produced on a TPU
-  (``platform`` absent or "tpu"); CPU-fallback records (explicit
-  ``fallback_reason``, a ``*_cpu_fallback`` metric name, or the
-  bench-failed sentinel unit) chart but never gate;
+  (``platform`` absent or "tpu"); records labelled as fallbacks
+  (explicit ``fallback_reason``, a ``*_cpu_fallback`` metric name, or
+  the bench-failed sentinel unit — bench.py no longer writes any, but
+  a trajectory may still carry them) chart but never gate;
 - direction comes from an explicit per-metric table plus name
   heuristics (``*_p99_s``/``*_wait_s``/``*pad_waste*``/``warm_ratio``
   are lower-is-better);
@@ -199,8 +201,8 @@ def _point_from_record(rec: dict, file: str, index: int) -> Point | None:
 
 def _tail_records(tail: Any) -> Iterable[dict]:
     """Recover labeled JSON-line records embedded in a runner wrapper's
-    captured ``tail`` text (BENCH_r02.json carries its result only
-    there)."""
+    captured ``tail`` text (a wrapper without ``parsed`` carries its
+    result only there)."""
     if isinstance(tail, list):
         lines: Iterable[str] = [str(x) for x in tail]
     elif isinstance(tail, str):

@@ -19,16 +19,22 @@ body can be the *verbatim* op sequence of ``apply_resize_matrices``
 is what makes the Pallas output BYTE-IDENTICAL to the XLA path, which
 tier-1 asserts across the full shape x depth matrix in interpret mode.
 
-Byte-identity + fallback contract:
+Where it runs (PR 21, first contact with Mosaic on a TPU v5 lite,
+jax 0.9.0):
 
-- ``interpret=True`` whenever the backend is not a real TPU, so the
-  kernel runs (and stays bit-exact) on the CPU CI mesh.
-- On TPU, rungs whose working set would blow the ~16 MB/core VMEM
-  budget (4K sources) fall back to the XLA path at trace time —
-  per-rung, deterministic, shape-keyed.
-- ``pallas_available()`` probes a real tiny kernel once per process;
-  any lowering/runtime failure disables the Pallas plane process-wide
-  and the program builders transparently keep the XLA path.
+- On CPU the kernel runs with ``interpret=True``; that is the vehicle of
+  the byte-identity tests and nothing else.
+- On a TPU Mosaic REFUSES this kernel as written: ``Unsupported cast:
+  uint8 -> float32`` for every shape, and for the 360p chroma plane the
+  ``(90, 360)`` block of ``A_h`` is not (8, 128)-tiled. A variant with
+  32-row blocks, lane-padded ``A_w`` and the casts routed through int32
+  does lower, but matched the XLA bytes at one block size and differed
+  by one LSB at another — byte identity there is a property of the MXU
+  accumulation order, not of the op sequence. So ``auto`` resolves to
+  the XLA path on every platform, and ``VLOG_PALLAS=1`` on a TPU raises
+  Mosaic's own error at the first trace instead of degrading. There is
+  no probe and no per-rung fallback: a plane is fused because it was
+  asked for, or it is not fused. Re-tile or delete: ROADMAP Speed 5.
 
 This is the ONLY module allowed to touch ``jax.experimental.pallas``
 (analysis/pallasshim.py enforces containment); program builders select
@@ -37,25 +43,13 @@ the plane via :func:`ladder_resize` / the ``VLOG_PALLAS`` knob.
 
 from __future__ import annotations
 
-import functools
-import logging
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-from vlog_tpu.ops.resize import apply_resize_matrices, resize_yuv420_with
-
-log = logging.getLogger(__name__)
-
-try:  # pallas ships with jax>=0.4.x; gate anyway (stripped-down wheels)
-    from jax.experimental import pallas as pl
-except Exception:  # noqa: BLE001 — absence just disables the fused plane
-    pl = None
-
-# VMEM working-set ceiling per grid cell on real TPU (bytes). ~16 MB/core
-# minus headroom for Mosaic's own scratch; interpret mode ignores it.
-_VMEM_BUDGET = 12 * 1024 * 1024
+from vlog_tpu.ops.resize import resize_yuv420_with
 
 
 def _interpret() -> bool:
@@ -76,17 +70,6 @@ def _block_rows(dst_h: int) -> int:
     return best
 
 
-def _cell_bytes(src_h: int, src_w: int, dst_h: int, dst_w: int,
-                bh: int) -> int:
-    """VMEM estimate for one grid cell: uint8 source block + its f32
-    cast + A_h row block + A_w + the (bh, src_w) intermediate + out."""
-    return (src_h * src_w * 5           # u8 source + f32 cast
-            + 4 * bh * src_h            # A_h block
-            + 4 * dst_w * src_w         # A_w (whole)
-            + 4 * bh * src_w            # A_h @ x intermediate
-            + bh * dst_w)               # uint8 out block
-
-
 def _rung_kernel(src_ref, ah_ref, aw_ref, out_ref):
     # VERBATIM op sequence of ops/resize.py apply_resize_matrices on a
     # (1, H, W) block — the byte-identity contract with the XLA path.
@@ -101,18 +84,12 @@ def _rung_kernel(src_ref, ah_ref, aw_ref, out_ref):
 def fused_resize_plane(plane, a_h, a_w):
     """(..., H, W) x (h, H) x (w, W) -> (..., h, w) uint8, one HBM pass.
 
-    Trace-time fallback to the XLA path when Pallas is absent or the
-    rung's working set exceeds the VMEM budget on real TPU (interpret
-    mode has no such limit). Output is byte-identical either way.
+    Never falls back: off-TPU the kernel is interpreted, on a TPU a
+    lowering error from Mosaic propagates (see the module docstring).
     """
     src_h, src_w = plane.shape[-2], plane.shape[-1]
     dst_h, dst_w = a_h.shape[0], a_w.shape[0]
     bh = _block_rows(dst_h)
-    interpret = _interpret()
-    if pl is None or (not interpret
-                      and _cell_bytes(src_h, src_w, dst_h, dst_w,
-                                      bh) > _VMEM_BUDGET):
-        return apply_resize_matrices(plane, a_h, a_w)
     lead = plane.shape[:-2]
     x = plane.reshape((-1, src_h, src_w))
     n = x.shape[0]
@@ -126,7 +103,7 @@ def fused_resize_plane(plane, a_h, a_w):
         ],
         out_specs=pl.BlockSpec((1, bh, dst_w), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, dst_h, dst_w), jnp.uint8),
-        interpret=interpret,
+        interpret=_interpret(),
     )(x, a_h, a_w)
     return out.reshape(lead + (dst_h, dst_w))
 
@@ -145,54 +122,19 @@ def resize_yuv420_pallas(y, u, v, rung_mats):
     )
 
 
-@functools.lru_cache(maxsize=1)
-def pallas_available() -> bool:
-    """One-shot probe: compile + run a real tiny fused kernel and check
-    it against the XLA path. Any failure (missing pallas, Mosaic
-    lowering error, wrong bytes) disables the fused plane process-wide
-    — the program builders then keep the XLA path transparently."""
-    if pl is None:
-        return False
-    try:
-        import numpy as np
-
-        from vlog_tpu.ops.resize import resample_matrix
-
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.integers(0, 256, (2, 32, 48), dtype=np.uint8))
-        a_h = jnp.asarray(resample_matrix(32, 16))
-        a_w = jnp.asarray(resample_matrix(48, 24))
-        got = jax.jit(fused_resize_plane)(x, a_h, a_w)
-        ref = apply_resize_matrices(x, a_h, a_w)
-        ok = bool(jnp.array_equal(got, ref))
-        if not ok:
-            log.warning("pallas ladder kernel output mismatched the XLA "
-                        "path; disabling VLOG_PALLAS for this process")
-        return ok
-    except Exception as exc:  # noqa: BLE001 — degrade, don't crash
-        log.warning("pallas ladder kernel unavailable (%s); using the "
-                    "XLA resize path", exc)
-        return False
-
-
 def use_pallas(mode: str | None = None) -> bool:
     """Resolve VLOG_PALLAS (auto|1|0) to the plane this process runs.
 
-    ``auto`` fuses only on real TPU (interpret mode is a correctness
-    vehicle, not a fast path); ``1`` forces the kernel wherever it
-    probes healthy (CI runs it interpreted for the byte-identity
-    matrix); ``0`` pins the XLA path.
+    ``auto`` and ``0`` are the XLA path on every platform; ``1`` is the
+    fused kernel wherever it is asked for — interpreted off-TPU (the
+    byte-identity test vehicle), compiled by Mosaic on a TPU, where the
+    kernel as written is refused and the refusal raises.
     """
     if mode is None:
         from vlog_tpu import config
 
         mode = config.PALLAS
-    mode = str(mode).strip().lower()
-    if mode in ("0", "off", "false"):
-        return False
-    if mode in ("1", "on", "true"):
-        return pallas_available()
-    return (not _interpret()) and pallas_available()
+    return str(mode).strip().lower() in ("1", "on", "true")
 
 
 def ladder_resize(pallas: bool) -> Callable:

@@ -2,36 +2,39 @@
 
 Cold-compile elimination has two halves:
 
-1. **Persistence.** The XLA programs for 4K chain ladders take a
-   minute-plus to compile; ``jax_compilation_cache_dir`` amortizes that
-   across worker restarts (first video of a geometry pays once per
-   fleet node, not once per process). This used to be a private helper
-   of the H.264 backend — now every codec backend (h264/hevc/av1 all
-   funnel through ``JaxBackend`` dispatch, but the HEVC/AV1 entry
-   modules arm it independently for their standalone tools) AND the
-   ASR engine call :func:`ensure_compile_cache` before first dispatch.
+1. **Persistence.** The XLA programs for 4K chain ladders take minutes
+   to compile; jax's persistent compilation cache amortizes that across
+   worker restarts. Every codec backend and the ASR engine call
+   :func:`ensure_compile_cache` before first dispatch.
 
-   Platform policy: auto-enabled on TPU only — CPU AOT entries record
-   exact host ISA features and reloading them on a different machine
-   risks SIGILL. An EXPLICIT ``VLOG_COMPILE_CACHE_DIR`` overrides that
-   and also drops the min-compile-time floor to zero so every program
-   persists; that is the mode the warm-vs-cold gate (and any CI on
-   this VM) measures.
+   Where the cache lives is decided outside the program first:
+
+   - ``JAX_COMPILATION_CACHE_DIR`` set: jax itself reads that variable;
+     this module sets NO directory in code and only reports it.
+   - either way every program persists (the min-compile-time floor is
+     dropped to zero), so a warm start recompiles nothing.
+   - unset, on an accelerator: one fixed path inside the checkout,
+     :data:`DEFAULT_CACHE_DIR`, resolved from this package's own
+     location. The path is part of nothing that moves (cwd, BASE_DIR,
+     pid), so a restarted worker finds what the last one compiled.
+   - unset, on CPU: no cache. CPU AOT entries record exact host ISA
+     features and reloading them on a different machine risks SIGILL.
 
 2. **Attribution.** ``compile_seconds()`` meters this process's
    cumulative backend-compile wall time via ``jax.monitoring``'s
    ``/jax/core/compile/backend_compile_duration`` events (a persistent-
    cache HIT skips the backend compile entirely, so warm processes
-   report a fraction of cold ones). bench.py / dryrun stamp the value
-   into their labeled records as ``compile_s`` so the trajectory can
-   tell kernel wins from cache wins across PRs.
+   report a fraction of cold ones).
 """
 
 from __future__ import annotations
 
+import os
 import threading
+from pathlib import Path
 
-from vlog_tpu import config
+# vlog_tpu/_xla_cache, beside the native coders' _build/ (git-ignored)
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parent.parent / "_xla_cache"
 
 # _state and _meter are only read/written under _lock (module-level
 # singletons, so the guarded-by annotation idiom for instance fields
@@ -53,13 +56,9 @@ def _register_meter_locked() -> None:
     if _meter["registered"]:
         return
     _meter["registered"] = True
-    try:
-        import jax.monitoring
+    import jax.monitoring
 
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_event_duration)
-    except Exception:  # noqa: BLE001 — the meter is observability only
-        pass
+    jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
 
 
 def compile_seconds() -> float:
@@ -72,38 +71,33 @@ def compile_seconds() -> float:
 
 def ensure_compile_cache() -> str | None:
     """Arm the persistent compile cache (idempotent); returns the cache
-    dir in effect, or None when disabled for this platform."""
+    dir in effect, or None when this platform runs without one."""
     with _lock:
         _register_meter_locked()
         if _state["armed"]:
             return _state["dir"]
         _state["armed"] = True
-    explicit = config.COMPILE_CACHE_DIR.strip()
-    try:
-        from pathlib import Path
+    import jax
 
-        import jax
-
-        if not explicit and jax.devices()[0].platform == "cpu":
-            return None
-        cache_dir = Path(explicit) if explicit \
-            else Path(config.BASE_DIR) / "xla_cache"
-        cache_dir.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0 if explicit else 5.0)
-        # jax initializes its cache object at most once per process; if
-        # a compile already ran before we armed, the new dir is ignored
-        # until the cache state is reset. Arming late must still work.
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+    if cache_dir is None and jax.devices()[0].platform != "cpu":
+        DEFAULT_CACHE_DIR.mkdir(exist_ok=True)
+        cache_dir = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # jax binds its cache object at most once per process; a compile
+        # that ran before this call bound it to "no cache".
         from jax.experimental.compilation_cache import (
             compilation_cache as _jcc)
 
         _jcc.reset_cache()
-        with _lock:
-            _state["dir"] = str(cache_dir)
-        return str(cache_dir)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        return None
+    if cache_dir is not None:
+        # Persist every program, not only those over jax's 1 s floor: a
+        # warm worker otherwise recompiles its hundreds of sub-second
+        # programs (56 s of a 205 s cold start on the chip, PR 21).
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    with _lock:
+        _state["dir"] = cache_dir
+    return cache_dir
 
 
 def reset_for_tests() -> None:

@@ -52,7 +52,7 @@ def hevc_chain_ladder_program(rungs: tuple[RungSpec, ...], src_h: int,
                               pallas: bool | None = None
                               ) -> tuple[Callable, dict]:
     """Resolve ``deblock`` (None -> config.HEVC_DEBLOCK) and ``pallas``
-    (None -> VLOG_PALLAS + probe) OUTSIDE the cache: resolving inside
+    (None -> VLOG_PALLAS) OUTSIDE the cache: resolving inside
     would let two different config states share one cache entry (tests
     monkeypatch the flags)."""
     if deblock is None:

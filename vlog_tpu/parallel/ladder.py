@@ -104,7 +104,7 @@ def ladder_local(y, u, v, mats: dict, rungs: tuple[RungSpec, ...], qps=None,
 def ladder_encode_program(rungs: tuple[RungSpec, ...], src_h: int, src_w: int,
                           mesh: Mesh | None = None,
                           pallas: bool | None = None) -> tuple[Callable, dict]:
-    """Resolve ``pallas`` (None -> VLOG_PALLAS + probe) OUTSIDE the
+    """Resolve ``pallas`` (None -> VLOG_PALLAS) OUTSIDE the
     cache — the hevc_ladder deblock idiom: resolving inside would let
     two different config states share one compiled entry."""
     if pallas is None:
@@ -420,7 +420,7 @@ def ladder_encode_grid(rungs: tuple[RungSpec, ...], src_h: int, src_w: int,
                        pallas: bool | None = None) -> GridProgram:
     """Grid-wide intra ladder: per-column encode programs.
 
-    ``pallas`` resolves (None -> VLOG_PALLAS + probe) here, outside the
+    ``pallas`` resolves (None -> VLOG_PALLAS) here, outside the
     caches, so the resolved plane keys both this cache and the
     per-column program cache.
     """

@@ -36,21 +36,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from vlog_tpu import config
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it top-level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the same semantics
-    under ``check_rep``. All ladder programs route through here so the
-    version split lives in exactly one place.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+# The tree's one spelling of jax's shard_map: analysis/meshshim.py keeps
+# every sharded program importing it from here.
+shard_map = jax.shard_map
 
 
 @dataclass(frozen=True)

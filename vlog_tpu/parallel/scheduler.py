@@ -64,6 +64,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Sequence
 
 from vlog_tpu import config
+from vlog_tpu.parallel.faults import probe_device
 
 __all__ = [
     "MeshScheduler", "SlotCancelled", "SlotLease", "SlotTicket",
@@ -460,7 +461,7 @@ class MeshScheduler:
             targets = list(self._quarantined)
         if not targets:
             return {}
-        fn = probe_fn or _default_probe
+        fn = probe_fn or probe_device
         m = self._metrics()
         results, healed = {}, []
         for d in targets:
@@ -611,18 +612,6 @@ class MeshScheduler:
                     max_workers=config.ENTROPY_THREADS,
                     thread_name_prefix="vlog-mesh-host")
             return self._host_pool
-
-
-def _default_probe(device) -> bool:
-    """The cheap reinstatement probe: put a tiny array on the device,
-    reduce it, pull the result. Anything a sick chip does wrong —
-    allocation, dispatch, the d2h pull — fails it (and a raising probe
-    counts as failing in :meth:`MeshScheduler.probe_quarantined`)."""
-    import jax
-    import jax.numpy as jnp
-
-    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), device)
-    return float(jax.block_until_ready(x).sum()) == 28.0
 
 
 _scheduler: MeshScheduler | None = None

@@ -46,6 +46,7 @@ from vlog_tpu.codecs import validate_codec_format
 from vlog_tpu.db.core import Database, Row, now as db_now, open_database
 from vlog_tpu.enums import AcceleratorKind, FailureClass, JobKind, VideoStatus
 from vlog_tpu.jobs import claims, state as js, videos as vids
+from vlog_tpu.parallel.faults import RepeatFaultDetector
 from vlog_tpu.utils import failpoints
 from vlog_tpu.worker.breaker import CircuitBreaker
 from vlog_tpu.worker.drain import (DRAIN_CANCEL_REASON, DrainState,
@@ -188,6 +189,7 @@ class WorkerDaemon(ComputeWatchdogMixin):
         self._tasks: set[asyncio.Task] = set()            # slot job tasks
         self.drain = DrainState()
         self._drain_task: asyncio.Task | None = None
+        self._repeat_faults = RepeatFaultDetector()
         if self.breaker is None:
             self.breaker = CircuitBreaker()
         if self.db_breaker is None:
@@ -664,6 +666,16 @@ class WorkerDaemon(ComputeWatchdogMixin):
                 # must not kill the loop; the devices just stay out
                 log.exception("device probe sweep failed")
 
+    def _fault_devices(self) -> tuple:
+        """The devices a failed attempt ran on: its slot lease's, or
+        every visible device without one."""
+        lease = getattr(_TICKET.get(), "lease", None)
+        if lease is not None:
+            return tuple(lease.devices)
+        import jax
+
+        return tuple(jax.devices())
+
     def _quarantine_for_fault(self, exc: BaseException) -> tuple:
         """After a device-classified fault, quarantine the faulting
         lease's devices (the slot renegotiates around the hole). Returns
@@ -920,7 +932,23 @@ class WorkerDaemon(ComputeWatchdogMixin):
 
                 att.set_error(f"{type(exc).__name__}: {exc}")
                 log.exception("job %s failed", job["id"])
-                if faults.is_device_fault(exc):
+                device_fault = faults.is_device_fault(exc)
+                if device_fault and await asyncio.to_thread(
+                        self._repeat_faults.repeats_on_healthy_devices,
+                        job["id"], exc, self._fault_devices()):
+                    # The same runtime error from the same job on devices
+                    # that compute right now: the program does not fit
+                    # this chip (e.g. a compile-time HBM RESOURCE_
+                    # EXHAUSTED) and never will — the job's failure, said
+                    # once, not a refund-and-requeue loop.
+                    att.attrs["deterministic_device_error"] = True
+                    self.breaker.record_failure()
+                    await self._fail(
+                        job, video,
+                        f"{type(exc).__name__}: {exc} (repeated on devices "
+                        "that pass the probe: not a hardware fault)",
+                        permanent=True)
+                elif device_fault:
                     # The HARDWARE failed the attempt, not the job: take
                     # the slot's devices out of rotation and requeue
                     # without burning the attempt budget (fail_job
@@ -1144,6 +1172,9 @@ class WorkerDaemon(ComputeWatchdogMixin):
             result = await self._sup()._run_with_timeout(
                 work, timeout, "transcode")
             self._mesh_span_attrs(tsp)
+            if result.run.mesh_shape:
+                # without a scheduler lease nothing above stamped it
+                tsp.attrs.setdefault("mesh.shape", result.run.mesh_shape)
         # stage busy-sums + per-rung times -> trace leaves; histograms
         # feed this process's /metrics on the worker health port
         obs_trace.record_run_stages(tsp, result.run.stage_s)
@@ -1361,15 +1392,19 @@ class WorkerDaemon(ComputeWatchdogMixin):
 async def _amain(args: argparse.Namespace) -> None:
     from vlog_tpu.db.schema import create_all
 
+    # Before the database is touched: a worker that would advertise an
+    # accelerator it does not have must never reach the claim loop.
+    backend = None
+    if not args.no_backend:
+        from vlog_tpu.backends import require_accelerator, select_backend
+
+        backend = select_backend(args.backend or None)
+        require_accelerator(backend, args.accelerator)
+
     config.ensure_dirs()
     db = open_database(args.db)
     await db.connect()
     await create_all(db)
-
-    backend = None
-    if not args.no_backend:
-        from vlog_tpu.backends import select_backend
-        backend = select_backend(args.backend or None)
 
     from vlog_tpu.jobs.alerts import AlertSink
     from vlog_tpu.jobs.webhooks import make_event_hook

@@ -33,6 +33,7 @@ from vlog_tpu.codecs import validate_codec_format
 from vlog_tpu.enums import AcceleratorKind, FailureClass, JobKind
 from vlog_tpu.obs import trace as obs_trace
 from vlog_tpu.obs.metrics import runtime as obs_runtime
+from vlog_tpu.parallel.faults import RepeatFaultDetector
 from vlog_tpu.storage import integrity
 from vlog_tpu.utils import failpoints
 from vlog_tpu.worker.breaker import CircuitBreaker
@@ -668,6 +669,7 @@ class RemoteWorker(ComputeWatchdogMixin):
         self._cancel_reason = ""
         self.drain = DrainState()
         self._drain_task: asyncio.Task | None = None
+        self._repeat_faults = RepeatFaultDetector()
         self._current_job_id: int | None = None
         if self.breaker is None:
             self.breaker = CircuitBreaker()
@@ -1024,7 +1026,18 @@ class RemoteWorker(ComputeWatchdogMixin):
                                 error=f"{type(exc).__name__}: {exc}")
                 log.exception("job %s failed", job["id"])
                 self.breaker.record_failure()
-                if faults.is_device_fault(exc):
+                device_fault = faults.is_device_fault(exc)
+                if device_fault and await asyncio.to_thread(
+                        self._repeat_faults.repeats_on_healthy_devices,
+                        job["id"], exc, _visible_devices()):
+                    # same error, same job, devices that compute now: the
+                    # program's failure (parallel/faults.py), not a refund
+                    await self._safe_fail(
+                        job["id"],
+                        f"{type(exc).__name__}: {exc} (repeated on devices "
+                        "that pass the probe: not a hardware fault)",
+                        permanent=True)
+                elif device_fault:
                     # the server's fail_job refunds the attempt for
                     # device_fault; the compute breaker (still recorded
                     # above) is this worker's containment — remote
@@ -1494,11 +1507,27 @@ class RemoteWorker(ComputeWatchdogMixin):
         self.stats.bump("completed")
 
 
+def _visible_devices() -> tuple:
+    """Remote workers run no slot scheduler: an attempt ran on every
+    visible device."""
+    import jax
+
+    return tuple(jax.devices())
+
+
 # --------------------------------------------------------------------------
 # Entrypoint
 # --------------------------------------------------------------------------
 
 async def _amain(args: argparse.Namespace) -> None:
+    # Before registration: a worker that would advertise an accelerator
+    # it does not have must never reach the API.
+    backend = None
+    if not args.no_backend:
+        from vlog_tpu.backends import require_accelerator, select_backend
+
+        backend = select_backend(args.backend or None)
+        require_accelerator(backend, args.accelerator)
     key = args.key
     if not key:
         key = await WorkerAPIClient.register(
@@ -1506,11 +1535,6 @@ async def _amain(args: argparse.Namespace) -> None:
             accelerator=args.accelerator)
         log.info("registered; api key (save it): %s", key)
     client = WorkerAPIClient(args.api, key)
-    backend = None
-    if not args.no_backend:
-        from vlog_tpu.backends import select_backend
-
-        backend = select_backend(args.backend or None)
     worker = RemoteWorker(
         client, name=args.name, work_dir=Path(args.work_dir),
         accelerator=AcceleratorKind(args.accelerator),
