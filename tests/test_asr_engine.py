@@ -244,6 +244,200 @@ def test_engine_survives_a_failed_batch(assets):
         'vlog_asr_batches_total{result="error"}') == errors_before + 1
 
 
+# --------------------------------------------------------------------------
+# The tick record: the engine's own account of where a tick went
+# --------------------------------------------------------------------------
+
+OLD_KEYS = {"rows", "n", "occupancy", "jobs", "elapsed_s"}
+NEW_KEYS = {"seq", "t_start", "t_dispatch", "t_ready", "t_end", "phase_s",
+            "gap_s", "windows", "wait_s", "build_s", "first_of_shape"}
+PHASES = ("coalesce", "lease", "take", "stack", "mel", "dispatch",
+          "device_wait", "parse", "deliver")
+
+
+def test_tick_record_accounts_for_every_cycle(assets):
+    """Four ticks of a shape no other test runs (max_new=7): every
+    record has the old keys with their old meaning and the new ones;
+    between two ``t_ready`` the named phases leave nothing out; the
+    first tick of the shape says so and books its build seconds. (Under
+    the suite's eight virtual devices the SECOND tick builds once more:
+    the pooled cache page comes back with the sharding the compiler
+    gave its output. The record is how that was seen; on one device it
+    reads 0.)"""
+    engine = AsrEngine(assets, batch_windows=2, tick_s=0.02)
+    try:
+        h = engine.begin_job("rec", language="en", max_new=7, beam=1)
+        for i in range(8):
+            h.submit(i, 25.0 * i, _tone(4.0))
+        got = list(h.results())
+        h.close()
+    finally:
+        engine.close()
+    assert sorted(len(g) for g in got) == [3] * 8      # (index, cues, wait)
+    log = engine.batch_log
+    assert [b["n"] for b in log] == [2, 2, 2, 2]
+    for k, b in enumerate(log):
+        assert set(b) == OLD_KEYS | NEW_KEYS
+        # rows: the bucket, rounded up to the (virtual) mesh's width
+        assert b["seq"] == k and b["rows"] % 2 == 0
+        assert b["occupancy"] == 2 / b["rows"]
+        assert b["jobs"] == ["rec", "rec"]
+        assert b["windows"] == [("rec", 2 * k), ("rec", 2 * k + 1)]
+        assert set(b["phase_s"]) == set(PHASES)
+        assert all(v >= 0.0 for v in b["phase_s"].values())
+        assert b["t_start"] <= b["t_dispatch"] <= b["t_ready"] <= b["t_end"]
+        # elapsed_s: stack to the token pull, so it ends before the parse
+        inside = sum(b["phase_s"][p] for p in ("stack", "mel", "dispatch",
+                                               "device_wait"))
+        assert inside <= b["elapsed_s"] <= b["t_ready"] - b["t_start"]
+        assert b["elapsed_s"] - inside < 0.05
+        assert b["wait_s"] == sorted(b["wait_s"], reverse=True) \
+            and len(b["wait_s"]) == 2 and min(b["wait_s"]) > 0.0
+    waits = {idx: w for idx, _cues, w in got}
+    assert [waits[0], waits[1]] == log[0]["wait_s"]
+    assert log[0]["gap_s"] is None
+    assert [b["first_of_shape"] for b in log] == [True, False, False, False]
+    assert log[0]["build_s"] > 0.0
+    assert [b["build_s"] for b in log[2:]] == [0.0, 0.0]
+    slack = []
+    for prev, b in zip(log, log[1:]):
+        cycle = b["t_ready"] - prev["t_ready"]
+        phases = (prev["phase_s"]["parse"] + prev["phase_s"]["deliver"]
+                  + sum(b["phase_s"][p] for p in PHASES[:7]))
+        assert b["gap_s"] == pytest.approx(
+            cycle - b["phase_s"]["device_wait"], abs=0.005)
+        assert phases <= cycle + 1e-6
+        slack.append(cycle - phases)
+    # 5 ms each, but a loaded machine may take the thread away between
+    # two spans: hold the best cycle to 5 ms and every one to 250
+    assert min(slack) < 0.005 and max(slack) < 0.25
+
+
+def test_gap_is_not_counted_across_an_idle_wait(assets):
+    engine = AsrEngine(assets, batch_windows=2, tick_s=0.0)
+    try:
+        h = engine.begin_job("idle", language="en", max_new=8, beam=1)
+        h.submit(0, 0.0, _tone(4.0))
+        assert len(list(h.results())) == 1
+        time.sleep(0.5)              # wait_for_work times out meanwhile
+        h.submit(1, 25.0, _tone(4.0))
+        assert len(list(h.results())) == 1
+        h.close()
+    finally:
+        engine.close()
+    first, second = engine.batch_log
+    assert first["gap_s"] is None and second["gap_s"] is None
+    # ... but the thread's seconds between the two t_ready are all in
+    # the phases: the timed-out waits count as the second tick's
+    # coalescing
+    cycle = second["t_ready"] - first["t_ready"]
+    named = (first["phase_s"]["parse"] + first["phase_s"]["deliver"]
+             + sum(second["phase_s"][p] for p in PHASES[:7]))
+    assert second["phase_s"]["coalesce"] >= 0.4
+    assert named <= cycle + 1e-6 and cycle - named < 0.25
+
+
+@pytest.mark.parametrize("stand_in", [False, True])
+def test_model_step_is_looked_up_at_call_time(assets, monkeypatch,
+                                              stand_in):
+    """Whoever swaps ``decode.generate_batch`` AFTER the engine was built
+    is called (the benchmark reads the model step there). A wrapper keeps
+    the step's own spans; a stand-in that opens none still leaves a
+    record (dispatch and device wait read 0, the instants fall back to
+    the ``asr.tick.generate`` span)."""
+    from vlog_tpu.asr import decode
+
+    engine = AsrEngine(assets, batch_windows=2, tick_s=0.02)
+    real = decode.generate_batch
+    calls = []
+
+    def swapped(assets_, feats, **kw):
+        calls.append(kw)
+        if stand_in:
+            eot = assets_.tokens.eot
+            return (np.full((feats.shape[0], 4), eot, np.int32),
+                    np.zeros(feats.shape[0], np.float32))
+        return real(assets_, feats, **kw)
+
+    monkeypatch.setattr(decode, "generate_batch", swapped)
+    try:
+        h = engine.begin_job("late", language="en", max_new=8, beam=1)
+        h.submit(0, 0.0, _tone(4.0))
+        assert len(list(h.results())) == 1
+        h.close()
+    finally:
+        engine.close()
+    assert len(calls) == 1 and calls[0]["max_new"] == 8
+    (b,) = engine.batch_log
+    if stand_in:
+        assert b["phase_s"]["dispatch"] == b["phase_s"]["device_wait"] == 0.0
+    else:
+        assert b["phase_s"]["device_wait"] > 0.0
+    assert b["t_start"] < b["t_dispatch"] <= b["t_ready"] <= b["t_end"]
+
+
+def test_entry_records_its_stages(assets):
+    """``stats_out`` gains the seconds of the entry's own stages beside
+    the keys that were there; the language pass lands in its histogram
+    and all four stages are spans of the caller's trace."""
+    from vlog_tpu.obs import trace as obs_trace
+
+    before = metric_value("vlog_asr_language_pass_seconds_count")
+    buf = obs_trace.TraceBuffer()
+    stats: dict = {}
+    engine = AsrEngine(assets, batch_windows=8, tick_s=0.05)
+    try:
+        with obs_trace.attach(obs_trace.TraceContext("t" * 16, None, buf)):
+            cues, lang, n = transcribe_audio_engine(
+                _tone(40.0), engine, job_key="staged", max_new=8, beam=1,
+                window_s=30.0, overlap_s=5.0, stats_out=stats)
+    finally:
+        engine.close()
+    assert n == 2 and lang
+    assert {"windows_total", "windows_live", "windows_resumed",
+            "windows_submitted", "queue_wait_mean_s",
+            "queue_wait_max_s"} <= set(stats)
+    for key in ("vad_s", "language_pass_s", "served_s", "stitch_s"):
+        assert stats[key] >= 0.0
+    assert stats["served_s"] >= stats["queue_wait_max_s"]
+    assert metric_value("vlog_asr_language_pass_seconds_count") == before + 1
+    spans = {s.name: s for s in buf.drain()}
+    assert set(spans) == {"asr.job.vad", "asr.job.language_pass",
+                          "asr.job.served", "asr.job.stitch"}
+    assert spans["asr.job.language_pass"].duration_s == \
+        stats["language_pass_s"]
+    assert {"build.trace_s", "build.lower_s", "build.compile_s",
+            "build.cache_load_s"} <= set(
+                spans["asr.job.language_pass"].attrs) or \
+        spans["asr.job.language_pass"].attrs == {}
+    # a language given from outside: no pass, no key
+    stats2: dict = {}
+    engine = AsrEngine(assets, batch_windows=8, tick_s=0.05)
+    try:
+        transcribe_audio_engine(_tone(10.0), engine, job_key="given",
+                                language="en", max_new=8, beam=1,
+                                stats_out=stats2)
+    finally:
+        engine.close()
+    assert "language_pass_s" not in stats2 and "vad_s" in stats2
+
+
+def test_device_seconds_books_the_wait_not_the_tick(assets):
+    name = 'vlog_device_seconds_total{plane="asr",rung="forward"}'
+    before = metric_value(name)
+    engine = AsrEngine(assets, batch_windows=2, tick_s=0.2)
+    try:
+        h = engine.begin_job("dev", language="en", max_new=8, beam=1)
+        h.submit(0, 0.0, _tone(4.0))
+        list(h.results())
+        h.close()
+    finally:
+        engine.close()
+    (b,) = engine.batch_log
+    assert metric_value(name) - before == pytest.approx(
+        b["phase_s"]["device_wait"], rel=1e-4, abs=1e-6)
+
+
 def test_get_engine_memoized_per_model_dir(tiny_model_dir):
     e1 = get_engine(str(tiny_model_dir))
     assert get_engine(str(tiny_model_dir)) is e1
@@ -521,12 +715,24 @@ class TestAsrAgreement:
              "VLOG_ASR_QUEUE_MAX")
     METRICS = ("vlog_asr_batches_total", "vlog_asr_windows_total",
                "vlog_asr_batch_occupancy", "vlog_asr_pad_waste",
-               "vlog_asr_windows_per_second", "vlog_asr_queue_wait_seconds")
+               "vlog_asr_windows_per_second", "vlog_asr_queue_wait_seconds",
+               "vlog_asr_language_pass_seconds")
     SITES = ("asr.submit", "asr.batch")
-    SPANS = ("worker.transcribe",)
+    SPANS = ("worker.transcribe", "asr.tick", "asr.tick.coalesce",
+             "asr.tick.lease", "asr.tick.take", "asr.tick.stack",
+             "asr.tick.mel", "asr.tick.generate", "asr.generate.dispatch",
+             "asr.generate.device_wait", "asr.tick.parse",
+             "asr.tick.deliver", "asr.job.vad", "asr.job.language_pass",
+             "asr.job.served", "asr.job.stitch")
     SPAN_ATTRS = ("asr.windows_total", "asr.windows_live",
                   "asr.windows_resumed", "asr.windows_submitted",
-                  "asr.queue_wait_mean_s", "asr.queue_wait_max_s")
+                  "asr.queue_wait_mean_s", "asr.queue_wait_max_s",
+                  "asr.vad_s", "asr.language_pass_s", "asr.served_s",
+                  "asr.stitch_s", "build.trace_s", "build.lower_s",
+                  "build.compile_s", "build.cache_load_s")
+    # the tick record's keys, as README "ASR plane" lists them
+    RECORD_KEYS = ("t_start", "t_dispatch", "t_ready", "t_end", "phase_s",
+                   "gap_s", "wait_s", "build_s", "first_of_shape")
 
     def test_knobs_parsed_and_documented(self):
         from vlog_tpu.analysis import registry as reg
@@ -550,6 +756,7 @@ class TestAsrAgreement:
 
         reg.assert_span_names(self.SPANS)
         reg.assert_documented(self.SPAN_ATTRS)
+        reg.assert_documented(self.RECORD_KEYS, backticked=True)
 
 
 # --------------------------------------------------------------------------

@@ -11,7 +11,10 @@ sites, observability knobs).
 
 from __future__ import annotations
 
+import glob
+import sys
 import threading
+import time
 from pathlib import Path
 
 import pytest
@@ -57,6 +60,92 @@ def test_span_without_context_is_dropped_but_safe():
     with obs_trace.span("orphan") as sp:
         pass
     assert sp.duration_s is not None   # timed, just not collected
+
+
+def test_span_starts_on_both_clocks_and_stores_the_old_shape():
+    before = time.monotonic()
+    with obs_trace.span("timed") as sp:
+        time.sleep(0.01)
+    assert before <= sp.started_mono <= time.monotonic()
+    assert sp.ended_mono == pytest.approx(sp.started_mono + sp.duration_s)
+    assert sp.duration_s >= 0.01
+    assert set(sp.to_dict()) == {"trace_id", "span_id", "parent_id", "name",
+                                 "started_at", "duration_s", "status",
+                                 "attrs"}
+
+
+class _FakeAnnotation:
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        self.log.append(("open", self.name, self.kwargs))
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+
+
+@pytest.mark.parametrize("jax_imported", [True, False])
+def test_span_mirrors_into_the_profiler_only_where_jax_is(monkeypatch,
+                                                          jax_imported):
+    """``vlog:<name>`` annotations, properly nested, carrying the scalar
+    attrs given at the open, and none at all in a process that has not
+    imported jax (an API process must never pay for it)."""
+    import jax
+
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    if not jax_imported:
+        monkeypatch.delitem(sys.modules, "jax")
+    with obs_trace.span("asr.tick", seq=3, rows=[8]) as outer:
+        with obs_trace.span("asr.tick.mel"):
+            pass
+        with pytest.raises(ValueError):
+            with obs_trace.span("asr.tick.generate"):
+                raise ValueError("x")
+    assert outer.duration_s is not None
+    if not jax_imported:
+        assert _FakeAnnotation.log == []
+        return
+    assert _FakeAnnotation.log == [
+        ("open", "vlog:asr.tick", {"seq": 3}),
+        ("open", "vlog:asr.tick.mel", {}), ("close", "vlog:asr.tick.mel"),
+        ("open", "vlog:asr.tick.generate", {}),
+        ("close", "vlog:asr.tick.generate"),
+        ("close", "vlog:asr.tick")]
+
+
+def test_spans_land_in_a_real_capture_on_the_traces_clock(tmp_path):
+    """A CPU capture read back: the program's spans are there as
+    ``vlog:`` events, the child inside the parent."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs_trace.span("asr.tick", seq=7):
+            with obs_trace.span("asr.tick.stack"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    pb = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                       / "*.xplane.pb"))[0]
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("vlog:"):
+                    found[ev.name] = (ev.start_ns, ev.start_ns
+                                      + ev.duration_ns, dict(ev.stats))
+    assert set(found) == {"vlog:asr.tick", "vlog:asr.tick.stack"}
+    outer, inner = found["vlog:asr.tick"], found["vlog:asr.tick.stack"]
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    assert inner[1] - inner[0] >= 2e6                   # ns
+    assert str(outer[2].get("seq")) == "7"
 
 
 def test_span_tree_assembly_under_concurrency():
@@ -455,3 +544,175 @@ class TestObservabilityAgreement:
 
         stage_names = [f"stage.{key[:-2]}" for key in obs_trace.STAGE_KEYS]
         reg.assert_span_names(tuple(stage_names) + self.SPAN_NAMES)
+
+
+# --------------------------------------------------------------------------
+# Named scopes: what a device trace is read by (obs/profiler.summarize)
+# --------------------------------------------------------------------------
+
+ASR_SCOPES = {
+    "beam": {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
+             "asr.encoder.mlp", "asr.cross_kv", "asr.cross_kv.tile",
+             "asr.prompt", "asr.token_rules", "asr.beam_select",
+             "asr.beam_reorder", "asr.decoder_step",
+             "asr.decoder_step.self_attn", "asr.decoder_step.cache_update",
+             "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
+             "asr.decoder_step.logits", "asr.beam_final"},
+    "greedy": {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
+               "asr.encoder.mlp", "asr.cross_kv", "asr.prompt",
+               "asr.token_rules", "asr.beam_select", "asr.decoder_step",
+               "asr.decoder_step.self_attn",
+               "asr.decoder_step.cache_update",
+               "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
+               "asr.decoder_step.logits"},
+    "mel": {"asr.mel"},
+    "ladder": {"ladder.resize", "ladder.intra", "ladder.motion_search",
+               "ladder.mc", "ladder.transform_quant", "ladder.deblock",
+               "ladder.bitproxy"},
+}
+
+
+def _scopes_in(jaxpr, prefix: str = "") -> set[str]:
+    """Every ``asr.*`` / ``ladder.*`` scope in the name stacks of a
+    jaxpr's equations, those of nested jaxprs (scan bodies, inner jits)
+    under their equation's own stack."""
+    import re
+
+    found: set[str] = set()
+    for eqn in jaxpr.eqns:
+        here = "/".join(p for p in (prefix, str(eqn.source_info.name_stack))
+                        if p)
+        found |= set(re.findall(
+            r"(?<![A-Za-z0-9_.])((?:asr|ladder)\.[A-Za-z0-9_.]+)", here))
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found |= _scopes_in(inner, here)
+    return found
+
+
+@pytest.mark.parametrize("program", sorted(ASR_SCOPES))
+def test_programs_carry_their_named_scopes(program):
+    """Tiny widths, nothing runs: the scopes are in the name stacks of
+    both generate programs, the mel program and the chain ladder (scan
+    bodies included), and the program names nothing else ``asr.*`` or
+    ``ladder.*`` (a typo would read as a scope of its own in a trace)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if program == "ladder":
+        from vlog_tpu.parallel.ladder import ladder_chain_program
+
+        fn, mats = ladder_chain_program((("r0", 32, 48, 30),), 64, 96,
+                                        search=4, mesh=None, deblock=True)
+        y = np.zeros((1, 3, 64, 96), np.uint8)
+        c = np.zeros((1, 3, 32, 48), np.uint8)
+        jaxpr = jax.make_jaxpr(fn)(
+            y, c, c, mats, {"r0": np.full((1, 3), 30, np.int32)},
+            {"r0": {"budget": np.float32(100.0),
+                    "alpha": np.float32(0.1)}})
+    elif program == "mel":
+        from vlog_tpu.asr.mel import log_mel_spectrogram
+
+        jaxpr = jax.make_jaxpr(log_mel_spectrogram)(
+            np.zeros((1, 16000), np.float32))
+    else:
+        from vlog_tpu.asr import decode
+        from vlog_tpu.asr.model import (DecoderCache, WhisperConfig,
+                                        init_random_params)
+
+        cfg = WhisperConfig(
+            d_model=32, encoder_layers=1, decoder_layers=1,
+            encoder_attention_heads=2, decoder_attention_heads=2,
+            encoder_ffn_dim=64, decoder_ffn_dim=64, vocab_size=120,
+            max_source_positions=50, max_target_positions=16)
+        beam = 3 if program == "beam" else 1
+        kw = dict(cfg=cfg, sot=100, eot=99, ts_begin=110, no_speech=105,
+                  max_new=4, timestamps=True)
+        fn = decode._generate_beam_jit if beam > 1 else decode._generate_jit
+        if beam > 1:
+            kw["beam"] = beam
+        jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
+            init_random_params(cfg), jnp.zeros((2, 80, 100)),
+            jnp.asarray([100, 101, 102], jnp.int32), jnp.zeros(120),
+            jnp.zeros(120), DecoderCache.create(cfg, 2 * beam, 7))
+    assert _scopes_in(jaxpr.jaxpr) == ASR_SCOPES[program]
+
+
+# --------------------------------------------------------------------------
+# The build meter: which thread rebuilt, and what the rebuild was
+# --------------------------------------------------------------------------
+
+def test_build_meter_books_by_thread_and_phase():
+    """A jit first run on a named thread shows under that thread's name
+    as trace, lower and compile seconds; the same call again adds
+    nothing (that is how a tick record's ``build_s`` reads 0)."""
+    import jax
+    import jax.numpy as jnp
+
+    from vlog_tpu.parallel import compile_cache as cc
+
+    cc.build_seconds()                    # first use arms the listener
+    compiled_before = cc.compile_seconds()
+    name = f"vlog-test-builder-{time.monotonic_ns()}"
+
+    @jax.jit
+    def fresh(x):
+        return jnp.tanh(x) * 3.0 + 1.0
+
+    def work():
+        fresh(jnp.ones(7)).block_until_ready()
+
+    for _ in range(2):
+        t = threading.Thread(target=work, name=name)
+        t.start()
+        t.join(60.0)
+        assert not t.is_alive()
+        if _ == 0:
+            first = cc.build_seconds()[name]
+    assert set(first) == {"trace", "lower", "compile", "cache_load"}
+    assert first["trace"] > 0.0 and first["lower"] > 0.0 \
+        and first["compile"] > 0.0
+    assert cc.build_seconds()[name] == first
+    assert cc.build_total(first) == pytest.approx(
+        first["trace"] + first["lower"] + first["compile"])
+    # the calling thread's own entry, zeros where it built nothing
+    mine = cc.thread_build_seconds()
+    assert set(mine) == set(first) and mine == cc.build_seconds().get(
+        threading.current_thread().name, dict.fromkeys(first, 0.0))
+    # compile_seconds() keeps its meaning: backend compile, process-wide
+    assert cc.compile_seconds() - compiled_before >= first["compile"] - 1e-9
+
+
+def test_build_meter_books_a_nested_trace_once(monkeypatch):
+    """jax reports a jit traced inside another's trace on its own and
+    again inside the outer event; the outer event is booked without
+    it. (On a thread of its own: the meter keeps each thread's open
+    traces apart, and this one's clock is made up.)"""
+    from vlog_tpu.parallel import compile_cache as cc
+
+    clock = [100.0]
+    trace_event = next(e for e, p in cc.BUILD_PHASES.items() if p == "trace")
+    name = f"vlog-test-nested-{time.monotonic_ns()}"
+
+    def events():
+        for now, duration in ((100.4, 0.3),     # inner: [100.1, 100.4]
+                              (100.8, 0.3),     # sibling: [100.5, 100.8]
+                              (101.0, 1.0),     # outer: holds both
+                              (102.0, 0.5)):    # a later top-level one
+            clock[0] = now
+            cc._on_event_duration(trace_event, duration)
+        cc._on_event_duration("/jax/some/other/event", 9.0)
+
+    cc.build_seconds()                          # listener armed
+    monkeypatch.setattr(cc, "_now", lambda: clock[0])
+    t = threading.Thread(target=events, name=name)
+    t.start()
+    t.join(10.0)
+    assert not t.is_alive()
+    booked = cc.build_seconds()[name]
+    assert booked["trace"] == pytest.approx(1.5)
+    assert booked["lower"] == booked["compile"] == 0.0

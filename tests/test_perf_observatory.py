@@ -416,6 +416,33 @@ class TestProfiler:
             time.sleep(0.1)
         assert p.status()["profiling"] is False
 
+    @pytest.mark.parametrize("source", ["explicit", "timer"])
+    def test_stop_leaves_a_summary_beside_the_artifact(
+            self, monkeypatch, tmp_path, source):
+        """Either way of stopping writes ``summary.json``; the explicit
+        stop (it arrives on the heartbeat task) only names the file and
+        leaves the writing to a thread of its own."""
+        from vlog_tpu.obs.profiler import DeviceProfiler
+
+        pytest.importorskip("jax")
+        monkeypatch.setattr(config, "PROFILE_DIR", str(tmp_path / source))
+        p = DeviceProfiler()
+        info = p.start(duration_s=1.0 if source == "timer" else 30.0)
+        assert info.get("profiling") is True, info
+        summary = Path(info["dir"]) / "summary.json"
+        if source == "explicit":
+            out = p.stop()
+            assert out["summary"] == str(summary)
+            p.wait_summary(30.0)
+        else:
+            deadline = time.monotonic() + 20.0
+            while not summary.exists() and time.monotonic() < deadline:
+                time.sleep(0.1)
+        got = json.loads(summary.read_text())
+        assert set(got) == {"window_s", "busy_s", "devices_traced",
+                            "by_scope", "idle_by_span", "programs"}
+        assert p.status()["profiling"] is False
+
     def test_mgmt_profile_verb_dispatch(self, monkeypatch, tmp_path):
         from vlog_tpu.worker import mgmt
 
@@ -424,6 +451,98 @@ class TestProfiler:
         st = mgmt.profile({"action": "status"})
         assert st["profiling"] is False
         assert st["root"].endswith("p3")
+
+
+# --------------------------------------------------------------------------
+# The reduction of a capture by the program's own names
+# --------------------------------------------------------------------------
+
+FIXTURES = Path(__file__).resolve().parent / "fixtures"
+
+
+@pytest.mark.parametrize("name,want", [
+    ("jit(f)/jit(main)/asr.decoder_step/asr.decoder_step.mlp/dot_general",
+     "asr.decoder_step.mlp"),
+    ("jit(local)/while/body/vmap(ladder.mc)/gather", "ladder.mc"),
+    ("jit(f)/transpose(jvp(asr.encoder))/mul", "asr.encoder"),
+    ("asr.mel", "asr.mel"),
+    ("jit(f)/while/body/add", None),
+    ("fusion.3", None),
+    ("jit(basr.mel)/x", None),
+])
+def test_scope_of_takes_the_innermost_program_scope(name, want):
+    from vlog_tpu.obs.profiler import scope_of
+
+    assert scope_of(name) == want
+
+
+def test_hlo_scopes_name_a_fusion_by_what_it_holds():
+    from vlog_tpu.obs.profiler import hlo_scopes
+
+    text = '''HloModule jit_f, is_scheduled=true
+
+%fused_computation (p0: f32[4]) -> f32[4] {
+  %p0 = f32[4]{0} parameter(0)
+  %t = f32[4]{0} tanh(%p0), metadata={op_name="jit(f)/asr.encoder/tanh"}
+  ROOT %g = f32[4]{0} negate(%t), metadata={op_name="jit(f)/asr.beam_reorder/neg"}
+}
+
+fused_computation.1 {
+  p1 = f32[4]{0} parameter(0)
+  a = f32[4]{0} abs(p1), metadata={op_name="jit(f)/asr.token_rules/abs"}
+  ROOT c = f32[4]{0} copy(a)
+}
+
+ENTRY %main.1 (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  %fusion.1 = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation
+  %fusion.2 = f32[4]{0} fusion(%x), kind=kLoop, calls=fused_computation.1
+  %named = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/asr.prompt/x"}
+  ROOT %plain = f32[4]{0} add(%fusion.1, %fusion.2)
+}
+'''
+    got = hlo_scopes(text)
+    assert got["fusion.1"] == "asr.beam_reorder"    # its root's
+    assert got["fusion.2"] == "asr.token_rules"     # most of its members'
+    assert got["named"] == "asr.prompt"             # its own first
+    assert got["t"] == "asr.encoder"
+    assert "plain" not in got and "x" not in got
+
+
+def test_summarize_reads_the_capture_recorded_on_the_chip(tmp_path):
+    """One 1 x 5 tick at tiny widths, recorded on the TPU by
+    ``tests/fixtures/record_asr_trace.py``: the reduction of the kept
+    capture is the one written beside it, and it reads as the program's
+    layers (the framework names come from the HLO protos the capture
+    holds; its events carry none)."""
+    import gzip
+
+    from vlog_tpu.obs.profiler import summarize
+
+    pb = tmp_path / "asr_tick.xplane.pb"
+    pb.write_bytes(gzip.decompress(
+        (FIXTURES / "asr_tick.xplane.pb.gz").read_bytes()))
+    got = summarize(pb)
+    want = json.loads((FIXTURES / "asr_tick.summary.json").read_text())
+    assert json.loads(json.dumps(got)) == want
+    assert got["devices_traced"] == 1
+    assert 0.0 < got["busy_s"] < got["window_s"]
+    assert sum(got["by_scope"].values()) == pytest.approx(got["busy_s"])
+    beam = got["programs"]["jit__generate_beam_jit"]
+    assert beam["runs"] == 1
+    assert {"asr.beam_reorder", "asr.beam_select", "asr.token_rules",
+            "asr.decoder_step.self_attn", "asr.decoder_step.cross_attn",
+            "asr.decoder_step.mlp", "asr.decoder_step.logits",
+            "asr.decoder_step.cache_update", "asr.encoder.attn",
+            "asr.cross_kv", "asr.prompt", "asr.beam_final"} <= set(
+                beam["by_scope"])
+    assert beam["by_scope"].get("unscoped", 0.0) < 0.1 * beam["seconds"]
+    assert set(got["programs"]["jit_log_mel_spectrogram"]["by_scope"]) \
+        <= {"asr.mel", "unscoped"}
+    # the device was idle while the engine waited for the window and
+    # while it pulled the tokens; both gaps carry the engine's own names
+    assert set(got["idle_by_span"]) == {"asr.tick.coalesce",
+                                        "asr.generate.device_wait"}
 
 
 # --------------------------------------------------------------------------

@@ -17,7 +17,10 @@ the real registries from source —
 - **span names**: literal first args of ``span()``/``event()`` calls
   and literal ``name=`` kwargs of ``obs_store.record()`` calls across
   the package, plus the synthesized ``stage.*`` names derived from
-  ``STAGE_KEYS`` in obs/trace.py —
+  ``STAGE_KEYS`` in obs/trace.py;
+- **scope names**: literal first args of ``jax.named_scope()`` (as a
+  ``with`` or as a decorator): the names a device trace is read by
+  (``obs/profiler.py::summarize``) —
 
 and checks both directions against the docs (README.md and
 docs/DESIGN.md): everything extracted must be documented, and every
@@ -49,6 +52,7 @@ _METRIC_CTORS = frozenset({"Counter", "Gauge", "Histogram", "Summary"})
 _KNOB_RE = re.compile(r"VLOG_[A-Z][A-Z0-9_]*")
 _HELP_RE = re.compile(r"#\s*(?:HELP|TYPE)\s+(vlog_\w+)")
 _DOC_SITE_RE = re.compile(r"`([a-z]+\.[a-z_]+)`")
+_SPAN_NAME_RE = re.compile(r"[a-z]+(?:\.[a-z_]+)+")
 
 
 def _documented(name: str, docs: str) -> bool:
@@ -202,11 +206,11 @@ def span_names(modules: list[Module]) -> set[str]:
             seg = _last_seg(node.func)
             if seg in ("span", "event"):
                 name = _str_arg(node)
-                if name and re.fullmatch(r"[a-z]+\.[a-z_]+", name):
+                if name and _SPAN_NAME_RE.fullmatch(name):
                     names.add(name)
             elif seg == "record":
                 name = _str_kwarg(node, "name")
-                if name and re.fullmatch(r"[a-z]+\.[a-z_]+", name):
+                if name and _SPAN_NAME_RE.fullmatch(name):
                     names.add(name)
         # synthesized stage.* spans: derived from STAGE_KEYS in obs/trace.py
         if "/".join(mod.pkg_parts) == "obs/trace.py":
@@ -221,6 +225,21 @@ def span_names(modules: list[Module]) -> set[str]:
                                 and isinstance(elt.value, str) \
                                 and elt.value.endswith("_s"):
                             names.add(f"stage.{elt.value[:-2]}")
+    return names
+
+
+def scope_names(modules: list[Module]) -> set[str]:
+    """Literal ``jax.named_scope`` names the package's programs carry."""
+    names: set[str] = set()
+    for mod in modules:
+        if mod.pkg_parts[0] == "analysis":
+            continue
+        for node in ast.walk(mod.tree):
+            if isinstance(node, ast.Call) \
+                    and _last_seg(node.func) == "named_scope":
+                name = _str_arg(node)
+                if name:
+                    names.add(name)
     return names
 
 
@@ -286,9 +305,11 @@ def run(modules: list[Module], pkg_dir) -> list[Finding]:
                 f"failpoint site {site} registered but undocumented"))
     families = {s.split(".", 1)[0] for s in sites}
     spans = span_names(modules)
+    scopes = scope_names(modules)
     for token in sorted(set(_DOC_SITE_RE.findall(docs))):
         if token.split(".", 1)[0] in families \
-                and token not in sites and token not in spans:
+                and token not in sites and token not in spans \
+                and token not in scopes:
             findings.append(Finding(
                 RULE, doc_file, 0,
                 f"docs document failpoint-shaped `{token}` but no such "
@@ -305,6 +326,11 @@ def run(modules: list[Module], pkg_dir) -> list[Finding]:
             findings.append(Finding(
                 RULE, doc_file, 0,
                 f"span name {name} emitted but undocumented"))
+    for name in sorted(scopes):
+        if not _documented(name, docs):
+            findings.append(Finding(
+                RULE, doc_file, 0,
+                f"named scope {name} in a program but undocumented"))
     return findings
 
 

@@ -31,6 +31,7 @@ from vlog_tpu.asr.model import (
     decoder_step,
     encode,
 )
+from vlog_tpu.obs import trace
 
 TIME_PRECISION = 0.02       # seconds per timestamp token step
 MAX_INITIAL_TIMESTAMP_INDEX = 50   # first cue within 1.0 s
@@ -179,25 +180,30 @@ def _generate_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
 
     # prefill the prompt (static small count of steps)
     logits = None
-    for i in range(plen):
-        tok = jnp.broadcast_to(prompt[i], (b,))
-        logits, cache = decoder_step(params, tok, jnp.int32(i), cache, ckv, cfg)
-    # no-speech probability from the first post-prompt distribution
-    probs0 = jax.nn.softmax(logits, axis=-1)
-    no_speech_prob = (probs0[:, no_speech] if no_speech >= 0
-                      else jnp.zeros(b))
+    with jax.named_scope("asr.prompt"):
+        for i in range(plen):
+            tok = jnp.broadcast_to(prompt[i], (b,))
+            logits, cache = decoder_step(params, tok, jnp.int32(i), cache,
+                                         ckv, cfg)
+        # no-speech probability from the first post-prompt distribution
+        probs0 = jax.nn.softmax(logits, axis=-1)
+        no_speech_prob = (probs0[:, no_speech] if no_speech >= 0
+                          else jnp.zeros(b))
 
     def step(carry, step_idx):
         cache, logits, last, penult, last_ts, finished = carry
-        lg = logits + suppress_vec
-        lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
-        if timestamps:
-            lg = apply_timestamp_rules(lg, last, penult, last_ts, step_idx,
-                                       ts_begin=ts_begin, eot=eot)
-        tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        tok = jnp.where(finished, eot, tok)
-        finished = finished | (tok == eot)
-        last_ts = jnp.where(tok >= ts_begin, tok, last_ts)
+        with jax.named_scope("asr.token_rules"):
+            lg = logits + suppress_vec
+            lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
+            if timestamps:
+                lg = apply_timestamp_rules(lg, last, penult, last_ts,
+                                           step_idx, ts_begin=ts_begin,
+                                           eot=eot)
+        with jax.named_scope("asr.beam_select"):    # a beam of one
+            tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            tok = jnp.where(finished, eot, tok)
+            finished = finished | (tok == eot)
+            last_ts = jnp.where(tok >= ts_begin, tok, last_ts)
         nxt_logits, cache2 = decoder_step(
             params, tok, (plen + step_idx).astype(jnp.int32), cache, ckv, cfg)
         return ((cache2, nxt_logits, tok, last, last_ts, finished), tok)
@@ -237,18 +243,20 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     neg = jnp.finfo(jnp.float32).min
 
     # beams share the window's audio: tile cross-KV rows K-fold
-    ckv = [(jnp.repeat(ck, k, axis=0), jnp.repeat(cv, k, axis=0))
-           for ck, cv in ckv]
+    with jax.named_scope("asr.cross_kv.tile"):
+        ckv = [(jnp.repeat(ck, k, axis=0), jnp.repeat(cv, k, axis=0))
+               for ck, cv in ckv]
     plen = prompt.shape[0]
 
     logits = None
-    for i in range(plen):
-        tok = jnp.broadcast_to(prompt[i], (bk,))
-        logits, cache = decoder_step(params, tok, jnp.int32(i), cache,
-                                     ckv, cfg)
-    probs0 = jax.nn.softmax(logits.reshape(b, k, -1)[:, 0], axis=-1)
-    no_speech_prob = (probs0[:, no_speech] if no_speech >= 0
-                      else jnp.zeros(b))
+    with jax.named_scope("asr.prompt"):
+        for i in range(plen):
+            tok = jnp.broadcast_to(prompt[i], (bk,))
+            logits, cache = decoder_step(params, tok, jnp.int32(i), cache,
+                                         ckv, cfg)
+        probs0 = jax.nn.softmax(logits.reshape(b, k, -1)[:, 0], axis=-1)
+        no_speech_prob = (probs0[:, no_speech] if no_speech >= 0
+                          else jnp.zeros(b))
 
     # beam 0 live at score 0; the rest start at -inf so step 0 fans out
     scores0 = jnp.tile(jnp.concatenate(
@@ -257,36 +265,40 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
 
     def step(carry, step_idx):
         cache, logits, scores, seqs, last, penult, last_ts, finished = carry
-        lg = logits + suppress_vec
-        lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
-        if timestamps:
-            lg = apply_timestamp_rules(lg, last, penult, last_ts, step_idx,
-                                       ts_begin=ts_begin, eot=eot)
-        lp = jax.nn.log_softmax(lg, axis=-1)                    # (bk, V)
-        v = lp.shape[-1]
-        ids = jnp.arange(v)
-        # finished beams: only EOT continues, score unchanged
-        lp = jnp.where(finished[:, None],
-                       jnp.where(ids[None, :] == eot, 0.0, neg), lp)
-        total = scores[:, None] + lp                            # (bk, V)
-        top_s, top_i = jax.lax.top_k(total.reshape(b, k * v), k)  # (b, k)
-        parent = top_i // v                                     # (b, k)
-        token = (top_i % v).astype(jnp.int32)
-        gparent = (parent + jnp.arange(b)[:, None] * k).reshape(bk)
+        with jax.named_scope("asr.token_rules"):
+            lg = logits + suppress_vec
+            lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
+            if timestamps:
+                lg = apply_timestamp_rules(lg, last, penult, last_ts,
+                                           step_idx, ts_begin=ts_begin,
+                                           eot=eot)
+            lp = jax.nn.log_softmax(lg, axis=-1)                # (bk, V)
+        with jax.named_scope("asr.beam_select"):
+            v = lp.shape[-1]
+            ids = jnp.arange(v)
+            # finished beams: only EOT continues, score unchanged
+            lp = jnp.where(finished[:, None],
+                           jnp.where(ids[None, :] == eot, 0.0, neg), lp)
+            total = scores[:, None] + lp                        # (bk, V)
+            top_s, top_i = jax.lax.top_k(total.reshape(b, k * v), k)
+            parent = top_i // v                                 # (b, k)
+            token = (top_i % v).astype(jnp.int32)
+            gparent = (parent + jnp.arange(b)[:, None] * k).reshape(bk)
+            token = token.reshape(bk)
+            scores = top_s.reshape(bk)
 
         def take(x):
             return jnp.take(x, gparent, axis=0)
 
-        token = token.reshape(bk)
-        scores = top_s.reshape(bk)
-        seqs = take(seqs).at[:, step_idx].set(token)
-        penult = take(last)
-        last = token
-        last_ts = jnp.where(token >= ts_begin, token, take(last_ts))
-        finished = take(finished) | (token == eot)
-        cache = DecoderCache(
-            k=jnp.take(cache.k, gparent, axis=1),
-            v=jnp.take(cache.v, gparent, axis=1))
+        with jax.named_scope("asr.beam_reorder"):
+            seqs = take(seqs).at[:, step_idx].set(token)
+            penult = take(last)
+            last = token
+            last_ts = jnp.where(token >= ts_begin, token, take(last_ts))
+            finished = take(finished) | (token == eot)
+            cache = DecoderCache(
+                k=jnp.take(cache.k, gparent, axis=1),
+                v=jnp.take(cache.v, gparent, axis=1))
         nxt_logits, cache = decoder_step(
             params, token, (plen + step_idx).astype(jnp.int32), cache,
             ckv, cfg)
@@ -304,13 +316,15 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     finished = _rest[-1]
 
     # length-normalized selection per window (generated tokens before EOT)
-    lens = jnp.sum(seqs != eot, axis=1).astype(jnp.float32)
-    norm = scores / jnp.maximum(lens, 1.0)
-    # prefer finished beams: unfinished get a -1e9 handicap
-    norm = jnp.where(finished, norm, norm - 1e9)
-    best = jnp.argmax(norm.reshape(b, k), axis=1)               # (b,)
-    best_rows = best + jnp.arange(b) * k
-    return jnp.take(seqs, best_rows, axis=0), no_speech_prob, cache
+    with jax.named_scope("asr.beam_final"):
+        lens = jnp.sum(seqs != eot, axis=1).astype(jnp.float32)
+        norm = scores / jnp.maximum(lens, 1.0)
+        # prefer finished beams: unfinished get a -1e9 handicap
+        norm = jnp.where(finished, norm, norm - 1e9)
+        best = jnp.argmax(norm.reshape(b, k), axis=1)           # (b,)
+        best_rows = best + jnp.arange(b) * k
+        best_seqs = jnp.take(seqs, best_rows, axis=0)
+    return best_seqs, no_speech_prob, cache
 
 
 def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
@@ -331,7 +345,26 @@ def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
     solo-vs-packed guarantee on this; tests/test_asr_engine.py breaks
     if it regresses. One shared prompt per call also means callers may
     only co-batch windows agreeing on (language, task, max_new, beam)
-    — the engine's BatchKey."""
+    — the engine's BatchKey.
+
+    Two spans split the call for whoever listens (the engine's tick
+    record): ``asr.generate.dispatch`` ends when the jitted call has
+    returned, ``asr.generate.device_wait`` is the two pulls, that is the
+    host's wait for the device program."""
+    with trace.span("asr.generate.dispatch"):
+        toks, nsp = _dispatch(assets, mel, language=language, task=task,
+                              max_new=max_new, timestamps=timestamps,
+                              beam=beam)
+    with trace.span("asr.generate.device_wait"):
+        return np.asarray(toks), np.asarray(nsp)
+
+
+def _dispatch(assets: WhisperAssets, mel: jnp.ndarray, *, language: str,
+              task: str, max_new: int | None, timestamps: bool, beam: int):
+    """Everything of :func:`generate_batch` up to the jitted call's
+    return: prompt and suppress vectors, the lease of the cache page,
+    host-to-device copies, the dispatch (and, the first time a shape
+    runs, its tracing, lowering and compile). Returns device arrays."""
     st = assets.tokens
     cfg = assets.cfg
     if max_new is None:
@@ -363,7 +396,7 @@ def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
     # return the FINAL buffers to the pool: the leased input pages were
     # consumed functionally (same shape either way)
     kv_pool.release(cache)
-    return np.asarray(toks), np.asarray(nsp)
+    return toks, nsp
 
 
 def detect_language(assets: WhisperAssets, mel: jnp.ndarray) -> str:

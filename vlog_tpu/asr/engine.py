@@ -27,10 +27,33 @@ drains or other demand is pending (work-conserving: a lone engine gets
 the full-mesh fallback lease, and gives it back as soon as a transcode
 job queues up).
 
-This module deliberately does NOT import the tracer: the tick thread is
-a batch server, and spans belong to the submitting jobs (the daemon
-wraps its transcription attempts in ``worker.transcribe`` spans carrying
-queue-wait/batch attributes from :meth:`JobHandle.results`).
+The engine traces itself. The tick thread runs under a trace context of
+its own (one trace id per engine, a ``TraceBuffer`` as the sink) and
+opens one span tree per tick: ``asr.tick`` (attrs ``seq``, ``n``,
+``rows``, ``key``) with the children ``asr.tick.coalesce`` (the wait for
+a first window, timed-out waits since the last tick included, and the
+coalescing sleep), ``asr.tick.lease``,
+``asr.tick.take``, ``asr.tick.stack``, ``asr.tick.mel``,
+``asr.tick.generate`` (whose children ``asr.generate.dispatch`` and
+``asr.generate.device_wait`` are opened inside
+``decode.generate_batch``), ``asr.tick.parse`` and ``asr.tick.deliver``.
+At the end of a tick the buffer is drained into the tick's ``batch_log``
+entry, which is **the tick record**: beside ``rows``, ``n``,
+``occupancy``, ``jobs`` and ``elapsed_s`` (stack to the token pull, so
+it ends before the parse) it holds ``seq``, ``t_start``, ``t_dispatch``
+(the jitted call returned: the device has the program), ``t_ready`` (the
+tokens are on the host), ``t_end``, ``phase_s`` (seconds per phase, from
+the spans' own durations), ``gap_s`` (``t_dispatch`` minus the previous
+tick's ``t_ready``; ``None`` on the first tick, after an idle wait and
+after a failed batch), ``windows``, ``wait_s``, ``build_s`` and
+``first_of_shape`` (``parallel/compile_cache.py::build_seconds``), all
+on ``time.monotonic()``. The entry is appended before the results are
+delivered; ``phase_s["deliver"]`` and ``t_end`` are filled in place
+afterwards. Every span is also a ``vlog:<name>`` annotation in a
+profiler capture (``obs/trace.py``), so device idle gaps can be named by
+phase (``obs/profiler.py::summarize``). Jobs keep their own spans: the
+daemon wraps an attempt in ``worker.transcribe`` and
+``worker/transcribe.py`` adds ``asr.job.*`` beneath it.
 """
 
 from __future__ import annotations
@@ -46,7 +69,19 @@ from vlog_tpu.asr import mel as melmod
 from vlog_tpu.asr.load import WhisperAssets, load_whisper
 from vlog_tpu.asr.queue import BatchKey, WindowQueue, WorkItem
 from vlog_tpu.asr.vtt import Cue
+from vlog_tpu.obs import trace
+from vlog_tpu.parallel import compile_cache
 from vlog_tpu.utils import failpoints
+
+# phases of a tick record, in the order a cycle runs them; the last part
+# of the span that reports each (asr.tick.<phase>, asr.generate.<phase>)
+PHASES = ("coalesce", "lease", "take", "stack", "mel", "dispatch",
+          "device_wait", "parse", "deliver")
+
+# (model config, rows, mesh width, BatchKey) shapes that have run in this
+# process: jit caches are per process, so "first of its shape" is too
+_SHAPES_SEEN: set[tuple] = set()
+_SHAPES_LOCK = threading.Lock()
 
 
 class AsrJobError(RuntimeError):
@@ -151,10 +186,20 @@ class AsrEngine:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._lease_held = threading.Event()    # observability only
-        # Batch composition log for tests/stats: one entry per tick with
-        # rows/occupancy and the job of every packed window.
+        # One tick record per tick (module docstring): batch composition
+        # for tests/stats, timing for whoever asks where a tick went.
         self.batch_log: list[dict] = []         # guarded-by: _lock
         self.windows_decoded = 0                # guarded-by: _lock
+        self._trace = trace.TraceContext(trace.new_id(), None,
+                                         trace.TraceBuffer())
+        # tick thread only
+        self._ticket = None
+        self._lease = None
+        self._seq = 0
+        self._prev_ready: float | None = None
+        # phase seconds of passes that served nothing (a timed-out wait,
+        # a failed batch) since the last record: the next tick's
+        self._carry_s = dict.fromkeys(PHASES, 0.0)
 
     # job lifecycle --------------------------------------------------------
 
@@ -171,8 +216,13 @@ class AsrEngine:
             self._jobs[job] = handle
             if not self._started:
                 self._started = True
+
+                def serve():
+                    with trace.attach(self._trace):
+                        self._run()
+
                 self._thread = threading.Thread(
-                    target=self._run, name="vlog-asr-engine", daemon=True)
+                    target=serve, name="vlog-asr-engine", daemon=True)
                 self._thread.start()
         return handle
 
@@ -219,55 +269,105 @@ class AsrEngine:
     # tick loop ------------------------------------------------------------
 
     def _run(self) -> None:
-        ticket = None
-        lease = None
-
-        def _release():
-            nonlocal ticket, lease
-            if ticket is not None:
-                ticket.close()   # releases the lease too
-            ticket = None
-            lease = None
-            self._lease_held.clear()
-
+        self._built()       # the meter is on before the first build
         try:
             while not self._stop.is_set():
-                if not self._queue.wait_for_work(timeout=0.2):
-                    if lease is not None or ticket is not None:
-                        _release()   # idle: give the slot back
-                    continue
-                if self.tick_s > 0:
+                self._cycle()
+        finally:
+            self._release()
+            self._trace.buffer.drain()
+
+    def _release(self) -> None:
+        if self._ticket is not None:
+            self._ticket.close()   # releases the lease too
+        self._ticket = None
+        self._lease = None
+        self._lease_held.clear()
+
+    def _acquire(self) -> bool:
+        """Hold a slot lease where a scheduler hands them out; False if
+        the engine is stopping."""
+        if self.scheduler is None or self._lease is not None:
+            return True
+        from vlog_tpu.parallel.scheduler import SlotCancelled
+
+        self._ticket = self.scheduler.admit()
+        try:
+            self._lease = self._ticket.acquire(cancel=self._stop)
+        except SlotCancelled:
+            self._release()
+            return False
+        self._lease_held.set()
+        return True
+
+    def _renegotiate(self) -> None:
+        """Work-conserving renegotiation at the tick boundary: a
+        full-mesh fallback lease shrinks to a slot as soon as other
+        demand queues; any lease goes back when the window queue
+        drains."""
+        if self._lease is None:
+            return
+        if self._queue.pending() == 0:
+            self._release()
+        elif (self._lease.is_full_mesh
+              and self.scheduler.snapshot()["pending"] > 0):
+            self._release()
+
+    @staticmethod
+    def _built() -> float:
+        """Seconds this thread has spent building programs so far."""
+        return compile_cache.build_total(
+            compile_cache.thread_build_seconds())
+
+    @staticmethod
+    def _add_phases(phase_s: dict[str, float],
+                    spans: list[trace.Span]) -> None:
+        """Each span's seconds under the phase its name ends in."""
+        for sp in spans:
+            leaf = sp.name.rsplit(".", 1)[-1]
+            if leaf in phase_s:
+                phase_s[leaf] += sp.duration_s
+
+    def _cycle(self) -> None:
+        """One pass of the tick loop under one ``asr.tick`` span; a pass
+        that served windows leaves a tick record."""
+        entry = None
+        built0 = self._built()
+        with trace.span("asr.tick") as tick:
+            with trace.span("asr.tick.coalesce"):
+                idle = not self._queue.wait_for_work(timeout=0.2)
+                if not idle and self.tick_s > 0:
                     # Coalesce: let concurrent jobs land windows before
                     # packing, so the first tick is not a batch of one.
                     time.sleep(self.tick_s)
-                if self.scheduler is not None and lease is None:
-                    from vlog_tpu.parallel.scheduler import SlotCancelled
-
-                    ticket = self.scheduler.admit()
-                    try:
-                        lease = ticket.acquire(cancel=self._stop)
-                    except SlotCancelled:
-                        _release()
-                        continue
-                    self._lease_held.set()
-                key = self._queue.pick_key()
-                if key is None:
-                    continue
-                items = self._queue.take(key, self.batch_windows)
+            if idle:
+                self._release()     # give the slot back
+            else:
+                with trace.span("asr.tick.lease"):
+                    leased = self._acquire()
+                items: list[WorkItem] = []
+                if leased:
+                    with trace.span("asr.tick.take"):
+                        key = self._queue.pick_key()
+                        if key is not None:
+                            items = self._queue.take(key,
+                                                     self.batch_windows)
                 if items:
-                    self._tick(key, items, lease)
-                # Work-conserving renegotiation at the tick boundary: a
-                # full-mesh fallback lease shrinks to a slot as soon as
-                # other demand queues; any lease goes back when the
-                # window queue drains.
-                if lease is not None:
-                    if self._queue.pending() == 0:
-                        _release()
-                    elif (lease.is_full_mesh
-                          and self.scheduler.snapshot()["pending"] > 0):
-                        _release()
-        finally:
-            _release()
+                    entry = self._tick(tick, key, items, built0)
+                with trace.span("asr.tick.lease"):
+                    self._renegotiate()
+        spans = self._trace.buffer.drain()
+        if entry is None:
+            # idle or failed: the thread's seconds go to the next tick's
+            # phases (its wait for a first window began here), but the
+            # device's time since the last tick is not that tick's gap
+            self._add_phases(self._carry_s, spans)
+            self._prev_ready = None
+            return
+        with self._lock:
+            self._fold(entry, tick, spans)
+        self._carry_s = dict.fromkeys(PHASES, 0.0)
+        self._prev_ready = entry["t_ready"]
 
     def _bucket_rows(self, n: int, width: int) -> int:
         """Smallest power-of-two bucket >= n (recompile-free: every batch
@@ -280,79 +380,133 @@ class AsrEngine:
             rows += (-rows) % width
         return rows
 
-    def _tick(self, key: BatchKey, items: list[WorkItem], lease) -> None:
-        t0 = time.monotonic()
+    def _tick(self, tick: trace.Span, key: BatchKey, items: list[WorkItem],
+              built0: float) -> dict | None:
+        """Decode one batch; returns its tick record (already in
+        ``batch_log``, its results delivered) or None if it failed."""
+        lease = self._lease
+        n = len(items)
         try:
-            failpoints.hit("asr.batch")
-            n = len(items)
-            mesh = None
-            width = 1
-            if lease is not None and lease.width > 1:
-                from vlog_tpu.parallel.mesh import make_mesh
-
-                mesh = make_mesh("data:-1", devices=list(lease.devices))
-                width = lease.width
-            elif lease is None and self.scheduler is None:
-                # No scheduler anywhere (CLI, quality_bench): the classic
-                # ad-hoc full-device mesh.
-                import jax
-
-                if len(jax.devices()) > 1:
+            with trace.span("asr.tick.stack") as stacking:
+                failpoints.hit("asr.batch")
+                mesh = None
+                width = 1
+                if lease is not None and lease.width > 1:
                     from vlog_tpu.parallel.mesh import make_mesh
 
-                    mesh = make_mesh()
-                    width = mesh.devices.size
-            rows = self._bucket_rows(n, width)
-            stack = [melmod.pad_or_trim(it.samples.astype(np.float32))
-                     for it in items]
-            stack += [np.zeros_like(stack[0])] * (rows - n)
-            batch = np.stack(stack)
-            feats = melmod.log_mel_spectrogram(
-                batch, n_mels=self.assets.cfg.num_mel_bins)
-            if mesh is not None:
-                from vlog_tpu.parallel.mesh import shard_frames
+                    mesh = make_mesh("data:-1", devices=list(lease.devices))
+                    width = lease.width
+                elif lease is None and self.scheduler is None:
+                    # No scheduler anywhere (CLI, quality_bench): the
+                    # classic ad-hoc full-device mesh.
+                    import jax
 
-                (feats,) = shard_frames(mesh, feats)
-            from vlog_tpu.asr.decode import generate_batch, parse_segments
+                    if len(jax.devices()) > 1:
+                        from vlog_tpu.parallel.mesh import make_mesh
 
-            toks, no_speech = generate_batch(
-                self.assets, feats, language=key.language, task=key.task,
-                max_new=key.max_new, beam=key.beam)
+                        mesh = make_mesh()
+                        width = mesh.devices.size
+                rows = self._bucket_rows(n, width)
+                stack = [melmod.pad_or_trim(it.samples.astype(np.float32))
+                         for it in items]
+                stack += [np.zeros_like(stack[0])] * (rows - n)
+                batch = np.stack(stack)
+            shape = (self.assets.cfg, rows, width, key)
+            with _SHAPES_LOCK:
+                first_of_shape = shape not in _SHAPES_SEEN
+                _SHAPES_SEEN.add(shape)
+            tick.attrs.update(seq=self._seq, n=n, rows=rows, key="/".join(
+                str(part) for part in key))
+            with trace.span("asr.tick.mel"):
+                feats = melmod.log_mel_spectrogram(
+                    batch, n_mels=self.assets.cfg.num_mel_bins)
+                if mesh is not None:
+                    from vlog_tpu.parallel.mesh import shard_frames
+
+                    (feats,) = shard_frames(mesh, feats)
+            # through the module at call time: whoever wraps
+            # decode.generate_batch after the engine was built is called
+            from vlog_tpu.asr import decode
+
+            with trace.span("asr.tick.generate") as generating:
+                toks, no_speech = decode.generate_batch(
+                    self.assets, feats, language=key.language,
+                    task=key.task, max_new=key.max_new, beam=key.beam)
             toks, no_speech = toks[:n], no_speech[:n]
             st = self.assets.tokens
             tokenizer = self.assets.tokenizer
-            elapsed = time.monotonic() - t0
-            results = []
-            for row, nsp, it in zip(toks, no_speech, items):
-                cues: list[Cue] = []
-                if st.no_speech is None or nsp <= 0.6:
-                    for seg in parse_segments(row, st,
-                                              window_s=self.window_s):
-                        text = tokenizer.decode(
-                            [t for t in seg.token_ids if t < st.sot])
-                        cues.append(Cue(it.start_s + seg.start_s,
-                                        it.start_s + seg.end_s, text))
-                results.append((it, cues, t0 - it.enqueued_at))
+            t0 = stacking.started_mono
+            elapsed = generating.ended_mono - t0
+            with trace.span("asr.tick.parse"):
+                results = []
+                for row, nsp, it in zip(toks, no_speech, items):
+                    cues: list[Cue] = []
+                    if st.no_speech is None or nsp <= 0.6:
+                        for seg in decode.parse_segments(
+                                row, st, window_s=self.window_s):
+                            text = tokenizer.decode(
+                                [t for t in seg.token_ids if t < st.sot])
+                            cues.append(Cue(it.start_s + seg.start_s,
+                                            it.start_s + seg.end_s, text))
+                    results.append((it, cues, t0 - it.enqueued_at))
         except Exception as exc:  # noqa: BLE001 — the engine must survive
             # one bad batch; the affected jobs' attempts fail through the
             # normal job-failure handling and the tick loop keeps serving.
             self._fail_items(items, exc)
             self._observe_batch_metrics(key, items, rows=0, elapsed=0.0,
-                                        failed=True)
-            return
+                                        device_wait=0.0, failed=True)
+            return None
+        entry = {
+            "rows": rows, "n": n, "occupancy": n / rows,
+            "jobs": [it.job for it in items], "elapsed_s": elapsed,
+            "seq": self._seq,
+            "windows": [(it.job, it.index) for it in items],
+            "wait_s": [wait_s for _it, _cues, wait_s in results],
+            "first_of_shape": first_of_shape,
+            "build_s": self._built() - built0,
+        }
+        self._seq += 1
         with self._lock:
+            self._fold(entry, tick, self._trace.buffer.snapshot())
             self.windows_decoded += n
-            self.batch_log.append({
-                "rows": rows, "n": n, "occupancy": n / rows,
-                "jobs": [it.job for it in items], "elapsed_s": elapsed,
-            })
+            self.batch_log.append(entry)
             handles = {it.job: self._jobs.get(it.job) for it in items}
-        for it, cues, wait_s in results:
-            h = handles.get(it.job)
-            if h is not None and not h._cancelled.is_set():
-                h._deliver(it.index, cues, wait_s)
-        self._observe_batch_metrics(key, items, rows=rows, elapsed=elapsed,
-                                    failed=False)
+        with trace.span("asr.tick.deliver"):
+            for it, cues, wait_s in results:
+                h = handles.get(it.job)
+                if h is not None and not h._cancelled.is_set():
+                    h._deliver(it.index, cues, wait_s)
+            self._observe_batch_metrics(
+                key, items, rows=rows, elapsed=elapsed,
+                device_wait=entry["phase_s"]["device_wait"], failed=False)
+        return entry
+
+    def _fold(self, entry: dict, tick: trace.Span,
+              spans: list[trace.Span]) -> None:
+        """The tick's spans closed so far into its record: seconds per
+        phase from the spans' own durations, the instants from their
+        ends. Called under ``_lock`` when the entry is appended (before
+        delivery) and again when the tick has closed."""
+        phase_s = dict(self._carry_s)
+        self._add_phases(phase_s, spans)
+        generating = dispatched = ready = None
+        for sp in spans:
+            if sp.name == "asr.tick.generate":
+                generating = sp
+            elif sp.name == "asr.generate.dispatch":
+                dispatched = sp.ended_mono
+            elif sp.name == "asr.generate.device_wait":
+                ready = sp.ended_mono
+        # a stand-in for decode.generate_batch opens no spans of its own
+        entry["t_start"] = tick.started_mono
+        entry["t_dispatch"] = (dispatched if dispatched is not None
+                               else generating.started_mono)
+        entry["t_ready"] = (ready if ready is not None
+                            else generating.ended_mono)
+        entry["t_end"] = tick.ended_mono
+        entry["phase_s"] = phase_s
+        entry["gap_s"] = (None if self._prev_ready is None
+                          else entry["t_dispatch"] - self._prev_ready)
 
     def _fail_items(self, items: list[WorkItem], exc: BaseException) -> None:
         with self._lock:
@@ -364,7 +518,7 @@ class AsrEngine:
 
     def _observe_batch_metrics(self, key: BatchKey, items: list[WorkItem],
                                *, rows: int, elapsed: float,
-                               failed: bool) -> None:
+                               device_wait: float, failed: bool) -> None:
         try:
             from vlog_tpu.obs.metrics import runtime
 
@@ -380,11 +534,10 @@ class AsrEngine:
             m.asr_pad_waste.set((rows - n) / rows if rows else 0.0)
             if elapsed > 0:
                 m.asr_windows_per_second.set(n / elapsed)
-                # whole batched forward (mel → generate → pull) counts
-                # as device time for the ASR plane — same always-on
-                # attribution as the ladder executor's
-                # vlog_device_seconds{plane="ladder"}
-                m.device_seconds.labels("asr", "forward").inc(elapsed)
+            # the host's wait on the tick's device program (the token
+            # pull), not the host's whole tick: stacking, mel dispatch
+            # and a first shape's compile are no device seconds
+            m.device_seconds.labels("asr", "forward").inc(device_wait)
             now = time.monotonic()
             for it in items:
                 m.asr_queue_wait.observe(max(0.0, now - it.enqueued_at))

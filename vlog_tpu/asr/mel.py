@@ -71,6 +71,7 @@ def mel_filter_bank(n_mels: int = 80, n_fft: int = N_FFT,
 
 
 @partial(jax.jit, static_argnames=("n_mels",))
+@jax.named_scope("asr.mel")
 def log_mel_spectrogram(audio: jnp.ndarray, *, n_mels: int = 80) -> jnp.ndarray:
     """(B, N_SAMPLES) float32 in [-1,1] -> (B, n_mels, N_FRAMES) features.
 

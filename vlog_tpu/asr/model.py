@@ -137,21 +137,27 @@ def _conv1d(p: Params, name: str, x: jnp.ndarray, stride: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("cfg",))
+@jax.named_scope("asr.encoder")
 def encode(params: Params, mel: jnp.ndarray, cfg: WhisperConfig) -> jnp.ndarray:
     """(B, n_mels, 3000) log-mel -> (B, 1500, d) encoder states."""
     p = params
-    x = jax.nn.gelu(_conv1d(p, "model.encoder.conv1", mel, 1), approximate=False)
-    x = jax.nn.gelu(_conv1d(p, "model.encoder.conv2", x, 2), approximate=False)
-    x = x.transpose(0, 2, 1)                                  # (B, T, d)
-    x = x + p["model.encoder.embed_positions.weight"][: x.shape[1]]
+    with jax.named_scope("asr.encoder.conv"):
+        x = jax.nn.gelu(_conv1d(p, "model.encoder.conv1", mel, 1),
+                        approximate=False)
+        x = jax.nn.gelu(_conv1d(p, "model.encoder.conv2", x, 2),
+                        approximate=False)
+        x = x.transpose(0, 2, 1)                              # (B, T, d)
+        x = x + p["model.encoder.embed_positions.weight"][: x.shape[1]]
     for i in range(cfg.encoder_layers):
         n = f"model.encoder.layers.{i}"
-        h = _layer_norm(p, f"{n}.self_attn_layer_norm", x)
-        x = x + _self_attn(p, f"{n}.self_attn", h,
-                           cfg.encoder_attention_heads, None)
-        h = _layer_norm(p, f"{n}.final_layer_norm", x)
-        h = jax.nn.gelu(_linear(p, f"{n}.fc1", h), approximate=False)
-        x = x + _linear(p, f"{n}.fc2", h)
+        with jax.named_scope("asr.encoder.attn"):
+            h = _layer_norm(p, f"{n}.self_attn_layer_norm", x)
+            x = x + _self_attn(p, f"{n}.self_attn", h,
+                               cfg.encoder_attention_heads, None)
+        with jax.named_scope("asr.encoder.mlp"):
+            h = _layer_norm(p, f"{n}.final_layer_norm", x)
+            h = jax.nn.gelu(_linear(p, f"{n}.fc1", h), approximate=False)
+            x = x + _linear(p, f"{n}.fc2", h)
     return _layer_norm(p, "model.encoder.layer_norm", x)
 
 
@@ -159,6 +165,7 @@ def encode(params: Params, mel: jnp.ndarray, cfg: WhisperConfig) -> jnp.ndarray:
 # Decoder (teacher-forced; the KV-cached incremental path is in decode.py)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("asr.cross_kv")
 def cross_kv(params: Params, enc: jnp.ndarray, cfg: WhisperConfig
              ) -> list[tuple[jnp.ndarray, jnp.ndarray]]:
     """Per-layer cross-attention K/V, computed once per audio window."""
@@ -233,6 +240,7 @@ class DecoderCache:
 jax.tree_util.register_dataclass(DecoderCache, ["k", "v"], [])
 
 
+@jax.named_scope("asr.decoder_step")
 def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
                  cache: DecoderCache, ckv, cfg: WhisperConfig
                  ) -> tuple[jnp.ndarray, DecoderCache]:
@@ -252,24 +260,33 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     mask = (jnp.arange(max_len) <= pos)[None, None, None, :]
     for i in range(cfg.decoder_layers):
         n = f"model.decoder.layers.{i}"
-        h = _layer_norm(p, f"{n}.self_attn_layer_norm", x)
-        q = (_linear(p, f"{n}.self_attn.q_proj", h) * hd ** -0.5)
-        k1 = _split_heads(_linear(p, f"{n}.self_attn.k_proj", h), nh)
-        v1 = _split_heads(_linear(p, f"{n}.self_attn.v_proj", h), nh)
-        ki = jax.lax.dynamic_update_slice_in_dim(cache.k[i], k1, pos, axis=2)
-        vi = jax.lax.dynamic_update_slice_in_dim(cache.v[i], v1, pos, axis=2)
-        new_k.append(ki)
-        new_v.append(vi)
-        att = _attention(_split_heads(q, nh), ki, vi, mask)
-        x = x + _linear(p, f"{n}.self_attn.out_proj", _merge_heads(att))
-        h = _layer_norm(p, f"{n}.encoder_attn_layer_norm", x)
-        x = x + _cross_attn(p, f"{n}.encoder_attn", h, ckv[i], nh)
-        h = _layer_norm(p, f"{n}.final_layer_norm", x)
-        h = jax.nn.gelu(_linear(p, f"{n}.fc1", h), approximate=False)
-        x = x + _linear(p, f"{n}.fc2", h)
-    x = _layer_norm(p, "model.decoder.layer_norm", x)
-    logits = (x @ p["model.decoder.embed_tokens.weight"].T)[:, 0, :]
-    cache = DecoderCache(k=jnp.stack(new_k), v=jnp.stack(new_v))
+        with jax.named_scope("asr.decoder_step.self_attn"):
+            h = _layer_norm(p, f"{n}.self_attn_layer_norm", x)
+            q = (_linear(p, f"{n}.self_attn.q_proj", h) * hd ** -0.5)
+            k1 = _split_heads(_linear(p, f"{n}.self_attn.k_proj", h), nh)
+            v1 = _split_heads(_linear(p, f"{n}.self_attn.v_proj", h), nh)
+        with jax.named_scope("asr.decoder_step.cache_update"):
+            ki = jax.lax.dynamic_update_slice_in_dim(
+                cache.k[i], k1, pos, axis=2)
+            vi = jax.lax.dynamic_update_slice_in_dim(
+                cache.v[i], v1, pos, axis=2)
+            new_k.append(ki)
+            new_v.append(vi)
+        with jax.named_scope("asr.decoder_step.self_attn"):
+            att = _attention(_split_heads(q, nh), ki, vi, mask)
+            x = x + _linear(p, f"{n}.self_attn.out_proj", _merge_heads(att))
+        with jax.named_scope("asr.decoder_step.cross_attn"):
+            h = _layer_norm(p, f"{n}.encoder_attn_layer_norm", x)
+            x = x + _cross_attn(p, f"{n}.encoder_attn", h, ckv[i], nh)
+        with jax.named_scope("asr.decoder_step.mlp"):
+            h = _layer_norm(p, f"{n}.final_layer_norm", x)
+            h = jax.nn.gelu(_linear(p, f"{n}.fc1", h), approximate=False)
+            x = x + _linear(p, f"{n}.fc2", h)
+    with jax.named_scope("asr.decoder_step.logits"):
+        x = _layer_norm(p, "model.decoder.layer_norm", x)
+        logits = (x @ p["model.decoder.embed_tokens.weight"].T)[:, 0, :]
+    with jax.named_scope("asr.decoder_step.cache_update"):
+        cache = DecoderCache(k=jnp.stack(new_k), v=jnp.stack(new_v))
     return logits, cache
 
 

@@ -84,6 +84,7 @@ def intra_bs(mbh: int, mbw: int):
     return jnp.asarray(bs), jnp.asarray(bs)
 
 
+@jax.named_scope("ladder.deblock")
 def p_bs(nz4, mv):
     """Boundary strengths for a P frame.
 
@@ -300,6 +301,7 @@ def _deblock_wavefront(y, u, v, qp, bs_v, bs_h, *, mbh, mbw):
     return y, u, v
 
 
+@jax.named_scope("ladder.deblock")
 def deblock_frame(y, u, v, *, qp, bs_v, bs_h):
     """Deblock one reconstructed frame in place of spec 8.7.
 

@@ -73,6 +73,7 @@ def _six_tap_shift(x, axis):
     return out
 
 
+@jax.named_scope("ladder.mc")
 def half_pel_planes(refp):
     """Edge-padded (Hp, Wp) int32 reference -> (b, h, j) planes, same
     shape/alignment (spec 8.4.2.2.1: b right-half, h down-half, j
@@ -127,6 +128,7 @@ def _gather_qpel(refp, planes, mv_q, *, pad, mb=16):
     return (pa + pb + 1) >> 1
 
 
+@jax.named_scope("ladder.motion_search")
 def motion_search(cur_y, ref_y, *, search: int = 8,
                   lam: int = MV_COST_LAMBDA, refp=None, planes=None):
     """Full-search integer ME + half- then quarter-pel refinement:
@@ -217,6 +219,7 @@ def _mv_maps(mv, mb: int):
     return dy, dx
 
 
+@jax.named_scope("ladder.mc")
 def mc_luma(ref_y, mv_q, *, search: int, planes=None, refp=None):
     """Luma prediction at quarter-pel MVs (spec 8.4.2.2).
 
@@ -230,6 +233,7 @@ def mc_luma(ref_y, mv_q, *, search: int, planes=None, refp=None):
     return _gather_qpel(refp, planes, mv_q, pad=pad)
 
 
+@jax.named_scope("ladder.mc")
 def mc_chroma(ref_c, mv_q, *, search: int):
     """Chroma prediction per 8.4.2.2.2: the luma quarter-pel MV value is
     interpreted directly on the eighth-chroma-pel grid (integer part
@@ -275,6 +279,7 @@ def _decimate_mb_luma(levels):
     return levels * keep[:, :, None, None, None, None]
 
 
+@jax.named_scope("ladder.transform_quant")
 def _inter_luma_residual(cur, pred, qp):
     """(H, W) residual -> levels (mbh, mbw, 4, 4, 4, 4) + recon plane."""
     h, w = cur.shape
@@ -291,6 +296,7 @@ def _inter_luma_residual(cur, pred, qp):
     return levels, recon
 
 
+@jax.named_scope("ladder.transform_quant")
 def _inter_chroma_residual(cur, pred, qpc):
     """(Hc, Wc) -> (dc (mbh, mbw, 2, 2), ac (mbh, mbw, 2, 2, 4, 4), recon)."""
     hc, wc = cur.shape

@@ -357,6 +357,13 @@ class RuntimeMetrics:
             "batch completed",
             buckets=(0.01, 0.05, 0.2, 1.0, 5.0, 20.0, 60.0, 300.0),
             registry=self.registry)
+        self.asr_language_pass = Histogram(
+            "vlog_asr_language_pass_seconds",
+            "Seconds a job's language pass took (its eager programs "
+            "queue behind the engine's beam program: under a second on "
+            "an idle device, tens of seconds beside full ticks)",
+            buckets=(0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 40.0, 80.0),
+            registry=self.registry)
         # Multi-tenant QoS plane (jobs/qos.py, jobs/claims.py): the
         # claim-side wait distribution per tenant — this is the
         # starvation bound's observable (p99 must stay under
@@ -380,9 +387,11 @@ class RuntimeMetrics:
             "vlog_device_seconds",
             "Accelerator-attributed busy seconds per batch by plane and "
             "rung (ladder: rung='compute' = shared device compute wait, "
-            "rung=<name> = that rung's d2h pull; asr: rung='forward') — "
-            "read next to host_busy_s/host_occupancy for the d2h-vs-"
-            "compute split",
+            "rung=<name> = that rung's d2h pull; asr: rung='forward' = "
+            "the host's wait on the tick's device program, the tick "
+            "record's device_wait, not the host's whole tick) — read "
+            "next to host_busy_s/host_occupancy for the d2h-vs-compute "
+            "split",
             ["plane", "rung"], registry=self.registry)
         self.slo_error_ratio = Gauge(
             "vlog_slo_error_ratio",

@@ -26,10 +26,21 @@ TensorBoard-loadable artifact directory under ``VLOG_PROFILE_DIR``
   profiling anyway.
 
 Outcomes land in ``vlog_profile_sessions_total{outcome}``.
+
+A stopped session also gets a ``summary.json`` beside its artifact:
+:func:`summarize` reduces the ``.xplane.pb`` to device seconds by the
+program's own ``jax.named_scope`` names (``asr.*``, ``ladder.*``) and
+device idle gaps by the program's own spans (``obs/trace.py`` mirrors
+every span into the capture as a ``vlog:<name>`` annotation). It is
+written by the thread that stopped the session once the lock is
+released: the timer's own thread, or for an explicit stop (which arrives
+on the heartbeat task) a daemon thread started for it.
 """
 
 from __future__ import annotations
 
+import bisect
+import json
 import logging
 import re
 import sys
@@ -43,12 +54,288 @@ log = logging.getLogger("vlog_tpu.profiler")
 
 _LABEL_RE = re.compile(r"[^a-zA-Z0-9_.-]+")
 
+GAP_NS = 50_000             # shorter device gaps are launch spacing
+EDGE_NS = 1_000_000         # a program run this close to an end may be cut
+TOP_PROGRAMS = 8
+ANNOTATION = "vlog:"
+# the program's named scopes in an op's framework name, outermost first:
+# "jit(f)/jit(main)/asr.decoder_step/asr.decoder_step.mlp/dot_general",
+# under a transform "jit(f)/while/body/vmap(ladder.mc)/gather"
+_SCOPE_RE = re.compile(r"(?<![A-Za-z0-9_.])((?:asr|ladder)\.[A-Za-z0-9_.]+)")
+# A TPU capture names a device-op event by its HLO instruction
+# ("%fusion.48 = (f32[1,200]...) fusion(...)") and carries no framework
+# name on the event (its stats are device_offset_ps, device_duration_ps
+# and a time scale; my chip run, PR 26). The framework names are in the
+# HLO protos the capture keeps per program in its "/host:metadata" plane:
+# instruction -> op_name.
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)[^=]*\{\s*$")
+_HLO_INSTRUCTION_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+
 
 def profile_root() -> Path:
     """The artifact root (``VLOG_PROFILE_DIR`` or BASE_DIR/profiles)."""
     if config.PROFILE_DIR:
         return Path(config.PROFILE_DIR)
     return Path(config.BASE_DIR) / "profiles"
+
+
+def scope_of(framework_name: str) -> str | None:
+    """``asr.decoder_step.mlp`` out of ``jit(f)/.../asr.decoder_step/
+    asr.decoder_step.mlp/dot_general``, ``ladder.mc`` out of
+    ``.../vmap(ladder.mc)/gather``: the last (innermost) ``asr.*`` /
+    ``ladder.*`` scope in the name, or None."""
+    found = _SCOPE_RE.findall(framework_name)
+    return found[-1] if found else None
+
+
+def _fields(buf: memoryview):
+    """``(field number, value)`` of one protobuf message's top level; a
+    length-delimited value comes as a memoryview. Enough of the wire
+    format to find the HLO protos of a capture, which
+    ``jax.profiler.ProfileData`` does not hand out."""
+    i, n = 0, len(buf)
+
+    def varint() -> int:
+        nonlocal i
+        value = shift = 0
+        while True:
+            byte = buf[i]
+            i += 1
+            value |= (byte & 0x7F) << shift
+            shift += 7
+            if byte < 0x80:
+                return value
+
+    while i < n:
+        key = varint()
+        kind = key & 7
+        if kind == 0:
+            yield key >> 3, varint()
+        elif kind == 2:
+            size = varint()
+            yield key >> 3, buf[i:i + size]
+            i += size
+        elif kind in (1, 5):
+            size = 8 if kind == 1 else 4
+            yield key >> 3, buf[i:i + size]
+            i += size
+        else:
+            raise ValueError(f"protobuf wire type {kind}")
+
+
+def hlo_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction: scope}`` of one optimized HLO module's text: each
+    instruction's own ``op_name``; a fusion without one takes its fused
+    computation's root's, else the scope most of its instructions
+    have."""
+    own: dict[str, str | None] = {}
+    calls: dict[str, str] = {}
+    members: dict[str, list[str]] = {}
+    roots: dict[str, str] = {}
+    computation = ""
+    for line in hlo_text.splitlines():
+        found = _HLO_INSTRUCTION_RE.match(line)
+        if found is None:
+            header = _HLO_COMPUTATION_RE.match(line)
+            if header is not None:
+                computation = header.group(1)
+            continue
+        name = found.group(2)
+        op_name = _HLO_OP_NAME_RE.search(line)
+        own[name] = scope_of(op_name.group(1)) if op_name else None
+        members.setdefault(computation, []).append(name)
+        if found.group(1):
+            roots[computation] = name
+        called = _HLO_CALLS_RE.search(line)
+        if called is not None:
+            calls[name] = called.group(1)
+    out = {}
+    for name, scope in own.items():
+        if scope is None and name in calls:
+            inside = calls[name]
+            scope = own.get(roots.get(inside, ""))
+            if scope is None:
+                held = [own[m] for m in members.get(inside, ()) if own[m]]
+                scope = max(set(held), key=held.count) if held else None
+        if scope is not None:
+            out[name] = scope
+    return out
+
+
+def _program_scopes(raw: bytes) -> dict[str, dict[str, str]]:
+    """``{program, as the "XLA Modules" line names it: {instruction:
+    scope}}`` from the HLO protos in a capture's ``/host:metadata``
+    plane; empty where the capture was taken without them."""
+    from jax._src.lib import xla_client
+
+    out: dict[str, dict[str, str]] = {}
+    for num, plane in _fields(memoryview(raw)):
+        if num != 1:                            # XSpace.planes
+            continue
+        parts = list(_fields(plane))
+        if not any(n == 2 and bytes(v) == b"/host:metadata"
+                   for n, v in parts):          # XPlane.name
+            continue
+        for n, entry in parts:
+            if n != 4:                          # XPlane.event_metadata
+                continue
+            meta = dict(_fields(entry)).get(2)  # map value: XEventMetadata
+            if meta is None:
+                continue
+            program = ""
+            for n2, v in _fields(meta):
+                if n2 == 2:                     # XEventMetadata.name
+                    program = bytes(v).decode()
+                elif n2 == 5:                   # .stats -> XStat.bytes_value
+                    proto = dict(_fields(v)).get(6)
+                    if proto is None:
+                        continue
+                    module = dict(_fields(proto)).get(1)  # HloProto.hlo_module
+                    text = xla_client._xla.HloModule \
+                        .from_serialized_hlo_module_proto(
+                            bytes(module)).to_string()
+                    out[program] = hlo_scopes(text)
+    return out
+
+
+def _leaves(events: list[tuple]) -> list[tuple]:
+    """Drop every event that contains the next one (sorted by start,
+    longest first at equal starts): an op that contains others (a
+    ``while`` around a scan's steps) is not counted on top of them."""
+    events.sort(key=lambda e: (e[0], -(e[1] - e[0])))
+    return [ev for i, ev in enumerate(events)
+            if not (i + 1 < len(events) and events[i + 1][0] < ev[1]
+                    and events[i + 1][1] <= ev[1])]
+
+
+def summarize(xplane_path: str | Path) -> dict:
+    """Reduce a capture to what the program's own names say:
+
+    - ``window_s``: first to last instant of device ops and ``vlog:``
+      annotations; ``busy_s``: seconds in which a leaf device op ran,
+      averaged over the device planes;
+    - ``by_scope``: leaf device-op seconds grouped by the innermost
+      ``asr.*`` / ``ladder.*`` named scope of the op's framework name
+      (:func:`scope_of`), else ``unscoped``. The name comes from the HLO
+      protos the capture holds (taken with ``enable_hlo_proto``, the
+      profiler's default; without them every op is ``unscoped``). A
+      fusion carries one name, so its seconds go to the scope of the op
+      XLA named it after;
+    - ``idle_by_span``: device gaps over 50 us summed under the
+      innermost (shortest) ``vlog:`` annotation of any host thread that
+      covers their middle, else ``no_program_span``;
+    - ``programs``: for the eight compiled programs ("XLA Modules"
+      line) that took most device time, their runs that lie wholly
+      inside the capture, those runs' seconds and the same by-scope
+      split over them alone, so that ``by_scope[s] / runs`` is scope
+      ``s``'s seconds in ONE run of the program however the capture
+      cut the runs at its ends.
+
+    The arithmetic is ``benchmark/harness/trace.py``'s, kept apart from
+    it: the yardstick does not import the program, nor the program it.
+    Reads the file with nothing but jax."""
+    import jax
+
+    data = jax.profiler.ProfileData.from_file(str(xplane_path))
+    by_program = _program_scopes(Path(xplane_path).read_bytes())
+    device_lines: list[list[tuple]] = []    # leaf candidates per device
+    module_lines: list[list[tuple]] = []    # program runs, beside them
+    spans: list[tuple[int, int, str]] = []
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            lines = {line.name: line for line in plane.lines}
+            if "XLA Ops" not in lines:
+                continue
+            runs = sorted(
+                (int(ev.start_ns), int(ev.start_ns) + int(ev.duration_ns),
+                 ev.name) for ev in (lines["XLA Modules"].events
+                                     if "XLA Modules" in lines else ()))
+            starts = [r[0] for r in runs]
+            events = []
+            scopes: dict[tuple, str] = {}   # one lookup per (program, op)
+            for ev in lines["XLA Ops"].events:
+                start = int(ev.start_ns)
+                i = bisect.bisect_right(starts, start) - 1
+                program = runs[i][2] if i >= 0 and start < runs[i][1] \
+                    else ""
+                scope = scopes.get((program, ev.name))
+                if scope is None:
+                    instruction = ev.name.split(" = ", 1)[0].strip() \
+                        .lstrip("%")
+                    scope = scopes[(program, ev.name)] = by_program.get(
+                        program, {}).get(instruction, "unscoped")
+                events.append((start, start + int(ev.duration_ns), scope))
+            if not events:
+                continue
+            device_lines.append(events)
+            module_lines.append([(s, e, name.split("(", 1)[0])
+                                 for s, e, name in runs])
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(ANNOTATION):
+                        start = int(ev.start_ns)
+                        spans.append((start, start + int(ev.duration_ns),
+                                      ev.name[len(ANNOTATION):]))
+    spans.sort()
+    edges = [t for events in device_lines for s, e, _ in events
+             for t in (s, e)] + [t for s, e, _ in spans for t in (s, e)]
+    lo, hi = (min(edges), max(edges)) if edges else (0, 0)
+
+    def covering(at: int) -> str:
+        best = None
+        for s, e, name in spans:
+            if s > at:
+                break
+            if e >= at and (best is None or e - s < best[0]):
+                best = (e - s, name)
+        return best[1] if best else "no_program_span"
+
+    busy_ns = 0
+    scope_ns: dict[str, int] = {}
+    gap_ns: dict[str, int] = {}
+
+    def gap(a: int, b: int) -> None:
+        if b - a > GAP_NS:
+            name = covering((a + b) // 2)
+            gap_ns[name] = gap_ns.get(name, 0) + (b - a)
+
+    programs: dict[str, dict] = {}
+    for events, runs in zip(device_lines, module_lines):
+        whole = [r for r in runs
+                 if r[0] - lo >= EDGE_NS and hi - r[1] >= EDGE_NS]
+        starts = [r[0] for r in whole]
+        for s, e, name in whole:
+            prog = programs.setdefault(
+                name, {"runs": 0, "ns": 0, "scope_ns": {}})
+            prog["runs"] += 1
+            prog["ns"] += e - s
+        prev_end = lo
+        for s, e, scope in _leaves(events):
+            busy_ns += e - s
+            scope_ns[scope] = scope_ns.get(scope, 0) + (e - s)
+            gap(prev_end, s)
+            prev_end = max(prev_end, e)
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s < whole[i][1]:
+                into = programs[whole[i][2]]["scope_ns"]
+                into[scope] = into.get(scope, 0) + (e - s)
+        gap(prev_end, hi)
+    n = max(len(device_lines), 1)
+
+    def seconds(d: dict[str, int], over: int = n) -> dict[str, float]:
+        return {k: v / over / 1e9 for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])}
+
+    top = sorted(programs.items(), key=lambda kv: -kv[1]["ns"])
+    return {"window_s": (hi - lo) / 1e9, "busy_s": busy_ns / n / 1e9,
+            "devices_traced": len(device_lines),
+            "by_scope": seconds(scope_ns), "idle_by_span": seconds(gap_ns),
+            "programs": {name: {"runs": p["runs"], "seconds": p["ns"] / 1e9,
+                                "by_scope": seconds(p["scope_ns"], 1)}
+                         for name, p in top[:TOP_PROGRAMS]}}
 
 
 def _bump(outcome: str) -> None:
@@ -69,6 +356,7 @@ class DeviceProfiler:
         self._started_at = 0.0                    # guarded-by: _lock
         self._duration_s = 0.0                    # guarded-by: _lock
         self._timer: threading.Timer | None = None  # guarded-by: _lock
+        self._summary_thread: threading.Thread | None = None  # guarded-by: _lock
 
     # ---- session lifecycle -------------------------------------------
 
@@ -121,13 +409,32 @@ class DeviceProfiler:
                 "duration_s": dur, "started_at": started}
 
     def stop(self) -> dict:
-        """Stop the active session early (idempotent)."""
+        """Stop the active session early (idempotent). The summary is
+        left to a thread of its own: this call arrives on the heartbeat
+        task. The returned ``summary`` is where it will be."""
         with self._lock:
-            return self._stop_locked(source="explicit")
+            out = self._stop_locked(source="explicit")
+        if "summary" in out:
+            writer = threading.Thread(
+                target=_write_summary, args=(out["dir"],),
+                name="vlog-profiler-summary", daemon=True)
+            with self._lock:
+                self._summary_thread = writer
+            writer.start()
+        return out
 
     def _timed_stop(self) -> None:
         with self._lock:
-            self._stop_locked(source="timer")
+            out = self._stop_locked(source="timer")
+        if "summary" in out:
+            _write_summary(out["dir"])
+
+    def wait_summary(self, timeout: float | None = None) -> None:
+        """Join the summary writer of the last explicit stop (tests)."""
+        with self._lock:
+            writer = self._summary_thread
+        if writer is not None:
+            writer.join(timeout)
 
     def _stop_locked(self, source: str) -> dict:
         if self._active_dir is None:
@@ -149,6 +456,7 @@ class DeviceProfiler:
         _bump("completed")
         log.info("profiling session stopped (%s): %s", source, active)
         return {"profiling": False, "dir": active,
+                "summary": str(Path(active) / "summary.json"),
                 "elapsed_s": round(time.time() - started, 2)}
 
     # ---- status ------------------------------------------------------
@@ -179,6 +487,23 @@ class DeviceProfiler:
                       reverse=True)[:32]
 
 
+def _write_summary(session_dir: str) -> None:
+    """``summary.json`` beside a stopped session's artifact; a capture
+    that cannot be read leaves a log line, never an exception."""
+    try:
+        found = sorted(Path(session_dir).glob(
+            "plugins/profile/*/*.xplane.pb"))
+        if not found:
+            raise FileNotFoundError("the session wrote no .xplane.pb")
+        summary = summarize(found[-1])
+        tmp = Path(session_dir) / "summary.json.tmp"
+        tmp.write_text(json.dumps(summary, indent=1))
+        tmp.rename(Path(session_dir) / "summary.json")
+    except Exception:   # noqa: BLE001 — the artifact itself is intact
+        log.warning("profile summary failed: %s", session_dir,
+                    exc_info=True)
+
+
 _profiler: DeviceProfiler | None = None
 _profiler_lock = threading.Lock()
 
@@ -199,4 +524,5 @@ def reset_profiler() -> None:
     with _profiler_lock:
         if _profiler is not None:
             _profiler.stop()
+            _profiler.wait_summary(30.0)
         _profiler = None

@@ -12,6 +12,15 @@ OpenTelemetry dependency):
 - **Durations are monotonic** — ``started_at`` is epoch time (for the
   waterfall's absolute axis) but the duration is measured on
   ``perf_counter`` so a clock step cannot produce negative spans.
+  ``started_mono`` is the same start on ``time.monotonic()`` (the clock
+  of the ASR engine's tick records and queue stamps); it is not stored.
+- **One clock with the device trace** — where ``jax`` is already
+  imported (the rule ``obs/profiler.py`` uses: API processes never pay
+  for it), ``span()`` also opens a ``jax.profiler.TraceAnnotation``
+  named ``vlog:<span name>``. With no profiler session on that costs one
+  atomic read; with one on, every span of the program lands in the
+  capture beside the device ops, so ``obs/profiler.py::summarize`` can
+  say which span a device idle gap fell under.
 - **Collection is a buffer, not a global** — spans land in the
   :class:`TraceBuffer` carried by the active :class:`TraceContext`;
   with no context (or no buffer) a span still times and nests but is
@@ -32,6 +41,7 @@ share the parent's ``started_at`` and carry ``synthetic: true``.
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 import uuid
@@ -68,6 +78,13 @@ class Span:
     duration_s: float | None = None      # None = instant marker / unknown
     status: str = "ok"                   # "ok" | "error"
     attrs: dict = field(default_factory=dict)
+    started_mono: float | None = None    # time.monotonic() at the start
+
+    @property
+    def ended_mono(self) -> float | None:
+        if self.started_mono is None or self.duration_s is None:
+            return None
+        return self.started_mono + self.duration_s
 
     def set_error(self, message: object) -> None:
         self.status = "error"
@@ -145,13 +162,27 @@ def attach(ctx: TraceContext | None) -> Iterator[TraceContext | None]:
         _CTX.reset(token)
 
 
+def _annotation(name: str, attrs: dict):
+    """A ``TraceAnnotation`` for the profiler's own trace, or None in a
+    process that has not imported jax (never import it from here). The
+    scalar attrs given at the open ride along as the event's stats."""
+    jax = sys.modules.get("jax")
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is None:
+        return None
+    return cls(f"vlog:{name}", **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float, str))})
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs: object) -> Iterator[Span]:
     """Open a child span of the current context (or a fresh root).
 
     On exit the duration is stamped from ``perf_counter``, an escaping
     exception marks the span ``error``, and the span is appended to the
-    context's buffer. Handlers that swallow exceptions themselves tag
+    context's buffer. While it is open a ``vlog:<name>`` annotation is
+    open in the profiler's trace (see the module docstring). Handlers that swallow exceptions themselves tag
     failures explicitly via :meth:`Span.set_error`.
     """
     parent = _CTX.get()
@@ -160,6 +191,10 @@ def span(name: str, **attrs: object) -> Iterator[Span]:
     sp = Span(trace_id, new_id(),
               parent.span_id if parent is not None else None,
               name, time.time(), attrs={k: v for k, v in attrs.items()})
+    annotation = _annotation(name, attrs)
+    if annotation is not None:
+        annotation.__enter__()
+    sp.started_mono = time.monotonic()
     t0 = time.perf_counter()
     token = _CTX.set(TraceContext(trace_id, sp.span_id, buf))
     try:
@@ -169,6 +204,8 @@ def span(name: str, **attrs: object) -> Iterator[Span]:
         raise
     finally:
         sp.duration_s = time.perf_counter() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         _CTX.reset(token)
         if buf is not None:
             buf.add(sp)

@@ -10,9 +10,11 @@ CAVLC/CABAC families.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("ladder.bitproxy")
 def cost_proxy(*level_arrays, batch_ndim: int = 0):
     """Bits proxy over level tensors: nnz + sum log2(1+|l|).
 

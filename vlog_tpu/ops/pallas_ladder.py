@@ -108,6 +108,7 @@ def fused_resize_plane(plane, a_h, a_w):
     return out.reshape(lead + (dst_h, dst_w))
 
 
+@jax.named_scope("ladder.resize")
 def resize_yuv420_pallas(y, u, v, rung_mats):
     """Drop-in for ops/resize.py ``resize_yuv420_with`` on the fused
     plane. Identity rungs (mats None) share the XLA path's clamp/cast

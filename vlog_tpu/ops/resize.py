@@ -163,6 +163,7 @@ def apply_resize_matrices(plane, a_h, a_w, out_dtype=jnp.uint8):
     return x.astype(out_dtype)
 
 
+@jax.named_scope("ladder.resize")
 def resize_yuv420_with(y, u, v, rung_mats):
     """Resize with prebuilt matrices (None = identity rung)."""
     if rung_mats is None:

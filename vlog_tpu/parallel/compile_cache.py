@@ -24,13 +24,31 @@ Cold-compile elimination has two halves:
    cumulative backend-compile wall time via ``jax.monitoring``'s
    ``/jax/core/compile/backend_compile_duration`` events (a persistent-
    cache HIT skips the backend compile entirely, so warm processes
-   report a fraction of cold ones).
+   report a fraction of cold ones). ``build_seconds()`` says which step
+   rebuilt and what the rebuild was: the same listener books, per
+   thread it was called on (jax calls it in the thread that builds),
+
+   - ``trace``: ``/jax/core/compile/jaxpr_trace_duration``, a jitted
+     function's Python body run to a jaxpr. A jit traced inside another
+     one's trace reports too; its seconds are taken off the outer
+     event's, so nothing is booked twice;
+   - ``lower``: ``/jax/core/compile/jaxpr_to_mlir_module_duration``;
+   - ``compile``: ``backend_compile_duration``, which in this jax
+     (0.9.0) wraps ``compile_or_get_cached`` and so holds a hit's
+     retrieval too;
+   - ``cache_load``: ``/jax/compilation_cache/cache_retrieval_time_sec``,
+     the part of ``compile`` that read a persistent-cache hit.
+
+   The listener is registered by the first call of either function, so
+   a process that never arms the cache (the benchmark's) meters too.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+import time
+from collections import deque
 from pathlib import Path
 
 # vlog_tpu/_xla_cache, beside the native coders' _build/ (git-ignored)
@@ -41,15 +59,46 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parent.parent / "_xla_cache"
 # does not apply here).
 _lock = threading.Lock()
 _state: dict = {"armed": False, "dir": None}
-_meter: dict = {"registered": False, "seconds": 0.0}
+_meter: dict = {"registered": False, "seconds": 0.0, "by_thread": {},
+                "open_traces": {}}
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _COMPILE_EVENT: "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_now = time.monotonic    # the listener's clock (tests give it their own)
+# a trace event stays here until the event of the jit that enclosed it
+# comes (or 64 later ones have: a top-level trace has no such event)
+_OPEN_TRACES = 64
 
 
 def _on_event_duration(event: str, duration: float, **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        with _lock:
-            _meter["seconds"] += float(duration)
+    phase = BUILD_PHASES.get(event)
+    if phase is None:
+        return
+    duration = float(duration)
+    now = _now()
+    thread = threading.current_thread().name
+    with _lock:
+        if event == _COMPILE_EVENT:
+            _meter["seconds"] += duration
+        booked = duration
+        if phase == "trace":
+            # events end innermost first: what began inside this one's
+            # stretch on this thread is already booked
+            started = now - duration
+            inner = _meter["open_traces"].setdefault(
+                thread, deque(maxlen=_OPEN_TRACES))
+            while inner and inner[-1][0] >= started:
+                booked -= inner.pop()[1]
+            inner.append((started, duration))
+            booked = max(booked, 0.0)
+        phases = _meter["by_thread"].setdefault(
+            thread, dict.fromkeys(BUILD_PHASES.values(), 0.0))
+        phases[phase] += booked
 
 
 def _register_meter_locked() -> None:
@@ -67,6 +116,33 @@ def compile_seconds() -> float:
     with _lock:
         _register_meter_locked()
         return _meter["seconds"]
+
+
+def build_seconds() -> dict[str, dict[str, float]]:
+    """``{thread name: {"trace", "lower", "compile", "cache_load"}}``:
+    seconds this process spent building programs, by the thread that
+    built them (module docstring). ``cache_load`` lies inside
+    ``compile``; :func:`build_total` adds the other three."""
+    with _lock:
+        _register_meter_locked()
+        return {t: dict(p) for t, p in _meter["by_thread"].items()}
+
+
+def thread_build_seconds() -> dict[str, float]:
+    """The calling thread's entry of :func:`build_seconds` (zeros if it
+    has built nothing): read before and after a stretch of work, the
+    difference is what that stretch spent building programs."""
+    name = threading.current_thread().name
+    with _lock:
+        _register_meter_locked()
+        return dict(_meter["by_thread"].get(name)
+                    or dict.fromkeys(BUILD_PHASES.values(), 0.0))
+
+
+def build_total(phases: dict[str, float]) -> float:
+    """Seconds of one thread's entry with nothing counted twice
+    (``cache_load`` lies inside ``compile``)."""
+    return phases["trace"] + phases["lower"] + phases["compile"]
 
 
 def ensure_compile_cache() -> str | None:
@@ -106,3 +182,5 @@ def reset_for_tests() -> None:
         _state["armed"] = False
         _state["dir"] = None
         _meter["seconds"] = 0.0
+        _meter["by_thread"] = {}
+        _meter["open_traces"] = {}

@@ -83,8 +83,9 @@ def _encode_rung(y, u, v, rung_mats, qp, resize=resize_yuv420_with):
     ry, ru, rv = resize(y, u, v, rung_mats)
     py, pu, pv = _pad_mb(ry, ru, rv)
     qv = jnp.broadcast_to(jnp.asarray(qp, jnp.int32), (py.shape[0],))
-    levels = jax.vmap(
-        lambda a, b, c, q: encode_frame(a, b, c, qp=q))(py, pu, pv, qv)
+    with jax.named_scope("ladder.intra"):
+        levels = jax.vmap(
+            lambda a, b, c, q: encode_frame(a, b, c, qp=q))(py, pu, pv, qv)
     return levels, ry
 
 
@@ -248,9 +249,10 @@ def _ladder_chain_cached(rungs: tuple[RungSpec, ...], src_h: int, src_w: int,
         ry = unflat(ry)
         mbh, mbw = py.shape[-2] // 16, py.shape[-1] // 16
 
-        i_out = jax.vmap(
-            lambda a, b, c, q: encode_frame(a, b, c, qp=q)
-        )(py[:, 0], pu[:, 0], pv[:, 0], qps[:, 0])
+        with jax.named_scope("ladder.intra"):
+            i_out = jax.vmap(
+                lambda a, b, c, q: encode_frame(a, b, c, qp=q)
+            )(py[:, 0], pu[:, 0], pv[:, 0], qps[:, 0])
         i_rec = (i_out["recon_y"], i_out["recon_u"], i_out["recon_v"])
         if deblock:
             ibs_v, ibs_h = intra_bs(mbh, mbw)

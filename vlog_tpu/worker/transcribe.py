@@ -26,6 +26,8 @@ from vlog_tpu import config
 from vlog_tpu.asr import mel as melmod
 from vlog_tpu.asr.vtt import Cue, format_vtt, stitch_windows
 from vlog_tpu.backends.base import ProgressFn
+from vlog_tpu.obs import trace
+from vlog_tpu.parallel import compile_cache
 
 
 class TranscriptionUnavailable(RuntimeError):
@@ -174,17 +176,31 @@ def transcribe_audio_engine(
     decodes strictly fewer windows and still produces a byte-identical
     VTT (cue floats survive the JSON round-trip exactly).
 
+    The entry records its own stages as spans (under the caller's
+    ``worker.transcribe`` where there is one) and their seconds in
+    ``stats_out``: ``asr.job.vad`` / ``vad_s`` (cut, speech spans, the
+    gate), ``asr.job.language_pass`` / ``language_pass_s`` (with what
+    the job's thread spent building the pass's eager programs as
+    ``build.*_s`` attrs), ``asr.job.served`` / ``served_s`` (first
+    submit to last result) and ``asr.job.stitch`` / ``stitch_s``. No
+    span per window: a three-hour recording has 432.
+
     Returns (stitched cues, language, total window count).
     """
     from vlog_tpu.asr.vad import speech_spans, window_has_speech
 
+    if stats_out is None:
+        stats_out = {}
     window_s = window_s or config.WHISPER_CHUNK_S
     overlap_s = overlap_s if overlap_s is not None else config.WHISPER_OVERLAP_S
-    windows = _cut_windows(samples, window_s=window_s, overlap_s=overlap_s)
-    spans = speech_spans(samples)
-    live = [i for i, (t0, w) in enumerate(windows)
-            if w.size and float(np.sqrt(np.mean(w ** 2))) > SILENCE_RMS
-            and window_has_speech(spans, t0, t0 + window_s)]
+    with trace.span("asr.job.vad") as stage:
+        windows = _cut_windows(samples, window_s=window_s,
+                               overlap_s=overlap_s)
+        spans = speech_spans(samples)
+        live = [i for i, (t0, w) in enumerate(windows)
+                if w.size and float(np.sqrt(np.mean(w ** 2))) > SILENCE_RMS
+                and window_has_speech(spans, t0, t0 + window_s)]
+    stats_out["vad_s"] = stage.duration_s
     per_window_cues: list[list[Cue]] = [[] for _ in windows]
 
     ckpt_windows: dict[str, list[list]] = {}
@@ -207,11 +223,20 @@ def transcribe_audio_engine(
                 pass
     to_submit = [i for i in live if i not in resumed]
 
+    if language is None and not live:
+        language = "en"
     if language is None:
         # The job's OWN first live window — co-batched jobs can never
         # pollute the language vote.
-        language = (engine.detect_language(windows[live[0]][1])
-                    if live else "en")
+        built0 = compile_cache.thread_build_seconds()
+        with trace.span("asr.job.language_pass") as stage:
+            language = engine.detect_language(windows[live[0]][1])
+            stage.attrs.update({
+                f"build.{phase}_s": round(seconds - built0[phase], 4)
+                for phase, seconds
+                in compile_cache.thread_build_seconds().items()})
+        stats_out["language_pass_s"] = stage.duration_s
+        _observe_language_pass(stage.duration_s)
 
     handle = engine.begin_job(
         job_key, language=language, max_new=max_new,
@@ -219,11 +244,10 @@ def transcribe_audio_engine(
     done = 0
     total = len(to_submit)
     waits: list[float] = []
-    if stats_out is not None:
-        stats_out.update({"windows_total": len(windows),
-                          "windows_live": len(live),
-                          "windows_resumed": len(resumed),
-                          "windows_submitted": total})
+    stats_out.update({"windows_total": len(windows),
+                      "windows_live": len(live),
+                      "windows_resumed": len(resumed),
+                      "windows_submitted": total})
 
     def _record(index: int, cues: list[Cue]) -> None:
         per_window_cues[index] = list(cues)
@@ -234,23 +258,25 @@ def transcribe_audio_engine(
         return {"v": 1, "language": language, "windows": dict(ckpt_windows)}
 
     def _wait_stats() -> None:
-        if stats_out is not None and waits:
+        if waits:
             stats_out["queue_wait_mean_s"] = round(
                 sum(waits) / len(waits), 4)
             stats_out["queue_wait_max_s"] = round(max(waits), 4)
 
     try:
-        for i in to_submit:
-            handle.submit(i, windows[i][0], windows[i][1])
-        for index, cues, wait_s in handle.results():
-            _record(index, cues)
-            waits.append(wait_s)
-            done += 1
-            if checkpoint_cb:
-                checkpoint_cb(_state(), done, total, False)
-            if progress_cb:
-                progress_cb(done, total,
-                            f"transcribed {done}/{total} windows")
+        with trace.span("asr.job.served", windows=total) as stage:
+            for i in to_submit:
+                handle.submit(i, windows[i][0], windows[i][1])
+            for index, cues, wait_s in handle.results():
+                _record(index, cues)
+                waits.append(wait_s)
+                done += 1
+                if checkpoint_cb:
+                    checkpoint_cb(_state(), done, total, False)
+                if progress_cb:
+                    progress_cb(done, total,
+                                f"transcribed {done}/{total} windows")
+        stats_out["served_s"] = stage.duration_s
     except BaseException:
         # Drain flush: keep whatever the engine already decoded for this
         # job (the in-flight batch), then write one final checkpoint so
@@ -268,7 +294,19 @@ def transcribe_audio_engine(
     finally:
         handle.close()
     _wait_stats()
-    return stitch_windows(per_window_cues), language, len(windows)
+    with trace.span("asr.job.stitch") as stage:
+        cues = stitch_windows(per_window_cues)
+    stats_out["stitch_s"] = stage.duration_s
+    return cues, language, len(windows)
+
+
+def _observe_language_pass(seconds: float) -> None:
+    try:
+        from vlog_tpu.obs.metrics import runtime
+
+        runtime().asr_language_pass.observe(seconds)
+    except Exception:  # noqa: BLE001 — metrics never break the job
+        pass
 
 
 def transcribe_video(
