@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """How ``asr_tick.xplane.pb`` and ``asr_tick.summary.json`` were made
-(on the chip, once, PR 26):
+(on the chip; PR 26, recorded again in PR 27 when the beam program
+changed):
 
     python3 tests/fixtures/record_asr_trace.py chiprun_out/asr_tick
 

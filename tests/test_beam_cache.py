@@ -1,0 +1,265 @@
+"""The beam program's self-K/V cache is written once and never moved.
+
+``_generate_beam_jit`` keeps beam history as an ancestry table that
+masks a per-window self-attention (asr/decode.py, asr/model.py). Three
+things are pinned here, on the CPU at tiny widths: the program gives
+token for token what a beam search gives that keeps the former
+formulation (the cache gathered by parent every step, each row attending
+over its own cache row); windows cannot touch each other; and the
+program's scan body holds the in-place writes and nothing else that
+moves a cache (counts of operations, never a time).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from vlog_tpu.asr import decode
+from vlog_tpu.asr.model import (DecoderCache, WhisperConfig, cross_kv,
+                                decoder_step, encode, init_random_params)
+
+CFG = WhisperConfig(
+    d_model=32, encoder_layers=1, decoder_layers=2,
+    encoder_attention_heads=2, decoder_attention_heads=2,
+    encoder_ffn_dim=64, decoder_ffn_dim=64, vocab_size=120,
+    max_source_positions=50, max_target_positions=40)
+VOCAB = dict(sot=100, eot=99, ts_begin=110, no_speech=105)
+PROMPT = (100, 101, 102)
+K = 5
+
+
+@pytest.fixture(scope="module")
+def params():
+    # N(0, 0.02^2) matrices at d_model 32 leave the tokens deaf to audio
+    # and history (every window decodes one repeated token): at 12 times
+    # that both windows give varied tokens and reorder their beams at
+    # nearly every step
+    return {name: (w * 12.0 if w.ndim >= 2 else w)
+            for name, w in init_random_params(CFG, seed=12).items()}
+
+
+def _mel(seed: int, windows: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    return rng.standard_normal((windows, 80, 100)).astype(np.float32)
+
+
+def _run(params, mel, *, max_new: int, timestamps: bool = True,
+         beam: int = K, page: DecoderCache | None = None):
+    rows = mel.shape[0] * beam
+    cache = page if page is not None else DecoderCache.create(
+        CFG, rows, len(PROMPT) + max_new)
+    toks, nsp, cache = decode._generate_beam_jit(
+        params, jnp.asarray(mel), jnp.asarray(PROMPT, jnp.int32),
+        jnp.zeros(CFG.vocab_size), jnp.zeros(CFG.vocab_size), cache,
+        cfg=CFG, max_new=max_new, timestamps=timestamps, beam=beam, **VOCAB)
+    return np.asarray(toks), np.asarray(nsp), cache
+
+
+def _gathering_beam_search(params, mel, *, max_new: int, timestamps: bool):
+    """The former formulation, kept here as the reference: the whole
+    cache is gathered by parent row each step (``jnp.take``) and
+    ``decoder_step`` runs with no table, so each row attends over its
+    own, reordered, cache row. Same rules, same top-K, same selection.
+    Returns (best tokens, no-speech probability, parents of every step)."""
+    eot, ts_begin = VOCAB["eot"], VOCAB["ts_begin"]
+    b, k = mel.shape[0], K
+    bk = b * k
+    neg = jnp.finfo(jnp.float32).min
+    ckv = [(jnp.repeat(ck, k, axis=0), jnp.repeat(cv, k, axis=0))
+           for ck, cv in cross_kv(params, encode(params, mel, CFG), CFG)]
+    cache = DecoderCache.create(CFG, bk, len(PROMPT) + max_new)
+    for i, tok in enumerate(PROMPT):
+        logits, cache = decoder_step(params, jnp.full((bk,), tok, jnp.int32),
+                                     jnp.int32(i), cache, ckv, CFG)
+    nsp = jax.nn.softmax(logits.reshape(b, k, -1)[:, 0],
+                         axis=-1)[:, VOCAB["no_speech"]]
+    scores = jnp.tile(jnp.concatenate(
+        [jnp.zeros((1,)), jnp.full((k - 1,), neg)]), (b,))
+    seqs = jnp.full((bk, max_new), eot, jnp.int32)
+    last = jnp.full((bk,), PROMPT[-1], jnp.int32)
+    penult = jnp.full((bk,), PROMPT[-2], jnp.int32)
+    last_ts = jnp.full((bk,), ts_begin - 1, jnp.int32)
+    finished = jnp.zeros((bk,), bool)
+    parents = []
+    v = CFG.vocab_size
+    for step in range(max_new):
+        lg = logits
+        if timestamps:
+            lg = decode.apply_timestamp_rules(
+                lg, last, penult, last_ts, jnp.int32(step),
+                ts_begin=ts_begin, eot=eot)
+        lp = jax.nn.log_softmax(lg, axis=-1)
+        lp = jnp.where(finished[:, None],
+                       jnp.where(jnp.arange(v)[None, :] == eot, 0.0, neg), lp)
+        top_s, top_i = jax.lax.top_k(
+            (scores[:, None] + lp).reshape(b, k * v), k)
+        parent = top_i // v
+        parents.append(np.asarray(parent))
+        token = (top_i % v).astype(jnp.int32).reshape(bk)
+        gparent = (parent + jnp.arange(b)[:, None] * k).reshape(bk)
+        scores = top_s.reshape(bk)
+        seqs = jnp.take(seqs, gparent, axis=0).at[:, step].set(token)
+        penult = jnp.take(last, gparent, axis=0)
+        last = token
+        last_ts = jnp.where(token >= ts_begin, token,
+                            jnp.take(last_ts, gparent, axis=0))
+        finished = jnp.take(finished, gparent, axis=0) | (token == eot)
+        cache = DecoderCache(k=jnp.take(cache.k, gparent, axis=1),
+                             v=jnp.take(cache.v, gparent, axis=1))
+        logits, cache = decoder_step(params, token,
+                                     jnp.int32(len(PROMPT) + step), cache,
+                                     ckv, CFG)
+    lens = jnp.sum(seqs != eot, axis=1).astype(jnp.float32)
+    norm = scores / jnp.maximum(lens, 1.0)
+    norm = jnp.where(finished, norm, norm - 1e9)
+    best = jnp.argmax(norm.reshape(b, k), axis=1) + jnp.arange(b) * k
+    return (np.asarray(jnp.take(seqs, best, axis=0)), np.asarray(nsp),
+            np.stack(parents))
+
+
+@pytest.mark.parametrize("timestamps", [True, False])
+def test_beam_program_matches_a_cache_gathering_beam_search(params,
+                                                            timestamps):
+    """K = 5, two windows of different audio, 20 steps: the tokens are
+    those of a beam search that gathers its cache by parent, and the run
+    is long enough that both windows reorder their beams (a parent other
+    than the identity), more than once."""
+    mel = _mel(21, 2)
+    want, want_nsp, parents = _gathering_beam_search(
+        params, jnp.asarray(mel), max_new=20, timestamps=timestamps)
+    got, got_nsp, _ = _run(params, mel, max_new=20, timestamps=timestamps)
+    for w in range(2):
+        reordering = [s for s in range(1, 20)
+                      if (parents[s, w] != np.arange(K)).any()]
+        assert len(reordering) >= 3, (w, parents[:, w])
+    assert not (want[0] == want[1]).all()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got_nsp, want_nsp, rtol=0, atol=1e-7)
+
+
+def test_a_window_is_bit_equal_alone_and_beside_others(params):
+    """At one bucket shape (4 windows x 5 beams, as the engine runs it):
+    a window's tokens and no-speech probability are bit-equal whether
+    the other rows are zero padding, another window's audio, or that
+    audio scaled up; and the neighbour's are its own whichever window
+    sits beside it. Nothing crosses a window."""
+    a, b = _mel(31, 1)[0], _mel(32, 1)[0]
+    zero = np.zeros_like(a)
+    alone, alone_nsp, _ = _run(params, np.stack([a, zero, zero, zero]),
+                               max_new=16)
+    beside, beside_nsp, _ = _run(params, np.stack([a, b, zero, zero]),
+                                 max_new=16)
+    loud, loud_nsp, _ = _run(params, np.stack([a, 7.0 * b, b, zero]),
+                             max_new=16)
+    assert not (beside[0] == beside[1]).all()
+    for toks, nsp in ((beside, beside_nsp), (loud, loud_nsp)):
+        np.testing.assert_array_equal(toks[0], alone[0])
+        assert nsp[0].tobytes() == alone_nsp[0].tobytes()
+    np.testing.assert_array_equal(loud[2], beside[1])
+    # zero-padded rows decode too, and alike
+    np.testing.assert_array_equal(alone[1], alone[3])
+    np.testing.assert_array_equal(beside[2], alone[1])
+
+
+def test_a_dirty_page_cannot_reach_the_tokens(params):
+    """The pool hands pages back unwashed: a page a previous generation
+    filled (other audio, another beam order) and one full of 1e6 give
+    bit-equal tokens to a zeroed page. The mask admits only (slot,
+    position) pairs written in this generation. (A masked term weighs
+    exactly 0, so any finite leftover is safe; NaN never was, before
+    the table or since: 0 x NaN.)"""
+    mel = _mel(41, 2)
+    clean, clean_nsp, _ = _run(params, mel, max_new=12)
+    _, _, used = _run(params, _mel(42, 2), max_new=12)
+    assert float(jnp.abs(used.k).max()) > 0.0
+    huge = DecoderCache(k=jnp.full_like(used.k, 1e6),
+                        v=jnp.full_like(used.v, -1e6))
+    for page in (used, huge):
+        toks, nsp, _ = _run(params, mel, max_new=12, page=page)
+        np.testing.assert_array_equal(toks, clean)
+        assert nsp.tobytes() == clean_nsp.tobytes()
+
+
+def _held(eqn) -> list:
+    """The jaxprs an equation holds (a scan's body, an inner jit's)."""
+    subs = [getattr(sub, "jaxpr", sub) for value in eqn.params.values()
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value])]
+    return [j for j in subs if hasattr(j, "eqns")]
+
+
+def _walk(jaxpr, prefix: str = ""):
+    """(equation, its whole name stack) of every equation, those of
+    held jaxprs under their holder's stack."""
+    for eqn in jaxpr.eqns:
+        stack = "/".join(p for p in (prefix, str(eqn.source_info.name_stack))
+                         if p)
+        yield eqn, stack
+        for j in _held(eqn):
+            yield from _walk(j, stack)
+
+
+@pytest.mark.parametrize("beam,program", [(K, "beam"), (1, "greedy")])
+def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
+        params, beam, program):
+    """What the scan body does to a rank-5 array: ``2 x decoder_layers``
+    ``dynamic_update_slice`` of one position each, one slice a layer for
+    K and for V to read the layer back, and nothing else: no gather, no
+    concatenate (``jnp.stack``), no rank-5 ``dynamic_slice``. The beam
+    program's only gather of anything with a ``max_len`` axis is the
+    ancestry table's, under its own named scope."""
+    fn = (decode._generate_beam_jit if program == "beam"
+          else decode._generate_jit)
+    kw = dict(cfg=CFG, max_new=6, timestamps=True, **VOCAB)
+    if program == "beam":
+        kw["beam"] = beam
+    max_len = len(PROMPT) + 6
+    windows = 3                  # not the layer count: shapes tell them apart
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
+        params, jnp.zeros((windows, 80, 100)),
+        jnp.asarray(PROMPT, jnp.int32),
+        jnp.zeros(CFG.vocab_size), jnp.zeros(CFG.vocab_size),
+        DecoderCache.create(CFG, windows * beam, max_len))
+    (scan,) = [e for e, _ in _walk(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    # equations that do something themselves, not those that hold a jaxpr
+    body = [(e, st) for e, st in _walk(scan.params["jaxpr"].jaxpr)
+            if not _held(e)]
+    page = (CFG.decoder_layers, windows * beam, CFG.decoder_attention_heads,
+            max_len, CFG.d_model // CFG.decoder_attention_heads)
+    on_cache: dict[str, list] = {}
+    for eqn, stack in body:
+        if any(getattr(v.aval, "shape", ()) == page for v in eqn.invars):
+            on_cache.setdefault(eqn.primitive.name, []).append((eqn, stack))
+    assert set(on_cache) == {"dynamic_update_slice", "slice"}
+    writes = on_cache["dynamic_update_slice"]
+    assert len(writes) == 2 * CFG.decoder_layers
+    for eqn, stack in writes:
+        assert eqn.invars[1].aval.shape == (1,) + page[1:3] + (1, page[4])
+        assert eqn.outvars[0].aval.shape == page
+        assert "asr.decoder_step.cache_update" in stack
+    assert len(on_cache["slice"]) == 2 * CFG.decoder_layers
+    assert all(e.outvars[0].aval.shape == (1,) + page[1:]
+               for e, _ in on_cache["slice"])
+    # nothing else in the body makes or gathers an array of that rank
+    for eqn, stack in body:
+        if eqn.primitive.name in ("gather", "concatenate", "dynamic_slice"):
+            assert all(len(getattr(v.aval, "shape", ())) < 5
+                       for v in (*eqn.invars, *eqn.outvars)), (eqn, stack)
+    table = [(e, st) for e, st in body if "asr.beam_ancestry" in st]
+    if program == "greedy":
+        assert not table
+        return
+    # the table: (windows, K, max_len) int32, gathered by parent and
+    # written at one position; no other gather sees a max_len axis
+    assert {e.primitive.name for e, _ in table} >= {
+        "gather", "dynamic_update_slice"}
+    with_len = [(e, st) for e, st in body
+                if e.primitive.name == "gather"
+                and max_len in e.invars[0].aval.shape[1:]]
+    assert [(e.invars[0].aval.shape, str(e.invars[0].aval.dtype))
+            for e, _ in with_len] == [((windows, beam, max_len), "int32")]
+    assert "asr.beam_ancestry" in with_len[0][1]

@@ -530,8 +530,8 @@ def test_summarize_reads_the_capture_recorded_on_the_chip(tmp_path):
     assert sum(got["by_scope"].values()) == pytest.approx(got["busy_s"])
     beam = got["programs"]["jit__generate_beam_jit"]
     assert beam["runs"] == 1
-    assert {"asr.beam_reorder", "asr.beam_select", "asr.token_rules",
-            "asr.decoder_step.self_attn", "asr.decoder_step.cross_attn",
+    assert {"asr.beam_reorder", "asr.beam_ancestry", "asr.beam_select",
+            "asr.token_rules", "asr.decoder_step.self_attn", "asr.decoder_step.cross_attn",
             "asr.decoder_step.mlp", "asr.decoder_step.logits",
             "asr.decoder_step.cache_update", "asr.encoder.attn",
             "asr.cross_kv", "asr.prompt", "asr.beam_final"} <= set(

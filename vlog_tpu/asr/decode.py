@@ -1,4 +1,4 @@
-"""Batched greedy decoding with Whisper's timestamp grammar.
+"""Batched beam-search and greedy decoding with Whisper's timestamp grammar.
 
 Faithful port of the generation *rules* the reference relies on through
 faster-whisper (beam/VAD pipeline, worker/transcription.py:92-133):
@@ -8,9 +8,11 @@ step. The loop itself is TPU-shaped: one ``lax.scan`` over steps with a
 static-shape KV cache, batched over 30 s windows so a long video decodes
 as a few large dispatches instead of thousands of small ones.
 
-Beam search is deliberately not the default: greedy+rules on batched
-windows keeps device utilization high; quality-sensitive callers can run
-fewer windows per batch with the teacher-forced scorer for rescoring.
+Beam search is the production default (``config.WHISPER_BEAM`` is 5, the
+reference's beam size); ``beam=1`` is the greedy scan. Neither program
+moves its self-attention K/V cache: every position is written once, in
+place, and the beam program keeps beam history as a small ancestry table
+that masks a per-window self-attention (``_generate_beam_jit``).
 """
 
 from __future__ import annotations
@@ -52,7 +54,12 @@ class KVCachePool:
     recur); a leased page may hold stale K/V from a previous job, which
     is BYTE-SAFE because ``decoder_step`` masks attention to positions
     <= pos and every such position is freshly written during this
-    generation's prefill/scan — dirty tail rows are unreachable.
+    generation's prefill/scan — dirty tail rows are unreachable. The
+    beam program's ancestry mask is narrower still: it admits only
+    (slot, position) pairs its table names, every slot writes its own
+    entry at every position <= pos in this generation, and the table
+    starts fresh in each call, so nothing a previous tenant left (its
+    entries or its beam order) can be reached.
     """
 
     _MAX_PAGES = 8          # retained pages across all shapes
@@ -231,10 +238,19 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
                        timestamps: bool, beam: int):
     """Batched beam search over B windows x K beams (flattened to B*K
     cache rows). One ``lax.scan`` over steps; each step scores all K*V
-    continuations per window, takes the global top-K, and gathers the KV
-    cache rows of the winning parents. Finished beams persist with
-    frozen scores (only EOT continues, at zero cost). Selection
-    normalizes by generated length (CTranslate2's length_penalty=1)."""
+    continuations per window and takes the global top-K. The self-K/V
+    cache is never reordered: row ``w*K + s`` is SLOT ``s`` of window
+    ``w``, a slot writes its one new position in place each step and the
+    entry stays where it was written. Which entries a beam may see is
+    the ancestry table ``anc`` int32 (B, K, max_len): ``anc[w, q, t]`` is
+    the slot that holds position ``t`` of beam ``q``'s history. After the
+    top-K it is gathered by parent (B*K*max_len int32, not the cache)
+    and ``anc[:, q, pos] = q``; ``decoder_step`` attends per window over
+    the K slots under that table's mask. The K rows of one window thus
+    share their cache slots; windows share nothing. Finished beams
+    persist with frozen scores (only EOT continues, at zero cost).
+    Selection normalizes by generated length (CTranslate2's
+    length_penalty=1)."""
     enc = encode(params, mel, cfg)
     ckv = cross_kv(params, enc, cfg)
     b = mel.shape[0]
@@ -263,8 +279,15 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
         [jnp.zeros((1,), jnp.float32),
          jnp.full((k - 1,), neg, jnp.float32)]), (b,))          # (bk,)
 
+    # ancestry: after the prompt every slot holds its own (identical)
+    # entries, so a beam's history starts as its own slot throughout
+    own_slot = jnp.broadcast_to(
+        jnp.arange(k, dtype=jnp.int32)[None, :, None], (b, k, 1))
+    anc0 = jnp.broadcast_to(own_slot, (b, k, cache.k.shape[3]))
+
     def step(carry, step_idx):
-        cache, logits, scores, seqs, last, penult, last_ts, finished = carry
+        (cache, anc, logits, scores, seqs, last, penult, last_ts,
+         finished) = carry
         with jax.named_scope("asr.token_rules"):
             lg = logits + suppress_vec
             lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
@@ -296,22 +319,25 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
             last = token
             last_ts = jnp.where(token >= ts_begin, token, take(last_ts))
             finished = take(finished) | (token == eot)
-            cache = DecoderCache(
-                k=jnp.take(cache.k, gparent, axis=1),
-                v=jnp.take(cache.v, gparent, axis=1))
-        nxt_logits, cache = decoder_step(
-            params, token, (plen + step_idx).astype(jnp.int32), cache,
-            ckv, cfg)
-        return ((cache, nxt_logits, scores, seqs, last, penult, last_ts,
-                 finished), finished)
+        pos = (plen + step_idx).astype(jnp.int32)
+        with jax.named_scope("asr.beam_ancestry"):
+            # a beam inherits its parent's history, then owns position
+            # ``pos``, which it is about to write into its own slot
+            anc = jnp.take_along_axis(anc, parent[:, :, None], axis=1)
+            anc = jax.lax.dynamic_update_slice(
+                anc, own_slot, (0, 0, pos))
+        nxt_logits, cache = decoder_step(params, token, pos, cache, ckv,
+                                         cfg, anc)
+        return ((cache, anc, nxt_logits, scores, seqs, last, penult,
+                 last_ts, finished), finished)
 
     seqs0 = jnp.full((bk, max_new), eot, jnp.int32)
-    init = (cache, logits, scores0, seqs0,
+    init = (cache, anc0, logits, scores0, seqs0,
             jnp.full((bk,), prompt[-1], jnp.int32),
             jnp.full((bk,), prompt[-2] if plen >= 2 else sot, jnp.int32),
             jnp.full((bk,), ts_begin - 1, jnp.int32),
             jnp.zeros((bk,), bool))
-    (cache, logits, scores, seqs, *_rest), fin_hist = jax.lax.scan(
+    (cache, _anc, logits, scores, seqs, *_rest), fin_hist = jax.lax.scan(
         step, init, jnp.arange(max_new))
     finished = _rest[-1]
 
@@ -337,10 +363,14 @@ def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
     with length-normalized selection (config.WHISPER_BEAM wires the
     production default; the reference runs beam-5).
 
-    Row independence is a load-bearing contract: no op here crosses
-    batch rows (per-row conv/attention/argmax, one shared prompt), so
-    row i's tokens never depend on rows j != i — zero-padded rows and
-    co-batched jobs cannot perturb a window's output. The continuous-
+    Window independence is a load-bearing contract: no op here crosses
+    windows (per-row conv/argmax, one shared prompt, attention and the
+    beam top-K inside one window), so window i's tokens never depend on
+    windows j != i — zero-padded rows and co-batched jobs cannot perturb
+    a window's output. At ``beam=1`` a window is one row; at ``beam>1``
+    the K beam rows of ONE window share that window's K self-K/V cache
+    slots (each row reads its history through the ancestry table's
+    mask), and nothing is shared between windows. The continuous-
     batching engine (asr/engine.py) builds its byte-identical
     solo-vs-packed guarantee on this; tests/test_asr_engine.py breaks
     if it regresses. One shared prompt per call also means callers may

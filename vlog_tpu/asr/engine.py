@@ -14,9 +14,11 @@ drain — the continuous-batching core.
 Determinism contract (the packing-invariance guarantee): a job's cues are
 a pure function of its own windows. Every forward runs at one of a fixed
 set of bucket shapes, zero-padded rows fill the remainder, and the
-Whisper forward has no cross-row ops (per-row conv, per-position
-layernorm, within-row attention) — so row i's tokens do not depend on
-rows j != i. Verified empirically across bucket sizes and mesh sharding
+Whisper forward has no cross-window ops (per-row conv, per-position
+layernorm, attention within a window: under beam search the K beam
+rows of ONE window attend over that window's K cache slots through an
+ancestry mask, asr/decode.py) — so window i's tokens do not depend on
+windows j != i. Verified empirically across bucket sizes and mesh sharding
 before this design was locked in; ``tests/test_asr_engine.py`` asserts
 byte-identical ``captions.vtt`` solo vs. packed with N other jobs.
 

@@ -240,14 +240,48 @@ class DecoderCache:
 jax.tree_util.register_dataclass(DecoderCache, ["k", "v"], [])
 
 
+def _beam_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    anc: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
+    """Self-attention of W windows x K beams over a cache nobody
+    reorders: q (W*K,H,1,hd) pre-scaled, k/v (W*K,H,T,hd), ``anc``
+    (W,K,T) with ``anc[w, q, t]`` the slot of window ``w`` that holds
+    position ``t`` of beam ``q``'s history, ``valid`` (T,) the written
+    positions.
+
+    Every query is scored against all K slots of its OWN window and the
+    mask keeps, per valid position, the one slot its ancestry names; the
+    softmax runs over the flattened (slot, position) axis, so the sum is
+    over the same entries a per-row attention over a gathered cache
+    sees. The contraction never leaves a window."""
+    w, kb, t = anc.shape
+    _, h, _, hd = q.shape
+    q = q.reshape(w, kb, h, hd)
+    k = k.reshape(w, kb, h, t, hd)
+    v = v.reshape(w, kb, h, t, hd)
+    scores = jnp.einsum("bqhd,bshtd->bhqst", q, k)
+    mask = ((anc[:, :, None, :] == jnp.arange(kb)[None, None, :, None])
+            & valid)[:, None]                           # (W,1,Kq,Ks,T)
+    scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores.reshape(w, h, kb, kb * t), axis=-1)
+    out = jnp.einsum("bhqst,bshtd->bqhd", probs.reshape(scores.shape), v)
+    return out.reshape(w * kb, h, 1, hd)
+
+
 @jax.named_scope("asr.decoder_step")
 def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
-                 cache: DecoderCache, ckv, cfg: WhisperConfig
+                 cache: DecoderCache, ckv, cfg: WhisperConfig,
+                 anc: jnp.ndarray | None = None
                  ) -> tuple[jnp.ndarray, DecoderCache]:
     """One decode step: (B,) tokens at position ``pos`` -> (B, V) logits.
 
-    XLA-friendly: every shape is static; the cache updates via
-    dynamic_update_slice at ``pos`` and attention masks positions > pos.
+    XLA-friendly: every shape is static; each layer writes its ONE new
+    K/V position into the stacked (layers, B, H, max_len, hd) arrays in
+    place (a dynamic_update_slice at ``[i, :, :, pos, :]``; nothing is
+    sliced out, updated and restacked) and attention masks positions >
+    pos. With ``anc`` (W, K, max_len), B is W windows x K beams and a
+    row attends through its ancestry table over its window's K cache
+    slots (:func:`_beam_attention`); without it each row attends over
+    its own cache row.
     """
     p = params
     nh = cfg.decoder_attention_heads
@@ -255,9 +289,8 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     max_len = cache.k.shape[3]
     x = (p["model.decoder.embed_tokens.weight"][tokens]
          + p["model.decoder.embed_positions.weight"][pos])[:, None, :]
-    new_k, new_v = [], []
-    # valid-position mask over the cache: (1,1,1,max_len)
-    mask = (jnp.arange(max_len) <= pos)[None, None, None, :]
+    ck, cv = cache.k, cache.v
+    valid = jnp.arange(max_len) <= pos      # written positions, (max_len,)
     for i in range(cfg.decoder_layers):
         n = f"model.decoder.layers.{i}"
         with jax.named_scope("asr.decoder_step.self_attn"):
@@ -266,14 +299,14 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
             k1 = _split_heads(_linear(p, f"{n}.self_attn.k_proj", h), nh)
             v1 = _split_heads(_linear(p, f"{n}.self_attn.v_proj", h), nh)
         with jax.named_scope("asr.decoder_step.cache_update"):
-            ki = jax.lax.dynamic_update_slice_in_dim(
-                cache.k[i], k1, pos, axis=2)
-            vi = jax.lax.dynamic_update_slice_in_dim(
-                cache.v[i], v1, pos, axis=2)
-            new_k.append(ki)
-            new_v.append(vi)
+            ck = jax.lax.dynamic_update_slice(
+                ck, k1[None], (i, 0, 0, pos, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cv, v1[None], (i, 0, 0, pos, 0))
         with jax.named_scope("asr.decoder_step.self_attn"):
-            att = _attention(_split_heads(q, nh), ki, vi, mask)
+            qh = _split_heads(q, nh)
+            att = (_attention(qh, ck[i], cv[i], valid) if anc is None
+                   else _beam_attention(qh, ck[i], cv[i], anc, valid))
             x = x + _linear(p, f"{n}.self_attn.out_proj", _merge_heads(att))
         with jax.named_scope("asr.decoder_step.cross_attn"):
             h = _layer_norm(p, f"{n}.encoder_attn_layer_norm", x)
@@ -285,9 +318,7 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     with jax.named_scope("asr.decoder_step.logits"):
         x = _layer_norm(p, "model.decoder.layer_norm", x)
         logits = (x @ p["model.decoder.embed_tokens.weight"].T)[:, 0, :]
-    with jax.named_scope("asr.decoder_step.cache_update"):
-        cache = DecoderCache(k=jnp.stack(new_k), v=jnp.stack(new_v))
-    return logits, cache
+    return logits, DecoderCache(k=ck, v=cv)
 
 
 def random_state_dict(cfg: WhisperConfig, seed: int = 0
