@@ -1,0 +1,197 @@
+"""Shared by the transcript model's tests: the tiny afmoe model, its
+engine and the comparison with the plain reference."""
+
+import json
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT / "benchmark") not in sys.path:
+    sys.path.insert(0, str(ROOT / "benchmark"))
+
+from reference import afmoe_ref as ref  # noqa: E402
+
+from vlog_tpu.lm.engine import LmEngine  # noqa: E402
+from vlog_tpu.lm.load import (EXPERT_NAMES, HF_NAMES,  # noqa: E402
+                              LmAssets, layer_leaves)
+from vlog_tpu.lm.model import BF16, F32, Geometry, LmConfig  # noqa: E402
+
+INIT_STD = 0.02
+BIAS_STD = 0.01
+
+# stated tolerance of the tiny comparison: bfloat16 products at hidden 64
+# read up to 0.09 of the logits' spread (measured over the lengths
+# below); a mechanism left out reads 0.5 and more
+LOGIT_TOL = 0.2
+# a router margin under this can fall either way on bfloat16 rounding
+ROUTE_EPS = 0.004
+
+
+class ByteTokenizer:
+    """UTF-8 bytes as ids: what ``write_tokenizer``'s file does."""
+
+    def encode(self, text: str) -> list[int]:
+        return list(text.encode("utf-8"))
+
+    def decode(self, ids) -> str:
+        return bytes(i for i in ids if 0 <= i < 256).decode(
+            "utf-8", errors="replace")
+
+
+def tiny_hf_config(**over) -> dict:
+    """A CPU-sized ``afmoe`` config of the published form: 2 dense + 4
+    expert layers, one whole period after the dense ones."""
+    cfg = {"model_type": "afmoe", "hidden_size": 64, "head_dim": 16,
+           "num_attention_heads": 8, "num_key_value_heads": 2,
+           "num_hidden_layers": 6, "num_dense_layers": 2,
+           "layer_types": [("full_attention" if i % 4 == 3
+                            else "sliding_attention") for i in range(6)],
+           "intermediate_size": 96, "moe_intermediate_size": 32,
+           "num_experts": 8, "num_experts_per_tok": 2,
+           "num_shared_experts": 1, "route_norm": True,
+           "route_scale": 2.826, "score_func": "sigmoid", "n_group": 1,
+           "topk_group": 1, "sliding_window": 16, "vocab_size": 512,
+           "rms_norm_eps": 1e-5, "rope_theta": 10000, "mup_enabled": True,
+           "tie_word_embeddings": False}
+    cfg.update(over)
+    return cfg
+
+
+def random_params(cfg: LmConfig, seed: int) -> dict:
+    """Seeded weights: matrices N(0, 0.02^2), norm weights 1, the
+    selection bias N(0, 0.01^2)."""
+    key = jax.random.PRNGKey(int(seed) % (2**31 - 1))
+    count = [0]
+
+    def draw(shape, kind):
+        count[0] += 1
+        k = jax.random.fold_in(key, count[0])
+        if kind == "ones":
+            return jnp.ones(shape, BF16)
+        if kind == "bias":
+            return jax.random.normal(k, shape, F32) * BIAS_STD
+        return (jax.random.normal(k, shape, F32) * INIT_STD).astype(BF16)
+
+    v, h = cfg.vocab_size, cfg.hidden_size
+    return {"embed": draw((v, h), "normal"), "head": draw((h, v), "normal"),
+            "final_norm": draw((h,), "ones"),
+            "layers": [{name: draw(shape, kind)
+                        for name, shape, kind in layer_leaves(cfg, li)}
+                       for li in range(cfg.num_layers)]}
+
+
+def to_state_dict(params: dict) -> dict:
+    """The program's layout under the published names, torch layouts."""
+    sd = {"model.embed_tokens.weight": params["embed"],
+          "lm_head.weight": params["head"].T,
+          "model.norm.weight": params["final_norm"]}
+    for li, lp in enumerate(params["layers"]):
+        base = f"model.layers.{li}."
+        for name, leaf in lp.items():
+            if name in EXPERT_NAMES:
+                for e in range(leaf.shape[0]):
+                    sd[f"{base}mlp.experts.{e}.{EXPERT_NAMES[name]}"
+                       ".weight"] = leaf[e].T
+            else:
+                sd[base + HF_NAMES[name]] = leaf.T if leaf.ndim == 2 else leaf
+    return sd
+
+
+def _byte_chars() -> list[str]:
+    """The character that stands for each byte in a ByteLevel
+    vocabulary (GPT-2's table: printable bytes are themselves, the rest
+    follow 255), in byte order."""
+    keep = [*range(33, 127), *range(161, 173), *range(174, 256)]
+    rest = [b for b in range(256) if b not in keep]
+    table = {b: chr(b) for b in keep}
+    table.update({b: chr(256 + i) for i, b in enumerate(rest)})
+    return [table[b] for b in range(256)]
+
+
+def write_tokenizer(path: Path) -> None:
+    """A ``tokenizer.json`` whose ids are UTF-8 bytes (0..255)."""
+    from tokenizers import Tokenizer, decoders, models, pre_tokenizers
+
+    tok = Tokenizer(models.BPE(
+        vocab={ch: b for b, ch in enumerate(_byte_chars())}, merges=[]))
+    tok.pre_tokenizer = pre_tokenizers.ByteLevel(add_prefix_space=False,
+                                                 use_regex=False)
+    tok.decoder = decoders.ByteLevel()
+    tok.save(str(path))
+
+
+def save_model_dir(path, hf_config: dict, params: dict, *,
+                   shards: int = 1) -> Path:
+    """A model directory as ``lm/load.py`` reads it: ``config.json``,
+    ``tokenizer.json`` and the weights in one file or, with ``shards``
+    over 1, in that many beside an index."""
+    from safetensors.flax import save_file
+
+    path = Path(path)
+    path.mkdir(parents=True, exist_ok=True)
+    (path / "config.json").write_text(json.dumps(hf_config, indent=1))
+    write_tokenizer(path / "tokenizer.json")
+    sd = to_state_dict(params)
+    if shards == 1:
+        save_file(sd, str(path / "model.safetensors"))
+        return path
+    names = sorted(sd)
+    weight_map = {}
+    for i in range(shards):
+        file = f"model-{i + 1:05d}-of-{shards:05d}.safetensors"
+        part = {n: sd[n] for n in names[i::shards]}
+        save_file(part, str(path / file))
+        weight_map.update(dict.fromkeys(part, file))
+    (path / "model.safetensors.index.json").write_text(
+        json.dumps({"weight_map": weight_map}))
+    return path
+
+
+def tiny(seed=7, bias_scale=20.0, router_scale=10.0, **over):
+    hf = tiny_hf_config(**over)
+    cfg = LmConfig.from_hf(hf)
+    params = random_params(cfg, seed)
+    for lp in params["layers"]:
+        if "bias" in lp:            # large enough to change choices
+            lp["bias"] = lp["bias"] * bias_scale
+            # scores spread over (0, 1), so that the chosen's sum (what
+            # route_norm divides by) differs from token to token
+            lp["router"] = lp["router"] * router_scale
+    return hf, cfg, params
+
+
+def geometry(cfg, rows=4, chunk=8, page=4, cap=128, block=2):
+    base = Geometry(rows=rows, chunk=chunk, page=page, context_cap=cap)
+    return Geometry(rows=rows, chunk=chunk, page=page, context_cap=cap,
+                    kv_block_pages=block,
+                    window_pages=rows * base.ring(cfg.sliding_window) + 1,
+                    full_pages=rows * base.max_pages + 1)
+
+
+def engine(cfg, params, **geo):
+    eng = LmEngine(LmAssets(cfg, params, None, "tiny"),
+                   geometry=geometry(cfg, **geo))
+    eng.prepare(300)
+    return eng
+
+
+def compare(req, hf, params, off=()):
+    """Largest logit error (over the reference's spread) of a finished
+    request's captured steps whose router margins stand, and the rank
+    gaps of its tokens."""
+    toks = req.tokens
+    full = np.concatenate([req.prompt, toks[:-1]]).astype(np.int32)
+    steps = sorted(req.logits)
+    out = ref.forward(params, hf, full,
+                      [req.prompt.size - 1 + i for i in steps], off=off)
+    errs, gaps = [], []
+    for row, i in enumerate(steps):
+        if out["route_gap"][row] < ROUTE_EPS:
+            continue
+        errs.append(ref.logit_error(req.logits[i], out["logits"][row]))
+        gaps.append(ref.rank_gap(toks[i], out["logits"][row]))
+    return errs, gaps

@@ -572,6 +572,11 @@ def get_engine(model_dir: str, *, scheduler=None) -> AsrEngine:
         _ENGINE_KEY = None
     if old is not None:
         old.close()
+    # one model engine is resident at a time: an idle transcript engine
+    # (lm/engine.py) gives the chip's memory back first
+    from vlog_tpu.lm import residency
+
+    residency.make_room("asr")
     ensure_compile_cache()
     assets = load_whisper(model_dir, quant)
     engine = AsrEngine(assets, scheduler=scheduler)

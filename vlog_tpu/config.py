@@ -543,6 +543,9 @@ WHISPER_OVERLAP_S: float = 5.0      # chunk overlap for stitching
 # (worker/transcription.py:92-133); 1 = the cheaper greedy scan.
 WHISPER_BEAM: int = _env_int("VLOG_WHISPER_BEAM", 5, lo=1, hi=16)
 TRANSCRIPTION_ENABLED: bool = _env_bool("VLOG_TRANSCRIPTION_ENABLED", True)
+# Transcript model directory (config.json + model.safetensors, lm/load.py).
+# Empty = off: no digest job is enqueued after a transcription.
+DIGEST_DIR: str = _env_str("VLOG_DIGEST_DIR", "")
 
 # Continuous-batching ASR engine (asr/engine.py): one shared Whisper
 # serving every transcription job on the worker.

@@ -22,6 +22,7 @@ class JobKind(str, enum.Enum):
     REENCODE = "reencode"
     SPRITE = "sprite"
     TRANSCRIPTION = "transcription"
+    DIGEST = "digest"
 
 
 class JobState(str, enum.Enum):

@@ -102,6 +102,24 @@ async def finalize_transcription(
     # captions.vtt just changed under the slug: evict any cached copy
     # (transcode publish invalidates via vids.finalize_ready already)
     await vids.invalidate_delivery(db, video_id)
+    if config.DIGEST_DIR and vtt_path:
+        # chapters and a summary from the captions, on a worker that
+        # holds the transcript model; the tenant is the transcription's
+        row = await db.fetch_one(
+            "SELECT tenant FROM jobs WHERE video_id=:v AND kind=:k",
+            {"v": video_id, "k": JobKind.TRANSCRIPTION.value})
+        tenant = (row["tenant"] if row else None) or qos.DEFAULT_TENANT
+        await claims.enqueue_job(db, video_id, JobKind.DIGEST,
+                                 tenant=tenant, admit=False)
+
+
+async def finalize_digest(db: Database, video_id: int, *,
+                          paths: list[str]) -> None:
+    """Publish ``chapters.vtt`` and ``digest.json`` through the
+    manifest-verified path, as the captions are."""
+    for p in paths:
+        await asyncio.to_thread(_publish_caption_manifest, p)
+    await vids.invalidate_delivery(db, video_id)
 
 
 def _publish_caption_manifest(vtt_path: str) -> None:

@@ -1,0 +1,617 @@
+"""The step engine: one resident transcript model serving every digest
+job, batching at the step.
+
+A thread of its own (``vlog-lm-engine``) runs steps under a
+``MeshScheduler`` lease held the way ``AsrEngine`` holds its own
+(acquired when there is work, given back when the engine drains or other
+demand queues). Every step takes ALL resident decoding rows (one token
+each, at most ``rows``) and at most ONE prefill chunk (at most ``chunk``
+tokens) of one waiting request; a request's row joins the decoding rows
+when its last chunk is done and leaves at its last token. Decoding is
+greedy and the next token goes back in on the device
+(``model.py::build_step``). Shapes are bucketed (the chunk: 0, page,
+2 page, ... chunk; the rows: always ``rows``) and :meth:`LmEngine.prepare`
+builds and runs every one of them, so nothing compiles once requests
+flow.
+
+The host runs one step ahead of the device: step ``n`` is planned and
+dispatched from what the host already knows (lengths and counts, never
+token values) before step ``n - 1``'s tokens are pulled, so the device
+goes from one step into the next while the host delivers. A request that
+ends early (``eos_id``) is noticed at delivery and leaves at the next
+plan; what the step in flight computed for it is dropped.
+
+The engine traces itself as ``AsrEngine`` does: one span tree per step,
+``lm.step`` with the children ``lm.step.admit`` (new requests in, the
+lease), ``lm.step.pages`` (page tables grown, window pages that fell
+behind the band given back), ``lm.step.stack`` (the plan's arrays),
+``lm.step.dispatch``, ``lm.step.device_wait`` (the pull of the step
+before) and ``lm.step.deliver``. The spans are folded into **the step
+record** (``step_log``): ``seq``, ``t_start``, ``t_dispatch`` (the call
+returned: the device has the program), ``t_ready`` (its tokens are on
+the host), ``t_end`` (delivered), ``phase_s``, ``gap_s`` (``t_dispatch``
+minus the end of the iteration before: the host's share of a step),
+``step_s`` (``t_ready`` minus the later of the step before's ``t_ready``
+and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
+``row_pos`` (the rows' positions), ``chunk`` (the bucket), ``context``
+(the chunk's first position),
+``chunk_tag``, ``emitted`` (tags of the requests that got a token),
+``pages_in_use`` by class, ``pages_freed``, ``expert_load`` and
+``window_pages`` (from the device, read out with the tokens),
+``build_s``; all instants on ``time.monotonic()``.
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from collections import deque
+
+import numpy as np
+
+from vlog_tpu.lm.cache import PagedCache, SeqPages
+from vlog_tpu.lm.load import LmAssets
+from vlog_tpu.lm.model import (Geometry, build_step, empty_cache,
+                               plan_shapes, unpack_ints)
+from vlog_tpu.obs import trace
+from vlog_tpu.parallel import compile_cache
+
+PHASES = ("admit", "pages", "stack", "dispatch", "device_wait", "deliver")
+THREAD = "vlog-lm-engine"
+
+
+class LmJobError(RuntimeError):
+    """The step that carried this request failed, or it can never fit."""
+
+
+class LmRequest:
+    """One prompt in the engine. ``wait()`` for the tokens."""
+
+    def __init__(self, tag: str, prompt: np.ndarray, max_new: int,
+                 eos_id: int | None, capture: tuple[int, ...]):
+        self.tag = tag
+        self.prompt = np.ascontiguousarray(prompt, np.int32)
+        self.max_new = int(max_new)
+        self.eos_id = eos_id
+        # output steps whose logits to keep (0 = the prompt's last
+        # position's; negative counts from the last token)
+        self.capture = {c if c >= 0 else self.max_new + c for c in capture}
+        self.tokens: list[int] = []
+        self.logits: dict[int, np.ndarray] = {}
+        self.error: BaseException | None = None
+        self.stats: dict = {"t_submit": time.monotonic()}
+        self._done = threading.Event()
+        # engine thread only
+        self.pages: SeqPages | None = None
+        self.row = -1
+        self.prefilled = 0          # prompt tokens planned so far
+        self.planned = 0            # output tokens planned so far
+        self.ended = False          # eos seen, or failed
+
+    def wait(self, timeout: float | None = None) -> list[int]:
+        if not self._done.wait(timeout):
+            raise TimeoutError(f"request {self.tag} is not done")
+        if self.error is not None:
+            raise LmJobError(str(self.error)) from self.error
+        return self.tokens
+
+    def done(self) -> bool:
+        return self._done.is_set()
+
+
+class LmEngine:
+    def __init__(self, assets: LmAssets, *, scheduler=None,
+                 geometry: Geometry | None = None):
+        self.assets = assets
+        self.cfg = assets.cfg
+        self.scheduler = scheduler
+        self.geo = geometry or default_geometry(assets.cfg)
+        self.geo.check(self.cfg)
+        self._lock = threading.Condition()          # lock-order: 24
+        self._inbox: deque[LmRequest] = deque()     # guarded-by: _lock
+        self._started = False                       # guarded-by: _lock
+        self.step_log: list[dict] = []              # guarded-by: _lock
+        self.requests_done = 0                      # guarded-by: _lock
+        self._stop = threading.Event()
+        self._ready = threading.Event()
+        self._failed: BaseException | None = None
+        self._thread: threading.Thread | None = None
+        self._lease_held = threading.Event()
+        self._trace = trace.TraceContext(trace.new_id(), None,
+                                         trace.TraceBuffer())
+        self.programs: dict[int, object] = {}       # bucket -> compiled
+        # engine thread only
+        self._cache: PagedCache | None = None
+        self._kv = None
+        self._last_tok = None
+        self._waiting: deque[LmRequest] = deque()
+        self._prefilling: LmRequest | None = None
+        self._rows: list[LmRequest | None] = [None] * self.geo.rows
+        self._flight: tuple | None = None           # (record, out)
+        self._ticket = None
+        self._lease = None
+        self._seq = 0
+        self._prev_end: float | None = None
+        self._prev_ready: float | None = None
+
+    # callers ----------------------------------------------------------
+
+    def submit(self, prompt, *, max_new: int, tag: str | None = None,
+               eos_id: int | None = None,
+               capture: tuple[int, ...] = ()) -> LmRequest:
+        """Queue one prompt (token ids); returns at once."""
+        req = LmRequest(tag or trace.new_id(), prompt, max_new, eos_id,
+                        capture)
+        if req.max_new < 1 or req.prompt.size < 1:
+            raise ValueError("a request needs a prompt and max_new >= 1")
+        with self._lock:
+            if self._stop.is_set():
+                raise LmJobError("the engine is closed")
+            self._start_locked()
+            self._inbox.append(req)
+            self._lock.notify_all()
+        return req
+
+    def prepare(self, timeout: float | None = None) -> None:
+        """Build and run every step shape (blocks until done)."""
+        with self._lock:
+            self._start_locked()
+        if not self._ready.wait(timeout):
+            raise TimeoutError("the engine is still building")
+        if self._failed is not None:
+            raise LmJobError(f"engine build failed: {self._failed}") \
+                from self._failed
+
+    def program_scopes(self) -> dict[str, dict[str, str]]:
+        """``{compiled program, as a device trace names it: {HLO
+        instruction: named scope}}`` of the step programs, from their
+        optimized HLO (``obs/profiler.py::hlo_scopes``): what a reader of
+        a capture taken WITHOUT the HLO protos needs to book a device op
+        to ``lm.attn.full`` or ``lm.moe.experts``. XLA's TPU lowering of
+        ``ragged_dot`` names its kernels ``ragged-dot-*`` and drops the
+        framework name; they are the grouped expert products."""
+        from vlog_tpu.obs.profiler import hlo_scopes
+
+        out = {}
+        for chunk, program in self.programs.items():
+            text = program.as_text()
+            scopes = hlo_scopes(text)
+            for line in text.splitlines():
+                name = line.strip().lstrip("%").split(" = ", 1)[0]
+                if name.startswith("ragged-dot") and " = " in line:
+                    scopes[name.removeprefix("ROOT ").lstrip("%")] = \
+                        "lm.moe.experts"
+            out[f"jit_lm_step_c{chunk}"] = scopes
+        return out
+
+    def active(self) -> bool:
+        """Serving (lease held, requests queued or resident)?"""
+        with self._lock:
+            queued = bool(self._inbox)
+        return queued or self._lease_held.is_set() or self._busy()
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {"steps": len(self.step_log),
+                    "requests_done": self.requests_done,
+                    "pending": len(self._inbox),
+                    "pages_in_use": self._cache.in_use()
+                    if self._cache else {"window": 0, "full": 0}}
+
+    def close(self) -> None:
+        self._stop.set()
+        with self._lock:
+            self._lock.notify_all()
+        t = self._thread
+        if t is not None and t.is_alive():
+            t.join(timeout=60)
+        self._kv = self._last_tok = None
+        self.programs = {}
+
+    def _start_locked(self) -> None:
+        if self._started:
+            return
+        self._started = True
+
+        def serve():
+            with trace.attach(self._trace):
+                self._run()
+
+        self._thread = threading.Thread(target=serve, name=THREAD,
+                                        daemon=True)
+        self._thread.start()
+
+    # the engine's thread ---------------------------------------------
+
+    def _run(self) -> None:
+        try:
+            self._build()
+        except BaseException as exc:  # noqa: BLE001 — surfaced by prepare
+            self._failed = exc
+            self._ready.set()
+            self._fail_all(exc)
+            return
+        self._ready.set()
+        try:
+            while not self._stop.is_set():
+                self._cycle()
+        finally:
+            self._fail_all(LmJobError("the engine closed"))
+            self._release()
+            self._trace.buffer.drain()
+
+    def _build(self) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        compile_cache.ensure_compile_cache()
+        cfg, geo = self.cfg, self.geo
+        self._cache = PagedCache(cfg, geo)
+        self._kv = empty_cache(cfg, geo)
+        self._last_tok = jnp.zeros((geo.rows,), jnp.int32)
+        for chunk in geo.chunk_buckets():
+            plan = {k: jax.ShapeDtypeStruct(s, d)
+                    for k, (s, d) in plan_shapes(cfg, geo, chunk).items()}
+            fn = jax.jit(build_step(cfg, geo, chunk), donate_argnums=(1, 2))
+            self.programs[chunk] = fn.lower(
+                self.assets.params, self._kv, self._last_tok, plan).compile()
+        for chunk, program in self.programs.items():
+            plan = {k: np.zeros(s, d)
+                    for k, (s, d) in plan_shapes(cfg, geo, chunk).items()}
+            if chunk:
+                plan["chunk_meta"][2] = -1
+            self._kv, self._last_tok, out = program(
+                self.assets.params, self._kv, self._last_tok, plan)
+            np.asarray(out["ints"])
+
+    def _busy(self) -> bool:
+        return (self._prefilling is not None or bool(self._waiting)
+                or self._flight is not None
+                or any(r is not None for r in self._rows))
+
+    @staticmethod
+    def _built() -> float:
+        return compile_cache.build_total(
+            compile_cache.thread_build_seconds())
+
+    def _release(self) -> None:
+        if self._ticket is not None:
+            self._ticket.close()
+        self._ticket = None
+        self._lease = None
+        self._lease_held.clear()
+
+    def _acquire(self) -> bool:
+        if self.scheduler is None or self._lease is not None:
+            return True
+        from vlog_tpu.parallel.scheduler import SlotCancelled
+
+        self._ticket = self.scheduler.admit()
+        try:
+            self._lease = self._ticket.acquire(cancel=self._stop)
+        except SlotCancelled:
+            self._release()
+            return False
+        self._lease_held.set()
+        return True
+
+    def _cycle(self) -> None:
+        """One iteration: plan and dispatch step ``n``, then pull and
+        deliver step ``n - 1``."""
+        built0 = self._built()
+        record = None
+        with trace.span("lm.step") as top:
+            with trace.span("lm.step.admit"):
+                with self._lock:
+                    if not self._inbox and not self._busy():
+                        self._lock.wait(0.2)
+                    while self._inbox:
+                        self._waiting.append(self._inbox.popleft())
+                if not self._busy():
+                    self._release()         # idle: give the slot back
+                    self._prev_end = self._prev_ready = None
+                    self._trace.buffer.drain()
+                    return
+                if not self._acquire():
+                    return
+            try:
+                step = self._plan()
+                if step is not None:
+                    record, plan = step
+                    with trace.span("lm.step.dispatch"):
+                        program = self.programs[record["chunk"]]
+                        self._kv, self._last_tok, out = program(
+                            self.assets.params, self._kv, self._last_tok,
+                            plan)
+                    record["t_dispatch"] = time.monotonic()
+                    record["gap_s"] = (
+                        None if self._prev_end is None
+                        else record["t_dispatch"] - self._prev_end)
+                prev, self._flight = self._flight, (
+                    None if step is None else (record, out))
+                if prev is not None:
+                    self._deliver(*prev)
+            except Exception as exc:  # noqa: BLE001 — the engine survives
+                self._fail_all(exc)
+                self._reset_device_state()
+                return
+        spans = self._trace.buffer.drain()
+        now = time.monotonic()
+        self._prev_end = now
+        if record is not None:
+            record["build_s"] = self._built() - built0
+            record["t_start"] = top.started_mono
+            record["host_phase_s"] = self._phases(spans)
+        if prev is not None:
+            done = prev[0]
+            done["t_end"] = now
+            # the pull and the delivery of a step happen one iteration
+            # after its plan: fold them into the step they belong to
+            mine = self._phases(spans)
+            done["phase_s"] = {**done.pop("host_phase_s"),
+                               "device_wait": mine["device_wait"],
+                               "deliver": mine["deliver"]}
+            with self._lock:
+                self.step_log.append(done)
+            self._observe(done)
+        if self.scheduler is not None and self._lease is not None \
+                and self._lease.is_full_mesh \
+                and self.scheduler.snapshot()["pending"] > 0 \
+                and self._flight is None:
+            self._release()
+
+    @staticmethod
+    def _phases(spans) -> dict:
+        out = dict.fromkeys(PHASES, 0.0)
+        for sp in spans:
+            leaf = sp.name.rsplit(".", 1)[-1]
+            if leaf in out and sp.name.startswith("lm.step."):
+                out[leaf] += sp.duration_s
+        return out
+
+    # planning ----------------------------------------------------------
+
+    def _next_prefill(self) -> LmRequest | None:
+        """The request whose chunk this step carries: the one in prefill,
+        else the first waiting one that a free row and the pools can
+        hold to its end."""
+        if self._prefilling is not None:
+            return self._prefilling
+        while self._waiting:
+            req = self._waiting[0]
+            total = req.prompt.size + req.max_new
+            if not self._cache.fits_ever(total):
+                self._waiting.popleft()
+                self._finish(req, LmJobError(
+                    f"request {req.tag}: {total} positions exceed the "
+                    f"cache (cap {self._cache.context_cap})"))
+                continue
+            if None not in self._rows:
+                return None
+            pages = self._cache.admit(total)
+            if pages is None:
+                return None
+            self._waiting.popleft()
+            req.pages = pages
+            req.row = self._rows.index(None)
+            self._rows[req.row] = req           # reserved; decodes later
+            req.stats["t_admit"] = time.monotonic()
+            self._prefilling = req
+            return req
+        return None
+
+    def _plan(self):
+        """Decide step ``n`` from what the host knows; ``None`` when
+        there is nothing to run. Updates the requests' planned state."""
+        geo = self.geo
+        r = geo.rows
+        # a row whose request ended early or is complete leaves now
+        for req in self._rows:
+            if req is not None and req is not self._prefilling and (
+                    req.ended or req.planned >= req.max_new):
+                self._leave(req)
+        freed = 0
+        with trace.span("lm.step.pages"):
+            pre = self._next_prefill()
+            deco = [req for req in self._rows
+                    if req is not None and req is not self._prefilling]
+            if pre is None and not deco:
+                return None
+            n = bucket = 0
+            if pre is not None:
+                p0 = pre.prefilled
+                n = min(geo.chunk, pre.prompt.size - p0)
+                bucket = next(b for b in geo.chunk_buckets() if b >= n)
+                freed += pre.pages.trim(p0)
+                pre.pages.extend(p0 + n)
+            for req in deco:
+                pos = req.prompt.size + req.planned - 1
+                freed += req.pages.trim(pos)
+                req.pages.extend(pos + 1)
+        with trace.span("lm.step.stack"):
+            shapes = plan_shapes(self.cfg, geo, bucket)
+            plan = {k: np.zeros(s, d) for k, (s, d) in shapes.items()}
+            emitted, captures = [], []
+            for req in deco:
+                i = req.row
+                pos = req.prompt.size + req.planned - 1
+                wtab, wbase, ftab = req.pages.tables()
+                plan["row_active"][i] = True
+                plan["row_pos"][i] = pos
+                plan["row_wtab"][i], plan["row_wbase"][i] = wtab, wbase
+                plan["row_ftab"][i] = ftab
+                if req.planned in req.capture:
+                    captures.append((req, req.planned, i))
+                emitted.append((req, i))
+                req.planned += 1
+            last_chunk = False
+            if pre is not None:
+                last_chunk = p0 + n >= pre.prompt.size
+                wtab, wbase, ftab = pre.pages.tables()
+                plan["chunk_ids"][:n] = pre.prompt[p0:p0 + n]
+                plan["chunk_meta"][:] = (p0, n, pre.row if last_chunk
+                                         else -1, wbase)
+                plan["chunk_wtab"], plan["chunk_ftab"] = wtab, ftab
+                pre.prefilled += n
+                pre.stats.setdefault("t_first_chunk", time.monotonic())
+                pre.stats["prefill_steps"] = pre.stats.get(
+                    "prefill_steps", 0) + 1
+                if last_chunk:
+                    if 0 in pre.capture:
+                        captures.append((pre, 0, r))
+                    emitted.append((pre, r))
+                    pre.planned = 1
+                    self._prefilling = None
+        record = {"seq": self._seq, "chunk": bucket,
+                  "decode_rows": len(deco), "prefill_tokens": n,
+                  "row_pos": plan["row_pos"][plan["row_active"]].tolist(),
+                  "context": p0 if pre is not None else None,
+                  "chunk_tag": pre.tag if pre is not None else None,
+                  "emitted": [req.tag for req, _ in emitted],
+                  "pages_in_use": self._cache.in_use(),
+                  "pages_freed": freed,
+                  "_emitted": emitted, "_captures": captures}
+        self._seq += 1
+        return record, plan
+
+    def _leave(self, req: LmRequest) -> None:
+        if req.pages is not None:
+            req.stats["peak_window_pages"] = req.pages.peak_window_pages
+            req.pages.release()
+            req.pages = None
+        if req.row >= 0 and self._rows[req.row] is req:
+            self._rows[req.row] = None
+
+    # delivery ------------------------------------------------------------
+
+    def _deliver(self, record: dict, out: dict) -> None:
+        with trace.span("lm.step.device_wait"):
+            ints = unpack_ints(self.cfg, self.geo, np.asarray(out["ints"]))
+        ready = time.monotonic()
+        record["t_ready"] = ready
+        begun = record["t_dispatch"] if self._prev_ready is None \
+            else max(self._prev_ready, record["t_dispatch"])
+        record["step_s"] = ready - begun
+        self._prev_ready = ready
+        record["expert_load"] = ints["expert_load"].tolist()
+        record["window_pages"] = ints["pages"].tolist()
+        with trace.span("lm.step.deliver"):
+            captures = [c for c in record.pop("_captures") if not c[0].ended]
+            if captures:
+                # the whole array in one transfer of a buffer that is
+                # ready: a program that sliced a row on the device would
+                # queue behind the step already dispatched and hold the
+                # host back a whole step (device idle 23% with every
+                # step of six requests kept; my chip run, PR 29)
+                logits = np.asarray(out["logits"])
+                for req, index, row in captures:
+                    req.logits[index] = logits[row].copy()
+            for req, row in record.pop("_emitted"):
+                if req.ended:
+                    continue            # ended early: dropped
+                tok = int(ints["tokens"][row])
+                req.tokens.append(tok)
+                if len(req.tokens) == 1:
+                    req.stats["t_first_token"] = ready
+                if len(req.tokens) >= req.max_new or tok == req.eos_id:
+                    self._finish(req, None)
+
+    def _finish(self, req: LmRequest, error: BaseException | None) -> None:
+        if req.done():
+            return
+        req.ended = True
+        req.error = error
+        req.stats["t_done"] = time.monotonic()
+        if error is None:
+            with self._lock:
+                self.requests_done += 1
+        req._done.set()
+
+    def _fail_all(self, exc: BaseException) -> None:
+        with self._lock:
+            pending = list(self._inbox)
+            self._inbox.clear()
+        flight = self._flight[0]["_emitted"] if self._flight else []
+        for req in {*pending, *self._waiting, *(r for r, _ in flight),
+                    *(r for r in self._rows if r is not None)}:
+            self._finish(req, exc)
+        self._waiting.clear()
+        self._flight = None
+        self._prefilling = None
+        for req in list(self._rows):
+            if req is not None:
+                self._leave(req)
+
+    def _reset_device_state(self) -> None:
+        """After a failed step the donated buffers are gone: new ones."""
+        import jax.numpy as jnp
+
+        self._kv = empty_cache(self.cfg, self.geo)
+        self._last_tok = jnp.zeros((self.geo.rows,), jnp.int32)
+        self._prev_end = self._prev_ready = None
+
+    def _observe(self, record: dict) -> None:
+        try:
+            from vlog_tpu.obs.metrics import runtime
+
+            runtime().device_seconds.labels("lm", "step").inc(
+                record["phase_s"]["device_wait"])
+        except Exception:  # noqa: BLE001 — metrics never break serving
+            pass
+
+
+def default_geometry(cfg) -> Geometry:
+    """``Geometry``'s defaults (32 rows, chunks of 2048, pages of 256,
+    a context cap of 40,960) with the pools sized so that every row can
+    hold the context cap."""
+    base = Geometry()
+    return Geometry(window_pages=base.rows * base.ring(cfg.sliding_window)
+                    + 1,
+                    full_pages=base.rows * base.max_pages + 1)
+
+
+# Per-process engine singleton ------------------------------------------
+
+_ENGINE: LmEngine | None = None
+_ENGINE_KEY: tuple | None = None
+_ENGINE_LOCK = threading.Lock()
+# one build at a time: two digest jobs claimed together would otherwise
+# both load the weights, and two copies of 8.6 GB pass the chip's 16
+_BUILD_LOCK = threading.Lock()
+
+
+def get_engine(model_dir: str, *, scheduler=None) -> LmEngine:
+    """The process's transcript engine, (re)built when the model
+    directory or the scheduler changes. One model engine is resident on
+    a worker at a time: building this one first waits for an
+    ``AsrEngine`` to go idle and tears it down (``residency.py``)."""
+    from vlog_tpu.lm import residency
+    from vlog_tpu.lm.load import load_model_dir
+
+    global _ENGINE, _ENGINE_KEY
+    key = (str(model_dir), id(scheduler))
+    with _BUILD_LOCK:
+        with _ENGINE_LOCK:
+            if _ENGINE is not None and _ENGINE_KEY == key:
+                return _ENGINE
+            old, _ENGINE, _ENGINE_KEY = _ENGINE, None, None
+        if old is not None:
+            old.close()
+        residency.make_room("lm")
+        engine = LmEngine(load_model_dir(model_dir), scheduler=scheduler)
+        with _ENGINE_LOCK:
+            _ENGINE, _ENGINE_KEY = engine, key
+        return engine
+
+
+def peek_engine() -> LmEngine | None:
+    with _ENGINE_LOCK:
+        return _ENGINE
+
+
+def reset_engine() -> None:
+    global _ENGINE, _ENGINE_KEY
+    with _ENGINE_LOCK:
+        old, _ENGINE, _ENGINE_KEY = _ENGINE, None, None
+    if old is not None:
+        old.close()

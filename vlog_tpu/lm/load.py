@@ -1,0 +1,166 @@
+"""A transcript model on disk: ``config.json`` (the published ``afmoe``
+keys), the weights as safetensors (one ``model.safetensors``, or the
+shards that ``model.safetensors.index.json`` names; the published names,
+torch layouts) and ``tokenizer.json``. Nothing is fetched: the operator
+points ``VLOG_DIGEST_DIR`` at a local directory, as ``VLOG_WHISPER_DIR``.
+A directory that lacks one of the three is refused (``LmLoadError``).
+
+The program's own layout (``model.py`` indexes it) is a nested dict:
+``embed`` (V, H), ``head`` (H, V), ``final_norm``, and per layer ``n1``
+.. ``n4``, ``wq`` ``wk`` ``wv`` ``wg`` (H, out), ``wo``, ``qn`` ``kn``,
+then ``w_gate`` ``w_up`` ``w_down`` (dense) or ``router`` (H, E),
+``bias`` (E,), ``e_gate`` ``e_up`` (E, H, I), ``e_down`` (E, I, H) and
+``s_gate`` ``s_up`` ``s_down`` (the shared expert). Everything bfloat16
+except ``bias`` (float32).
+"""
+
+from __future__ import annotations
+
+import json
+from dataclasses import dataclass
+from pathlib import Path
+from typing import Any
+
+import jax.numpy as jnp
+
+from vlog_tpu.lm.model import BF16, F32, LmConfig
+
+
+class LmLoadError(RuntimeError):
+    pass
+
+
+class _HfTokenizer:
+    def __init__(self, path: Path):
+        from tokenizers import Tokenizer
+
+        self._tok = Tokenizer.from_file(str(path))
+
+    def encode(self, text: str) -> list[int]:
+        return list(self._tok.encode(text, add_special_tokens=False).ids)
+
+    def decode(self, ids) -> str:
+        return self._tok.decode(list(ids), skip_special_tokens=True)
+
+
+@dataclass
+class LmAssets:
+    cfg: LmConfig
+    params: dict
+    tokenizer: Any
+    model_name: str
+    eos_id: int | None = None
+
+
+def layer_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
+    """``(our key, shape, kind)`` of one layer's leaves."""
+    h, hd = cfg.hidden_size, cfg.head_dim
+    q, kv = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
+    out = [("n1", (h,), "ones"), ("n2", (h,), "ones"), ("n3", (h,), "ones"),
+           ("n4", (h,), "ones"), ("wq", (h, q), "normal"),
+           ("wk", (h, kv), "normal"), ("wv", (h, kv), "normal"),
+           ("wg", (h, q), "normal"), ("wo", (q, h), "normal"),
+           ("qn", (hd,), "ones"), ("kn", (hd,), "ones")]
+    if li < cfg.num_dense_layers:
+        i = cfg.intermediate_size
+        out += [("w_gate", (h, i), "normal"), ("w_up", (h, i), "normal"),
+                ("w_down", (i, h), "normal")]
+    else:
+        e, i = cfg.num_experts, cfg.moe_intermediate_size
+        out += [("router", (h, e), "normal"), ("bias", (e,), "bias"),
+                ("e_gate", (e, h, i), "normal"), ("e_up", (e, h, i), "normal"),
+                ("e_down", (e, i, h), "normal")]
+        if cfg.num_shared_experts:
+            s = i * cfg.num_shared_experts
+            out += [("s_gate", (h, s), "normal"), ("s_up", (h, s), "normal"),
+                    ("s_down", (s, h), "normal")]
+    return out
+
+
+# our key -> the published name under ``model.layers.<i>.``; matrices are
+# stored (out, in) as torch keeps them
+HF_NAMES = {
+    "n1": "input_layernorm.weight", "n2": "post_attention_layernorm.weight",
+    "n3": "pre_mlp_layernorm.weight", "n4": "post_mlp_layernorm.weight",
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wg": "self_attn.gate_proj.weight",
+    "wo": "self_attn.o_proj.weight", "qn": "self_attn.q_norm.weight",
+    "kn": "self_attn.k_norm.weight", "w_gate": "mlp.gate_proj.weight",
+    "w_up": "mlp.up_proj.weight", "w_down": "mlp.down_proj.weight",
+    "router": "mlp.router.gate.weight", "bias": "mlp.expert_bias",
+    "s_gate": "mlp.shared_experts.gate_proj.weight",
+    "s_up": "mlp.shared_experts.up_proj.weight",
+    "s_down": "mlp.shared_experts.down_proj.weight"}
+EXPERT_NAMES = {"e_gate": "gate_proj", "e_up": "up_proj",
+                "e_down": "down_proj"}
+
+
+def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
+    def get(name):
+        try:
+            return jnp.asarray(sd[name])
+        except KeyError:
+            raise LmLoadError(f"weights lack {name!r}") from None
+
+    layers = []
+    for li in range(cfg.num_layers):
+        base = f"model.layers.{li}."
+        lp = {}
+        for name, _shape, kind in layer_leaves(cfg, li):
+            if name in EXPERT_NAMES:
+                proj = EXPERT_NAMES[name]
+                lp[name] = jnp.stack([
+                    get(f"{base}mlp.experts.{e}.{proj}.weight").T
+                    for e in range(cfg.num_experts)]).astype(BF16)
+            else:
+                leaf = get(base + HF_NAMES[name])
+                leaf = leaf.T if leaf.ndim == 2 else leaf
+                lp[name] = leaf.astype(F32 if kind == "bias" else BF16)
+        layers.append(lp)
+    return {"embed": get("model.embed_tokens.weight").astype(BF16),
+            "head": get("lm_head.weight").T.astype(BF16),
+            "final_norm": get("model.norm.weight").astype(BF16),
+            "layers": layers}
+
+
+def _read_weights(path: Path) -> dict:
+    """Every tensor of the directory: the shards an index names, else
+    the one ``model.safetensors``."""
+    from safetensors.flax import load_file
+
+    index = path / "model.safetensors.index.json"
+    if index.exists():
+        try:
+            shards = sorted(set(json.loads(index.read_text())[
+                "weight_map"].values()))
+        except (OSError, ValueError, KeyError, AttributeError) as exc:
+            raise LmLoadError(f"{index}: no readable weight_map") from exc
+    else:
+        shards = ["model.safetensors"]
+    sd: dict = {}
+    for shard in shards:
+        if not (path / shard).exists():
+            raise LmLoadError(f"{path}: no {shard}")
+        sd.update(load_file(str(path / shard)))
+    return sd
+
+
+def load_model_dir(path: str | Path) -> LmAssets:
+    path = Path(path)
+    try:
+        hf = json.loads((path / "config.json").read_text())
+    except (OSError, ValueError) as exc:
+        raise LmLoadError(f"{path}: no readable config.json") from exc
+    tok_file = path / "tokenizer.json"
+    if not tok_file.exists():
+        # byte ids fed to a 200,192-row vocabulary would still "succeed"
+        raise LmLoadError(f"{path}: no tokenizer.json")
+    cfg = LmConfig.from_hf(hf)
+    params = from_state_dict(cfg, _read_weights(path))
+    eos = hf.get("eos_token_id")
+    if not (isinstance(eos, int) and 0 <= eos < cfg.vocab_size):
+        eos = None
+    return LmAssets(cfg=cfg, params=params,
+                    tokenizer=_HfTokenizer(tok_file),
+                    model_name=hf.get("_name_or_path") or path.name,
+                    eos_id=eos)

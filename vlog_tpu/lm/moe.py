@@ -1,0 +1,81 @@
+"""The expert layer: sigmoid routing with a selection bias, top-k of all
+experts, dropless grouped products, a shared expert beside them.
+
+``route`` is float32 end to end (scores, the biased choice, the
+normalised weights). ``experts`` sorts the (token, choice) pairs by
+expert and runs ONE grouped product per projection over every expert
+(``jax.lax.ragged_dot``: rows of the sorted activations against the
+expert their group names), so no token is dropped and no capacity is
+set; a token's ``k`` results are weighted and summed back in token
+order. bfloat16 operands, float32 accumulation.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+F32 = jnp.float32
+BF16 = jnp.bfloat16
+
+
+def route(x: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int,
+          route_norm: bool, route_scale: float
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``x`` (T, H) float32, ``router`` (H, E), ``bias`` (E,) float32.
+    Returns ``(chosen (T, k) int32, weights (T, k) float32, scores
+    (T, E))``: the choice is by ``score + bias``, the weight is the
+    score alone, over the chosen's sum where ``route_norm``, times
+    ``route_scale``."""
+    with jax.named_scope("lm.moe.router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(F32), router.astype(F32), precision=lax.Precision.HIGHEST,
+            preferred_element_type=F32))
+        _, chosen = lax.top_k(scores + bias.astype(F32), top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if route_norm:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        return chosen.astype(jnp.int32), weights * route_scale, scores
+
+
+def swiglu(x: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array
+           ) -> jax.Array:
+    """``down(silu(gate x) * (up x))`` on (T, H) rows; float32 out."""
+    xb = x.astype(BF16)
+    g = jnp.dot(xb, gate, preferred_element_type=F32)
+    u = jnp.dot(xb, up, preferred_element_type=F32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(BF16), down,
+                   preferred_element_type=F32)
+
+
+def experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
+            gate: jax.Array, up: jax.Array, down: jax.Array,
+            valid: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The routed part. ``x`` (T, H), ``chosen``/``weights`` (T, k),
+    ``gate``/``up`` (E, H, I), ``down`` (E, I, H), ``valid`` (T,) bool.
+    Returns ``(out (T, H) float32, tokens per expert (E,) int32, experts
+    that hold any row () int32)``; the count is of valid tokens only
+    (padding is computed, not counted), the experts held are of every
+    row (their weights are read)."""
+    t, k = chosen.shape
+    n_experts = gate.shape[0]
+    with jax.named_scope("lm.moe.dispatch"):
+        flat = chosen.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+        counted = jnp.bincount(
+            flat, weights=jnp.repeat(valid, k).astype(jnp.int32),
+            length=n_experts).astype(jnp.int32)
+        xs = x.astype(BF16)[order // k]
+    with jax.named_scope("lm.moe.experts"):
+        g = lax.ragged_dot(xs, gate, sizes, preferred_element_type=F32)
+        u = lax.ragged_dot(xs, up, sizes, preferred_element_type=F32)
+        ys = lax.ragged_dot((jax.nn.silu(g) * u).astype(BF16), down, sizes,
+                            preferred_element_type=F32)
+    with jax.named_scope("lm.moe.combine"):
+        ys = ys * weights.reshape(-1)[order][:, None]
+        back = jnp.argsort(order)
+        out = ys[back].reshape(t, k, -1).sum(axis=1)
+    return out, counted, jnp.sum(sizes > 0).astype(jnp.int32)

@@ -61,7 +61,8 @@ ANNOTATION = "vlog:"
 # the program's named scopes in an op's framework name, outermost first:
 # "jit(f)/jit(main)/asr.decoder_step/asr.decoder_step.mlp/dot_general",
 # under a transform "jit(f)/while/body/vmap(ladder.mc)/gather"
-_SCOPE_RE = re.compile(r"(?<![A-Za-z0-9_.])((?:asr|ladder)\.[A-Za-z0-9_.]+)")
+_SCOPE_RE = re.compile(
+    r"(?<![A-Za-z0-9_.])((?:asr|ladder|lm)\.[A-Za-z0-9_.]+)")
 # A TPU capture names a device-op event by its HLO instruction
 # ("%fusion.48 = (f32[1,200]...) fusion(...)") and carries no framework
 # name on the event (its stats are device_offset_ps, device_duration_ps

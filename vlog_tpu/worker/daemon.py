@@ -145,7 +145,8 @@ class WorkerDaemon(ComputeWatchdogMixin):
     name: str
     accelerator: AcceleratorKind = AcceleratorKind.TPU
     kinds: tuple[JobKind, ...] = (JobKind.TRANSCODE, JobKind.REENCODE,
-                                  JobKind.SPRITE, JobKind.TRANSCRIPTION)
+                                  JobKind.SPRITE, JobKind.TRANSCRIPTION,
+                                  JobKind.DIGEST)
     video_dir: Path = field(default_factory=lambda: config.VIDEO_DIR)
     backend: Any = None                    # backends.Backend; lazy-selected
     poll_interval_s: float = field(
@@ -585,6 +586,11 @@ class WorkerDaemon(ComputeWatchdogMixin):
                         if not self._asr_engine_active():
                             kinds = tuple(k for k in kinds
                                           if k != JobKind.TRANSCRIPTION)
+                        # the digest plane is gated the same way: its
+                        # step engine holds the one ticket
+                        if not self._lm_engine_active():
+                            kinds = tuple(k for k in kinds
+                                          if k != JobKind.DIGEST)
                         if not kinds:
                             break
                     # Batched claim: one transaction fills as many free
@@ -629,6 +635,15 @@ class WorkerDaemon(ComputeWatchdogMixin):
         from vlog_tpu.asr.engine import peek_engine
 
         eng = peek_engine()
+        return eng is not None and eng.active()
+
+    def _lm_engine_active(self) -> bool:
+        """The same question of the transcript model's step engine;
+        never builds it (nor imports its plane)."""
+        import sys
+
+        mod = sys.modules.get("vlog_tpu.lm.engine")
+        eng = mod.peek_engine() if mod is not None else None
         return eng is not None and eng.active()
 
     async def _run_slot_job(self, job: Row, ticket: Any) -> None:
@@ -816,6 +831,7 @@ class WorkerDaemon(ComputeWatchdogMixin):
             JobKind.REENCODE: self._run_reencode,
             JobKind.SPRITE: self._run_sprites,
             JobKind.TRANSCRIPTION: self._run_transcription,
+            JobKind.DIGEST: self._run_digest,
         }[kind]
         # Trace the attempt: a local daemon shares the server's DB, so
         # its spans (worker origin) go straight into job_spans under the
@@ -1384,6 +1400,40 @@ class WorkerDaemon(ComputeWatchdogMixin):
             "video_id": video["id"], "slug": video["slug"],
             "language": result.language})
 
+    async def _run_digest(self, job: Row, video: Row) -> None:
+        """Chapters and a summary from ``captions.vtt`` through the
+        worker's shared step engine (worker/digest.py). A device kind
+        like transcription: the ENGINE holds the one scheduler ticket,
+        and building it first evicts an idle ASR engine (one model
+        engine is resident at a time, lm/residency.py)."""
+        from vlog_tpu.worker.digest import digest_video
+
+        out_dir = self.video_dir / video["slug"]
+        timeout = config.transcode_timeout_s(
+            float(video["duration_s"] or 0.0), "720p")
+        stats: dict[str, Any] = {}
+
+        def work():
+            return digest_video(out_dir, scheduler=self.scheduler,
+                                job_key=f"job-{job['id']}", stats_out=stats)
+
+        from vlog_tpu.obs import trace as obs_trace
+
+        with obs_trace.span("worker.digest", video_id=video["id"]) as dsp:
+            result = await self._sup()._run_with_timeout(
+                work, timeout, "digest")
+            for k, v in stats.items():
+                dsp.attrs[f"digest.{k}"] = v
+        from vlog_tpu.jobs.finalize import finalize_digest
+
+        await finalize_digest(self.db, video["id"], paths=[
+            result.chapters_path, result.digest_path])
+        await claims.complete_job(self.db, job["id"], self.name)
+        self.stats.bump("completed")
+        await self._emit("video.digested", {
+            "video_id": video["id"], "slug": video["slug"],
+            "chapters": result.chapters})
+
 
 # --------------------------------------------------------------------------
 # Entrypoint
@@ -1478,7 +1528,8 @@ def main(argv: list[str] | None = None) -> None:
     parser.add_argument("--accelerator", default="tpu",
                         choices=[a.value for a in AcceleratorKind])
     parser.add_argument("--kinds",
-                        default="transcode,reencode,sprite,transcription")
+                        default="transcode,reencode,sprite,transcription,"
+                                "digest")
     parser.add_argument("--backend", default="",
                         help="force a registered backend by name")
     parser.add_argument("--no-backend", action="store_true",
