@@ -7,6 +7,7 @@ standing in for multi-chip TPU hardware
 """
 
 import asyncio
+import contextlib
 import os
 import uuid
 
@@ -80,6 +81,40 @@ def _vlog_thread_leak_gate():
         left = leaked()
     assert not left, ("test leaked non-daemon vlog-* threads: "
                       + ", ".join(sorted(t.name for t in left)))
+
+
+@pytest.fixture(autouse=True)
+def _cell_sizes_at_the_stated_precision(request):
+    """``benchmark_checks/test_benchmark_sizes.py`` compiles each cell's
+    beam program for a described chip without naming a matmul precision,
+    so at JAX's default (a float32 product is one bfloat16 pass), while
+    the cell runs at its configuration's ``deployment.matmul_precision``
+    (``highest``: six passes and their operand splits, which are
+    temporaries). Until PR 30 the five-fold cross-K/V tile hid the
+    difference (8 x 5 ``small``: 5.60 GB at the default, 5.59 GB at
+    ``highest``); without the tile the program counts 3.89 GB at the
+    default and 4.50 GB at ``highest`` against the file's 4.0 GiB =
+    4.29 GB. The file is the benchmark's and is not edited here: this
+    fixture runs its tests at the precision the configuration they load
+    states, which is what the chip runs. A ``benchmark`` PR should set
+    the precision in the file and drop this."""
+    config_name = getattr(getattr(request.node, "callspec", None),
+                          "params", {}).get("config_name")
+    stated = contextlib.nullcontext()
+    if (request.node.path.name == "test_benchmark_sizes.py"
+            and config_name is not None):
+        import json
+        from pathlib import Path
+
+        import jax
+
+        cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                          / "configs" / f"{config_name}.json").read_text())
+        want = cfg["deployment"].get("matmul_precision", "default")
+        if want != "default":
+            stated = jax.default_matmul_precision(want)
+    with stated:
+        yield
 
 
 @pytest.fixture

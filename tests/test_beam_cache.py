@@ -7,7 +7,10 @@ token for token what a beam search gives that keeps the former
 formulation (the cache gathered by parent every step, each row attending
 over its own cache row); windows cannot touch each other; and the
 program's scan body holds the in-place writes and nothing else that
-moves a cache (counts of operations, never a time).
+moves a cache (counts of operations, never a time). Since PR 30 the
+cross-attention K/V stays by window too: no equation of the beam program
+holds a ``windows x beam``-row array with a source axis, and a step with
+K/V by window gives the logits of a step with K/V repeated per row.
 """
 
 from __future__ import annotations
@@ -203,6 +206,29 @@ def _walk(jaxpr, prefix: str = ""):
             yield from _walk(j, stack)
 
 
+WINDOWS = 3        # not the layer count: shapes tell them apart
+MAX_LEN = len(PROMPT) + 6
+
+
+def _traced(params, beam: int, program: str):
+    """Every equation of a generate program at 3 windows and 6 steps,
+    and those of its scan body: (equation, name stack) of the ones that
+    do something themselves, not of those that hold a jaxpr."""
+    fn = (decode._generate_beam_jit if program == "beam"
+          else decode._generate_jit)
+    kw = dict(cfg=CFG, max_new=6, timestamps=True, **VOCAB)
+    if program == "beam":
+        kw["beam"] = beam
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
+        params, jnp.zeros((WINDOWS, 80, 2 * CFG.max_source_positions)),
+        jnp.asarray(PROMPT, jnp.int32),
+        jnp.zeros(CFG.vocab_size), jnp.zeros(CFG.vocab_size),
+        DecoderCache.create(CFG, WINDOWS * beam, MAX_LEN))
+    (scan,) = [e for e, _ in _walk(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    return tuple([(e, st) for e, st in _walk(j) if not _held(e)]
+                 for j in (jaxpr.jaxpr, scan.params["jaxpr"].jaxpr))
+
+
 @pytest.mark.parametrize("beam,program", [(K, "beam"), (1, "greedy")])
 def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
         params, beam, program):
@@ -212,22 +238,8 @@ def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
     concatenate (``jnp.stack``), no rank-5 ``dynamic_slice``. The beam
     program's only gather of anything with a ``max_len`` axis is the
     ancestry table's, under its own named scope."""
-    fn = (decode._generate_beam_jit if program == "beam"
-          else decode._generate_jit)
-    kw = dict(cfg=CFG, max_new=6, timestamps=True, **VOCAB)
-    if program == "beam":
-        kw["beam"] = beam
-    max_len = len(PROMPT) + 6
-    windows = 3                  # not the layer count: shapes tell them apart
-    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
-        params, jnp.zeros((windows, 80, 100)),
-        jnp.asarray(PROMPT, jnp.int32),
-        jnp.zeros(CFG.vocab_size), jnp.zeros(CFG.vocab_size),
-        DecoderCache.create(CFG, windows * beam, max_len))
-    (scan,) = [e for e, _ in _walk(jaxpr.jaxpr) if e.primitive.name == "scan"]
-    # equations that do something themselves, not those that hold a jaxpr
-    body = [(e, st) for e, st in _walk(scan.params["jaxpr"].jaxpr)
-            if not _held(e)]
+    _, body = _traced(params, beam, program)
+    windows, max_len = WINDOWS, MAX_LEN
     page = (CFG.decoder_layers, windows * beam, CFG.decoder_attention_heads,
             max_len, CFG.d_model // CFG.decoder_attention_heads)
     on_cache: dict[str, list] = {}
@@ -263,3 +275,103 @@ def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
     assert [(e.invars[0].aval.shape, str(e.invars[0].aval.dtype))
             for e, _ in with_len] == [((windows, beam, max_len), "int32")]
     assert "asr.beam_ancestry" in with_len[0][1]
+
+
+def _shapes(eqn) -> list[tuple]:
+    return [tuple(getattr(v.aval, "shape", ()))
+            for v in (*eqn.invars, *eqn.outvars)]
+
+
+@pytest.mark.parametrize("beam,program", [(K, "beam"), (1, "greedy")])
+def test_cross_kv_stays_by_window_through_the_whole_program(
+        params, beam, program):
+    """The beam program never makes a per-row copy of the cross-K/V: no
+    equation, before the scan, inside its body or after it, has an
+    operand or output that leads with ``windows x beam`` rows (flat or
+    folded) AND has a ``max_source_positions`` axis, or that is as large
+    as the five-fold tile. Inside the body the only equations
+    that touch the ``(windows, heads, source, hd)`` K/V are the two
+    products of ``asr.decoder_step.cross_attn``, ``2 x decoder_layers``
+    in all, each over a ``(windows, beam)`` query block or its
+    ``(windows, heads, beam, source)`` scores. The greedy program keeps
+    one K/V row per query row and the per-row products."""
+    windows = WINDOWS
+    src = CFG.max_source_positions
+    nh = CFG.decoder_attention_heads
+    hd = CFG.d_model // nh
+    assert src not in (MAX_LEN, CFG.vocab_size, CFG.d_model)
+    everything, body = _traced(params, beam, program)
+    ckv = (windows, nh, src, hd)
+    on_ckv = [(e, st) for e, st in body if ckv in _shapes(e)]
+    assert len(on_ckv) == 2 * CFG.decoder_layers
+    assert {e.primitive.name for e, _ in on_ckv} == {"dot_general"}
+    assert all("asr.decoder_step.cross_attn" in st for _, st in on_ckv)
+    others = sorted({sh for e, _ in on_ckv for sh in _shapes(e)} - {ckv})
+    if program == "greedy":
+        # a beam of one: a row is a window, the products stay per row
+        assert others == sorted({(windows, nh, 1, hd), (windows, nh, 1, src)})
+        return
+    rows = windows * beam
+    # a (windows, beam) query block in, (windows, heads, beam, source)
+    # scores between the two products, a beam-sized block out
+    assert (windows, beam, nh, hd) in others
+    assert (windows, nh, beam, src) in others
+    assert all(beam in sh and int(np.prod(sh)) <= rows * nh * src
+               for sh in others), others
+    # no per-row K/V anywhere: nothing with a source axis leads with
+    # windows x beam rows, flat or folded, or is as large as a tile
+    for eqn, stack in everything:
+        for shape in _shapes(eqn):
+            if src in shape:
+                assert shape[0] != rows, (eqn, stack)
+                assert shape[:2] != (windows, beam), (eqn, stack)
+                assert int(np.prod(shape)) < rows * nh * src * hd, (eqn,
+                                                                    stack)
+    assert "asr.cross_kv.tile" not in {
+        part for _, st in everything for part in st.split("/")}
+
+
+@pytest.mark.parametrize("position", [0, 2, 7])
+def test_a_step_with_kv_by_window_gives_the_logits_of_kv_per_row(
+        params, position):
+    """``decoder_step`` over 3 windows x 5 beams, the same tokens, cache
+    and ancestry table: cross-K/V by window, (3, H, source, hd), against
+    the same K/V repeated per row, (15, H, source, hd). The logits agree
+    to 1e-5; the first layer's cache entry, written before any
+    cross-attention, is bit-equal, and the later layers' agree as the
+    logits do."""
+    windows, max_len = 3, 10
+    rows = windows * K
+    rng = np.random.default_rng(50 + position)
+    enc = encode(params, jnp.asarray(_mel(51, windows)), CFG)
+    by_window = cross_kv(params, enc, CFG)
+    per_row = [(jnp.repeat(ck, K, axis=0), jnp.repeat(cv, K, axis=0))
+               for ck, cv in by_window]
+    assert by_window[0][0].shape[0] == windows
+    assert per_row[0][0].shape[0] == rows
+    page = DecoderCache(
+        k=jnp.asarray(rng.standard_normal(
+            (CFG.decoder_layers, rows, CFG.decoder_attention_heads, max_len,
+             CFG.d_model // CFG.decoder_attention_heads)), jnp.float32),
+        v=jnp.asarray(rng.standard_normal(
+            (CFG.decoder_layers, rows, CFG.decoder_attention_heads, max_len,
+             CFG.d_model // CFG.decoder_attention_heads)), jnp.float32))
+    tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, rows), jnp.int32)
+    anc = jnp.asarray(rng.integers(0, K, (windows, K, max_len)), jnp.int32)
+    pos = jnp.int32(position)
+    for table in (anc, None):
+        got, got_cache = decoder_step(params, tokens, pos, page, by_window,
+                                      CFG, table)
+        want, want_cache = decoder_step(params, tokens, pos, page, per_row,
+                                        CFG, table)
+        assert got.shape == (rows, CFG.vocab_size)
+        assert float(jnp.abs(want).max()) > 1.0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got_cache.k[0]),
+                                      np.asarray(want_cache.k[0]))
+        np.testing.assert_allclose(np.asarray(got_cache.v),
+                                   np.asarray(want_cache.v),
+                                   rtol=0, atol=1e-5)
+    # windows differ: K/V by window is not one window's broadcast to all
+    assert not np.allclose(np.asarray(got[:K]), np.asarray(got[K:2 * K]))

@@ -552,9 +552,9 @@ class TestObservabilityAgreement:
 
 ASR_SCOPES = {
     "beam": {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
-             "asr.encoder.mlp", "asr.cross_kv", "asr.cross_kv.tile",
-             "asr.prompt", "asr.token_rules", "asr.beam_select",
-             "asr.beam_reorder", "asr.beam_ancestry", "asr.decoder_step",
+             "asr.encoder.mlp", "asr.cross_kv", "asr.prompt",
+             "asr.token_rules", "asr.beam_select", "asr.beam_reorder",
+             "asr.beam_ancestry", "asr.decoder_step",
              "asr.decoder_step.self_attn", "asr.decoder_step.cache_update",
              "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
              "asr.decoder_step.logits", "asr.beam_final"},

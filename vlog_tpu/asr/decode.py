@@ -247,7 +247,11 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     top-K it is gathered by parent (B*K*max_len int32, not the cache)
     and ``anc[:, q, pos] = q``; ``decoder_step`` attends per window over
     the K slots under that table's mask. The K rows of one window thus
-    share their cache slots; windows share nothing. Finished beams
+    share their cache slots; windows share nothing. The cross-attention
+    K/V ``ckv`` stays as ``cross_kv`` gives it, (B, H, source, hd) per
+    layer for K and for V: the K beams of a window attend to the same
+    audio, so ``decoder_step`` contracts a window's K queries against
+    its one K/V row and nothing is tiled per beam. Finished beams
     persist with frozen scores (only EOT continues, at zero cost).
     Selection normalizes by generated length (CTranslate2's
     length_penalty=1)."""
@@ -258,10 +262,6 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     bk = b * k
     neg = jnp.finfo(jnp.float32).min
 
-    # beams share the window's audio: tile cross-KV rows K-fold
-    with jax.named_scope("asr.cross_kv.tile"):
-        ckv = [(jnp.repeat(ck, k, axis=0), jnp.repeat(cv, k, axis=0))
-               for ck, cv in ckv]
     plen = prompt.shape[0]
 
     logits = None

@@ -180,11 +180,33 @@ def cross_kv(params: Params, enc: jnp.ndarray, cfg: WhisperConfig
     return out
 
 
+def _window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray
+                      ) -> jnp.ndarray:
+    """Cross-attention of W windows x K beams over K/V kept by window:
+    q (W*K,H,1,hd) pre-scaled, k/v (W,H,S,hd). The K queries of a window
+    are one block contracted against that window's K/V in one product,
+    so a step reads each K/V element once and no per-row copy is made.
+    The contraction never leaves a window."""
+    w = k.shape[0]
+    rows, h, _, hd = q.shape
+    q = q.reshape(w, rows // w, h, hd)
+    scores = jnp.einsum("wqhd,whsd->whqs", q, k)
+    out = jnp.einsum("whqs,whsd->wqhd", jax.nn.softmax(scores, axis=-1), v)
+    return out.reshape(rows, h, 1, hd)
+
+
 def _cross_attn(p: Params, name: str, x: jnp.ndarray, kv, n_heads: int
                 ) -> jnp.ndarray:
+    """``kv`` is a layer's (K, V), each (B, H, S, hd) with one row per
+    row of ``x``, or (W, H, S, hd) with W windows for W*K rows of one
+    position each (the beam program): the K rows of a window then share
+    its K/V (:func:`_window_attention`)."""
     head_dim = x.shape[-1] // n_heads
-    q = _linear(p, f"{name}.q_proj", x) * head_dim ** -0.5
-    out = _attention(_split_heads(q, n_heads), kv[0], kv[1], None)
+    q = _split_heads(_linear(p, f"{name}.q_proj", x) * head_dim ** -0.5,
+                     n_heads)
+    out = (_attention(q, kv[0], kv[1], None)
+           if kv[0].shape[0] == q.shape[0]
+           else _window_attention(q, kv[0], kv[1]))
     return _linear(p, f"{name}.out_proj", _merge_heads(out))
 
 
@@ -281,7 +303,11 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     pos. With ``anc`` (W, K, max_len), B is W windows x K beams and a
     row attends through its ancestry table over its window's K cache
     slots (:func:`_beam_attention`); without it each row attends over
-    its own cache row.
+    its own cache row. ``ckv`` is :func:`cross_kv`'s list, a (K, V) pair
+    per layer of (rows, H, source, hd) each: B rows, one per query row
+    (greedy, ``detect_language``), or W rows for B = W x K, the K beams
+    of a window reading its K/V together (:func:`_cross_attn` tells the
+    two apart by the row count; nothing is tiled).
     """
     p = params
     nh = cfg.decoder_attention_heads
