@@ -457,13 +457,13 @@ def run_asr_quant(beam: int) -> dict:
     zeros = jnp.zeros(cfg.vocab_size, jnp.float32)
     max_new = 24
     kw = dict(cfg=cfg, sot=3, eot=1, ts_begin=cfg.vocab_size - 2,
-              no_speech=-1, max_new=max_new, timestamps=False)
+              no_speech=-1, max_new=max_new, timestamps=False, beam=1)
 
     def decode_with(p):
         cache = dec.kv_pool.lease(cfg, mel.shape[0],
                                   prompt.shape[0] + max_new)
-        toks, _, cache = dec._generate_jit(p, mel, prompt, zeros, zeros,
-                                           cache, **kw)
+        toks, _, cache = dec._generate_beam_jit(p, mel, prompt, zeros,
+                                                zeros, cache, **kw)
         dec.kv_pool.release(cache)
         return np.asarray(toks)
 

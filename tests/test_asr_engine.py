@@ -26,6 +26,7 @@ from __future__ import annotations
 import asyncio
 import json
 import re
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -46,8 +47,7 @@ from vlog_tpu.jobs import claims, videos as vids
 from vlog_tpu.media.audio import AudioData, write_wav
 from vlog_tpu.utils import failpoints
 from vlog_tpu.worker.daemon import WorkerDaemon
-from vlog_tpu.worker.transcribe import (transcribe_audio,
-                                        transcribe_audio_engine,
+from vlog_tpu.worker.transcribe import (transcribe_audio_engine,
                                         transcribe_video)
 
 
@@ -179,6 +179,8 @@ def test_engine_packs_windows_from_concurrent_jobs(assets):
     assert batch["n"] == 5 and batch["rows"] == 8
     assert batch["jobs"] == ["A", "B", "A", "B", "A"]
     assert batch["occupancy"] == pytest.approx(5 / 8)
+    # close() freed what the plane put on the device: the cache pages
+    assert engine.stats()["kv_pool"]["retained"] == 0
 
 
 def test_engine_backfills_freed_rows_across_ticks(assets):
@@ -446,6 +448,34 @@ def test_get_engine_memoized_per_model_dir(tiny_model_dir):
     assert peek_engine() is None
 
 
+def test_two_jobs_claimed_together_load_the_weights_once(tiny_model_dir,
+                                                        monkeypatch):
+    """Two transcription jobs claimed in one round both call
+    ``get_engine``: the host builds one engine at a time, so the second
+    caller gets the first one's engine and Whisper loads once (the twin
+    of ``tests/test_digest_job.py``'s test of the transcript plane)."""
+    from vlog_tpu.asr import engine as asr_engine
+
+    loads = []
+    real = asr_engine.load_whisper
+
+    def slow_load(model_dir, quant=None):
+        loads.append(model_dir)
+        time.sleep(0.2)             # long enough for the second to arrive
+        return real(model_dir, quant)
+
+    monkeypatch.setattr(asr_engine, "load_whisper", slow_load)
+    got = []
+    threads = [threading.Thread(
+        target=lambda: got.append(get_engine(str(tiny_model_dir))))
+        for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert len(loads) == 1 and len(got) == 2 and got[0] is got[1]
+
+
 def test_load_whisper_memoized_on_dir_and_mtime(tiny_model_dir):
     from vlog_tpu.asr import load as load_mod
 
@@ -459,9 +489,16 @@ def test_load_whisper_memoized_on_dir_and_mtime(tiny_model_dir):
 # Determinism: byte-identical captions solo vs. packed
 # --------------------------------------------------------------------------
 
-def _run_jobs(assets, jobs: list[tuple[str, np.ndarray]],
-              tick_s: float = 0.3):
-    engine = AsrEngine(assets, batch_windows=8, tick_s=tick_s)
+def _run_jobs(assets, jobs: list[tuple[str, np.ndarray]], windows: int):
+    """The jobs through one engine, co-batched for certain: the engine
+    sees an empty queue until every job has all its windows queued
+    (``windows`` in all), so its first tick takes them together however
+    late a job's thread was scheduled."""
+    engine = AsrEngine(assets, batch_windows=8, tick_s=0.0)
+    gate = threading.Event()
+    wait_for_work = engine._queue.wait_for_work
+    engine._queue.wait_for_work = (
+        lambda timeout: gate.wait(timeout) and wait_for_work(timeout))
     try:
         with ThreadPoolExecutor(max_workers=len(jobs)) as ex:
             futs = {
@@ -471,9 +508,17 @@ def _run_jobs(assets, jobs: list[tuple[str, np.ndarray]],
                     window_s=30.0, overlap_s=5.0)
                 for name, sam in jobs
             }
+            deadline = time.monotonic() + 300
+            while (engine.stats()["pending"] < windows
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            queued = engine.stats()["pending"]
+            gate.set()
             out = {name: f.result(timeout=300) for name, f in futs.items()}
     finally:
+        gate.set()
         engine.close()
+    assert queued == windows
     return out, engine.batch_log
 
 
@@ -483,10 +528,12 @@ def test_vtt_byte_identical_solo_vs_packed(assets):
     co-batched with another job the whole way."""
     sam_a = _tone(65.0, 220.0)                  # 3 windows at 25 s stride
     sam_b = _tone(40.0, 330.0)                  # 2 windows
-    solo, _ = _run_jobs(assets, [("A", sam_a)])
-    packed, log = _run_jobs(assets, [("A", sam_a), ("B", sam_b)])
-    # prove the runs actually shared a forward, not just a process
-    assert any(len(set(b["jobs"])) > 1 for b in log)
+    solo, _ = _run_jobs(assets, [("A", sam_a)], windows=3)
+    packed, log = _run_jobs(assets, [("A", sam_a), ("B", sam_b)], windows=5)
+    # prove the runs actually shared a forward, not just a process: the
+    # one tick carried every window of both jobs
+    assert [sorted(set(b["jobs"])) for b in log] == [["A", "B"]]
+    assert log[0]["n"] == 5
     vtt_solo = format_vtt(solo["A"][0])
     vtt_packed = format_vtt(packed["A"][0])
     assert vtt_packed == vtt_solo
@@ -765,19 +812,22 @@ class TestAsrAgreement:
 
 @pytest.mark.slow
 def test_asr_packing_microbench(assets):
-    """Windows/sec through the shared engine (many small jobs packed
-    into full buckets) vs. the pre-engine sequential path (one padded
-    partial batch per job). Eight 3-window jobs: sequential burns eight
-    forwards at 3/8 occupancy; the engine packs the same 24 windows
-    into three full forwards."""
+    """Windows/sec through the shared engine with the jobs submitted
+    together (many small jobs packed into full buckets) vs. the same
+    jobs run one after another through the engine (one padded partial
+    batch per job). Eight 3-window jobs: one after another burns eight
+    forwards of three windows each; together the engine packs the same
+    24 windows into three full forwards."""
     jobs = [(f"j{k}", _tone(65.0, 200.0 + 15.0 * k)) for k in range(8)]
 
-    # warm the single bucket shape both paths run at, outside the clock
+    # warm the bucket shapes of both runs (eight windows packed, three
+    # for a job alone) outside the clock
     warm_engine = AsrEngine(assets, batch_windows=8, tick_s=0.05)
     try:
-        transcribe_audio_engine(_tone(190.0), warm_engine, job_key="warm",
-                                language="en", max_new=8, beam=1,
-                                window_s=30.0, overlap_s=5.0)
+        for seconds in (190.0, 65.0):
+            transcribe_audio_engine(
+                _tone(seconds), warm_engine, job_key="warm", language="en",
+                max_new=8, beam=1, window_s=30.0, overlap_s=5.0)
     finally:
         warm_engine.close()
 
@@ -797,11 +847,17 @@ def test_asr_packing_microbench(assets):
     windows = sum(r[2] for r in results)
     assert windows == 24 and stats["windows"] == 24
 
+    engine = AsrEngine(assets, batch_windows=8, tick_s=0.02)
     t0 = time.perf_counter()
-    for _name, sam in jobs:
-        transcribe_audio(sam, assets, language="en", max_new=8,
-                         window_s=30.0, overlap_s=5.0, batch_windows=8)
-    wall_seq = time.perf_counter() - t0
+    try:
+        for name, sam in jobs:
+            transcribe_audio_engine(sam, engine, job_key=name, language="en",
+                                    max_new=8, beam=1, window_s=30.0,
+                                    overlap_s=5.0)
+        wall_seq = time.perf_counter() - t0
+        assert engine.stats()["batches"] == len(jobs)
+    finally:
+        engine.close()
 
     engine_wps = windows / wall_engine
     seq_wps = windows / wall_seq

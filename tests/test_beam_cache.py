@@ -210,16 +210,13 @@ WINDOWS = 3        # not the layer count: shapes tell them apart
 MAX_LEN = len(PROMPT) + 6
 
 
-def _traced(params, beam: int, program: str):
-    """Every equation of a generate program at 3 windows and 6 steps,
+def _traced(params, beam: int):
+    """Every equation of the generate program at 3 windows and 6 steps,
     and those of its scan body: (equation, name stack) of the ones that
     do something themselves, not of those that hold a jaxpr."""
-    fn = (decode._generate_beam_jit if program == "beam"
-          else decode._generate_jit)
-    kw = dict(cfg=CFG, max_new=6, timestamps=True, **VOCAB)
-    if program == "beam":
-        kw["beam"] = beam
-    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
+    kw = dict(cfg=CFG, max_new=6, timestamps=True, beam=beam, **VOCAB)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: decode._generate_beam_jit(*a, **kw))(
         params, jnp.zeros((WINDOWS, 80, 2 * CFG.max_source_positions)),
         jnp.asarray(PROMPT, jnp.int32),
         jnp.zeros(CFG.vocab_size), jnp.zeros(CFG.vocab_size),
@@ -229,16 +226,17 @@ def _traced(params, beam: int, program: str):
                  for j in (jaxpr.jaxpr, scan.params["jaxpr"].jaxpr))
 
 
-@pytest.mark.parametrize("beam,program", [(K, "beam"), (1, "greedy")])
+@pytest.mark.parametrize("beam", [K, 1])
 def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
-        params, beam, program):
+        params, beam):
     """What the scan body does to a rank-5 array: ``2 x decoder_layers``
     ``dynamic_update_slice`` of one position each, one slice a layer for
     K and for V to read the layer back, and nothing else: no gather, no
-    concatenate (``jnp.stack``), no rank-5 ``dynamic_slice``. The beam
+    concatenate (``jnp.stack``), no rank-5 ``dynamic_slice``. The
     program's only gather of anything with a ``max_len`` axis is the
-    ancestry table's, under its own named scope."""
-    _, body = _traced(params, beam, program)
+    ancestry table's, under its own named scope; a beam of one is the
+    same body over one slot a window."""
+    _, body = _traced(params, beam)
     windows, max_len = WINDOWS, MAX_LEN
     page = (CFG.decoder_layers, windows * beam, CFG.decoder_attention_heads,
             max_len, CFG.d_model // CFG.decoder_attention_heads)
@@ -262,9 +260,6 @@ def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
             assert all(len(getattr(v.aval, "shape", ())) < 5
                        for v in (*eqn.invars, *eqn.outvars)), (eqn, stack)
     table = [(e, st) for e, st in body if "asr.beam_ancestry" in st]
-    if program == "greedy":
-        assert not table
-        return
     # the table: (windows, K, max_len) int32, gathered by parent and
     # written at one position; no other gather sees a max_len axis
     assert {e.primitive.name for e, _ in table} >= {
@@ -282,9 +277,8 @@ def _shapes(eqn) -> list[tuple]:
             for v in (*eqn.invars, *eqn.outvars)]
 
 
-@pytest.mark.parametrize("beam,program", [(K, "beam"), (1, "greedy")])
-def test_cross_kv_stays_by_window_through_the_whole_program(
-        params, beam, program):
+@pytest.mark.parametrize("beam", [K, 1])
+def test_cross_kv_stays_by_window_through_the_whole_program(params, beam):
     """The beam program never makes a per-row copy of the cross-K/V: no
     equation, before the scan, inside its body or after it, has an
     operand or output that leads with ``windows x beam`` rows (flat or
@@ -293,22 +287,22 @@ def test_cross_kv_stays_by_window_through_the_whole_program(
     that touch the ``(windows, heads, source, hd)`` K/V are the two
     products of ``asr.decoder_step.cross_attn``, ``2 x decoder_layers``
     in all, each over a ``(windows, beam)`` query block or its
-    ``(windows, heads, beam, source)`` scores. The greedy program keeps
-    one K/V row per query row and the per-row products."""
+    ``(windows, heads, beam, source)`` scores. A beam of one keeps one
+    K/V row per query row and the per-row products."""
     windows = WINDOWS
     src = CFG.max_source_positions
     nh = CFG.decoder_attention_heads
     hd = CFG.d_model // nh
     assert src not in (MAX_LEN, CFG.vocab_size, CFG.d_model)
-    everything, body = _traced(params, beam, program)
+    everything, body = _traced(params, beam)
     ckv = (windows, nh, src, hd)
     on_ckv = [(e, st) for e, st in body if ckv in _shapes(e)]
     assert len(on_ckv) == 2 * CFG.decoder_layers
     assert {e.primitive.name for e, _ in on_ckv} == {"dot_general"}
     assert all("asr.decoder_step.cross_attn" in st for _, st in on_ckv)
     others = sorted({sh for e, _ in on_ckv for sh in _shapes(e)} - {ckv})
-    if program == "greedy":
-        # a beam of one: a row is a window, the products stay per row
+    if beam == 1:
+        # a row is a window, the products stay per row
         assert others == sorted({(windows, nh, 1, hd), (windows, nh, 1, src)})
         return
     rows = windows * beam

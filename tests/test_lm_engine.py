@@ -4,18 +4,16 @@ evicting, the step record adds up, and the engine swaps residency with
 the ASR plane at a job boundary."""
 
 # slowlane-ok(module): the tiny model's step programs build in seconds
-import sys
 import threading
-import types
 
 import numpy as np
 import pytest
 
 from lm_helpers import engine, geometry, save_model_dir, tiny
 
-from vlog_tpu.lm import residency
 from vlog_tpu.lm.cache import PagedCache
 from vlog_tpu.lm.engine import PHASES, LmJobError
+from vlog_tpu.parallel.engine_host import HOST
 
 
 @pytest.fixture(scope="module")
@@ -122,50 +120,49 @@ def test_eos_ends_a_request_early(model):
     assert out == toks[:stop + 1]
 
 
-class _StubAsr:
-    """What residency needs of an ASR plane: peek, active, reset."""
+class _StubEngine:
+    """What the host needs of a plane's engine: ``active`` and
+    ``close``."""
 
     def __init__(self):
         self.busy = True
-        self.resets = 0
-        self.module = types.ModuleType("vlog_tpu.asr.engine")
-        self.module.peek_engine = lambda: self if self.resets == 0 else None
-        self.module.reset_engine = self._reset
+        self.closes = 0
 
     def active(self):
         return self.busy
 
-    def _reset(self):
-        self.resets += 1
+    def close(self):
+        self.closes += 1
 
 
 def test_residency_swaps_at_a_job_boundary(model, monkeypatch, tmp_path):
-    from vlog_tpu.asr import decode
     from vlog_tpu.lm import engine as lm_engine
 
     hf, _cfg, params = model
-    stub = _StubAsr()
-    monkeypatch.setitem(sys.modules, "vlog_tpu.asr.engine", stub.module)
-    pool_resets = []
-    monkeypatch.setattr(decode.kv_pool, "reset",
-                        lambda: pool_resets.append(1))
-    with pytest.raises(TimeoutError):       # a busy engine is never torn down
-        residency.make_room("lm", timeout_s=0.1, poll_s=0.01)
-    assert stub.resets == 0
-    threading.Timer(0.1, lambda: setattr(stub, "busy", False)).start()
+    stub = _StubEngine()
+    HOST.obtain("stub", "key", lambda: stub)
+    assert HOST.obtain("stub", "key", _StubEngine) is stub     # resident
     model_dir = save_model_dir(tmp_path / "m", hf, params, shards=3)
     monkeypatch.setattr(lm_engine, "default_geometry", lambda cfg: geometry(
         cfg, rows=2, chunk=16, page=4, cap=64))
-    lm_engine.reset_engine()
+    monkeypatch.setattr(HOST, "room_timeout_s", 0.1)
+    monkeypatch.setattr(HOST, "poll_s", 0.01)
     try:
+        with pytest.raises(TimeoutError):   # a busy engine is never closed
+            lm_engine.get_engine(str(model_dir))
+        assert stub.closes == 0 and HOST.active("stub")
+        assert lm_engine.peek_engine() is None
+        monkeypatch.setattr(HOST, "room_timeout_s", 30.0)
+        threading.Timer(0.1, lambda: setattr(stub, "busy", False)).start()
         eng = lm_engine.get_engine(str(model_dir))
-        assert stub.resets == 1 and pool_resets == [1]
+        assert stub.closes == 1 and HOST.peek("stub") is None
         assert lm_engine.get_engine(str(model_dir)) is eng
         assert eng.geo.rows == 2 and eng.geo.page == 4
         assert len(eng.submit(np.arange(10), max_new=3).wait(300)) == 3
-        # and the reverse: an idle transcript engine makes room for ASR
-        monkeypatch.undo()
-        assert residency.make_room("asr", timeout_s=10.0) == ["lm"]
-        assert lm_engine.peek_engine() is None
+        # and the reverse: an idle transcript engine makes room
+        other = HOST.obtain("stub", "key", _StubEngine)
+        assert other.closes == 0 and lm_engine.peek_engine() is None
+        assert not HOST.active("lm")
     finally:
+        HOST.evict("stub")
         lm_engine.reset_engine()

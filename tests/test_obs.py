@@ -550,21 +550,16 @@ class TestObservabilityAgreement:
 # Named scopes: what a device trace is read by (obs/profiler.summarize)
 # --------------------------------------------------------------------------
 
+_BEAM_SCOPES = {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
+                "asr.encoder.mlp", "asr.cross_kv", "asr.prompt",
+                "asr.token_rules", "asr.beam_select", "asr.beam_reorder",
+                "asr.beam_ancestry", "asr.decoder_step",
+                "asr.decoder_step.self_attn", "asr.decoder_step.cache_update",
+                "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
+                "asr.decoder_step.logits", "asr.beam_final"}
 ASR_SCOPES = {
-    "beam": {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
-             "asr.encoder.mlp", "asr.cross_kv", "asr.prompt",
-             "asr.token_rules", "asr.beam_select", "asr.beam_reorder",
-             "asr.beam_ancestry", "asr.decoder_step",
-             "asr.decoder_step.self_attn", "asr.decoder_step.cache_update",
-             "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
-             "asr.decoder_step.logits", "asr.beam_final"},
-    "greedy": {"asr.encoder", "asr.encoder.conv", "asr.encoder.attn",
-               "asr.encoder.mlp", "asr.cross_kv", "asr.prompt",
-               "asr.token_rules", "asr.beam_select", "asr.decoder_step",
-               "asr.decoder_step.self_attn",
-               "asr.decoder_step.cache_update",
-               "asr.decoder_step.cross_attn", "asr.decoder_step.mlp",
-               "asr.decoder_step.logits"},
+    "beam": _BEAM_SCOPES,
+    "beam1": _BEAM_SCOPES,      # a beam of one is the same program
     "mel": {"asr.mel"},
     "ladder": {"ladder.resize", "ladder.intra", "ladder.motion_search",
                "ladder.mc", "ladder.transform_quant", "ladder.deblock",
@@ -596,7 +591,8 @@ def _scopes_in(jaxpr, prefix: str = "") -> set[str]:
 @pytest.mark.parametrize("program", sorted(ASR_SCOPES))
 def test_programs_carry_their_named_scopes(program):
     """Tiny widths, nothing runs: the scopes are in the name stacks of
-    both generate programs, the mel program and the chain ladder (scan
+    the generate program (at beam 3 and at a beam of one), the mel
+    program and the chain ladder (scan
     bodies included), and the program names nothing else ``asr.*`` or
     ``ladder.*`` (a typo would read as a scope of its own in a trace)."""
     import jax
@@ -631,11 +627,9 @@ def test_programs_carry_their_named_scopes(program):
             max_source_positions=50, max_target_positions=16)
         beam = 3 if program == "beam" else 1
         kw = dict(cfg=cfg, sot=100, eot=99, ts_begin=110, no_speech=105,
-                  max_new=4, timestamps=True)
-        fn = decode._generate_beam_jit if beam > 1 else decode._generate_jit
-        if beam > 1:
-            kw["beam"] = beam
-        jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(
+                  max_new=4, timestamps=True, beam=beam)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: decode._generate_beam_jit(*a, **kw))(
             init_random_params(cfg), jnp.zeros((2, 80, 100)),
             jnp.asarray([100, 101, 102], jnp.int32), jnp.zeros(120),
             jnp.zeros(120), DecoderCache.create(cfg, 2 * beam, 7))

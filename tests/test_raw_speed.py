@@ -367,12 +367,12 @@ def test_generation_reuses_kv_pages_across_calls():
     prompt = jnp.asarray([3, 4], jnp.int32)
     zeros = jnp.zeros(cfg.vocab_size, jnp.float32)
     kw = dict(cfg=cfg, sot=3, eot=1, ts_begin=cfg.vocab_size - 2,
-              no_speech=-1, max_new=8, timestamps=False)
+              no_speech=-1, max_new=8, timestamps=False, beam=1)
 
     def run():
         cache = dec.kv_pool.lease(cfg, 2, prompt.shape[0] + 8)
-        toks, _, cache = dec._generate_jit(params, mel, prompt, zeros,
-                                           zeros, cache, **kw)
+        toks, _, cache = dec._generate_beam_jit(params, mel, prompt, zeros,
+                                                zeros, cache, **kw)
         dec.kv_pool.release(cache)
         return np.asarray(toks)
 
@@ -583,13 +583,13 @@ def test_asr_quant_microbench():
     zeros = jnp.zeros(cfg.vocab_size, jnp.float32)
     max_new = 32
     kw = dict(cfg=cfg, sot=3, eot=1, ts_begin=cfg.vocab_size - 2,
-              no_speech=-1, max_new=max_new, timestamps=False)
+              no_speech=-1, max_new=max_new, timestamps=False, beam=1)
 
     def wps(p, reps=3):
         def once():
             cache = dec.kv_pool.lease(cfg, windows, 2 + max_new)
-            toks, _, cache = dec._generate_jit(p, mel, prompt, zeros,
-                                               zeros, cache, **kw)
+            toks, _, cache = dec._generate_beam_jit(p, mel, prompt, zeros,
+                                                    zeros, cache, **kw)
             jax.block_until_ready(toks)
             dec.kv_pool.release(cache)
 

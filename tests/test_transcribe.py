@@ -20,7 +20,7 @@ from vlog_tpu.media.audio import AudioData, write_wav
 from vlog_tpu.worker.transcribe import (
     TranscriptionUnavailable,
     _cut_windows,
-    transcribe_audio,
+    transcribe_audio_engine,
     transcribe_video,
 )
 
@@ -89,23 +89,40 @@ def assets(tiny_model_dir):
     return load_whisper(tiny_model_dir)
 
 
+def _through_engine(assets, samples, **kw):
+    """One job through an engine of its own; (cues, language, windows)
+    and the engine's tick records."""
+    from vlog_tpu.asr.engine import AsrEngine
+
+    engine = AsrEngine(assets, batch_windows=8, tick_s=0.05)
+    try:
+        out = transcribe_audio_engine(samples, engine, job_key="t",
+                                      language="en", **kw)
+    finally:
+        engine.close()
+    return out, engine.batch_log
+
+
 @pytest.mark.slow  # ~10s multi-window decode; single-window tests stay fast
 def test_transcribe_audio_batches_and_stitches(assets):
     samples = _tone(40.0)     # 2 windows at 25 s stride
     calls = []
-    cues, lang = transcribe_audio(
-        samples, assets, language="en", max_new=8,
+    (cues, lang, windows), log = _through_engine(
+        assets, samples, max_new=8,
         progress_cb=lambda d, t, m: calls.append((d, t)))
-    assert lang == "en"
+    assert lang == "en" and windows == 2
     assert calls[-1][0] == calls[-1][1] == 2
+    assert sum(b["n"] for b in log) == 2      # cut, batched, stitched
     for c in cues:
         assert 0.0 <= c.start_s <= c.end_s <= 60.0
 
 
 def test_silence_skips_model(assets):
     samples = np.zeros(16000 * 35, np.float32)
-    cues, _ = transcribe_audio(samples, assets, language="en", max_new=4)
-    assert cues == []
+    (cues, _lang, windows), log = _through_engine(assets, samples,
+                                                  max_new=4)
+    assert cues == [] and windows == 2
+    assert log == []            # no window reached the model
 
 
 def test_transcribe_video_writes_vtt(tmp_path, tiny_model_dir, assets):

@@ -1,4 +1,4 @@
-"""Batched beam-search and greedy decoding with Whisper's timestamp grammar.
+"""Batched beam-search decoding with Whisper's timestamp grammar.
 
 Faithful port of the generation *rules* the reference relies on through
 faster-whisper (beam/VAD pipeline, worker/transcription.py:92-133):
@@ -8,11 +8,12 @@ step. The loop itself is TPU-shaped: one ``lax.scan`` over steps with a
 static-shape KV cache, batched over 30 s windows so a long video decodes
 as a few large dispatches instead of thousands of small ones.
 
-Beam search is the production default (``config.WHISPER_BEAM`` is 5, the
-reference's beam size); ``beam=1`` is the greedy scan. Neither program
-moves its self-attention K/V cache: every position is written once, in
-place, and the beam program keeps beam history as a small ancestry table
-that masks a per-window self-attention (``_generate_beam_jit``).
+There is ONE generate program, ``_generate_beam_jit``: beam search at the
+production default (``config.WHISPER_BEAM`` is 5, the reference's beam
+size), and at ``beam=1`` a beam of one, which picks the arg-max token of
+every step. It never moves its self-attention K/V cache: every position
+is written once, in place, and beam history is a small ancestry table
+that masks a per-window self-attention.
 """
 
 from __future__ import annotations
@@ -46,7 +47,7 @@ MAX_INITIAL_TIMESTAMP_INDEX = 50   # first cue within 1.0 s
 class KVCachePool:
     """Static-shape DecoderCache pages, reused across engine ticks.
 
-    The generation loops take the cache as an ARGUMENT and return the
+    The generate program takes the cache as an ARGUMENT and returns the
     final buffers, so the allocation lives here instead of inside the
     jit — the continuous-batching engine used to materialize a fresh
     (layers, B, H, max_len, hd) zeros pair every tick. Pages are keyed
@@ -55,7 +56,7 @@ class KVCachePool:
     is BYTE-SAFE because ``decoder_step`` masks attention to positions
     <= pos and every such position is freshly written during this
     generation's prefill/scan — dirty tail rows are unreachable. The
-    beam program's ancestry mask is narrower still: it admits only
+    scan's ancestry mask is narrower still: it admits only
     (slot, position) pairs its table names, every slot writes its own
     entry at every position <= pos in this generation, and the table
     starts fresh in each call, so nothing a previous tenant left (its
@@ -171,62 +172,8 @@ def apply_timestamp_rules(logits, last, penult, last_ts, step_idx, *,
 
 
 # --------------------------------------------------------------------------
-# Generation
-# --------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("cfg", "sot", "eot", "ts_begin",
-                                   "no_speech", "max_new", "timestamps"))
-def _generate_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
-                  cache, *, cfg: WhisperConfig, sot: int, eot: int,
-                  ts_begin: int, no_speech: int, max_new: int,
-                  timestamps: bool):
-    enc = encode(params, mel, cfg)
-    ckv = cross_kv(params, enc, cfg)
-    b = mel.shape[0]
-    plen = prompt.shape[0]
-
-    # prefill the prompt (static small count of steps)
-    logits = None
-    with jax.named_scope("asr.prompt"):
-        for i in range(plen):
-            tok = jnp.broadcast_to(prompt[i], (b,))
-            logits, cache = decoder_step(params, tok, jnp.int32(i), cache,
-                                         ckv, cfg)
-        # no-speech probability from the first post-prompt distribution
-        probs0 = jax.nn.softmax(logits, axis=-1)
-        no_speech_prob = (probs0[:, no_speech] if no_speech >= 0
-                          else jnp.zeros(b))
-
-    def step(carry, step_idx):
-        cache, logits, last, penult, last_ts, finished = carry
-        with jax.named_scope("asr.token_rules"):
-            lg = logits + suppress_vec
-            lg = jnp.where(step_idx == 0, lg + begin_suppress_vec, lg)
-            if timestamps:
-                lg = apply_timestamp_rules(lg, last, penult, last_ts,
-                                           step_idx, ts_begin=ts_begin,
-                                           eot=eot)
-        with jax.named_scope("asr.beam_select"):    # a beam of one
-            tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            tok = jnp.where(finished, eot, tok)
-            finished = finished | (tok == eot)
-            last_ts = jnp.where(tok >= ts_begin, tok, last_ts)
-        nxt_logits, cache2 = decoder_step(
-            params, tok, (plen + step_idx).astype(jnp.int32), cache, ckv, cfg)
-        return ((cache2, nxt_logits, tok, last, last_ts, finished), tok)
-
-    init = (cache, logits,
-            jnp.full((b,), prompt[-1], jnp.int32),      # last
-            jnp.full((b,), prompt[-2] if plen >= 2 else sot, jnp.int32),
-            jnp.full((b,), ts_begin - 1, jnp.int32),    # no timestamp yet
-            jnp.zeros((b,), bool))
-    (cache, *_), toks = jax.lax.scan(step, init, jnp.arange(max_new))
-    return jnp.transpose(toks), no_speech_prob, cache  # (B, max_new)
-
-
-# --------------------------------------------------------------------------
-# Beam search (the reference's quality bar: faster-whisper beam_size=5,
-# worker/transcription.py:92-133)
+# Generation: beam search (the reference's quality bar: faster-whisper
+# beam_size=5, worker/transcription.py:92-133)
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("cfg", "sot", "eot", "ts_begin",
@@ -359,18 +306,19 @@ def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
                    beam: int = 1) -> tuple[np.ndarray, np.ndarray]:
     """Decode a batch of 30 s mel windows -> (tokens, no_speech_prob).
 
-    ``beam=1`` is the greedy scan; ``beam>1`` runs batched beam search
-    with length-normalized selection (config.WHISPER_BEAM wires the
-    production default; the reference runs beam-5).
+    One program serves every ``beam``: batched beam search with
+    length-normalized selection (config.WHISPER_BEAM wires the
+    production default; the reference runs beam-5), which at ``beam=1``
+    keeps each step's arg-max token.
 
     Window independence is a load-bearing contract: no op here crosses
     windows (per-row conv/argmax, one shared prompt, attention and the
     beam top-K inside one window), so window i's tokens never depend on
     windows j != i — zero-padded rows and co-batched jobs cannot perturb
-    a window's output. At ``beam=1`` a window is one row; at ``beam>1``
-    the K beam rows of ONE window share that window's K self-K/V cache
-    slots (each row reads its history through the ancestry table's
-    mask), and nothing is shared between windows. The continuous-
+    a window's output. The K beam rows of ONE window share that window's
+    K self-K/V cache slots (each row reads its history through the
+    ancestry table's mask; at ``beam=1`` a window is one row and one
+    slot), and nothing is shared between windows. The continuous-
     batching engine (asr/engine.py) builds its byte-identical
     solo-vs-packed guarantee on this; tests/test_asr_engine.py breaks
     if it regresses. One shared prompt per call also means callers may
@@ -413,18 +361,14 @@ def _dispatch(assets: WhisperAssets, mel: jnp.ndarray, *, language: str,
         cfg=cfg, sot=st.sot, eot=st.eot, ts_begin=st.timestamp_begin,
         no_speech=st.no_speech if st.no_speech is not None else -1,
         max_new=int(max_new), timestamps=timestamps)
-    rows = mel.shape[0] * (int(beam) if beam > 1 else 1)
-    cache = kv_pool.lease(cfg, rows, len(prompt) + int(max_new))
-    args = (assets.params, jnp.asarray(mel),
-            jnp.asarray(prompt, jnp.int32), jnp.asarray(sup),
-            jnp.asarray(bsup), cache)
-    if beam > 1:
-        toks, nsp, cache = _generate_beam_jit(*args, beam=int(beam),
-                                              **kwargs)
-    else:
-        toks, nsp, cache = _generate_jit(*args, **kwargs)
+    beam = max(1, int(beam))
+    cache = kv_pool.lease(cfg, mel.shape[0] * beam,
+                          len(prompt) + int(max_new))
+    toks, nsp, cache = _generate_beam_jit(
+        assets.params, jnp.asarray(mel), jnp.asarray(prompt, jnp.int32),
+        jnp.asarray(sup), jnp.asarray(bsup), cache, beam=beam, **kwargs)
     # return the FINAL buffers to the pool: the leased input pages were
-    # consumed functionally (same shape either way)
+    # consumed functionally (same shape)
     kv_pool.release(cache)
     return toks, nsp
 

@@ -7,7 +7,7 @@ with the WhisperPipe/WhisperFlow serving shape (PAPERS.md): a per-process
 singleton owns the Whisper assets (loaded once via the memoized
 ``load_whisper``) and a cross-job :class:`~vlog_tpu.asr.queue.WindowQueue`;
 a tick thread packs windows from many concurrent jobs into fixed-shape
-bucketed batches and runs one batched mel -> encode -> greedy-decode
+bucketed batches and runs one batched mel -> encode -> beam-decode
 forward per tick. Freed batch rows backfill from the queue as jobs' tails
 drain — the continuous-batching core.
 
@@ -23,11 +23,14 @@ before this design was locked in; ``tests/test_asr_engine.py`` asserts
 byte-identical ``captions.vtt`` solo vs. packed with N other jobs.
 
 Mesh integration: the ENGINE owns the slot demand, not the jobs — N
-concurrent transcriptions share one ``MeshScheduler`` ticket, acquired
-when the queue has work and released at tick boundaries when the queue
-drains or other demand is pending (work-conserving: a lone engine gets
-the full-mesh fallback lease, and gives it back as soon as a transcode
-job queues up).
+concurrent transcriptions share one ``MeshScheduler`` ticket
+(``parallel/engine_host.py::HeldLease``), acquired when the queue has
+work and released at tick boundaries when the queue drains or other
+demand is pending (work-conserving: a lone engine gets the full-mesh
+fallback lease, and gives it back as soon as a transcode job queues
+up). Which engine is resident in the process, and who builds the next
+one, is ``parallel/engine_host.py::HOST``'s to say; ``get_engine`` below
+only knows how to load this plane's assets.
 
 The engine traces itself. The tick thread runs under a trace context of
 its own (one trace id per engine, a ``TraceBuffer`` as the sink) and
@@ -73,6 +76,7 @@ from vlog_tpu.asr.queue import BatchKey, WindowQueue, WorkItem
 from vlog_tpu.asr.vtt import Cue
 from vlog_tpu.obs import trace
 from vlog_tpu.parallel import compile_cache
+from vlog_tpu.parallel.engine_host import HOST, HeldLease
 from vlog_tpu.utils import failpoints
 
 # phases of a tick record, in the order a cycle runs them; the last part
@@ -187,7 +191,7 @@ class AsrEngine:
         self._started = False                   # guarded-by: _lock
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
-        self._lease_held = threading.Event()    # observability only
+        self._hold = HeldLease(scheduler)
         # One tick record per tick (module docstring): batch composition
         # for tests/stats, timing for whoever asks where a tick went.
         self.batch_log: list[dict] = []         # guarded-by: _lock
@@ -195,8 +199,6 @@ class AsrEngine:
         self._trace = trace.TraceContext(trace.new_id(), None,
                                          trace.TraceBuffer())
         # tick thread only
-        self._ticket = None
-        self._lease = None
         self._seq = 0
         self._prev_ready: float | None = None
         # phase seconds of passes that served nothing (a timed-out wait,
@@ -218,14 +220,8 @@ class AsrEngine:
             self._jobs[job] = handle
             if not self._started:
                 self._started = True
-
-                def serve():
-                    with trace.attach(self._trace):
-                        self._run()
-
-                self._thread = threading.Thread(
-                    target=serve, name="vlog-asr-engine", daemon=True)
-                self._thread.start()
+                self._thread = trace.start_thread(
+                    self._trace, self._run, name="vlog-asr-engine")
         return handle
 
     def detect_language(self, samples: np.ndarray) -> str:
@@ -243,7 +239,7 @@ class AsrEngine:
         The daemon uses this to keep claiming transcription jobs that
         will pile onto the running engine even when mesh capacity reads
         zero."""
-        return self._lease_held.is_set() or self._queue.pending() > 0
+        return self._hold.held.is_set() or self._queue.pending() > 0
 
     def stats(self) -> dict:
         from vlog_tpu.asr.decode import kv_pool
@@ -258,11 +254,14 @@ class AsrEngine:
                     "kv_pool": kv_pool.stats()}
 
     def close(self) -> None:
+        from vlog_tpu.asr.decode import kv_pool
+
         self._stop.set()
         self._queue.close()
         t = self._thread
         if t is not None and t.is_alive():
             t.join(timeout=30)
+        kv_pool.reset()     # the cache pages are this plane's device memory
 
     def _drop(self, job: str) -> None:
         with self._lock:
@@ -271,70 +270,28 @@ class AsrEngine:
     # tick loop ------------------------------------------------------------
 
     def _run(self) -> None:
-        self._built()       # the meter is on before the first build
+        compile_cache.thread_built_s()  # the meter is on before a build
         try:
             while not self._stop.is_set():
                 self._cycle()
         finally:
-            self._release()
+            self._hold.release()
             self._trace.buffer.drain()
 
-    def _release(self) -> None:
-        if self._ticket is not None:
-            self._ticket.close()   # releases the lease too
-        self._ticket = None
-        self._lease = None
-        self._lease_held.clear()
-
-    def _acquire(self) -> bool:
-        """Hold a slot lease where a scheduler hands them out; False if
-        the engine is stopping."""
-        if self.scheduler is None or self._lease is not None:
-            return True
-        from vlog_tpu.parallel.scheduler import SlotCancelled
-
-        self._ticket = self.scheduler.admit()
-        try:
-            self._lease = self._ticket.acquire(cancel=self._stop)
-        except SlotCancelled:
-            self._release()
-            return False
-        self._lease_held.set()
-        return True
-
     def _renegotiate(self) -> None:
-        """Work-conserving renegotiation at the tick boundary: a
-        full-mesh fallback lease shrinks to a slot as soon as other
-        demand queues; any lease goes back when the window queue
-        drains."""
-        if self._lease is None:
-            return
+        """Work-conserving renegotiation at the tick boundary: any lease
+        goes back when the window queue drains; a full-mesh fallback
+        lease shrinks to a slot as soon as other demand queues."""
         if self._queue.pending() == 0:
-            self._release()
-        elif (self._lease.is_full_mesh
-              and self.scheduler.snapshot()["pending"] > 0):
-            self._release()
-
-    @staticmethod
-    def _built() -> float:
-        """Seconds this thread has spent building programs so far."""
-        return compile_cache.build_total(
-            compile_cache.thread_build_seconds())
-
-    @staticmethod
-    def _add_phases(phase_s: dict[str, float],
-                    spans: list[trace.Span]) -> None:
-        """Each span's seconds under the phase its name ends in."""
-        for sp in spans:
-            leaf = sp.name.rsplit(".", 1)[-1]
-            if leaf in phase_s:
-                phase_s[leaf] += sp.duration_s
+            self._hold.release()
+        else:
+            self._hold.yield_full_mesh()
 
     def _cycle(self) -> None:
         """One pass of the tick loop under one ``asr.tick`` span; a pass
         that served windows leaves a tick record."""
         entry = None
-        built0 = self._built()
+        built0 = compile_cache.thread_built_s()
         with trace.span("asr.tick") as tick:
             with trace.span("asr.tick.coalesce"):
                 idle = not self._queue.wait_for_work(timeout=0.2)
@@ -343,10 +300,10 @@ class AsrEngine:
                     # packing, so the first tick is not a batch of one.
                     time.sleep(self.tick_s)
             if idle:
-                self._release()     # give the slot back
+                self._hold.release()    # give the slot back
             else:
                 with trace.span("asr.tick.lease"):
-                    leased = self._acquire()
+                    leased = self._hold.acquire(self._stop)
                 items: list[WorkItem] = []
                 if leased:
                     with trace.span("asr.tick.take"):
@@ -363,7 +320,7 @@ class AsrEngine:
             # idle or failed: the thread's seconds go to the next tick's
             # phases (its wait for a first window began here), but the
             # device's time since the last tick is not that tick's gap
-            self._add_phases(self._carry_s, spans)
+            trace.add_phase_seconds(self._carry_s, spans)
             self._prev_ready = None
             return
         with self._lock:
@@ -386,7 +343,7 @@ class AsrEngine:
               built0: float) -> dict | None:
         """Decode one batch; returns its tick record (already in
         ``batch_log``, its results delivered) or None if it failed."""
-        lease = self._lease
+        lease = self._hold.lease
         n = len(items)
         try:
             with trace.span("asr.tick.stack") as stacking:
@@ -465,7 +422,7 @@ class AsrEngine:
             "windows": [(it.job, it.index) for it in items],
             "wait_s": [wait_s for _it, _cues, wait_s in results],
             "first_of_shape": first_of_shape,
-            "build_s": self._built() - built0,
+            "build_s": compile_cache.thread_built_s() - built0,
         }
         self._seq += 1
         with self._lock:
@@ -489,8 +446,7 @@ class AsrEngine:
         phase from the spans' own durations, the instants from their
         ends. Called under ``_lock`` when the entry is appended (before
         delivery) and again when the tick has closed."""
-        phase_s = dict(self._carry_s)
-        self._add_phases(phase_s, spans)
+        phase_s = trace.add_phase_seconds(dict(self._carry_s), spans)
         generating = dispatched = ready = None
         for sp in spans:
             if sp.name == "asr.tick.generate":
@@ -547,60 +503,31 @@ class AsrEngine:
             pass
 
 
-# Per-process engine singleton -------------------------------------------
-
-_ENGINE: AsrEngine | None = None
-_ENGINE_KEY: tuple | None = None
-_ENGINE_LOCK = threading.Lock()
-
+# The process's engine (parallel/engine_host.py holds it) --------------
 
 def get_engine(model_dir: str, *, scheduler=None) -> AsrEngine:
     """The process's shared engine, (re)built when the checkpoint dir,
     quant mode, or scheduler changes (tests swap tiny model dirs; the
-    daemon always passes its one scheduler singleton)."""
+    daemon always passes its one scheduler singleton). One model engine
+    is resident at a time and one is built at a time: the host first
+    waits for another plane's engine to go idle and closes it."""
     from vlog_tpu.asr.load import resolve_quant
-    from vlog_tpu.parallel.compile_cache import ensure_compile_cache
 
-    global _ENGINE, _ENGINE_KEY
     quant = resolve_quant()
-    key = (str(model_dir), id(scheduler), quant)
-    with _ENGINE_LOCK:
-        if _ENGINE is not None and _ENGINE_KEY == key:
-            return _ENGINE
-        old = _ENGINE
-        _ENGINE = None
-        _ENGINE_KEY = None
-    if old is not None:
-        old.close()
-    # one model engine is resident at a time: an idle transcript engine
-    # (lm/engine.py) gives the chip's memory back first
-    from vlog_tpu.lm import residency
 
-    residency.make_room("asr")
-    ensure_compile_cache()
-    assets = load_whisper(model_dir, quant)
-    engine = AsrEngine(assets, scheduler=scheduler)
-    with _ENGINE_LOCK:
-        if _ENGINE is None:
-            _ENGINE = engine
-            _ENGINE_KEY = key
-        else:            # lost the race; serve the winner
-            engine.close()
-        return _ENGINE
+    def build() -> AsrEngine:
+        compile_cache.ensure_compile_cache()
+        return AsrEngine(load_whisper(model_dir, quant),
+                         scheduler=scheduler)
+
+    return HOST.obtain("asr", (str(model_dir), id(scheduler), quant), build)
 
 
 def peek_engine() -> AsrEngine | None:
-    """The process engine if one exists — never builds one (the daemon's
-    claim loop asks "is the engine already serving?" without forcing a
-    checkpoint load)."""
-    with _ENGINE_LOCK:
-        return _ENGINE
+    """The process engine if one exists — never builds one."""
+    return HOST.peek("asr")
 
 
 def reset_engine() -> None:
     """Tear down the process engine (tests)."""
-    global _ENGINE, _ENGINE_KEY
-    with _ENGINE_LOCK:
-        old, _ENGINE, _ENGINE_KEY = _ENGINE, None, None
-    if old is not None:
-        old.close()
+    HOST.evict("asr")

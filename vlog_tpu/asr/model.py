@@ -198,8 +198,9 @@ def _window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray
 def _cross_attn(p: Params, name: str, x: jnp.ndarray, kv, n_heads: int
                 ) -> jnp.ndarray:
     """``kv`` is a layer's (K, V), each (B, H, S, hd) with one row per
-    row of ``x``, or (W, H, S, hd) with W windows for W*K rows of one
-    position each (the beam program): the K rows of a window then share
+    row of ``x`` (teacher forcing, ``detect_language``, a beam of one),
+    or (W, H, S, hd) with W windows for W*K rows of one position each
+    (the generate program at K > 1): the K rows of a window then share
     its K/V (:func:`_window_attention`)."""
     head_dim = x.shape[-1] // n_heads
     q = _split_heads(_linear(p, f"{name}.q_proj", x) * head_dim ** -0.5,
@@ -302,12 +303,13 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     sliced out, updated and restacked) and attention masks positions >
     pos. With ``anc`` (W, K, max_len), B is W windows x K beams and a
     row attends through its ancestry table over its window's K cache
-    slots (:func:`_beam_attention`); without it each row attends over
-    its own cache row. ``ckv`` is :func:`cross_kv`'s list, a (K, V) pair
-    per layer of (rows, H, source, hd) each: B rows, one per query row
-    (greedy, ``detect_language``), or W rows for B = W x K, the K beams
-    of a window reading its K/V together (:func:`_cross_attn` tells the
-    two apart by the row count; nothing is tiled).
+    slots (:func:`_beam_attention`); without it (the generate program's
+    prompt steps, ``detect_language``) each row attends over its own
+    cache row. ``ckv`` is :func:`cross_kv`'s list, a (K, V) pair per
+    layer of (rows, H, source, hd) each: B rows, one per query row
+    (``detect_language``, a beam of one), or W rows for B = W x K, the
+    K beams of a window reading its K/V together (:func:`_cross_attn`
+    tells the two apart by the row count; nothing is tiled).
     """
     p = params
     nh = cfg.decoder_attention_heads

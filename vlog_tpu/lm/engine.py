@@ -2,14 +2,14 @@
 job, batching at the step.
 
 A thread of its own (``vlog-lm-engine``) runs steps under a
-``MeshScheduler`` lease held the way ``AsrEngine`` holds its own
-(acquired when there is work, given back when the engine drains or other
-demand queues). Every step takes ALL resident decoding rows (one token
-each, at most ``rows``) and at most ONE prefill chunk (at most ``chunk``
-tokens) of one waiting request; a request's row joins the decoding rows
-when its last chunk is done and leaves at its last token. Decoding is
-greedy and the next token goes back in on the device
-(``model.py::build_step``). Shapes are bucketed (the chunk: 0, page,
+``MeshScheduler`` lease (``parallel/engine_host.py::HeldLease``, as
+``AsrEngine`` holds its own: acquired when there is work, given back
+when the engine drains or other demand queues). Every step takes ALL
+resident decoding rows (one token each, at most ``rows``) and at most
+ONE prefill chunk (at most ``chunk`` tokens) of one waiting request; a
+request's row joins the decoding rows when its last chunk is done and
+leaves at its last token. Decoding is greedy and the next token goes
+back in on the device (``model.py::build_step``). Shapes are bucketed (the chunk: 0, page,
 2 page, ... chunk; the rows: always ``rows``) and :meth:`LmEngine.prepare`
 builds and runs every one of them, so nothing compiles once requests
 flow.
@@ -55,6 +55,7 @@ from vlog_tpu.lm.model import (Geometry, build_step, empty_cache,
                                plan_shapes, unpack_ints)
 from vlog_tpu.obs import trace
 from vlog_tpu.parallel import compile_cache
+from vlog_tpu.parallel.engine_host import HOST, HeldLease
 
 PHASES = ("admit", "pages", "stack", "dispatch", "device_wait", "deliver")
 THREAD = "vlog-lm-engine"
@@ -116,7 +117,7 @@ class LmEngine:
         self._ready = threading.Event()
         self._failed: BaseException | None = None
         self._thread: threading.Thread | None = None
-        self._lease_held = threading.Event()
+        self._hold = HeldLease(scheduler)
         self._trace = trace.TraceContext(trace.new_id(), None,
                                          trace.TraceBuffer())
         self.programs: dict[int, object] = {}       # bucket -> compiled
@@ -128,8 +129,6 @@ class LmEngine:
         self._prefilling: LmRequest | None = None
         self._rows: list[LmRequest | None] = [None] * self.geo.rows
         self._flight: tuple | None = None           # (record, out)
-        self._ticket = None
-        self._lease = None
         self._seq = 0
         self._prev_end: float | None = None
         self._prev_ready: float | None = None
@@ -188,7 +187,7 @@ class LmEngine:
         """Serving (lease held, requests queued or resident)?"""
         with self._lock:
             queued = bool(self._inbox)
-        return queued or self._lease_held.is_set() or self._busy()
+        return queued or self._hold.held.is_set() or self._busy()
 
     def stats(self) -> dict:
         with self._lock:
@@ -212,14 +211,8 @@ class LmEngine:
         if self._started:
             return
         self._started = True
-
-        def serve():
-            with trace.attach(self._trace):
-                self._run()
-
-        self._thread = threading.Thread(target=serve, name=THREAD,
-                                        daemon=True)
-        self._thread.start()
+        self._thread = trace.start_thread(self._trace, self._run,
+                                          name=THREAD)
 
     # the engine's thread ---------------------------------------------
 
@@ -237,7 +230,7 @@ class LmEngine:
                 self._cycle()
         finally:
             self._fail_all(LmJobError("the engine closed"))
-            self._release()
+            self._hold.release()
             self._trace.buffer.drain()
 
     def _build(self) -> None:
@@ -269,36 +262,10 @@ class LmEngine:
                 or self._flight is not None
                 or any(r is not None for r in self._rows))
 
-    @staticmethod
-    def _built() -> float:
-        return compile_cache.build_total(
-            compile_cache.thread_build_seconds())
-
-    def _release(self) -> None:
-        if self._ticket is not None:
-            self._ticket.close()
-        self._ticket = None
-        self._lease = None
-        self._lease_held.clear()
-
-    def _acquire(self) -> bool:
-        if self.scheduler is None or self._lease is not None:
-            return True
-        from vlog_tpu.parallel.scheduler import SlotCancelled
-
-        self._ticket = self.scheduler.admit()
-        try:
-            self._lease = self._ticket.acquire(cancel=self._stop)
-        except SlotCancelled:
-            self._release()
-            return False
-        self._lease_held.set()
-        return True
-
     def _cycle(self) -> None:
         """One iteration: plan and dispatch step ``n``, then pull and
         deliver step ``n - 1``."""
-        built0 = self._built()
+        built0 = compile_cache.thread_built_s()
         record = None
         with trace.span("lm.step") as top:
             with trace.span("lm.step.admit"):
@@ -308,11 +275,11 @@ class LmEngine:
                     while self._inbox:
                         self._waiting.append(self._inbox.popleft())
                 if not self._busy():
-                    self._release()         # idle: give the slot back
+                    self._hold.release()    # idle: give the slot back
                     self._prev_end = self._prev_ready = None
                     self._trace.buffer.drain()
                     return
-                if not self._acquire():
+                if not self._hold.acquire(self._stop):
                     return
             try:
                 step = self._plan()
@@ -339,7 +306,7 @@ class LmEngine:
         now = time.monotonic()
         self._prev_end = now
         if record is not None:
-            record["build_s"] = self._built() - built0
+            record["build_s"] = compile_cache.thread_built_s() - built0
             record["t_start"] = top.started_mono
             record["host_phase_s"] = self._phases(spans)
         if prev is not None:
@@ -354,20 +321,13 @@ class LmEngine:
             with self._lock:
                 self.step_log.append(done)
             self._observe(done)
-        if self.scheduler is not None and self._lease is not None \
-                and self._lease.is_full_mesh \
-                and self.scheduler.snapshot()["pending"] > 0 \
-                and self._flight is None:
-            self._release()
+        if self._flight is None:
+            self._hold.yield_full_mesh()
 
     @staticmethod
     def _phases(spans) -> dict:
-        out = dict.fromkeys(PHASES, 0.0)
-        for sp in spans:
-            leaf = sp.name.rsplit(".", 1)[-1]
-            if leaf in out and sp.name.startswith("lm.step."):
-                out[leaf] += sp.duration_s
-        return out
+        return trace.add_phase_seconds(dict.fromkeys(PHASES, 0.0), spans,
+                                       under="lm.step.")
 
     # planning ----------------------------------------------------------
 
@@ -570,48 +530,23 @@ def default_geometry(cfg) -> Geometry:
                     full_pages=base.rows * base.max_pages + 1)
 
 
-# Per-process engine singleton ------------------------------------------
-
-_ENGINE: LmEngine | None = None
-_ENGINE_KEY: tuple | None = None
-_ENGINE_LOCK = threading.Lock()
-# one build at a time: two digest jobs claimed together would otherwise
-# both load the weights, and two copies of 8.6 GB pass the chip's 16
-_BUILD_LOCK = threading.Lock()
-
+# The process's engine (parallel/engine_host.py holds it) --------------
 
 def get_engine(model_dir: str, *, scheduler=None) -> LmEngine:
     """The process's transcript engine, (re)built when the model
     directory or the scheduler changes. One model engine is resident on
-    a worker at a time: building this one first waits for an
-    ``AsrEngine`` to go idle and tears it down (``residency.py``)."""
-    from vlog_tpu.lm import residency
+    a worker at a time and one is built at a time: the host first waits
+    for an ``AsrEngine`` to go idle and closes it."""
     from vlog_tpu.lm.load import load_model_dir
 
-    global _ENGINE, _ENGINE_KEY
-    key = (str(model_dir), id(scheduler))
-    with _BUILD_LOCK:
-        with _ENGINE_LOCK:
-            if _ENGINE is not None and _ENGINE_KEY == key:
-                return _ENGINE
-            old, _ENGINE, _ENGINE_KEY = _ENGINE, None, None
-        if old is not None:
-            old.close()
-        residency.make_room("lm")
-        engine = LmEngine(load_model_dir(model_dir), scheduler=scheduler)
-        with _ENGINE_LOCK:
-            _ENGINE, _ENGINE_KEY = engine, key
-        return engine
+    return HOST.obtain(
+        "lm", (str(model_dir), id(scheduler)),
+        lambda: LmEngine(load_model_dir(model_dir), scheduler=scheduler))
 
 
 def peek_engine() -> LmEngine | None:
-    with _ENGINE_LOCK:
-        return _ENGINE
+    return HOST.peek("lm")
 
 
 def reset_engine() -> None:
-    global _ENGINE, _ENGINE_KEY
-    with _ENGINE_LOCK:
-        old, _ENGINE, _ENGINE_KEY = _ENGINE, None, None
-    if old is not None:
-        old.close()
+    HOST.evict("lm")

@@ -47,11 +47,12 @@ import time
 import uuid
 from contextvars import ContextVar
 from dataclasses import dataclass, field
-from typing import Iterator
+from typing import Callable, Iterable, Iterator
 
 __all__ = [
     "Span", "TraceBuffer", "TraceContext", "new_id", "current", "capture",
-    "attach", "span", "event", "record_run_stages",
+    "attach", "start_thread", "span", "event", "record_run_stages",
+    "add_phase_seconds",
 ]
 
 # The five cumulative busy-seconds fields RunResult.stage_s has carried
@@ -162,6 +163,21 @@ def attach(ctx: TraceContext | None) -> Iterator[TraceContext | None]:
         _CTX.reset(token)
 
 
+def start_thread(ctx: TraceContext | None, target: Callable[[], None], *,
+                 name: str) -> threading.Thread:
+    """Start a daemon thread that runs ``target`` under ``ctx`` (a
+    thread inherits no context): a model engine's thread under the
+    engine's own trace."""
+
+    def serve() -> None:
+        with attach(ctx):
+            target()
+
+    thread = threading.Thread(target=serve, name=name, daemon=True)
+    thread.start()
+    return thread
+
+
 def _annotation(name: str, attrs: dict):
     """A ``TraceAnnotation`` for the profiler's own trace, or None in a
     process that has not imported jax (never import it from here). The
@@ -259,3 +275,17 @@ def record_run_stages(parent: Span, stage_s: dict | None) -> None:
                   started_at=parent.started_at, synthetic=True)
         else:
             parent.attrs[key] = val
+
+
+def add_phase_seconds(phase_s: dict[str, float], spans: Iterable[Span], *,
+                      under: str = "") -> dict[str, float]:
+    """Add each span's seconds to the phase its name ends in
+    (``asr.tick.mel`` -> ``mel``) and return ``phase_s``: how a model
+    engine folds one cycle's spans into its tick or step record. Spans
+    whose last part is no key of ``phase_s``, or whose name does not
+    start with ``under``, are left out."""
+    for sp in spans:
+        leaf = sp.name.rsplit(".", 1)[-1]
+        if leaf in phase_s and sp.name.startswith(under):
+            phase_s[leaf] += sp.duration_s
+    return phase_s

@@ -145,6 +145,11 @@ def build_total(phases: dict[str, float]) -> float:
     return phases["trace"] + phases["lower"] + phases["compile"]
 
 
+def thread_built_s() -> float:
+    """Seconds the calling thread has spent building programs so far."""
+    return build_total(thread_build_seconds())
+
+
 def ensure_compile_cache() -> str | None:
     """Arm the persistent compile cache (idempotent); returns the cache
     dir in effect, or None when this platform runs without one."""

@@ -46,6 +46,7 @@ from vlog_tpu.codecs import validate_codec_format
 from vlog_tpu.db.core import Database, Row, now as db_now, open_database
 from vlog_tpu.enums import AcceleratorKind, FailureClass, JobKind, VideoStatus
 from vlog_tpu.jobs import claims, state as js, videos as vids
+from vlog_tpu.parallel.engine_host import HOST
 from vlog_tpu.parallel.faults import RepeatFaultDetector
 from vlog_tpu.utils import failpoints
 from vlog_tpu.worker.breaker import CircuitBreaker
@@ -553,6 +554,9 @@ class WorkerDaemon(ComputeWatchdogMixin):
         if self.scheduler is None or self.scheduler.slots <= 1:
             return await self.poll_once()
         device_kinds = (JobKind.TRANSCODE, JobKind.REENCODE)
+        # kinds whose device demand a shared model engine owns, by the
+        # name the engine's plane gives the host
+        engine_planes = {JobKind.TRANSCRIPTION: "asr", JobKind.DIGEST: "lm"}
         batch: list[tuple[Row, Any]] = []
         try:
             # The hold freezes slot grants for the round, making the
@@ -583,14 +587,15 @@ class WorkerDaemon(ComputeWatchdogMixin):
                     if capacity <= 0:
                         kinds = tuple(k for k in self.kinds
                                       if k not in device_kinds)
-                        if not self._asr_engine_active():
-                            kinds = tuple(k for k in kinds
-                                          if k != JobKind.TRANSCRIPTION)
                         # the digest plane is gated the same way: its
-                        # step engine holds the one ticket
-                        if not self._lm_engine_active():
-                            kinds = tuple(k for k in kinds
-                                          if k != JobKind.DIGEST)
+                        # step engine holds the one ticket. The host
+                        # never builds an engine (an idle worker must
+                        # not page in weights from the claim loop) nor
+                        # imports a plane.
+                        kinds = tuple(
+                            k for k in kinds
+                            if k not in engine_planes
+                            or HOST.active(engine_planes[k]))
                         if not kinds:
                             break
                     # Batched claim: one transaction fills as many free
@@ -627,24 +632,6 @@ class WorkerDaemon(ComputeWatchdogMixin):
                 self._tasks.add(task)
                 task.add_done_callback(self._tasks.discard)
         return bool(batch)
-
-    def _asr_engine_active(self) -> bool:
-        """Is the shared ASR engine already serving (lease held or
-        windows queued)? Never builds the engine — an idle worker must
-        not page in Whisper weights from the claim loop."""
-        from vlog_tpu.asr.engine import peek_engine
-
-        eng = peek_engine()
-        return eng is not None and eng.active()
-
-    def _lm_engine_active(self) -> bool:
-        """The same question of the transcript model's step engine;
-        never builds it (nor imports its plane)."""
-        import sys
-
-        mod = sys.modules.get("vlog_tpu.lm.engine")
-        eng = mod.peek_engine() if mod is not None else None
-        return eng is not None and eng.active()
 
     async def _run_slot_job(self, job: Row, ticket: Any) -> None:
         """One slot job's task body: _process_claimed with the same
@@ -1405,7 +1392,7 @@ class WorkerDaemon(ComputeWatchdogMixin):
         worker's shared step engine (worker/digest.py). A device kind
         like transcription: the ENGINE holds the one scheduler ticket,
         and building it first evicts an idle ASR engine (one model
-        engine is resident at a time, lm/residency.py)."""
+        engine is resident at a time, parallel/engine_host.py)."""
         from vlog_tpu.worker.digest import digest_video
 
         out_dir = self.video_dir / video["slug"]

@@ -66,88 +66,6 @@ def _cut_windows(samples: np.ndarray, *, window_s: float, overlap_s: float
     return out
 
 
-def transcribe_audio(
-    samples: np.ndarray,
-    assets,
-    *,
-    language: str | None = None,
-    window_s: float | None = None,
-    overlap_s: float | None = None,
-    batch_windows: int = 8,
-    max_new: int | None = None,
-    progress_cb: ProgressFn | None = None,
-) -> tuple[list[Cue], str]:
-    """16 kHz mono float PCM -> stitched cues + language code."""
-    from vlog_tpu.asr.decode import (detect_language, generate_batch,
-                                     parse_segments)
-
-    window_s = window_s or config.WHISPER_CHUNK_S
-    overlap_s = overlap_s if overlap_s is not None else config.WHISPER_OVERLAP_S
-    windows = _cut_windows(samples, window_s=window_s, overlap_s=overlap_s)
-    # VAD: decode only windows that overlap detected speech (the
-    # reference's faster-whisper vad_filter analog, asr/vad.py); the RMS
-    # gate stays as a cheap pre-filter for all-silence windows
-    from vlog_tpu.asr.vad import speech_spans, window_has_speech
-
-    spans = speech_spans(samples)
-    live = [i for i, (t0, w) in enumerate(windows)
-            if w.size and float(np.sqrt(np.mean(w ** 2))) > SILENCE_RMS
-            and window_has_speech(spans, t0, t0 + window_s)]
-    per_window_cues: list[list[Cue]] = [[] for _ in windows]
-    tokenizer = assets.tokenizer
-    st = assets.tokens
-
-    # Multi-chip: shard the window batch over the mesh's data axis —
-    # each device decodes its windows, collective-free (SURVEY §2d.5).
-    import jax
-
-    n_dev = len(jax.devices())
-    mesh = None
-    if n_dev > 1:
-        from vlog_tpu.parallel.mesh import make_mesh
-
-        mesh = make_mesh()
-        batch_windows += (-batch_windows) % n_dev
-
-    done = 0
-    for b0 in range(0, len(live), batch_windows):
-        idxs = live[b0:b0 + batch_windows]
-        n_real = len(idxs)
-        stack = [melmod.pad_or_trim(windows[i][1].astype(np.float32))
-                 for i in idxs]
-        if mesh is not None:     # pad so the batch divides the mesh
-            stack += [np.zeros_like(stack[0])] * ((-n_real) % n_dev)
-        batch = np.stack(stack)
-        feats = melmod.log_mel_spectrogram(batch,
-                                           n_mels=assets.cfg.num_mel_bins)
-        if language is None:
-            # Detect from the first live window only: cheap (one window's
-            # encoder pass) and never polluted by zero-padding rows.
-            language = detect_language(assets, feats[:1])
-        if mesh is not None:
-            from vlog_tpu.parallel.mesh import shard_frames
-
-            (feats,) = shard_frames(mesh, feats)
-        toks, no_speech = generate_batch(assets, feats, language=language,
-                                         max_new=max_new,
-                                         beam=config.WHISPER_BEAM)
-        toks, no_speech = toks[:n_real], no_speech[:n_real]
-        for row, nsp, i in zip(toks, no_speech, idxs):
-            if st.no_speech is not None and nsp > 0.6:
-                continue
-            t0 = windows[i][0]
-            for seg in parse_segments(row, st, window_s=window_s):
-                text = tokenizer.decode([t for t in seg.token_ids
-                                         if t < st.sot])
-                per_window_cues[i].append(
-                    Cue(t0 + seg.start_s, t0 + seg.end_s, text))
-        done += len(idxs)
-        if progress_cb:
-            progress_cb(done, len(live),
-                        f"transcribed {done}/{len(live)} windows")
-    return stitch_windows(per_window_cues), language or "en"
-
-
 def transcribe_audio_engine(
     samples: np.ndarray,
     engine,
@@ -316,7 +234,6 @@ def transcribe_video(
     model_dir: str | None = None,
     language: str | None = None,
     progress_cb: ProgressFn | None = None,
-    batch_windows: int = 8,     # legacy knob; the engine sizes its own
     max_new: int | None = None,
     engine=None,
     job_key: str | None = None,
