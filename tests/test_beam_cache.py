@@ -7,7 +7,8 @@ token for token what a beam search gives that keeps the former
 formulation (the cache gathered by parent every step, each row attending
 over its own cache row); windows cannot touch each other; and the
 program's scan body holds the in-place writes and nothing else that
-moves a cache (counts of operations, never a time). Since PR 30 the
+moves a cache (counts of operations, never a time; what the TPU compiler
+makes of it is tests/test_beam_cache_layout.py's). Since PR 30 the
 cross-attention K/V stays by window too: no equation of the beam program
 holds a ``windows x beam``-row array with a source axis, and a step with
 K/V by window gives the logits of a step with K/V repeated per row.
@@ -22,8 +23,10 @@ import jax
 import jax.numpy as jnp
 
 from vlog_tpu.asr import decode
-from vlog_tpu.asr.model import (DecoderCache, WhisperConfig, cross_kv,
-                                decoder_step, encode, init_random_params)
+from vlog_tpu.asr.model import (DecoderCache, StepCache, WhisperConfig,
+                                _attention, _beam_attention, _split_heads,
+                                cross_kv, decoder_step, encode,
+                                init_random_params)
 
 CFG = WhisperConfig(
     d_model=32, encoder_layers=1, decoder_layers=2,
@@ -74,7 +77,7 @@ def _gathering_beam_search(params, mel, *, max_new: int, timestamps: bool):
     neg = jnp.finfo(jnp.float32).min
     ckv = [(jnp.repeat(ck, k, axis=0), jnp.repeat(cv, k, axis=0))
            for ck, cv in cross_kv(params, encode(params, mel, CFG), CFG)]
-    cache = DecoderCache.create(CFG, bk, len(PROMPT) + max_new)
+    cache = StepCache.create(CFG, bk, len(PROMPT) + max_new)
     for i, tok in enumerate(PROMPT):
         logits, cache = decoder_step(params, jnp.full((bk,), tok, jnp.int32),
                                      jnp.int32(i), cache, ckv, CFG)
@@ -111,8 +114,8 @@ def _gathering_beam_search(params, mel, *, max_new: int, timestamps: bool):
         last_ts = jnp.where(token >= ts_begin, token,
                             jnp.take(last_ts, gparent, axis=0))
         finished = jnp.take(finished, gparent, axis=0) | (token == eot)
-        cache = DecoderCache(k=jnp.take(cache.k, gparent, axis=1),
-                             v=jnp.take(cache.v, gparent, axis=1))
+        cache = StepCache(k=jnp.take(cache.k, gparent, axis=1),
+                          v=jnp.take(cache.v, gparent, axis=1))
         logits, cache = decoder_step(params, token,
                                      jnp.int32(len(PROMPT) + step), cache,
                                      ckv, CFG)
@@ -229,36 +232,50 @@ def _traced(params, beam: int):
 @pytest.mark.parametrize("beam", [K, 1])
 def test_scan_body_writes_the_cache_in_place_and_never_moves_it(
         params, beam):
-    """What the scan body does to a rank-5 array: ``2 x decoder_layers``
-    ``dynamic_update_slice`` of one position each, one slice a layer for
-    K and for V to read the layer back, and nothing else: no gather, no
-    concatenate (``jnp.stack``), no rank-5 ``dynamic_slice``. The
-    program's only gather of anything with a ``max_len`` axis is the
-    ancestry table's, under its own named scope; a beam of one is the
-    same body over one slot a window."""
+    """What the scan body does to the carried ``(layers, rows, max_len,
+    d_model)`` arrays: ``2 x decoder_layers`` ``dynamic_update_slice``
+    of one position each (the projection's ``(rows, 1, d_model)`` slab),
+    one slice a layer for K and for V to read the layer back, the
+    layout pin on the two arrays the step hands on, and nothing else:
+    no gather, concatenate (``jnp.stack``) or ``dynamic_slice`` of
+    anything as large as one layer of the cache. The rank-5 page of the
+    program's boundary never enters the body. The program's only gather
+    of anything with a ``max_len`` axis is the ancestry table's, under
+    its own named scope; a beam of one is the same body over one slot a
+    window."""
     _, body = _traced(params, beam)
     windows, max_len = WINDOWS, MAX_LEN
-    page = (CFG.decoder_layers, windows * beam, CFG.decoder_attention_heads,
-            max_len, CFG.d_model // CFG.decoder_attention_heads)
+    rows = windows * beam
+    carried = (CFG.decoder_layers, rows, max_len, CFG.d_model)
+    page = (CFG.decoder_layers, rows, CFG.decoder_attention_heads, max_len,
+            CFG.d_model // CFG.decoder_attention_heads)
     on_cache: dict[str, list] = {}
     for eqn, stack in body:
-        if any(getattr(v.aval, "shape", ()) == page for v in eqn.invars):
+        assert page not in _shapes(eqn), (eqn, stack)
+        if any(getattr(v.aval, "shape", ()) == carried for v in eqn.invars):
             on_cache.setdefault(eqn.primitive.name, []).append((eqn, stack))
-    assert set(on_cache) == {"dynamic_update_slice", "slice"}
+    assert set(on_cache) == {"dynamic_update_slice", "slice",
+                             "layout_constraint"}
     writes = on_cache["dynamic_update_slice"]
     assert len(writes) == 2 * CFG.decoder_layers
     for eqn, stack in writes:
-        assert eqn.invars[1].aval.shape == (1,) + page[1:3] + (1, page[4])
-        assert eqn.outvars[0].aval.shape == page
+        assert eqn.invars[1].aval.shape == (1, rows, 1, CFG.d_model)
+        assert eqn.outvars[0].aval.shape == carried
         assert "asr.decoder_step.cache_update" in stack
     assert len(on_cache["slice"]) == 2 * CFG.decoder_layers
-    assert all(e.outvars[0].aval.shape == (1,) + page[1:]
-               for e, _ in on_cache["slice"])
-    # nothing else in the body makes or gathers an array of that rank
+    assert all(e.outvars[0].aval.shape == (1,) + carried[1:]
+               and "asr.decoder_step.self_attn" in st
+               for e, st in on_cache["slice"])
+    assert len(on_cache["layout_constraint"]) == 2      # K and V, once
+    # nothing else in the body makes or gathers a layer's worth of cache
+    # (an array with a max_len axis and a layer's elements or more)
+    layer = int(np.prod(carried[1:]))
+    assert max_len not in (CFG.vocab_size, CFG.d_model,
+                           CFG.max_target_positions)
     for eqn, stack in body:
         if eqn.primitive.name in ("gather", "concatenate", "dynamic_slice"):
-            assert all(len(getattr(v.aval, "shape", ())) < 5
-                       for v in (*eqn.invars, *eqn.outvars)), (eqn, stack)
+            assert all(max_len not in sh or int(np.prod(sh)) < layer
+                       for sh in _shapes(eqn)), (eqn, stack)
     table = [(e, st) for e, st in body if "asr.beam_ancestry" in st]
     # the table: (windows, K, max_len) int32, gathered by parent and
     # written at one position; no other gather sees a max_len axis
@@ -343,20 +360,18 @@ def test_a_step_with_kv_by_window_gives_the_logits_of_kv_per_row(
                for ck, cv in by_window]
     assert by_window[0][0].shape[0] == windows
     assert per_row[0][0].shape[0] == rows
-    page = DecoderCache(
+    cache = StepCache(
         k=jnp.asarray(rng.standard_normal(
-            (CFG.decoder_layers, rows, CFG.decoder_attention_heads, max_len,
-             CFG.d_model // CFG.decoder_attention_heads)), jnp.float32),
+            (CFG.decoder_layers, rows, max_len, CFG.d_model)), jnp.float32),
         v=jnp.asarray(rng.standard_normal(
-            (CFG.decoder_layers, rows, CFG.decoder_attention_heads, max_len,
-             CFG.d_model // CFG.decoder_attention_heads)), jnp.float32))
+            (CFG.decoder_layers, rows, max_len, CFG.d_model)), jnp.float32))
     tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, rows), jnp.int32)
     anc = jnp.asarray(rng.integers(0, K, (windows, K, max_len)), jnp.int32)
     pos = jnp.int32(position)
     for table in (anc, None):
-        got, got_cache = decoder_step(params, tokens, pos, page, by_window,
+        got, got_cache = decoder_step(params, tokens, pos, cache, by_window,
                                       CFG, table)
-        want, want_cache = decoder_step(params, tokens, pos, page, per_row,
+        want, want_cache = decoder_step(params, tokens, pos, cache, per_row,
                                         CFG, table)
         assert got.shape == (rows, CFG.vocab_size)
         assert float(jnp.abs(want).max()) > 1.0
@@ -369,3 +384,71 @@ def test_a_step_with_kv_by_window_gives_the_logits_of_kv_per_row(
                                    rtol=0, atol=1e-5)
     # windows differ: K/V by window is not one window's broadcast to all
     assert not np.allclose(np.asarray(got[:K]), np.asarray(got[K:2 * K]))
+
+
+@pytest.mark.parametrize("beam", [K, 1])
+@pytest.mark.parametrize("form", ["beam", "per_row"])
+def test_attention_over_a_step_cache_layer_is_the_per_row_attention(
+        form, beam):
+    """One layer of a :class:`StepCache`, ``(rows, max_len, d_model)``
+    with the heads side by side, random K/V, position 6 of 10 and a
+    tail of 1e6 behind it. ``beam``: ``_beam_attention`` under an
+    ancestry table whose parents cross, against float64 attention of
+    each row over the history the table names (the parent's cache
+    gathered by row). ``per_row``: the prompt steps' ``_attention`` over
+    the layer split into heads, against each row over its own row. To
+    1e-6, and the tail weighs nothing."""
+    windows, max_len, pos, nh = 3, 10, 6, 4
+    hd = 8
+    rows = windows * beam
+    rng = np.random.default_rng(60 + beam)
+    k = rng.standard_normal((rows, max_len, nh * hd)).astype(np.float32)
+    v = rng.standard_normal((rows, max_len, nh * hd)).astype(np.float32)
+    k[:, pos + 1:] = 1e6
+    v[:, pos + 1:] = -1e6
+    q = rng.standard_normal((rows, nh, 1, hd)).astype(np.float32)
+    valid = jnp.arange(max_len) <= pos
+    if form == "beam":
+        anc = rng.integers(0, beam, (windows, beam, max_len))
+        if beam > 1:
+            # crossed for certain: beams 0 and 1 swap slots at position 2
+            anc[:, 0, 2], anc[:, 1, 2] = 1, 0
+        got = _beam_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                              jnp.asarray(anc, jnp.int32), valid)
+    else:
+        anc = np.broadcast_to(np.arange(beam)[None, :, None],
+                              (windows, beam, max_len))
+        got = _attention(jnp.asarray(q), _split_heads(jnp.asarray(k), nh),
+                         _split_heads(jnp.asarray(v), nh), valid)
+    assert got.shape == (rows, nh, 1, hd)
+    kh = k.astype(np.float64).reshape(windows, beam, max_len, nh, hd)
+    vh = v.astype(np.float64).reshape(windows, beam, max_len, nh, hd)
+    t = np.arange(pos + 1)
+    for w in range(windows):
+        for b in range(beam):
+            hist_k = kh[w, anc[w, b, t], t]             # (pos+1, nh, hd)
+            hist_v = vh[w, anc[w, b, t], t]
+            sc = np.einsum("hd,thd->ht", q[w * beam + b, :, 0], hist_k)
+            pr = np.exp(sc - sc.max(axis=1, keepdims=True))
+            pr /= pr.sum(axis=1, keepdims=True)
+            want = np.einsum("ht,thd->hd", pr, hist_v)
+            np.testing.assert_allclose(np.asarray(got[w * beam + b, :, 0]),
+                                       want, rtol=0, atol=1e-6)
+
+
+def test_a_page_survives_the_step_form_and_back():
+    """``StepCache.from_page`` and ``to_page`` move every entry of the
+    (layers, rows, heads, max_len, hd) page to (layers, rows, max_len,
+    d_model) and back: head ``h`` of a position is columns ``h * hd`` to
+    ``(h + 1) * hd`` of its slab."""
+    rng = np.random.default_rng(70)
+    page = DecoderCache(
+        k=jnp.asarray(rng.standard_normal((2, 3, 4, 5, 8)), jnp.float32),
+        v=jnp.asarray(rng.standard_normal((2, 3, 4, 5, 8)), jnp.float32))
+    steps = StepCache.from_page(page)
+    assert steps.k.shape == (2, 3, 5, 32)
+    np.testing.assert_array_equal(np.asarray(steps.k[1, 2, 4, 8:16]),
+                                  np.asarray(page.k[1, 2, 1, 4]))
+    back = steps.to_page(4)
+    np.testing.assert_array_equal(np.asarray(back.k), np.asarray(page.k))
+    np.testing.assert_array_equal(np.asarray(back.v), np.asarray(page.v))

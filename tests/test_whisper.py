@@ -80,7 +80,7 @@ def test_decoder_logits_match_torch(assets, torch_model):
 
 def test_incremental_step_matches_teacher_forcing(assets):
     """The KV-cache generation path must agree with the full decoder."""
-    from vlog_tpu.asr.model import (DecoderCache, cross_kv, decode_logits,
+    from vlog_tpu.asr.model import (StepCache, cross_kv, decode_logits,
                                     decoder_step, encode)
     import jax.numpy as jnp
 
@@ -90,7 +90,7 @@ def test_incremental_step_matches_teacher_forcing(assets):
     enc = encode(assets.params, mel, assets.cfg)
     full = np.asarray(decode_logits(assets.params, toks, enc, assets.cfg))
     ckv = cross_kv(assets.params, enc, assets.cfg)
-    cache = DecoderCache.create(assets.cfg, 1, 6)
+    cache = StepCache.create(assets.cfg, 1, 6)
     for i in range(6):
         lg, cache = decoder_step(assets.params,
                                  jnp.asarray(toks[:, i], jnp.int32),
@@ -217,7 +217,7 @@ def test_beam_score_not_worse_than_greedy(assets):
     greedy sequence under the model (the point of beam search)."""
     import jax
 
-    from vlog_tpu.asr.model import DecoderCache, cross_kv, decoder_step, encode
+    from vlog_tpu.asr.model import StepCache, cross_kv, decoder_step, encode
 
     rng = np.random.default_rng(13)
     mel = rng.standard_normal((1, 80, 3000)).astype(np.float32)
@@ -234,7 +234,7 @@ def test_beam_score_not_worse_than_greedy(assets):
         cfg = assets.cfg
         enc = encode(assets.params, jnp.asarray(mel), cfg)
         ckv = cross_kv(assets.params, enc, cfg)
-        cache = DecoderCache.create(cfg, 1, len(prompt) + n_new)
+        cache = StepCache.create(cfg, 1, len(prompt) + n_new)
         total, logits = 0.0, None
         toks = prompt + [int(t) for t in seq if t != st.eot]
         for i, t in enumerate(toks):

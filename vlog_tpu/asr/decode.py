@@ -13,7 +13,9 @@ production default (``config.WHISPER_BEAM`` is 5, the reference's beam
 size), and at ``beam=1`` a beam of one, which picks the arg-max token of
 every step. It never moves its self-attention K/V cache: every position
 is written once, in place, and beam history is a small ancestry table
-that masks a per-window self-attention.
+that masks a per-window self-attention. Between its edges it carries the
+cache as a ``StepCache`` whose physical layout it states (``_pinned``),
+so that a one-position write is one position's bytes.
 """
 
 from __future__ import annotations
@@ -25,10 +27,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from vlog_tpu.asr.load import SpecialTokens, WhisperAssets
 from vlog_tpu.asr.model import (
     DecoderCache,
+    StepCache,
     WhisperConfig,
     cross_kv,
     decoder_step,
@@ -176,6 +180,20 @@ def apply_timestamp_rules(logits, last, penult, last_ts, step_idx, *,
 # beam_size=5, worker/transcription.py:92-133)
 # --------------------------------------------------------------------------
 
+_ROW_MAJOR = Layout(major_to_minor=(0, 1, 2, 3))
+
+
+def _pinned(cache: StepCache) -> StepCache:
+    """The generate program's carry with its physical layout stated:
+    row-major, ``d_model`` in the lanes and ``max_len`` in the
+    sublanes, so a step's entry for a layer is one sublane row of each
+    tile, written in place. Left to itself the TPU compiler puts
+    ``max_len`` in the lanes (the attention products want it there) and
+    a one-position write then touches every tile of the layer."""
+    return jax.tree.map(lambda x: with_layout_constraint(x, _ROW_MAJOR),
+                        cache)
+
+
 @partial(jax.jit, static_argnames=("cfg", "sot", "eot", "ts_begin",
                                    "no_speech", "max_new", "timestamps",
                                    "beam"))
@@ -201,7 +219,14 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     its one K/V row and nothing is tiled per beam. Finished beams
     persist with frozen scores (only EOT continues, at zero cost).
     Selection normalizes by generated length (CTranslate2's
-    length_penalty=1)."""
+    length_penalty=1).
+
+    ``cache`` comes in and goes out as the pool's page, (layers, B*K,
+    H, max_len, hd); nothing a previous tenant left in it can reach the
+    tokens (every reachable position is written in this call). In
+    between the program carries it as a ``StepCache``, relaid once at
+    each edge and pinned (:func:`_pinned`) before the prompt steps and
+    at the end of every scan step."""
     enc = encode(params, mel, cfg)
     ckv = cross_kv(params, enc, cfg)
     b = mel.shape[0]
@@ -211,6 +236,7 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
 
     plen = prompt.shape[0]
 
+    cache = _pinned(StepCache.from_page(cache))
     logits = None
     with jax.named_scope("asr.prompt"):
         for i in range(plen):
@@ -230,7 +256,7 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
     # entries, so a beam's history starts as its own slot throughout
     own_slot = jnp.broadcast_to(
         jnp.arange(k, dtype=jnp.int32)[None, :, None], (b, k, 1))
-    anc0 = jnp.broadcast_to(own_slot, (b, k, cache.k.shape[3]))
+    anc0 = jnp.broadcast_to(own_slot, (b, k, plen + max_new))
 
     def step(carry, step_idx):
         (cache, anc, logits, scores, seqs, last, penult, last_ts,
@@ -275,6 +301,7 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
                 anc, own_slot, (0, 0, pos))
         nxt_logits, cache = decoder_step(params, token, pos, cache, ckv,
                                          cfg, anc)
+        cache = _pinned(cache)
         return ((cache, anc, nxt_logits, scores, seqs, last, penult,
                  last_ts, finished), finished)
 
@@ -297,7 +324,8 @@ def _generate_beam_jit(params, mel, prompt, suppress_vec, begin_suppress_vec,
         best = jnp.argmax(norm.reshape(b, k), axis=1)           # (b,)
         best_rows = best + jnp.arange(b) * k
         best_seqs = jnp.take(seqs, best_rows, axis=0)
-    return best_seqs, no_speech_prob, cache
+    return (best_seqs, no_speech_prob,
+            cache.to_page(cfg.decoder_attention_heads))
 
 
 def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
@@ -383,7 +411,7 @@ def detect_language(assets: WhisperAssets, mel: jnp.ndarray) -> str:
     enc = encode(assets.params, jnp.asarray(mel), cfg)
     ckv = cross_kv(assets.params, enc, cfg)
     b = enc.shape[0]
-    cache = DecoderCache.create(cfg, b, 1)
+    cache = StepCache.create(cfg, b, 1)
     logits, _ = decoder_step(assets.params,
                              jnp.full((b,), st.sot, jnp.int32),
                              jnp.int32(0), cache, ckv, cfg)

@@ -246,7 +246,8 @@ def decode_logits(params: Params, tokens: jnp.ndarray, enc: jnp.ndarray,
 
 @dataclass
 class DecoderCache:
-    """Preallocated self-attention K/V ring: (layers, B, H, max_len, hd)."""
+    """A self-attention K/V page, (layers, B, H, max_len, hd): what the
+    pool keeps and the generate program takes and returns."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -263,13 +264,43 @@ class DecoderCache:
 jax.tree_util.register_dataclass(DecoderCache, ["k", "v"], [])
 
 
+@dataclass
+class StepCache:
+    """Self-attention K/V as :func:`decoder_step` reads and writes it,
+    (layers, B, max_len, d_model): a row's position is the K (or V)
+    projection as it comes, the heads side by side along ``d_model``,
+    so a step's new entry for a layer is one ``(B, 1, d_model)`` slab.
+    """
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+
+    @classmethod
+    def create(cls, cfg: WhisperConfig, batch: int, max_len: int
+               ) -> "StepCache":
+        shape = (cfg.decoder_layers, batch, max_len, cfg.d_model)
+        return cls(k=jnp.zeros(shape), v=jnp.zeros(shape))
+
+    @classmethod
+    def from_page(cls, page: DecoderCache) -> "StepCache":
+        merge = jax.vmap(_merge_heads)
+        return cls(k=merge(page.k), v=merge(page.v))
+
+    def to_page(self, n_heads: int) -> DecoderCache:
+        split = jax.vmap(partial(_split_heads, n_heads=n_heads))
+        return DecoderCache(k=split(self.k), v=split(self.v))
+
+
+jax.tree_util.register_dataclass(StepCache, ["k", "v"], [])
+
+
 def _beam_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     anc: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """Self-attention of W windows x K beams over a cache nobody
-    reorders: q (W*K,H,1,hd) pre-scaled, k/v (W*K,H,T,hd), ``anc``
-    (W,K,T) with ``anc[w, q, t]`` the slot of window ``w`` that holds
-    position ``t`` of beam ``q``'s history, ``valid`` (T,) the written
-    positions.
+    reorders: q (W*K,H,1,hd) pre-scaled, k/v (W*K,T,d_model) a layer of
+    a :class:`StepCache`, ``anc`` (W,K,T) with ``anc[w, q, t]`` the slot
+    of window ``w`` that holds position ``t`` of beam ``q``'s history,
+    ``valid`` (T,) the written positions.
 
     Every query is scored against all K slots of its OWN window and the
     mask keeps, per valid position, the one slot its ancestry names; the
@@ -279,31 +310,32 @@ def _beam_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     w, kb, t = anc.shape
     _, h, _, hd = q.shape
     q = q.reshape(w, kb, h, hd)
-    k = k.reshape(w, kb, h, t, hd)
-    v = v.reshape(w, kb, h, t, hd)
-    scores = jnp.einsum("bqhd,bshtd->bhqst", q, k)
+    k = k.reshape(w, kb, t, h, hd)
+    v = v.reshape(w, kb, t, h, hd)
+    scores = jnp.einsum("bqhd,bsthd->bhqst", q, k)
     mask = ((anc[:, :, None, :] == jnp.arange(kb)[None, None, :, None])
             & valid)[:, None]                           # (W,1,Kq,Ks,T)
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.reshape(w, h, kb, kb * t), axis=-1)
-    out = jnp.einsum("bhqst,bshtd->bqhd", probs.reshape(scores.shape), v)
+    out = jnp.einsum("bhqst,bsthd->bqhd", probs.reshape(scores.shape), v)
     return out.reshape(w * kb, h, 1, hd)
 
 
 @jax.named_scope("asr.decoder_step")
 def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
-                 cache: DecoderCache, ckv, cfg: WhisperConfig,
+                 cache: StepCache, ckv, cfg: WhisperConfig,
                  anc: jnp.ndarray | None = None
-                 ) -> tuple[jnp.ndarray, DecoderCache]:
+                 ) -> tuple[jnp.ndarray, StepCache]:
     """One decode step: (B,) tokens at position ``pos`` -> (B, V) logits.
 
     XLA-friendly: every shape is static; each layer writes its ONE new
-    K/V position into the stacked (layers, B, H, max_len, hd) arrays in
-    place (a dynamic_update_slice at ``[i, :, :, pos, :]``; nothing is
-    sliced out, updated and restacked) and attention masks positions >
-    pos. With ``anc`` (W, K, max_len), B is W windows x K beams and a
-    row attends through its ancestry table over its window's K cache
-    slots (:func:`_beam_attention`); without it (the generate program's
+    K/V position, the projection's (B, 1, d_model) output as it comes,
+    into the stacked (layers, B, max_len, d_model) arrays in place (a
+    dynamic_update_slice at ``[i, :, pos, :]``; nothing is sliced out,
+    updated and restacked) and attention masks positions > pos. With
+    ``anc`` (W, K, max_len), B is W windows x K beams and a row attends
+    through its ancestry table over its window's K cache slots
+    (:func:`_beam_attention`); without it (the generate program's
     prompt steps, ``detect_language``) each row attends over its own
     cache row. ``ckv`` is :func:`cross_kv`'s list, a (K, V) pair per
     layer of (rows, H, source, hd) each: B rows, one per query row
@@ -314,7 +346,7 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     p = params
     nh = cfg.decoder_attention_heads
     hd = cfg.d_model // nh
-    max_len = cache.k.shape[3]
+    max_len = cache.k.shape[2]
     x = (p["model.decoder.embed_tokens.weight"][tokens]
          + p["model.decoder.embed_positions.weight"][pos])[:, None, :]
     ck, cv = cache.k, cache.v
@@ -324,16 +356,16 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
         with jax.named_scope("asr.decoder_step.self_attn"):
             h = _layer_norm(p, f"{n}.self_attn_layer_norm", x)
             q = (_linear(p, f"{n}.self_attn.q_proj", h) * hd ** -0.5)
-            k1 = _split_heads(_linear(p, f"{n}.self_attn.k_proj", h), nh)
-            v1 = _split_heads(_linear(p, f"{n}.self_attn.v_proj", h), nh)
+            k1 = _linear(p, f"{n}.self_attn.k_proj", h)
+            v1 = _linear(p, f"{n}.self_attn.v_proj", h)
         with jax.named_scope("asr.decoder_step.cache_update"):
-            ck = jax.lax.dynamic_update_slice(
-                ck, k1[None], (i, 0, 0, pos, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v1[None], (i, 0, 0, pos, 0))
+            ck = jax.lax.dynamic_update_slice(ck, k1[None], (i, 0, pos, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v1[None], (i, 0, pos, 0))
         with jax.named_scope("asr.decoder_step.self_attn"):
             qh = _split_heads(q, nh)
-            att = (_attention(qh, ck[i], cv[i], valid) if anc is None
+            att = (_attention(qh, _split_heads(ck[i], nh),
+                              _split_heads(cv[i], nh), valid)
+                   if anc is None
                    else _beam_attention(qh, ck[i], cv[i], anc, valid))
             x = x + _linear(p, f"{n}.self_attn.out_proj", _merge_heads(att))
         with jax.named_scope("asr.decoder_step.cross_attn"):
@@ -346,7 +378,7 @@ def decoder_step(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     with jax.named_scope("asr.decoder_step.logits"):
         x = _layer_norm(p, "model.decoder.layer_norm", x)
         logits = (x @ p["model.decoder.embed_tokens.weight"].T)[:, 0, :]
-    return logits, DecoderCache(k=ck, v=cv)
+    return logits, StepCache(k=ck, v=cv)
 
 
 def random_state_dict(cfg: WhisperConfig, seed: int = 0
