@@ -1,5 +1,5 @@
-"""Shared by the transcript model's tests: the tiny afmoe model, its
-engine and the comparison with the plain reference."""
+"""Shared by the transcript model's tests: the tiny afmoe and KeyeVL2
+models, their engine and the comparison with the plain references."""
 
 import json
 import sys
@@ -14,10 +14,11 @@ if str(ROOT / "benchmark") not in sys.path:
     sys.path.insert(0, str(ROOT / "benchmark"))
 
 from reference import afmoe_ref as ref  # noqa: E402
+from reference import keye_ref  # noqa: E402
 
 from vlog_tpu.lm.engine import LmEngine  # noqa: E402
-from vlog_tpu.lm.load import (EXPERT_NAMES, HF_NAMES,  # noqa: E402
-                              LmAssets, layer_leaves)
+from vlog_tpu.lm.load import (EXPERT_NAMES, LmAssets,  # noqa: E402
+                              layer_leaves, layer_names)
 from vlog_tpu.lm.model import BF16, F32, Geometry, LmConfig  # noqa: E402
 
 INIT_STD = 0.02
@@ -70,8 +71,8 @@ def random_params(cfg: LmConfig, seed: int) -> dict:
     def draw(shape, kind):
         count[0] += 1
         k = jax.random.fold_in(key, count[0])
-        if kind == "ones":
-            return jnp.ones(shape, BF16)
+        if kind in ("ones", "zeros"):
+            return jnp.full(shape, kind == "ones", BF16)
         if kind == "bias":
             return jax.random.normal(k, shape, F32) * BIAS_STD
         return (jax.random.normal(k, shape, F32) * INIT_STD).astype(BF16)
@@ -84,8 +85,9 @@ def random_params(cfg: LmConfig, seed: int) -> dict:
                        for li in range(cfg.num_layers)]}
 
 
-def to_state_dict(params: dict) -> dict:
-    """The program's layout under the published names, torch layouts."""
+def to_state_dict(params: dict, names: dict) -> dict:
+    """The program's layout under the published names (``names``: the
+    family's, ``load.layer_names``), torch layouts."""
     sd = {"model.embed_tokens.weight": params["embed"],
           "lm_head.weight": params["head"].T,
           "model.norm.weight": params["final_norm"]}
@@ -97,7 +99,7 @@ def to_state_dict(params: dict) -> dict:
                     sd[f"{base}mlp.experts.{e}.{EXPERT_NAMES[name]}"
                        ".weight"] = leaf[e].T
             else:
-                sd[base + HF_NAMES[name]] = leaf.T if leaf.ndim == 2 else leaf
+                sd[base + names[name]] = leaf.T if leaf.ndim == 2 else leaf
     return sd
 
 
@@ -135,7 +137,7 @@ def save_model_dir(path, hf_config: dict, params: dict, *,
     path.mkdir(parents=True, exist_ok=True)
     (path / "config.json").write_text(json.dumps(hf_config, indent=1))
     write_tokenizer(path / "tokenizer.json")
-    sd = to_state_dict(params)
+    sd = to_state_dict(params, layer_names(LmConfig.from_hf(hf_config)))
     if shards == 1:
         save_file(sd, str(path / "model.safetensors"))
         return path
@@ -164,12 +166,14 @@ def tiny(seed=7, bias_scale=20.0, router_scale=10.0, **over):
     return hf, cfg, params
 
 
-def geometry(cfg, rows=4, chunk=8, page=4, cap=128, block=2):
+def geometry(cfg, rows=4, chunk=8, page=4, cap=128, block=2,
+             full_pages=None):
     base = Geometry(rows=rows, chunk=chunk, page=page, context_cap=cap)
+    ring = base.ring(cfg.sliding_window)
     return Geometry(rows=rows, chunk=chunk, page=page, context_cap=cap,
                     kv_block_pages=block,
-                    window_pages=rows * base.ring(cfg.sliding_window) + 1,
-                    full_pages=rows * base.max_pages + 1)
+                    window_pages=rows * ring + 1 if ring else 0,
+                    full_pages=full_pages or rows * base.max_pages + 1)
 
 
 def engine(cfg, params, **geo):
@@ -194,4 +198,66 @@ def compare(req, hf, params, off=()):
             continue
         errs.append(ref.logit_error(req.logits[i], out["logits"][row]))
         gaps.append(ref.rank_gap(toks[i], out["logits"][row]))
+    return errs, gaps
+
+
+# ---- KeyeVL2 at tiny widths ---------------------------------------------
+
+# a selection margin (a query's 16th index score over its 17th) under
+# this can fall either way on the bfloat16 rounding of the indexer's
+# operands; the other side then attends another key
+SELECT_EPS = 0.05
+
+
+def tiny_keye_hf_config(**over) -> dict:
+    """A CPU-sized config of the published ``KeyeVL2`` form: four layers
+    of sparse attention (4 index heads of 8, 16 keys kept) and 8 experts
+    top 2."""
+    cfg = {"model_type": "KeyeVL2", "hidden_size": 64, "head_dim": 16,
+           "num_attention_heads": 8, "num_key_value_heads": 2,
+           "num_hidden_layers": 4, "intermediate_size": 96,
+           "moe_intermediate_size": 32, "num_experts": 8,
+           "num_local_experts": 8, "num_experts_per_tok": 2,
+           "norm_topk_prob": True, "decoder_sparse_step": 1,
+           "mlp_only_layers": [], "attention_bias": False,
+           "sliding_window": None, "use_sliding_window": False,
+           "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 10000000,
+           "rope_scaling": {"mrope_section": [2, 3, 3],
+                            "rope_type": "default", "type": "default"},
+           "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 4,
+                         "indexer_num_kv_heads": 1, "kv_chunk_size": 8,
+                         "q_chunk_size": 8, "topk": 16},
+           "tie_word_embeddings": False}
+    cfg.update(over)
+    return cfg
+
+
+def tiny_keye(seed=9, index_scale=40.0, router_scale=30.0, **over):
+    hf = tiny_keye_hf_config(**over)
+    cfg = LmConfig.from_hf(hf)
+    params = random_params(cfg, seed)
+    for lp in params["layers"]:
+        # index scores and router logits spread far enough that most
+        # choices stand by more than bfloat16's rounding
+        for name in ("iq", "iw"):
+            lp[name] = lp[name] * index_scale
+        lp["router"] = lp["router"] * router_scale
+    return hf, cfg, params
+
+
+def compare_keye(req, hf, params, **how):
+    """As :func:`compare`, against ``keye_ref``: positions whose router
+    or selection margin stands."""
+    toks = req.tokens
+    full = np.concatenate([req.prompt, toks[:-1]]).astype(np.int32)
+    steps = sorted(req.logits)
+    out = keye_ref.forward(params, hf, full,
+                           [req.prompt.size - 1 + i for i in steps], **how)
+    errs, gaps = [], []
+    for row, i in enumerate(steps):
+        if out["route_gap"][row] < ROUTE_EPS \
+                or out["select_gap"][row] < SELECT_EPS:
+            continue
+        errs.append(keye_ref.logit_error(req.logits[i], out["logits"][row]))
+        gaps.append(keye_ref.rank_gap(toks[i], out["logits"][row]))
     return errs, gaps

@@ -166,3 +166,146 @@ def test_residency_swaps_at_a_job_boundary(model, monkeypatch, tmp_path):
     finally:
         HOST.evict("stub")
         lm_engine.reset_engine()
+
+
+# ---- a model whose every layer is of the full class ---------------------
+
+def test_a_pool_smaller_than_the_rows_finishes_every_request_and_counts():
+    """Four rows, a pool that holds two of the longest requests: the
+    pool, not the rows, bounds what is resident. Every request finishes,
+    rows stand empty while the next in line waits for pages, and the
+    plan, the step record and the counters say so."""
+    from lm_helpers import tiny_keye
+
+    _hf, cfg, params = tiny_keye()
+    # 40 + 8 positions = 12 pages a request; 25 pages hold two
+    eng = engine(cfg, params, rows=4, chunk=16, page=4, cap=64,
+                 full_pages=26)
+    rng = np.random.default_rng(2)
+    try:
+        assert eng.geo.window_pages == 0
+        reqs = [eng.submit(rng.integers(0, cfg.vocab_size, 40), max_new=8)
+                for _ in range(7)]
+        for r in reqs:
+            assert len(r.wait(300)) == 8
+        for _ in range(100):
+            if eng.stats()["pages_in_use"]["full"] == 0:
+                break
+            threading.Event().wait(0.05)
+        stats = eng.stats()
+        log = list(eng.step_log)
+    finally:
+        eng.close()
+    assert stats["requests_done"] == 7
+    assert stats["pool"]["full"] == {"capacity": 25, "in_use": 0,
+                                     "reserved": 0}
+    assert stats["pool"]["window"] == {"capacity": 0, "in_use": 0,
+                                       "reserved": 0}
+    waited = [rec for rec in log if rec["pool_wait_rows"]]
+    assert waited and stats["pool_wait"] == {
+        "steps": len(waited),
+        "rows": sum(rec["pool_wait_rows"] for rec in waited)}
+    for rec in log:
+        # never more resident than the pool holds, whatever the rows
+        assert rec["decode_rows"] <= 2 and rec["pages_in_use"]["full"] <= 25
+        assert rec["pages_in_use"]["window"] == 0
+        # at least two rows were free whenever a request waited for
+        # pages (fewer are counted once fewer requests wait)
+        assert rec["pool_wait_rows"] <= 2
+    # with the pool at every row's need nothing waits
+    eng = engine(cfg, params, rows=4, chunk=16, page=4, cap=64)
+    try:
+        for r in [eng.submit(np.arange(40), max_new=8) for _ in range(6)]:
+            r.wait(300)
+        assert eng.stats()["pool_wait"] == {"steps": 0, "rows": 0}
+        assert max(rec["decode_rows"] for rec in eng.step_log) >= 3
+    finally:
+        eng.close()
+
+
+def test_a_request_the_pool_can_never_hold_is_refused(model):
+    from lm_helpers import tiny_keye
+
+    _hf, cfg, params = tiny_keye()
+    eng = engine(cfg, params, rows=2, chunk=16, page=4, cap=64,
+                 full_pages=9)
+    try:
+        with pytest.raises(LmJobError, match="exceed the cache"):
+            eng.submit(np.zeros(40, np.int32), max_new=4).wait(60)
+        assert len(eng.submit(np.zeros(20, np.int32), max_new=4)
+                   .wait(60)) == 4
+    finally:
+        eng.close()
+
+
+def test_the_default_pools_follow_the_models_layers(model):
+    """Every row its longest request where that fits the budget; a model
+    whose every layer is of the full class gets the pages that fit, and
+    one without window layers no window pool."""
+    import json as _json
+    from pathlib import Path
+
+    from vlog_tpu.lm import engine as lm_engine
+    from vlog_tpu.lm.model import LmConfig
+
+    _hf, afmoe, _params = model
+    geo = lm_engine.default_geometry(afmoe)
+    assert geo.window_pages == 32 * geo.ring(afmoe.sliding_window) + 1
+    assert geo.full_pages == 32 * geo.max_pages + 1
+    configs = Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+    keye = LmConfig.from_hf(_json.loads(
+        (configs / "keye_vl2_lm_6l.json").read_text()))
+    assert keye.position_bytes() == (0, 13_056)
+    geo = lm_engine.default_geometry(keye)
+    geo.check(keye)
+    assert geo.window_pages == 0
+    assert geo.full_pages - 1 == lm_engine.POOL_BYTES // (256 * 13_056) \
+        == 1285 < 32 * geo.max_pages
+    trinity = LmConfig.from_hf(_json.loads(
+        (configs / "trinity_mini_6l.json").read_text()))
+    geo = lm_engine.default_geometry(trinity)
+    assert (geo.window_pages, geo.full_pages) == (513, 5121)
+
+
+def test_a_digest_runs_the_second_family_from_its_model_directory(
+        monkeypatch, tmp_path):
+    """``config.json`` names the family; the same loader, engine and
+    ``digest_tokens`` serve it, and no environment variable chooses."""
+    from lm_helpers import keye_ref, tiny_keye
+
+    from vlog_tpu.lm import engine as lm_engine
+    from vlog_tpu.lm import load
+    from vlog_tpu.worker.digest import digest_tokens
+
+    hf, _cfg, params = tiny_keye()
+    model_dir = save_model_dir(tmp_path / "keye", hf, params, shards=2)
+    assets = load.load_model_dir(model_dir)
+    assert assets.cfg.model_type == "KeyeVL2" and assets.cfg.index_topk == 16
+    assert sorted(assets.params["layers"][0]) == sorted(
+        name for name, _shape, _kind in load.layer_leaves(assets.cfg, 0))
+    monkeypatch.setattr(lm_engine, "default_geometry", lambda cfg: geometry(
+        cfg, rows=2, chunk=16, page=4, cap=64))
+    try:
+        eng = lm_engine.get_engine(str(model_dir))
+        stats: dict = {}
+        prompt = np.arange(30) % 256
+        req = digest_tokens(eng, prompt, max_new=4, job_key="k",
+                            capture=(0, 3), stats_out=stats)
+        assert stats["prompt_tokens"] == 30 and stats["output_tokens"] == 4
+        scopes = set().union(*(set(v.values())
+                               for v in eng.program_scopes().values()))
+    finally:
+        lm_engine.reset_engine()
+    assert {"lm.attn.index", "lm.attn.select", "lm.attn.sparse",
+            "lm.cache.write", "lm.moe.experts"} <= scopes
+    assert not {"lm.attn.window", "lm.attn.full", "lm.attn.gate"} & scopes
+    full = np.concatenate([prompt, req.tokens[:-1]]).astype(np.int32)
+    out = keye_ref.forward(params, hf, full, [29, 32])
+    for row, step in enumerate((0, 3)):
+        assert keye_ref.logit_error(req.logits[step], out["logits"][row]) \
+            < 1.2
+    # a tensor under another name is refused by that name
+    sd = load._read_weights(model_dir)
+    sd.pop("model.layers.1.self_attn.indexer.wk.weight")
+    with pytest.raises(load.LmLoadError, match="indexer.wk.weight"):
+        load.from_state_dict(assets.cfg, sd)

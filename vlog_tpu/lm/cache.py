@@ -3,8 +3,12 @@ and two classes of page table.
 
 A page holds ``page`` positions of K and V for every layer of its class
 (the layers share page numbers; ``model.py::empty_cache`` holds one K and
-one V array per layer). Page 0 of each pool is never handed out: the
-step program points unallocated table slots and padded rows there.
+one V array per layer and, for a model with an indexer, one array of its
+keys on the same page numbers: a second pool of the full class, no class
+of its own). Page 0 of each pool is never handed out: the step program
+points unallocated table slots and padded rows there. A model without
+window layers has no window pool, no window table and reserves nothing
+of that class.
 
 - **full class** (``full_attention`` layers): a sequence's table grows
   by a page whenever its positions pass a page boundary and keeps every
@@ -20,6 +24,10 @@ step program points unallocated table slots and padded rows there.
 Admission is by reservation: a request is admitted only if both pools
 can hold it to its end beside everything already admitted
 (:meth:`PagedCache.admit`), so nothing is ever evicted mid-request.
+Where the full pool is smaller than ``rows`` longest requests (every
+layer of the full class: 13 KB a position over six layers), it is the
+pool and not the rows that bounds what is resident, and rows stand empty
+while the next request waits for pages (the engine counts them).
 Used from the engine's thread alone; no lock.
 """
 
@@ -34,9 +42,10 @@ from vlog_tpu.lm.model import Geometry, LmConfig
 
 class PagePool:
     def __init__(self, pages: int):
-        if pages < 2:
+        """``pages`` 0: the class has no layer, no page and no cost."""
+        if pages == 1 or pages < 0:
             raise ValueError("a pool needs page 0 and at least one more")
-        self.capacity = pages - 1               # page 0 is never handed out
+        self.capacity = max(pages - 1, 0)       # page 0 is never handed out
         self._free = list(range(pages - 1, 0, -1))
         self.reserved = 0
 
@@ -68,7 +77,7 @@ class SeqPages:
         last = (upto - 1) // c.page
         while len(self.full) <= last:
             self.full.append(c.full.take())
-        while self.win_first + len(self.win) <= last:
+        while c.ring and self.win_first + len(self.win) <= last:
             self.win.append(c.window.take())
         self.peak_window_pages = max(self.peak_window_pages, len(self.win))
 
@@ -143,3 +152,11 @@ class PagedCache:
 
     def in_use(self) -> dict:
         return {"window": self.window.in_use, "full": self.full.in_use}
+
+    def pools(self) -> dict:
+        """Per class: pages that can be handed out, pages handed out and
+        pages spoken for by the admitted requests."""
+        return {name: {"capacity": p.capacity, "in_use": p.in_use,
+                       "reserved": p.reserved}
+                for name, p in (("window", self.window),
+                                ("full", self.full))}

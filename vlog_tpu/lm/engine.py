@@ -36,8 +36,12 @@ and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
 ``row_pos`` (the rows' positions), ``chunk`` (the bucket), ``context``
 (the chunk's first position),
 ``chunk_tag``, ``emitted`` (tags of the requests that got a token),
-``pages_in_use`` by class, ``pages_freed``, ``expert_load`` and
-``window_pages`` (from the device, read out with the tokens),
+``pages_in_use`` by class, ``pages_freed``, ``pool_wait_rows`` (rows that
+stood empty in this step because the request next in line waited for
+pages, not for a row: the pool, not ``rows``, bounded what was
+resident), ``expert_load`` and ``window_pages`` or, for a model with an
+indexer, ``sparse_keys`` (keys one layer attended, keys a causal-dense
+layer would have; from the device, read out with the tokens),
 ``build_s``; all instants on ``time.monotonic()``.
 """
 
@@ -113,6 +117,8 @@ class LmEngine:
         self._started = False                       # guarded-by: _lock
         self.step_log: list[dict] = []              # guarded-by: _lock
         self.requests_done = 0                      # guarded-by: _lock
+        self.pool_wait_steps = 0                    # guarded-by: _lock
+        self.pool_wait_rows = 0                     # guarded-by: _lock
         self._stop = threading.Event()
         self._ready = threading.Event()
         self._failed: BaseException | None = None
@@ -190,12 +196,20 @@ class LmEngine:
         return queued or self._hold.held.is_set() or self._busy()
 
     def stats(self) -> dict:
+        """Counts since the engine began; ``pool`` per class the pages
+        that can be handed out, are handed out and are spoken for, and
+        ``pool_wait`` the steps in which, and the rows that, stood empty
+        for want of pages (summed over those steps)."""
+        cache = self._cache
         with self._lock:
             return {"steps": len(self.step_log),
                     "requests_done": self.requests_done,
                     "pending": len(self._inbox),
-                    "pages_in_use": self._cache.in_use()
-                    if self._cache else {"window": 0, "full": 0}}
+                    "pages_in_use": cache.in_use()
+                    if cache else {"window": 0, "full": 0},
+                    "pool": cache.pools() if cache else {},
+                    "pool_wait": {"steps": self.pool_wait_steps,
+                                  "rows": self.pool_wait_rows}}
 
     def close(self) -> None:
         self._stop.set()
@@ -320,6 +334,9 @@ class LmEngine:
                                "deliver": mine["deliver"]}
             with self._lock:
                 self.step_log.append(done)
+                if done["pool_wait_rows"]:
+                    self.pool_wait_steps += 1
+                    self.pool_wait_rows += done["pool_wait_rows"]
             self._observe(done)
         if self._flight is None:
             self._hold.yield_full_mesh()
@@ -373,6 +390,11 @@ class LmEngine:
         freed = 0
         with trace.span("lm.step.pages"):
             pre = self._next_prefill()
+            # nobody was admitted although a row is free and a request
+            # waits: it waits for pages, and the rows that the waiting
+            # requests would have taken stand empty
+            starved = 0 if pre is not None else min(
+                self._rows.count(None), len(self._waiting))
             deco = [req for req in self._rows
                     if req is not None and req is not self._prefilling]
             if pre is None and not deco:
@@ -391,6 +413,7 @@ class LmEngine:
         with trace.span("lm.step.stack"):
             shapes = plan_shapes(self.cfg, geo, bucket)
             plan = {k: np.zeros(s, d) for k, (s, d) in shapes.items()}
+            windowed = "row_wtab" in plan
             emitted, captures = [], []
             for req in deco:
                 i = req.row
@@ -398,7 +421,8 @@ class LmEngine:
                 wtab, wbase, ftab = req.pages.tables()
                 plan["row_active"][i] = True
                 plan["row_pos"][i] = pos
-                plan["row_wtab"][i], plan["row_wbase"][i] = wtab, wbase
+                if windowed:
+                    plan["row_wtab"][i], plan["row_wbase"][i] = wtab, wbase
                 plan["row_ftab"][i] = ftab
                 if req.planned in req.capture:
                     captures.append((req, req.planned, i))
@@ -411,7 +435,9 @@ class LmEngine:
                 plan["chunk_ids"][:n] = pre.prompt[p0:p0 + n]
                 plan["chunk_meta"][:] = (p0, n, pre.row if last_chunk
                                          else -1, wbase)
-                plan["chunk_wtab"], plan["chunk_ftab"] = wtab, ftab
+                plan["chunk_ftab"] = ftab
+                if windowed:
+                    plan["chunk_wtab"] = wtab
                 pre.prefilled += n
                 pre.stats.setdefault("t_first_chunk", time.monotonic())
                 pre.stats["prefill_steps"] = pre.stats.get(
@@ -430,6 +456,7 @@ class LmEngine:
                   "emitted": [req.tag for req, _ in emitted],
                   "pages_in_use": self._cache.in_use(),
                   "pages_freed": freed,
+                  "pool_wait_rows": starved,
                   "_emitted": emitted, "_captures": captures}
         self._seq += 1
         return record, plan
@@ -446,7 +473,15 @@ class LmEngine:
 
     def _deliver(self, record: dict, out: dict) -> None:
         with trace.span("lm.step.device_wait"):
-            ints = unpack_ints(self.cfg, self.geo, np.asarray(out["ints"]))
+            # polled, not pulled with a blocking wait: about one window
+            # in seven lost 2.2 to 2.5 s in ONE blocking pull of these
+            # counters while the device had long finished this step and
+            # the next (3 of 19 runs; none of 8 polled; my chip runs,
+            # PR 33: not proof, PERF.md section 7)
+            pending = out["ints"]
+            while not pending.is_ready():
+                time.sleep(2e-4)
+            ints = unpack_ints(self.cfg, self.geo, np.asarray(pending))
         ready = time.monotonic()
         record["t_ready"] = ready
         begun = record["t_dispatch"] if self._prev_ready is None \
@@ -454,7 +489,10 @@ class LmEngine:
         record["step_s"] = ready - begun
         self._prev_ready = ready
         record["expert_load"] = ints["expert_load"].tolist()
-        record["window_pages"] = ints["pages"].tolist()
+        if "keys" in ints:
+            record["sparse_keys"] = ints["keys"].tolist()
+        else:
+            record["window_pages"] = ints["pages"].tolist()
         with trace.span("lm.step.deliver"):
             captures = [c for c in record.pop("_captures") if not c[0].ended]
             if captures:
@@ -520,14 +558,28 @@ class LmEngine:
             pass
 
 
+POOL_BYTES = 4 << 30        # the most a default pool takes of the chip
+
+
 def default_geometry(cfg) -> Geometry:
     """``Geometry``'s defaults (32 rows, chunks of 2048, pages of 256,
     a context cap of 40,960) with the pools sized so that every row can
-    hold the context cap."""
+    hold the context cap, where that stays under ``POOL_BYTES`` a class;
+    a model whose every layer is of the full class (13 KB a position
+    over six layers) gets the pages that fit, and its requests wait for
+    pages with rows to spare. No window layers, no window pool."""
     base = Geometry()
-    return Geometry(window_pages=base.rows * base.ring(cfg.sliding_window)
-                    + 1,
-                    full_pages=base.rows * base.max_pages + 1)
+    window_b, full_b = cfg.position_bytes()
+
+    def pages(per_row: int, position_bytes: int) -> int:
+        if not position_bytes:
+            return 0
+        return min(base.rows * per_row,
+                   POOL_BYTES // (base.page * position_bytes)) + 1
+
+    return Geometry(
+        window_pages=pages(base.ring(cfg.sliding_window), window_b),
+        full_pages=pages(base.max_pages, full_b))
 
 
 # The process's engine (parallel/engine_host.py holds it) --------------

@@ -1,5 +1,6 @@
-"""A transcript model on disk: ``config.json`` (the published ``afmoe``
-keys), the weights as safetensors (one ``model.safetensors``, or the
+"""A transcript model on disk: ``config.json`` (the published keys of a
+family ``LmConfig.from_hf`` builds: ``afmoe``, or ``KeyeVL2``'s language
+model; ``model_type`` says which), the weights as safetensors (one ``model.safetensors``, or the
 shards that ``model.safetensors.index.json`` names; the published names,
 torch layouts) and ``tokenizer.json``. Nothing is fetched: the operator
 points ``VLOG_DIGEST_DIR`` at a local directory, as ``VLOG_WHISPER_DIR``.
@@ -11,7 +12,18 @@ The program's own layout (``model.py`` indexes it) is a nested dict:
 then ``w_gate`` ``w_up`` ``w_down`` (dense) or ``router`` (H, E),
 ``bias`` (E,), ``e_gate`` ``e_up`` (E, H, I), ``e_down`` (E, I, H) and
 ``s_gate`` ``s_up`` ``s_down`` (the shared expert). Everything bfloat16
-except ``bias`` (float32).
+except ``bias`` (float32). A ``KeyeVL2`` layer has ``n1`` ``n2``, ``wq``
+``wk`` ``wv`` ``wo``, ``qn`` ``kn``, the indexer's ``iq`` (H, heads x
+dim), ``ik`` (H, dim), ``ikn`` ``ikb`` (its key's LayerNorm) and ``iw``
+(H, heads), then ``router`` and the three expert stacks: no gate, no
+bias, no dense layer, no shared expert.
+
+No published checkpoint's index has been met for either family (this
+machine has no network): the names below are the published modelling
+code's for ``afmoe`` and, for ``KeyeVL2``, those of the family its
+config descends from with the indexer's as the published sparse
+attention names them; a checkpoint that names a tensor otherwise is
+refused by that name (``LmLoadError``), never half loaded.
 """
 
 from __future__ import annotations
@@ -53,9 +65,22 @@ class LmAssets:
 
 
 def layer_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
-    """``(our key, shape, kind)`` of one layer's leaves."""
+    """``(our key, shape, kind)`` of one layer's leaves; ``kind`` is
+    ``normal``, ``ones``, ``zeros`` or ``bias``."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q, kv = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
+    if cfg.index_topk:
+        e, i = cfg.num_experts, cfg.moe_intermediate_size
+        ih, idim = cfg.index_heads, cfg.index_head_dim
+        return [("n1", (h,), "ones"), ("n2", (h,), "ones"),
+                ("wq", (h, q), "normal"), ("wk", (h, kv), "normal"),
+                ("wv", (h, kv), "normal"), ("wo", (q, h), "normal"),
+                ("qn", (hd,), "ones"), ("kn", (hd,), "ones"),
+                ("iq", (h, ih * idim), "normal"), ("ik", (h, idim), "normal"),
+                ("ikn", (idim,), "ones"), ("ikb", (idim,), "zeros"),
+                ("iw", (h, ih), "normal"), ("router", (h, e), "normal"),
+                ("e_gate", (e, h, i), "normal"), ("e_up", (e, h, i), "normal"),
+                ("e_down", (e, i, h), "normal")]
     out = [("n1", (h,), "ones"), ("n2", (h,), "ones"), ("n3", (h,), "ones"),
            ("n4", (h,), "ones"), ("wq", (h, q), "normal"),
            ("wk", (h, kv), "normal"), ("wv", (h, kv), "normal"),
@@ -91,8 +116,23 @@ HF_NAMES = {
     "s_gate": "mlp.shared_experts.gate_proj.weight",
     "s_up": "mlp.shared_experts.up_proj.weight",
     "s_down": "mlp.shared_experts.down_proj.weight"}
+KEYE_NAMES = {
+    "n1": "input_layernorm.weight", "n2": "post_attention_layernorm.weight",
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "qn": "self_attn.q_norm.weight", "kn": "self_attn.k_norm.weight",
+    "iq": "self_attn.indexer.wq.weight", "ik": "self_attn.indexer.wk.weight",
+    "ikn": "self_attn.indexer.k_norm.weight",
+    "ikb": "self_attn.indexer.k_norm.bias",
+    "iw": "self_attn.indexer.weights_proj.weight",
+    "router": "mlp.gate.weight"}
 EXPERT_NAMES = {"e_gate": "gate_proj", "e_up": "up_proj",
                 "e_down": "down_proj"}
+
+
+def layer_names(cfg: LmConfig) -> dict:
+    """Our key -> the family's published name under its layer."""
+    return KEYE_NAMES if cfg.model_type == "KeyeVL2" else HF_NAMES
 
 
 def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
@@ -102,6 +142,7 @@ def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
         except KeyError:
             raise LmLoadError(f"weights lack {name!r}") from None
 
+    names = layer_names(cfg)
     layers = []
     for li in range(cfg.num_layers):
         base = f"model.layers.{li}."
@@ -113,7 +154,7 @@ def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
                     get(f"{base}mlp.experts.{e}.{proj}.weight").T
                     for e in range(cfg.num_experts)]).astype(BF16)
             else:
-                leaf = get(base + HF_NAMES[name])
+                leaf = get(base + names[name])
                 leaf = leaf.T if leaf.ndim == 2 else leaf
                 lp[name] = leaf.astype(F32 if kind == "bias" else BF16)
         layers.append(lp)

@@ -1,5 +1,6 @@
-"""The expert layer: sigmoid routing with a selection bias, top-k of all
-experts, dropless grouped products, a shared expert beside them.
+"""The expert layer: sigmoid routing with a selection bias or softmax
+routing without one, top-k of all experts, dropless grouped products, a
+shared expert beside them where the model has one.
 
 ``route`` is float32 end to end (scores, the biased choice, the
 normalised weights). ``experts`` sorts the (token, choice) pairs by
@@ -20,19 +21,24 @@ F32 = jnp.float32
 BF16 = jnp.bfloat16
 
 
-def route(x: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int,
-          route_norm: bool, route_scale: float
+def route(x: jax.Array, router: jax.Array, bias: jax.Array | None, *,
+          top_k: int, route_norm: bool, route_scale: float,
+          score_func: str = "sigmoid"
           ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """``x`` (T, H) float32, ``router`` (H, E), ``bias`` (E,) float32.
-    Returns ``(chosen (T, k) int32, weights (T, k) float32, scores
-    (T, E))``: the choice is by ``score + bias``, the weight is the
-    score alone, over the chosen's sum where ``route_norm``, times
-    ``route_scale``."""
+    """``x`` (T, H) float32, ``router`` (H, E), ``bias`` (E,) float32 or
+    ``None``. Returns ``(chosen (T, k) int32, weights (T, k) float32,
+    scores (T, E))``: the scores are ``sigmoid`` or ``softmax`` (over the
+    experts) of the router's logits, the choice is by ``score + bias``,
+    the weight is the score alone, over the chosen's sum where
+    ``route_norm``, times ``route_scale``."""
     with jax.named_scope("lm.moe.router"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(F32), router.astype(F32), precision=lax.Precision.HIGHEST,
-            preferred_element_type=F32))
-        _, chosen = lax.top_k(scores + bias.astype(F32), top_k)
+            preferred_element_type=F32)
+        scores = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
+            else jax.nn.sigmoid(logits)
+        _, chosen = lax.top_k(
+            scores if bias is None else scores + bias.astype(F32), top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if route_norm:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)

@@ -2,9 +2,10 @@
 
 Runs after a transcription job has written ``captions.vtt`` and only on
 a worker whose ``VLOG_DIGEST_DIR`` names a model directory
-(``lm/load.py``). The job reads the cues, builds ``instruction +
-transcript`` (one ``[HH:MM:SS] text`` line per cue, cut at the engine's
-context cap), asks the process's shared step engine (``lm/engine.py``)
+(``lm/load.py``; its ``config.json`` says which of the families the
+program builds it is, and nothing here depends on that). The job reads
+the cues, builds ``instruction + transcript`` (one ``[HH:MM:SS] text``
+line per cue, cut at the engine's context cap), asks the process's shared step engine (``lm/engine.py``)
 for at most ``max_new`` tokens, and writes ``chapters.vtt`` and
 ``digest.json`` beside ``captions.vtt``.
 
