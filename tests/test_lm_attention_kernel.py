@@ -1,0 +1,223 @@
+"""The chunk form of ``paged_attention`` (lm/attention_kernel.py).
+
+On the CPU the kernel runs in Pallas's interpreter at tiny widths and is
+held to the loop form, which tests/test_lm_model.py holds to the plain
+references: same blocks, same online softmax, so the two agree to
+float32 rounding. One test compiles both families' ``lm_step_c2048`` at
+the cells' shapes for a described v5e (no chip attached, nothing runs)
+and reads the optimized HLO: the chunk's attention is a Mosaic custom
+call under its layer's scope, the pools reach it without a copy, and no
+float32 array of chunk x block keys x heads is left in the step.
+
+The topology is described inside a fixture (the TPU's library belongs
+to one process at a time; tests/test_beam_cache_layout.py and
+tests/benchmark_checks/test_benchmark_sizes.py load it the same way).
+"""
+
+from __future__ import annotations
+
+import functools
+import json
+import math
+import os
+import re
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vlog_tpu.lm import attention_kernel
+from vlog_tpu.lm import model as lm_model
+
+ROOT = Path(__file__).resolve().parents[1]
+NKV, G, HD, PAGE, BP = 2, 2, 16, 4, 2
+POOL, WIDTH = 48, 12           # pages in the pool, slots in the table
+KEYS = WIDTH * PAGE
+
+
+def _case(seed, nq, p0, n, window, base_pages, masked):
+    """The arguments of one ``paged_attention`` call for a chunk of
+    ``nq`` queries from ``p0`` of which ``n`` are real."""
+    rng = np.random.default_rng(seed)
+    pk, pv = (jnp.asarray(rng.normal(size=(POOL, PAGE, NKV, HD)),
+                          jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(1, nq, NKV, G, HD)) * 0.4, jnp.bfloat16)
+    table = np.zeros((1, WIDTH), np.int32)
+    base = base_pages * PAGE
+    live = (p0 + n - 1 - base) // PAGE + 1 if n else 0
+    table[0, :live] = rng.permutation(np.arange(1, POOL))[:live]
+    chosen = jnp.asarray(rng.random((1, nq, KEYS)) < 0.4) if masked else None
+    qpos = (p0 + jnp.arange(nq, dtype=jnp.int32))[None]
+    last = jnp.asarray([p0 + n - 1 if n else -1], jnp.int32)
+    return (q, qpos, last, pk, pv, jnp.asarray(table),
+            jnp.asarray([base], jnp.int32)), dict(
+                window=window, page=PAGE, block_pages=BP, chosen=chosen)
+
+
+def _both_forms(monkeypatch, args, kw, q_tile):
+    """``paged_attention`` in the loop form, then in the kernel form
+    (the interpreter in the kernel's place, ``q_tile`` queries a tile)."""
+    assert lm_model.attention_form(1, args[0].shape[1], NKV, HD,
+                                   PAGE) == "loop"
+    loop = lm_model.paged_attention(*args, **kw)
+    monkeypatch.setattr(lm_model, "attention_form", lambda *_: "kernel")
+    monkeypatch.setattr(
+        attention_kernel, "chunk_attention", functools.partial(
+            attention_kernel.chunk_attention, q_tile=q_tile, interpret=True))
+    return loop, lm_model.paged_attention(*args, **kw)
+
+
+def _same(loop, kernel):
+    (want, want_pages), (got, got_pages) = loop, kernel
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert np.asarray(got_pages).tolist() == np.asarray(want_pages).tolist()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+    return np.asarray(got)
+
+
+MASKS = {"causal": (None, 0, False), "window": (8, 2, False),
+         "chosen": (None, 0, True)}
+# p0, n of a 16-query chunk: the whole bucket from a block's edge; a
+# context that ends mid-page and mid-block; a chunk shorter than its
+# bucket; an absent chunk
+CHUNKS = {"aligned": (16, 16), "mid_page": (21, 16), "short": (21, 9),
+          "absent": (21, 0)}
+
+
+@pytest.mark.parametrize("chunk", CHUNKS)
+@pytest.mark.parametrize("mask", MASKS)
+def test_the_kernel_equals_the_loop(monkeypatch, mask, chunk):
+    (window, base_pages, masked), (p0, n) = MASKS[mask], CHUNKS[chunk]
+    args, kw = _case(3, 16, p0, n, window, base_pages, masked)
+    got = _same(*_both_forms(monkeypatch, args, kw, q_tile=8))
+    if n:
+        assert np.abs(got[0, :n]).max() > 0.05      # it attended something
+    else:
+        assert not got.any()                        # absent: zeros
+
+
+@pytest.mark.parametrize("tiles", [2, 4, 8, 16])
+@pytest.mark.parametrize("mask", MASKS)
+def test_every_bucket_over_the_tile(monkeypatch, mask, tiles):
+    """The cells' buckets are 2, 4, 8 and 16 query tiles (256 to 2,048
+    over 128); a tile's own block range differs with its place."""
+    window, base_pages, masked = MASKS[mask]
+    nq = 2 * tiles
+    args, kw = _case(tiles, nq, 45 - nq, nq - 1, window,
+                     base_pages and (45 - nq - 7) // PAGE, masked)
+    _same(*_both_forms(monkeypatch, args, kw, q_tile=2))
+
+
+def test_a_query_no_key_is_chosen_for_reads_zeros(monkeypatch):
+    args, kw = _case(5, 16, 8, 16, None, 0, True)
+    kw["chosen"] = kw["chosen"].at[0, 3].set(False).at[0, 11].set(False)
+    got = _same(*_both_forms(monkeypatch, args, kw, q_tile=8))
+    assert not got[0, 3].any() and not got[0, 11].any() and got[0, 4].any()
+
+
+def test_the_form_is_read_from_the_call(monkeypatch):
+    """A chunk on a TPU takes the kernel where Mosaic tiles its shapes;
+    rows of one query, several sequences, any other backend and shapes
+    the kernel does not tile take the loop."""
+    form = lm_model.attention_form      # (sequences, queries, nkv, hd, page)
+    assert form(1, 2048, 4, 128, 256) == "loop"                # the CPU
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    assert form(1, 2048, 4, 128, 256) == "kernel"
+    assert form(1, 256, 4, 128, 256) == "kernel"
+    assert form(32, 1, 4, 128, 256) == "loop"
+    assert form(1, 1, 4, 128, 256) == "loop"
+    assert form(2, 2048, 4, 128, 256) == "loop"
+    assert form(1, 16, 2, 16, 4) == "loop"
+    assert form(1, 2048, 3, 128, 256) == "loop"
+
+
+# --------------------------------------------------------------------------
+# what the TPU compiler makes of the cells' chunk step
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("config_name,weights", [
+    ("trinity_mini_6l", "afmoe_weights"), ("keye_vl2_lm_6l", "keye_weights")])
+def test_the_cells_chunk_step_compiles_to_the_kernel(
+        monkeypatch, one_chip, no_compile_cache, config_name, weights):
+    if str(ROOT / "benchmark") not in sys.path:
+        sys.path.insert(0, str(ROOT / "benchmark"))
+    make_params = __import__(f"models.{weights}",
+                             fromlist=["make_params"]).make_params
+    cfgd = json.loads(
+        (ROOT / "benchmark" / "configs" / f"{config_name}.json").read_text())
+    cfg, dep = lm_model.LmConfig.from_hf(cfgd), cfgd["deployment"]
+    geo = lm_model.Geometry(**{k: int(dep[k]) for k in (
+        "rows", "chunk", "page", "context_cap", "kv_block_pages",
+        "window_pages", "full_pages")})
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    # the described chip is not the process's backend: steer the one
+    # question the program asks of it
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    step = lm_model.build_step(cfg, geo, geo.chunk)
+    assert step.attn_chunk_form == "kernel"
+    plan = {k: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for k, (s, d) in lm_model.plan_shapes(cfg, geo,
+                                                  geo.chunk).items()}
+    text = jax.jit(step, donate_argnums=(1, 2)).lower(
+        described(jax.eval_shape(lambda: make_params(cfgd, 7))),
+        described(jax.eval_shape(lambda: lm_model.empty_cache(cfg, geo))),
+        jax.ShapeDtypeStruct((geo.rows,), jnp.int32, sharding=one_chip),
+        plan).compile().as_text()
+
+    calls = [ln for ln in text.splitlines()
+             if " custom-call(" in ln and "lm_chunk_attention" in ln]
+    assert len(calls) == cfg.num_layers
+    assert all('custom_call_target="tpu_custom_call"' in ln for ln in calls)
+    scopes = {"lm.attn.sparse": cfg.num_layers} if cfg.index_topk else {
+        "lm.attn.window": cfg.window_layers, "lm.attn.full": cfg.full_layers}
+    assert {s: sum(f"/{s}/lm_chunk_attention" in ln for ln in calls)
+            for s in scopes} == scopes
+    # the pools reach the kernel as they lie: a bitcast, never a copy
+    names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
+             ln for ln in text.splitlines() if " = " in ln}
+    for ln in calls:
+        operands = re.search(r"custom-call\(([^)]*)\)", ln).group(1)
+        pools = [o.strip().lstrip("%") for o in
+                 re.sub(r"/\*[^*]*\*/", "", operands).split(",")][4:12]
+        assert all(" bitcast(" in names[o] for o in pools), pools
+    # a block's scores (chunk x block keys x heads, float32) stay on the
+    # chip: the loop form leaves f32[1,4,8,2048,1024] in the step
+    scores = geo.chunk * geo.kv_block_pages * geo.page \
+        * cfg.num_attention_heads
+    left = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]", text)
+            if math.prod(int(d) for d in m.group(1).split(",")) == scores}
+    assert not left

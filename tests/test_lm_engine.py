@@ -106,6 +106,30 @@ def test_step_record_adds_up(model):
         rec["gap_s"] >= 0 for rec in log[1:] if rec["gap_s"] is not None)
 
 
+def test_the_step_record_says_which_form_the_chunk_attended_in(model):
+    """``attn_chunk_form`` is fixed when the bucket's program is built
+    (``model.py::attention_form``): on this backend every chunk attends
+    in the loop; a step without a chunk has none."""
+    _hf, cfg, params = model
+    eng = engine(cfg, params, rows=4)
+    try:
+        for r in [eng.submit(np.arange(n) % 512, max_new=4)
+                  for n in (21, 5)]:
+            r.wait(300)
+        log, stats = list(eng.step_log), eng.stats()
+        assert eng.attn_forms == {b: "loop" if b else None
+                                  for b in eng.geo.chunk_buckets()}
+    finally:
+        eng.close()
+    with_chunk = [rec for rec in log if rec["chunk"]]
+    assert with_chunk and len(with_chunk) < len(log)
+    assert {rec["attn_chunk_form"] for rec in with_chunk} == {"loop"}
+    assert all(rec["attn_chunk_form"] is None for rec in log
+               if not rec["chunk"])
+    assert stats["attn"] == {"kernel_steps": 0,
+                             "loop_steps": len(with_chunk)}
+
+
 def test_eos_ends_a_request_early(model):
     _hf, cfg, params = model
     eng = engine(cfg, params)

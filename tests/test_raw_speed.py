@@ -692,6 +692,14 @@ def test_pallasshim_shim_and_shim_users_are_clean(tmp_path):
             def fused(x):
                 return pl.pallas_call(lambda r, o: None)(x)
         """,
+        # so may the transcript model's attention kernel (PR 34)
+        "lm/attention_kernel.py": """\
+            from jax.experimental import pallas as pl
+            from jax.experimental.pallas import tpu as pltpu
+
+            def chunk_attention(x):
+                return pl.pallas_call(lambda r, o: None)(x)
+        """,
         # sanctioned call sites import the shim, not jax
         "parallel/ladder.py": """\
             from pkg.ops.pallas_ladder import fused

@@ -1,6 +1,6 @@
-"""pallasshim: Pallas kernel code stays inside ops/pallas_ladder.py.
+"""pallasshim: Pallas kernel code stays inside the kernel modules.
 
-``ops/pallas_ladder.py`` is the tree's single Pallas surface: it owns the
+``ops/pallas_ladder.py`` is the ladder's Pallas surface: it owns the
 ``jax.experimental.pallas`` import, the interpret-mode switch for
 non-TPU backends, and the byte-identity contract with the XLA resize
 path. Program builders select a *plane* via
@@ -10,7 +10,13 @@ past that module: the call site explodes under ``JAX_PLATFORMS=cpu`` (no
 interpret switch), ignores ``VLOG_PALLAS``, and silently forks the
 byte-identity contract the tier-1 matrix asserts.
 
-Rule: outside ``ops/pallas_ladder.py``, no module may
+``lm/attention_kernel.py`` (PR 34) is the second kernel module, under
+the same terms: the transcript model's chunk attention, which
+``lm/model.py::attention_form`` selects from the call's shapes and the
+backend (the CPU keeps the XLA loop; tests run the kernel interpreted),
+so ``model.py`` never sees ``pallas_call`` either.
+
+Rule: outside those two modules, no module may
 
 - ``from jax.experimental import pallas`` (or ``pallas as pl``)
 - ``import jax.experimental.pallas`` / any ``jax.experimental.pallas.*``
@@ -33,15 +39,18 @@ from vlog_tpu.analysis.core import Finding, Module, dotted_name
 
 RULE = "pallasshim"
 
-_SHIM = "ops/pallas_ladder.py (the only sanctioned Pallas surface)"
+_SHIM = ("ops/pallas_ladder.py or lm/attention_kernel.py (the sanctioned "
+         "Pallas surfaces)")
 _PALLAS_ROOT = "jax.experimental.pallas"
 
 
+_KERNEL_MODULES = (("ops", "pallas_ladder.py"), ("lm", "attention_kernel.py"))
+
+
 def _exempt(mod: Module) -> bool:
-    # The kernel module itself, and the analysis package (this file
+    # The kernel modules themselves, and the analysis package (this file
     # quotes the banned spellings in docstrings/tests).
-    return (mod.pkg_parts == ("ops", "pallas_ladder.py")
-            or mod.pkg_parts[0] == "analysis")
+    return mod.pkg_parts in _KERNEL_MODULES or mod.pkg_parts[0] == "analysis"
 
 
 def _is_pallas_module(name: str | None) -> bool:

@@ -1,0 +1,209 @@
+"""The chunk form of ``model.py::paged_attention`` as one Mosaic kernel.
+
+One sequence, ``Q`` consecutive query positions (a prefill chunk), keys
+read straight from the paged pools through the page table. A tile's
+scores, its running maximum, sum and accumulator live in VMEM: nothing
+of ``queries x keys`` size crosses HBM. The mathematics and the
+precision are the loop's (``paged_attention``): bfloat16 ``q`` (already
+scaled), K and V; float32 scores, mask, online softmax and accumulator,
+one softmax step a block of ``block_pages`` pages; ``p`` rounded to
+bfloat16 for the value product.
+
+The grid is ``(query tiles, key blocks)``, the key blocks innermost and
+as many as the chunk's last query reads (a dynamic bound). A tile is
+``Q_TILE`` queries of ALL heads, folded head-major into rows (a kv
+head's ``(g * Q_TILE, hd)`` against its ``(keys, hd)``), so a K/V block
+is fetched once for every head. A page reaches the kernel as the pool
+holds it: ``(pages, page, nkv, hd)`` is, byte for byte, ``(pages * page
+* nkv, hd)`` with rows by position then kv head, two kv heads to a
+32-bit word (XLA passes the pool as a bitcast, never a copy); a pair of
+heads is read with a sublane stride and split in VMEM. A key block that
+no query of a tile can see (above the diagonal, outside the band, past
+the last live page) does no work, and its index map stays on the block
+before it, so it fetches nothing either. A block that every query of
+the tile sees whole skips the positional mask.
+
+Sized on the chip (PERF.md section 6, PR 34): ``Q_TILE`` 128 with the
+geometry's block of 4 pages reads 4.7 ms for 2,048 queries over 18k keys
+(64: 5.1 ms; 256 spills: 7.9 ms; the loop: 23.0 ms).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+F32 = jnp.float32
+BF16 = jnp.bfloat16
+MASKED = -1e30
+Q_TILE = 128                    # queries a tile (x g heads = its rows)
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def supported(nq: int, nkv: int, hd: int, page: int) -> bool:
+    """Shapes Mosaic tiles: a head is one 128-lane block (a page is then
+    read as the pool holds it, two kv heads a 32-bit word), a page whole
+    int8 mask tiles, the chunk whole query tiles."""
+    tq = min(nq, Q_TILE)
+    return hd == 128 and nkv % 2 == 0 and page % 32 == 0 \
+        and nq % tq == 0 and tq % 32 == 0
+
+
+def _pages_seen(meta, qi, *, tq: int, page: int, window: int | None):
+    """``[lo, hi)``: the table slots that hold a key some query of tile
+    ``qi`` attends (causal, the band, live pages)."""
+    p0, n_pages, base = meta[0], meta[1], meta[2]
+    q_lo = p0 + qi * tq
+    hi = jnp.minimum(n_pages, (q_lo + tq - 1 - base) // page + 1)
+    lo = 0 if window is None else \
+        jnp.maximum(q_lo - window + 1 - base, 0) // page
+    return lo, hi
+
+
+def _kernel(table, meta, q_ref, *refs, tq: int, nkv: int, g: int, hd: int,
+            page: int, bp: int, window: int | None, masked: bool):
+    del table
+    k_refs, v_refs = refs[:bp], refs[bp:2 * bp]
+    refs = refs[2 * bp:]
+    chosen_ref = refs[0] if masked else None
+    o_ref, m_s, l_s, acc_s = refs[-4:]
+    qi, kb = pl.program_id(0), pl.program_id(1)
+    keys, rows = bp * page, g * tq
+    p0, n_pages, base = meta[0], meta[1], meta[2]
+    q_lo = p0 + qi * tq
+    lo, hi = _pages_seen(meta, qi, tq=tq, page=page, window=window)
+
+    @pl.when(kb == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, MASKED, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+
+    k_lo = base + kb * keys
+
+    def heads(page_refs):
+        """A block's pages ``(page * nkv, hd)``, rows by position then kv
+        head, as one ``(keys, hd)`` array a kv head. Two heads share a
+        32-bit word, so a pair is read with a sublane stride and split."""
+        out = []
+        for pair in range(nkv // 2):
+            words = jnp.concatenate(
+                [r.bitcast(jnp.uint32)[pl.ds(pair, page, stride=nkv // 2), :]
+                 for r in page_refs], axis=0)
+            out += [pltpu.bitcast(w, F32).astype(BF16)
+                    for w in (words << 16, words & jnp.uint32(0xFFFF0000))]
+        return out
+
+    def tile(edge: bool):
+        ok = None
+        if edge:
+            qpos = q_lo + lax.broadcasted_iota(jnp.int32, (tq, keys), 0)
+            kpos = k_lo + lax.broadcasted_iota(jnp.int32, (tq, keys), 1)
+            ok = (kpos <= qpos) & (kpos < base + n_pages * page)
+            if window is not None:
+                ok &= kpos > qpos - window
+        if masked:
+            picked = chosen_ref[...].astype(jnp.int32) != 0
+            ok = picked if ok is None else ok & picked
+        for h, (k, v) in enumerate(zip(heads(k_refs), heads(v_refs))):
+            at = pl.ds(h * rows, rows)
+            q = q_ref[h * g:(h + 1) * g].reshape(rows, hd)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32)  # (rows, keys)
+            if ok is not None:
+                s = jnp.where(ok[None], s.reshape(g, tq, keys),
+                              MASKED).reshape(rows, keys)
+            m_prev = m_s[at]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # a masked score underflows to 0 against any real maximum; a
+            # row with no key yet (m_new MASKED) is wiped by the first
+            # real one's scale, or zeroed at the end
+            p = jnp.exp(s - m_new)
+            scale = jnp.exp(m_prev - m_new)
+            l_s[at] = l_s[at] * scale + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[at] = acc_s[at] * scale + jnp.dot(
+                p.astype(BF16), v, preferred_element_type=F32)
+            m_s[at] = m_new
+
+    seen = (kb * bp < hi) & ((kb + 1) * bp > lo)
+    whole = (k_lo + keys - 1 <= q_lo) & ((kb + 1) * bp <= n_pages)
+    if window is not None:
+        whole &= k_lo > q_lo + tq - 1 - window
+    pl.when(seen & whole)(functools.partial(tile, False))
+    pl.when(seen & jnp.logical_not(whole))(functools.partial(tile, True))
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        out = jnp.where(m_s[...] > 0.5 * MASKED,
+                        acc_s[...] / jnp.maximum(l_s[...], 1e-30), 0.0)
+        o_ref[...] = out.reshape(nkv * g, tq, hd)
+
+
+def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
+                    pool_k: jax.Array, pool_v: jax.Array, table: jax.Array,
+                    base: jax.Array, *, window: int | None, page: int,
+                    block_pages: int, chosen: jax.Array | None = None,
+                    q_tile: int = Q_TILE, interpret: bool = False
+                    ) -> jax.Array:
+    """``q`` (Q, nkv, g, hd) bfloat16, scaled, at positions ``p0 +
+    arange(Q)``; the pools ``(pages, page, nkv, hd)``; ``table`` (W,)
+    physical pages from position ``base`` () on, the first ``n_pages`` ()
+    of them live; ``chosen`` (Q, keys) bool where given
+    (``paged_attention``). Returns (Q, nkv, g, hd) float32."""
+    nq, nkv, g, hd = q.shape
+    tq, bp, width = min(nq, q_tile), block_pages, table.shape[0]
+    keys = bp * page
+    meta = jnp.stack([p0, n_pages, base]).astype(jnp.int32)
+    # as many key blocks as the last query reads; the interpreter takes
+    # no dynamic bound: there the steps past them are skipped one by one
+    n_blocks = -(-width // bp) if interpret else \
+        jnp.maximum((n_pages + bp - 1) // bp, 1)
+    seen = functools.partial(_pages_seen, tq=tq, page=page, window=window)
+
+    def block(kb, qi, meta):
+        """The key block tile ``qi`` holds at grid step ``kb``: its own
+        where it reads one, else the nearest it does read (no fetch)."""
+        lo, hi = seen(meta, qi)
+        return jnp.clip(kb, lo // bp, jnp.maximum(hi - 1, 0) // bp)
+
+    def kv_spec(j):
+        def index(qi, kb, table, meta):
+            slot = jnp.clip(block(kb, qi, meta) * bp + j, 0,
+                            jnp.minimum(jnp.maximum(meta[1], 1), width) - 1)
+            return table[slot], 0
+        return pl.BlockSpec((page * nkv, hd), index)
+
+    # the heads lead, so a tile's rows are head-major; a page's rows are
+    # (position, kv head): the pool's own bytes, no relayout
+    by_head = pl.BlockSpec((nkv * g, tq, hd), lambda qi, kb, *_: (0, qi, 0))
+    in_specs = [by_head] + [kv_spec(j) for _ in "kv" for j in range(bp)]
+    args = [q.reshape(nq, nkv * g, hd).transpose(1, 0, 2)]
+    args += [pool_k.reshape(-1, hd)] * bp + [pool_v.reshape(-1, hd)] * bp
+    if chosen is not None:
+        in_specs.append(pl.BlockSpec(
+            (tq, keys), lambda qi, kb, table, meta:
+            (qi, block(kb, qi, meta))))
+        args.append(chosen.astype(jnp.int8))
+    rows = nkv * g * tq
+    out = pl.pallas_call(
+        functools.partial(_kernel, tq=tq, nkv=nkv, g=g, hd=hd, page=page,
+                          bp=bp, window=window, masked=chosen is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nq // tq, n_blocks),
+            in_specs=in_specs, out_specs=by_head,
+            scratch_shapes=[pltpu.VMEM((rows, 1), F32),
+                            pltpu.VMEM((rows, 1), F32),
+                            pltpu.VMEM((rows, hd), F32)]),
+        out_shape=jax.ShapeDtypeStruct((nkv * g, nq, hd), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="lm_chunk_attention",
+    )(table.astype(jnp.int32), meta, *args)
+    return out.transpose(1, 0, 2).reshape(nq, nkv, g, hd)

@@ -34,7 +34,9 @@ minus the end of the iteration before: the host's share of a step),
 ``step_s`` (``t_ready`` minus the later of the step before's ``t_ready``
 and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
 ``row_pos`` (the rows' positions), ``chunk`` (the bucket), ``context``
-(the chunk's first position),
+(the chunk's first position), ``attn_chunk_form`` (the form the chunk's
+attention took in this bucket's program, ``"kernel"`` or ``"loop"``:
+``model.py::attention_form``; ``None`` for a step without a chunk),
 ``chunk_tag``, ``emitted`` (tags of the requests that got a token),
 ``pages_in_use`` by class, ``pages_freed``, ``pool_wait_rows`` (rows that
 stood empty in this step because the request next in line waited for
@@ -127,6 +129,8 @@ class LmEngine:
         self._trace = trace.TraceContext(trace.new_id(), None,
                                          trace.TraceBuffer())
         self.programs: dict[int, object] = {}       # bucket -> compiled
+        self.attn_forms: dict[int, str | None] = {}  # bucket -> chunk form
+        self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
         # engine thread only
         self._cache: PagedCache | None = None
         self._kv = None
@@ -174,7 +178,9 @@ class LmEngine:
         a capture taken WITHOUT the HLO protos needs to book a device op
         to ``lm.attn.full`` or ``lm.moe.experts``. XLA's TPU lowering of
         ``ragged_dot`` names its kernels ``ragged-dot-*`` and drops the
-        framework name; they are the grouped expert products."""
+        framework name; they are the grouped expert products. (The chunk
+        attention's kernel, ``lm_chunk_attention``, keeps its ``op_name``
+        and its layer's scope with it.)"""
         from vlog_tpu.obs.profiler import hlo_scopes
 
         out = {}
@@ -197,9 +203,10 @@ class LmEngine:
 
     def stats(self) -> dict:
         """Counts since the engine began; ``pool`` per class the pages
-        that can be handed out, are handed out and are spoken for, and
+        that can be handed out, are handed out and are spoken for,
         ``pool_wait`` the steps in which, and the rows that, stood empty
-        for want of pages (summed over those steps)."""
+        for want of pages (summed over those steps), and ``attn`` the
+        steps whose chunk attended in each form."""
         cache = self._cache
         with self._lock:
             return {"steps": len(self.step_log),
@@ -209,7 +216,9 @@ class LmEngine:
                     if cache else {"window": 0, "full": 0},
                     "pool": cache.pools() if cache else {},
                     "pool_wait": {"steps": self.pool_wait_steps,
-                                  "rows": self.pool_wait_rows}}
+                                  "rows": self.pool_wait_rows},
+                    "attn": {f"{form}_steps": n
+                             for form, n in self.attn_steps.items()}}
 
     def close(self) -> None:
         self._stop.set()
@@ -259,7 +268,9 @@ class LmEngine:
         for chunk in geo.chunk_buckets():
             plan = {k: jax.ShapeDtypeStruct(s, d)
                     for k, (s, d) in plan_shapes(cfg, geo, chunk).items()}
-            fn = jax.jit(build_step(cfg, geo, chunk), donate_argnums=(1, 2))
+            step = build_step(cfg, geo, chunk)
+            self.attn_forms[chunk] = step.attn_chunk_form
+            fn = jax.jit(step, donate_argnums=(1, 2))
             self.programs[chunk] = fn.lower(
                 self.assets.params, self._kv, self._last_tok, plan).compile()
         for chunk, program in self.programs.items():
@@ -337,6 +348,8 @@ class LmEngine:
                 if done["pool_wait_rows"]:
                     self.pool_wait_steps += 1
                     self.pool_wait_rows += done["pool_wait_rows"]
+                if done["attn_chunk_form"]:
+                    self.attn_steps[done["attn_chunk_form"]] += 1
             self._observe(done)
         if self._flight is None:
             self._hold.yield_full_mesh()
@@ -452,6 +465,7 @@ class LmEngine:
                   "decode_rows": len(deco), "prefill_tokens": n,
                   "row_pos": plan["row_pos"][plan["row_active"]].tolist(),
                   "context": p0 if pre is not None else None,
+                  "attn_chunk_form": self.attn_forms[bucket],
                   "chunk_tag": pre.tag if pre is not None else None,
                   "emitted": [req.tag for req, _ in emitted],
                   "pages_in_use": self._cache.in_use(),

@@ -45,8 +45,10 @@ step's tokens pass the projections, the dense MLPs and the experts
 together; attention runs per sequence over the paged cache: the chunk's
 and the rows' new K/V are written into their pages first, then each
 query block reads its sequence's pages ``kv_block_pages`` at a time
-under an online softmax (never more than queries x block scores), from
-the table's first page to the page of its last query. A table is a
+under an online softmax (never more than queries x block scores; for a
+chunk on a TPU inside one kernel, ``attention_kernel.py``, where they
+never leave the chip), from the table's first page to the page of its
+last query. A table is a
 list of physical pages from logical page ``base // page`` on: for a
 full layer ``base`` is 0, for a window layer the host hands in only the
 pages the band touches (``cache.py``), so a window layer visits no page
@@ -67,7 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from vlog_tpu.lm import moe
+from vlog_tpu.lm import attention_kernel, moe
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -291,6 +293,14 @@ def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float
     return (x - mu) * lax.rsqrt(var + eps) * w.astype(F32) + b.astype(F32)
 
 
+def attention_form(seqs: int, nq: int, nkv: int, hd: int, page: int) -> str:
+    """``"kernel"`` or ``"loop"``: the form :func:`paged_attention` takes
+    for ``seqs`` sequences of ``nq`` queries each."""
+    chunk = seqs == 1 and nq > 1 and attention_kernel.supported(
+        nq, nkv, hd, page)
+    return "kernel" if chunk and jax.default_backend() == "tpu" else "loop"
+
+
 def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
                     pool_k: jax.Array, pool_v: jax.Array, table: jax.Array,
                     base: jax.Array, *, window: int | None, page: int,
@@ -305,11 +315,23 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     (S, Q, keys) bool, where given, the keys each query attends (by
     position from ``base`` on; whole blocks wide). Returns
     ``(out (S, Q, nkv, g, hd) float32, pages visited (S,))``.
+
+    Two forms of the one algorithm, chosen by :func:`attention_form`
+    from the call's shapes and the backend: rows of one query each run
+    the loop below; a prefill chunk (one sequence of ``Q`` consecutive
+    positions from ``qpos[0, 0]`` on) runs on a TPU as one kernel that
+    keeps a block's scores on the chip (``attention_kernel.py``).
     """
     s, nq, nkv, g, hd = q.shape
     width = table.shape[1]
     keys = block_pages * page
     n_pages = jnp.where(last_pos >= 0, (last_pos - base) // page + 1, 0)
+    if attention_form(s, nq, nkv, hd, page) == "kernel":
+        out = attention_kernel.chunk_attention(
+            q[0], qpos[0, 0], n_pages[0], pool_k, pool_v, table[0], base[0],
+            window=window, page=page, block_pages=block_pages,
+            chosen=None if chosen is None else chosen[0])
+        return out[None], n_pages
     n_blocks = (jnp.max(n_pages) + block_pages - 1) // block_pages
     lane = jnp.arange(keys, dtype=jnp.int32)
 
@@ -804,4 +826,8 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
 
     step.__name__ = f"lm_step_c{chunk}"
     step.__qualname__ = step.__name__
+    # the form the chunk's attention takes in this program (None: no chunk)
+    step.attn_chunk_form = attention_form(
+        1, chunk, cfg.num_key_value_heads, cfg.head_dim, geo.page) \
+        if chunk else None
     return step
