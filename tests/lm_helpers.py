@@ -1,5 +1,6 @@
-"""Shared by the transcript model's tests: the tiny afmoe and KeyeVL2
-models, their engine and the comparison with the plain references."""
+"""Shared by the transcript model's tests: the tiny afmoe, KeyeVL2 and
+xing4_0 models, their engine and the comparison with the plain
+references."""
 
 import json
 import sys
@@ -15,6 +16,7 @@ if str(ROOT / "benchmark") not in sys.path:
 
 from reference import afmoe_ref as ref  # noqa: E402
 from reference import keye_ref  # noqa: E402
+from reference import xing_ref  # noqa: E402
 
 from vlog_tpu.lm.engine import LmEngine  # noqa: E402
 from vlog_tpu.lm.load import (EXPERT_NAMES, LmAssets,  # noqa: E402
@@ -23,6 +25,9 @@ from vlog_tpu.lm.model import BF16, F32, Geometry, LmConfig  # noqa: E402
 
 INIT_STD = 0.02
 BIAS_STD = 0.01
+# a hyper-connection's projection at hidden 64: its 24 outputs then have
+# a standard deviation of 0.48, as the benchmark's have at hidden 3,584
+HC_STD = 0.03
 
 # stated tolerance of the tiny comparison: bfloat16 products at hidden 64
 # read up to 0.09 of the logits' spread (measured over the lengths
@@ -62,7 +67,8 @@ def tiny_hf_config(**over) -> dict:
     return cfg
 
 
-def random_params(cfg: LmConfig, seed: int) -> dict:
+def random_params(cfg: LmConfig, seed: int, init_std: float = INIT_STD
+                  ) -> dict:
     """Seeded weights: matrices N(0, 0.02^2), norm weights 1, the
     selection bias N(0, 0.01^2)."""
     key = jax.random.PRNGKey(int(seed) % (2**31 - 1))
@@ -73,9 +79,12 @@ def random_params(cfg: LmConfig, seed: int) -> dict:
         k = jax.random.fold_in(key, count[0])
         if kind in ("ones", "zeros"):
             return jnp.full(shape, kind == "ones", BF16)
+        if kind in ("bias_ones", "bias_zeros"):
+            return jnp.full(shape, kind == "bias_ones", F32)
         if kind == "bias":
             return jax.random.normal(k, shape, F32) * BIAS_STD
-        return (jax.random.normal(k, shape, F32) * INIT_STD).astype(BF16)
+        std = HC_STD if kind == "hc" else init_std
+        return (jax.random.normal(k, shape, F32) * std).astype(BF16)
 
     v, h = cfg.vocab_size, cfg.hidden_size
     return {"embed": draw((v, h), "normal"), "head": draw((h, v), "normal"),
@@ -261,3 +270,58 @@ def compare_keye(req, hf, params, **how):
         errs.append(keye_ref.logit_error(req.logits[i], out["logits"][row]))
         gaps.append(keye_ref.rank_gap(toks[i], out["logits"][row]))
     return errs, gaps
+
+
+# ---- xing4_0 at tiny widths ---------------------------------------------
+
+def tiny_xing_hf_config(**over) -> dict:
+    """A CPU-sized config of the published ``xing4_0`` form: latent
+    attention (4 heads of 16 + 8 over a latent of 16 + 8), four residual
+    streams, 1 dense + 3 expert layers of 8 experts top 2 beside a shared
+    one. YaRN's original length is 32 so that the test's positions
+    cross its ramp."""
+    cfg = {"model_type": "xing4_0", "hidden_size": 64,
+           "num_attention_heads": 4, "num_key_value_heads": 4,
+           "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+           "qk_rope_head_dim": 8, "v_head_dim": 16, "num_hidden_layers": 4,
+           "first_k_dense_replace": 1, "intermediate_size": 96,
+           "moe_intermediate_size": 32, "n_routed_experts": 8,
+           "num_experts_per_tok": 2, "n_shared_experts": 1,
+           "norm_topk_prob": True, "routed_scaling_factor": 2,
+           "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+           "n_group": 1, "topk_group": 1, "moe_layer_freq": 1, "ep_size": 1,
+           "hidden_act": "silu", "vocab_size": 512, "rms_norm_eps": 1e-6,
+           "rope_theta": 10000, "max_position_embeddings": 2048,
+           "rope_scaling": {"type": "yarn", "factor": 64,
+                            "original_max_position_embeddings": 32,
+                            "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                            "mscale_all_dim": 1},
+           "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+           "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+           "attention_bias": False, "tie_word_embeddings": False,
+           "num_nextn_predict_layers": 1}
+    cfg.update(over)
+    return cfg
+
+
+def tiny_xing(seed=11, bias_scale=20.0, init_std=0.125, **over):
+    """Matrices N(0, 0.125^2): at hidden 64 a product then keeps its
+    input's size, as N(0, 0.02^2) does at the published 3,584; at 0.02 a
+    sublayer would add next to nothing to the residual state and what
+    the residual path does would not show in the logits."""
+    hf = tiny_xing_hf_config(**over)
+    cfg = LmConfig.from_hf(hf)
+    params = random_params(cfg, seed, init_std)
+    for lp in params["layers"]:
+        if "bias" in lp:            # large enough to change choices
+            lp["bias"] = lp["bias"] * bias_scale
+    return hf, cfg, params
+
+
+def xing_rows(req, hf, params, **how):
+    """The reference's full forward pass over a finished request's
+    prompt plus served tokens, at its captured steps."""
+    full = np.concatenate([req.prompt, req.tokens[:-1]]).astype(np.int32)
+    steps = sorted(req.logits)
+    return steps, xing_ref.forward(
+        params, hf, full, [req.prompt.size - 1 + i for i in steps], **how)

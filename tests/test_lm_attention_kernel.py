@@ -221,3 +221,196 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     left = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]", text)
             if math.prod(int(d) for d in m.group(1).split(",")) == scores}
     assert not left
+
+
+# --------------------------------------------------------------------------
+# latent attention's chunk: the expanded form as a kernel
+# --------------------------------------------------------------------------
+
+L_HEADS, L_NOPE, L_ROPE, L_RANK, L_V = 4, 16, 8, 16, 16
+
+
+def _latent_case(seed, nq, p0, n):
+    """``expanded_attention``'s arguments for a chunk of ``nq`` queries
+    from ``p0`` of which ``n`` are real, over a pool of latents with the
+    positions in the lanes."""
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.normal(size=(POOL, L_RANK + L_ROPE, PAGE)),
+                       jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(nq, L_HEADS, L_NOPE + L_ROPE)),
+                    jnp.float32)
+    w = jnp.asarray(rng.normal(size=(L_RANK, L_HEADS, L_NOPE + L_V)) * 0.3,
+                    jnp.bfloat16)
+    table = np.zeros(WIDTH, np.int32)
+    live = (p0 + n - 1) // PAGE + 1 if n else 0
+    table[:live] = rng.permutation(np.arange(1, POOL))[:live]
+    return (q, jnp.int32(p0), jnp.int32(p0 + n - 1 if n else -1), pool,
+            jnp.asarray(table), w), dict(nope=L_NOPE, scale=0.2, page=PAGE,
+                                         block_pages=BP)
+
+
+def _latent_forms(monkeypatch, args, kw, q_tile):
+    assert lm_model.latent_chunk_form(
+        args[0].shape[0], L_NOPE, L_ROPE, L_RANK, L_V, PAGE) \
+        == "latent_expanded_loop"
+    loop = lm_model.expanded_attention(*args, **kw)
+    monkeypatch.setattr(lm_model, "latent_chunk_form",
+                        lambda *_: "latent_expanded_kernel")
+    monkeypatch.setattr(
+        attention_kernel, "latent_chunk_attention", functools.partial(
+            attention_kernel.latent_chunk_attention, q_tile=q_tile,
+            interpret=True))
+    return loop, lm_model.expanded_attention(*args, **kw)
+
+
+@pytest.mark.parametrize("q_tile", [4, 16])
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_the_latent_kernel_equals_the_loop(monkeypatch, chunk, q_tile):
+    p0, n = CHUNKS[chunk]
+    args, kw = _latent_case(11, 16, p0, n)
+    loop, kernel = _latent_forms(monkeypatch, args, kw, q_tile)
+    assert kernel.shape == loop.shape == (16, L_HEADS, L_V)
+    assert kernel.dtype == jnp.float32
+    # the rows past the chunk's real queries attend the keys they see in
+    # both forms; an absent chunk reads zeros
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(loop),
+                               rtol=2e-6, atol=2e-6)
+    if not n:
+        assert not np.asarray(kernel).any()
+
+
+@pytest.mark.parametrize("tiles", [2, 8])
+def test_every_latent_bucket_over_the_sub_tile(monkeypatch, tiles):
+    args, kw = _latent_case(13, 4 * tiles, 9, 4 * tiles - 3)
+    loop, kernel = _latent_forms(monkeypatch, args, kw, 4)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(loop),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_the_latent_form_is_read_from_the_call(monkeypatch):
+    # the cell's shapes: a kernel on a TPU, the loop anywhere else
+    shapes = (128, 64, 512, 128, 256)
+    assert lm_model.latent_chunk_form(2048, *shapes) == "latent_expanded_loop"
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    for nq in (256, 512, 1024, 2048):
+        assert lm_model.latent_chunk_form(nq, *shapes) \
+            == "latent_expanded_kernel"
+    # the tiny model's heads are no lane blocks: the loop on any backend
+    assert lm_model.latent_chunk_form(16, L_NOPE, L_ROPE, L_RANK, L_V,
+                                      PAGE) == "latent_expanded_loop"
+
+
+def _rows_case(seed, lasts):
+    """``absorbed_attention``'s arguments for one query a sequence at
+    the positions ``lasts`` (-1: the sequence is absent)."""
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.normal(size=(POOL, L_RANK + L_ROPE, PAGE)),
+                       jnp.bfloat16)
+    rows = len(lasts)
+    q = jnp.asarray(rng.normal(size=(rows, L_HEADS, L_NOPE + L_ROPE)),
+                    jnp.float32)
+    w = jnp.asarray(rng.normal(size=(L_RANK, L_HEADS, L_NOPE + L_V)) * 0.3,
+                    jnp.bfloat16)
+    table = np.zeros((rows, WIDTH), np.int32)
+    for i, last in enumerate(lasts):
+        live = last // PAGE + 1 if last >= 0 else 0
+        table[i, :live] = rng.permutation(np.arange(1, POOL))[:live]
+    last = jnp.asarray(lasts, jnp.int32)
+    return (q, last, last, pool, jnp.asarray(table), w), dict(
+        nope=L_NOPE, scale=0.2, page=PAGE, block_pages=BP)
+
+
+@pytest.mark.parametrize("lasts", [
+    (0, 13, -1, 30, 47), (47, 47, 47), (-1, -1), (3, 4, 7, 8)],
+    ids=["mixed", "longest", "absent", "page_edges"])
+def test_the_rows_kernel_equals_the_loop(monkeypatch, lasts):
+    """Rows of one page and of twelve, a row that is absent, rows at a
+    page's and a block's edge: each reads its own pages and no more."""
+    args, kw = _rows_case(17, lasts)
+    assert lm_model.latent_rows_form(L_HEADS, L_RANK + L_ROPE,
+                                     PAGE) == "loop"
+    loop = lm_model.absorbed_attention(*args, **kw)
+    monkeypatch.setattr(lm_model, "latent_rows_form", lambda *_: "kernel")
+    monkeypatch.setattr(
+        attention_kernel, "latent_rows_attention", functools.partial(
+            attention_kernel.latent_rows_attention, interpret=True))
+    kernel = lm_model.absorbed_attention(*args, **kw)
+    assert kernel.shape == loop.shape == (len(lasts), L_HEADS, L_V)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(loop),
+                               rtol=2e-6, atol=2e-6)
+    for i, last in enumerate(lasts):
+        assert np.asarray(kernel[i]).any() == (last >= 0)
+
+
+def test_the_rows_form_is_read_from_the_call(monkeypatch):
+    assert lm_model.latent_rows_form(32, 576, 256) == "loop"
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    assert lm_model.latent_rows_form(32, 576, 256) == "kernel"
+    assert lm_model.latent_rows_form(L_HEADS, L_RANK + L_ROPE,
+                                     PAGE) == "loop"
+
+
+def test_the_chapters_cells_chunk_step_compiles_to_the_latent_kernel(
+        monkeypatch, one_chip, no_compile_cache):
+    if str(ROOT / "benchmark") not in sys.path:
+        sys.path.insert(0, str(ROOT / "benchmark"))
+    from models.xing_weights import make_params
+    cfgd = json.loads((ROOT / "benchmark" / "configs" / "xing4_29b_6l.json"
+                       ).read_text())
+    cfg, dep = lm_model.LmConfig.from_hf(cfgd), cfgd["deployment"]
+    geo = lm_model.Geometry(**{k: int(dep[k]) for k in (
+        "rows", "chunk", "page", "context_cap", "kv_block_pages",
+        "window_pages", "full_pages")})
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    step = lm_model.build_step(cfg, geo, geo.chunk)
+    assert step.attn_chunk_form == "latent_expanded_kernel"
+    assert step.attn_rows_form == "latent_absorbed"
+    plan = {k: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for k, (s, d) in lm_model.plan_shapes(cfg, geo,
+                                                  geo.chunk).items()}
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        described(jax.eval_shape(lambda: make_params(cfgd, 7))),
+        described(jax.eval_shape(lambda: lm_model.empty_cache(cfg, geo))),
+        jax.ShapeDtypeStruct((geo.rows,), jnp.int32, sharding=one_chip),
+        plan).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if " custom-call(" in ln and "lm_latent_chunk_attention" in ln]
+    assert len(calls) == cfg.num_layers == 6
+    assert all('custom_call_target="tpu_custom_call"' in ln for ln in calls)
+    assert all("/lm.attn.latent.chunk/lm_latent_chunk_attention" in ln
+               for ln in calls)
+    rows = [ln for ln in text.splitlines()
+            if " custom-call(" in ln and "lm_latent_rows_attention" in ln]
+    assert len(rows) == cfg.num_layers
+    assert all("/lm.attn.latent.rows/lm_latent_rows_attention" in ln
+               for ln in rows)
+    calls += rows
+    # the pool reaches the kernel as it lies: a bitcast, never a copy,
+    # and nowhere in the step is a whole pool relaid (880 MB a layer)
+    names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
+             ln for ln in text.splitlines() if " = " in ln}
+    for ln in calls:
+        operands = re.search(r"custom-call\(([^)]*)\)", ln).group(1)
+        pools = [o.strip().lstrip("%") for o in
+                 re.sub(r"/\*[^*]*\*/", "", operands).split(",")][
+                     -geo.kv_block_pages:]
+        assert all(" bitcast(" in names[o] for o in pools), pools
+    pages = geo.full_pages
+    assert not [ln for ln in text.splitlines()
+                if re.search(rf"= bf16\[{pages},[0-9,]+\][^ ]* (copy|transpose)"
+                             r"\(", ln)]
+    # a block's scores and its expanded keys stay on the chip
+    scores = geo.chunk * geo.kv_block_pages * geo.page \
+        * cfg.num_attention_heads
+    left = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]", text)
+            if math.prod(int(d) for d in m.group(1).split(",")) == scores}
+    assert not left
+    # weights, pool and a chunk step's temporaries fit the chip
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9

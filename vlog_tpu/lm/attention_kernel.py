@@ -26,6 +26,29 @@ the tile sees whole skips the positional mask.
 Sized on the chip (PERF.md section 6, PR 34): ``Q_TILE`` 128 with the
 geometry's block of 4 pages reads 4.7 ms for 2,048 queries over 18k keys
 (64: 5.1 ms; 256 spills: 7.9 ms; the loop: 23.0 ms).
+
+**Latent attention's chunk** (:func:`latent_chunk_attention`, the
+expanded form of ``model.py::expanded_attention``) is a second kernel
+over the same pages: the pool holds one latent ``(c | k_rope)`` a
+position, and a head's keys and values are products of it. The grid is
+``(heads, key blocks)``, the key blocks innermost; a step reads a block
+of latents through the page table, expands it for ITS head in VMEM
+(``k = c Wuk[h]``, ``v = c Wuv[h]``: once for all of the chunk's
+queries, nothing expanded crosses HBM) and runs the chunk's queries
+against it in sub-tiles of ``LATENT_Q_TILE`` under the same online
+softmax, the rope part of the score as a second product against the
+block's own ``k_rope`` lanes. A latent is read once a head; the
+mathematics and the precision are the loop's.
+
+**Latent attention's rows** (:func:`latent_rows_attention`, the
+absorbed form of ``model.py::absorbed_attention``): one query a
+sequence, every head's query already carried into the latent's space,
+so the latent is ONE key head whose first lanes are also the value. The
+grid is ``(rows, key blocks)``; a step reads a block of ONE row's pages
+through that row's table and runs the row's 32 heads against it (scores
+and the value product against the same bytes); a row's blocks past its
+last page neither compute nor fetch, so a step's work is the sum of the
+rows' contexts and not 32 times the longest (the loop's).
 """
 
 from __future__ import annotations
@@ -42,6 +65,7 @@ F32 = jnp.float32
 BF16 = jnp.bfloat16
 MASKED = -1e30
 Q_TILE = 128                    # queries a tile (x g heads = its rows)
+LATENT_Q_TILE = 256             # queries a sub-tile of the latent kernel
 VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -207,3 +231,234 @@ def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
         name="lm_chunk_attention",
     )(table.astype(jnp.int32), meta, *args)
     return out.transpose(1, 0, 2).reshape(nq, nkv, g, hd)
+
+
+# --------------------------------------------------------------------------
+# latent attention: a chunk, expanded a head at a time
+# --------------------------------------------------------------------------
+
+def latent_supported(nq: int, nope: int, rope: int, rank: int, vd: int,
+                     page: int) -> bool:
+    """Shapes Mosaic tiles: a head's parts whole 128-lane blocks, the
+    latent's parts whole sublane tiles (the rope part half a lane block
+    of q), a page whole lane blocks (positions lie in the lanes), the
+    chunk whole query sub-tiles."""
+    tq = min(nq, LATENT_Q_TILE)
+    return nope % 128 == 0 and rank % 128 == 0 and vd % 128 == 0 \
+        and rope % 64 == 0 and page % 128 == 0 and nq % tq == 0 \
+        and tq % 16 == 0
+
+
+def _latent_kernel(table, meta, qn_ref, qr_ref, wk_ref, wv_ref, *refs,
+                   tq: int, page: int, bp: int, rank: int):
+    del table
+    page_refs = refs[:bp]
+    o_ref, k_s, v_s, m_s, l_s, acc_s = refs[bp:]
+    kb = pl.program_id(1)
+    nq, keys = qn_ref.shape[0], bp * page
+    p0, n_pages = meta[0], meta[1]
+
+    @pl.when(kb == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, MASKED, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+
+    # this head's keys and values of the block, once for every query.
+    # A page arrives as the pool holds it, (latent, page): positions in
+    # the lanes, so k and v come out transposed, (dims, keys), which is
+    # what the score product wants of k anyway
+    lat = jnp.concatenate([r[...] for r in page_refs], axis=1)
+    c, k_rope = lat[:rank], lat[rank:]                      # (.., keys)
+    k_s[...] = jnp.dot(wk_ref[...], c,
+                       preferred_element_type=F32).astype(BF16)
+    v_s[...] = jnp.dot(wv_ref[...], c,
+                       preferred_element_type=F32).astype(BF16)
+    k_lo = kb * keys
+
+    def tile(i, edge: bool):
+        at = pl.ds(pl.multiple_of(i * tq, tq), tq)
+        s = jnp.dot(qn_ref[at, :], k_s[...], preferred_element_type=F32) \
+            + jnp.dot(qr_ref[at, :], k_rope,
+                      preferred_element_type=F32)           # (tq, keys)
+        if edge:
+            qpos = p0 + i * tq + lax.broadcasted_iota(
+                jnp.int32, (tq, keys), 0)
+            kpos = k_lo + lax.broadcasted_iota(jnp.int32, (tq, keys), 1)
+            s = jnp.where((kpos <= qpos) & (kpos < n_pages * page), s,
+                          MASKED)
+        m_prev = m_s[at]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        scale = jnp.exp(m_prev - m_new)
+        l_s[at] = l_s[at] * scale + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[at] = acc_s[at] * scale + lax.dot_general(
+            p.astype(BF16), v_s[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=F32)
+        m_s[at] = m_new
+
+    def sub_tile(i, carry):
+        q_lo = p0 + i * tq
+        seen = k_lo <= q_lo + tq - 1
+        whole = (k_lo + keys - 1 <= q_lo) & ((kb + 1) * bp <= n_pages)
+        pl.when(seen & whole)(functools.partial(tile, i, False))
+        pl.when(seen & jnp.logical_not(whole))(
+            functools.partial(tile, i, True))
+        return carry
+
+    lax.fori_loop(0, nq // tq, sub_tile, None)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = jnp.where(
+            m_s[...] > 0.5 * MASKED,
+            acc_s[...] / jnp.maximum(l_s[...], 1e-30), 0.0)
+
+
+def latent_chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
+                           pool: jax.Array, table: jax.Array,
+                           w_kvb: jax.Array, *, nope: int, page: int,
+                           block_pages: int, q_tile: int = LATENT_Q_TILE,
+                           interpret: bool = False) -> jax.Array:
+    """``q`` (Q, heads, nope + rope) bfloat16, scaled, rope part rotated,
+    at positions ``p0 + arange(Q)``; ``pool`` (pages, rank + rope, page)
+    bfloat16; ``table`` (W,) physical pages from position 0 on, the
+    first ``n_pages`` () of them live; ``w_kvb`` (rank, heads, nope + v)
+    bfloat16. Returns (Q, heads, v) float32."""
+    nq, nh, _hd = q.shape
+    rank, _nh, wide = w_kvb.shape
+    vd, latent = wide - nope, pool.shape[1]
+    tq, bp, width = min(nq, q_tile), block_pages, table.shape[0]
+    keys = bp * page
+    meta = jnp.stack([p0, n_pages]).astype(jnp.int32)
+    n_blocks = -(-width // bp) if interpret else \
+        jnp.maximum((n_pages + bp - 1) // bp, 1)
+
+    def page_spec(j):
+        def index(h, kb, table, meta):
+            last = jnp.minimum(jnp.maximum(meta[1], 1), width) - 1
+            # past the last live page: stay on it (no fetch, masked)
+            return table[jnp.minimum(kb * bp + j, last)], 0
+        return pl.BlockSpec((latent, page), index)
+
+    def by_head(*tail):
+        return pl.BlockSpec((None,) + tail, lambda h, kb, *_: (h, 0, 0))
+
+    by_h = q.transpose(1, 0, 2)                     # (heads, Q, nope + rope)
+    w = w_kvb.transpose(1, 2, 0)                    # (heads, nope + v, rank)
+    by_page = pool.reshape(-1, page)    # (pages x latent, page): a bitcast
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, tq=tq, page=page, bp=bp, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nh, n_blocks),
+            in_specs=[by_head(nq, nope), by_head(nq, latent - rank),
+                      by_head(nope, rank), by_head(vd, rank)]
+            + [page_spec(j) for j in range(bp)],
+            out_specs=by_head(nq, vd),
+            scratch_shapes=[pltpu.VMEM((nope, keys), BF16),
+                            pltpu.VMEM((vd, keys), BF16),
+                            pltpu.VMEM((nq, 1), F32),
+                            pltpu.VMEM((nq, 1), F32),
+                            pltpu.VMEM((nq, vd), F32)]),
+        out_shape=jax.ShapeDtypeStruct((nh, nq, vd), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="lm_latent_chunk_attention",
+    )(table.astype(jnp.int32), meta, by_h[..., :nope], by_h[..., nope:],
+      w[:, :nope], w[:, nope:], *([by_page] * bp))
+    return out.transpose(1, 0, 2)
+
+
+# --------------------------------------------------------------------------
+# latent attention: the rows, absorbed
+# --------------------------------------------------------------------------
+
+def latent_rows_supported(nh: int, latent: int, page: int) -> bool:
+    """Shapes Mosaic tiles: the heads whole sublane tiles of the score
+    product's rows, the latent whole sublane tiles of a page, a page
+    whole lane blocks."""
+    return nh % 16 == 0 and latent % 16 == 0 and page % 128 == 0
+
+
+def _rows_kernel(table, pos, q_ref, *refs, page: int, bp: int, width: int):
+    del table
+    page_refs = refs[:bp]
+    o_ref, m_s, l_s, acc_s = refs[bp:]
+    r, kb = pl.program_id(0), pl.program_id(1)
+    keys = bp * page
+    last = pos[r]                           # -1: the row is absent
+    n_pages = jnp.where(last >= 0, last // page + 1, 0)
+
+    @pl.when(kb == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, MASKED, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+
+    @pl.when(kb * bp < n_pages)
+    def _():
+        lat = jnp.concatenate([p[...] for p in page_refs], axis=1)
+        s = jnp.dot(q_ref[...], lat, preferred_element_type=F32)
+        kpos = kb * keys + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= last, s, MASKED)  # (heads, keys)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        scale = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * scale + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * scale + lax.dot_general(
+            p.astype(BF16), lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=F32)
+        m_s[...] = m_new
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = jnp.where(
+            m_s[...] > 0.5 * MASKED,
+            acc_s[...] / jnp.maximum(l_s[...], 1e-30), 0.0)
+
+
+def latent_rows_attention(q: jax.Array, last_pos: jax.Array, pool: jax.Array,
+                          table: jax.Array, *, page: int, block_pages: int,
+                          interpret: bool = False) -> jax.Array:
+    """``q`` (S, heads, latent) bfloat16, scaled, each head's query in
+    the latent's space (``[q_nope Wuk^T | q_rope]``), the one query of
+    sequence ``s`` at position ``last_pos[s]`` (-1: absent, reads
+    zeros); ``pool`` (pages, latent, page) bfloat16; ``table`` (S, W)
+    physical pages from position 0 on. Returns (S, heads, latent)
+    float32: ``softmax(q . latent) latent`` over the positions up to
+    ``last_pos``, of which the caller keeps the value's lanes."""
+    rows, nh, latent = q.shape
+    bp, width = block_pages, table.shape[1]
+    n_pages = jnp.where(last_pos >= 0, last_pos // page + 1, 0)
+    n_blocks = -(-width // bp) if interpret else \
+        jnp.maximum((jnp.max(n_pages) + bp - 1) // bp, 1)
+
+    def page_spec(j):
+        def index(r, kb, table, pos):
+            live = jnp.where(pos[r] >= 0, pos[r] // page + 1, 0)
+            last = jnp.minimum(jnp.maximum(live, 1), width) - 1
+            # past the row's last page: stay on it (no fetch, no work)
+            return table[r * width + jnp.minimum(kb * bp + j, last)], 0
+        return pl.BlockSpec((latent, page), index)
+
+    by_row = pl.BlockSpec((None, nh, latent), lambda r, kb, *_: (r, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, page=page, bp=bp, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(rows, n_blocks),
+            in_specs=[by_row] + [page_spec(j) for j in range(bp)],
+            out_specs=by_row,
+            scratch_shapes=[pltpu.VMEM((nh, 1), F32),
+                            pltpu.VMEM((nh, 1), F32),
+                            pltpu.VMEM((nh, latent), F32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, nh, latent), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="lm_latent_rows_attention",
+    )(table.reshape(-1).astype(jnp.int32), last_pos.astype(jnp.int32), q,
+      *([pool.reshape(-1, page)] * bp))

@@ -5,7 +5,10 @@ A page holds ``page`` positions of K and V for every layer of its class
 (the layers share page numbers; ``model.py::empty_cache`` holds one K and
 one V array per layer and, for a model with an indexer, one array of its
 keys on the same page numbers: a second pool of the full class, no class
-of its own). Page 0 of each pool is never handed out: the step program
+of its own; for a model with latent attention ONE array a layer of the
+latent's width in place of K and V, again on the full class's page
+numbers: a latent is "the new positions' rows" like any K or V, so it
+needs no class of table, only a pool of another width). Page 0 of each pool is never handed out: the step program
 points unallocated table slots and padded rows there. A model without
 window layers has no window pool, no window table and reserves nothing
 of that class.
@@ -25,7 +28,9 @@ Admission is by reservation: a request is admitted only if both pools
 can hold it to its end beside everything already admitted
 (:meth:`PagedCache.admit`), so nothing is ever evicted mid-request.
 Where the full pool is smaller than ``rows`` longest requests (every
-layer of the full class: 13 KB a position over six layers), it is the
+layer of the full class: 13 KB a position over six layers of K, V and
+indexer keys for ``KeyeVL2``, 6.9 KB of latents for ``xing4_0``;
+``afmoe``'s full class holds only its full layers, 2 KB each), it is the
 pool and not the rows that bounds what is resident, and rows stand empty
 while the next request waits for pages (the engine counts them).
 Used from the engine's thread alone; no lock.

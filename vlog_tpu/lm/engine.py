@@ -36,14 +36,20 @@ and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
 ``row_pos`` (the rows' positions), ``chunk`` (the bucket), ``context``
 (the chunk's first position), ``attn_chunk_form`` (the form the chunk's
 attention took in this bucket's program, ``"kernel"`` or ``"loop"``:
-``model.py::attention_form``; ``None`` for a step without a chunk),
+``model.py::attention_form``, or for latent attention
+``"latent_expanded_kernel"`` / ``"latent_expanded_loop"``; ``None`` for
+a step without a chunk), ``attn_rows_form`` (the rows': ``"loop"``,
+``"gathered"`` or ``"latent_absorbed"``), ``rows_context`` (positions
+the rows' attention read a layer: each row's position plus one),
 ``chunk_tag``, ``emitted`` (tags of the requests that got a token),
 ``pages_in_use`` by class, ``pages_freed``, ``pool_wait_rows`` (rows that
 stood empty in this step because the request next in line waited for
 pages, not for a row: the pool, not ``rows``, bounded what was
 resident), ``expert_load`` and ``window_pages`` or, for a model with an
 indexer, ``sparse_keys`` (keys one layer attended, keys a causal-dense
-layer would have; from the device, read out with the tokens),
+layer would have; from the device, read out with the tokens) or, for a
+model with hyper-connections, ``hc_defect`` (the largest distance of a
+row or column sum of any ``H_res`` of the step from 1; from the device),
 ``build_s``; all instants on ``time.monotonic()``.
 """
 
@@ -130,7 +136,9 @@ class LmEngine:
                                          trace.TraceBuffer())
         self.programs: dict[int, object] = {}       # bucket -> compiled
         self.attn_forms: dict[int, str | None] = {}  # bucket -> chunk form
+        self.attn_rows_form: str | None = None
         self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
+        self.hc_defect_max = 0.0                    # guarded-by: _lock
         # engine thread only
         self._cache: PagedCache | None = None
         self._kv = None
@@ -180,7 +188,9 @@ class LmEngine:
         ``ragged_dot`` names its kernels ``ragged-dot-*`` and drops the
         framework name; they are the grouped expert products. (The chunk
         attention's kernel, ``lm_chunk_attention``, keeps its ``op_name``
-        and its layer's scope with it.)"""
+        and its layer's scope with it.) A latent-attention model's
+        programs carry ``lm.attn.latent.project``, ``.expand``, ``.chunk``,
+        ``.rows`` and ``lm.hc.map``, ``.pre``, ``.post`` the same way."""
         from vlog_tpu.obs.profiler import hlo_scopes
 
         out = {}
@@ -205,11 +215,15 @@ class LmEngine:
         """Counts since the engine began; ``pool`` per class the pages
         that can be handed out, are handed out and are spoken for,
         ``pool_wait`` the steps in which, and the rows that, stood empty
-        for want of pages (summed over those steps), and ``attn`` the
-        steps whose chunk attended in each form."""
+        for want of pages (summed over those steps), ``attn`` the steps
+        whose chunk attended in each form, ``attn_rows_form`` the form of
+        the rows, and
+        ``hc_defect_max`` the largest ``hc_defect`` of any step (0.0 for
+        a model with one residual stream)."""
         cache = self._cache
         with self._lock:
             return {"steps": len(self.step_log),
+                    "hc_defect_max": self.hc_defect_max,
                     "requests_done": self.requests_done,
                     "pending": len(self._inbox),
                     "pages_in_use": cache.in_use()
@@ -218,7 +232,8 @@ class LmEngine:
                     "pool_wait": {"steps": self.pool_wait_steps,
                                   "rows": self.pool_wait_rows},
                     "attn": {f"{form}_steps": n
-                             for form, n in self.attn_steps.items()}}
+                             for form, n in self.attn_steps.items()},
+                    "attn_rows_form": self.attn_rows_form}
 
     def close(self) -> None:
         self._stop.set()
@@ -270,6 +285,7 @@ class LmEngine:
                     for k, (s, d) in plan_shapes(cfg, geo, chunk).items()}
             step = build_step(cfg, geo, chunk)
             self.attn_forms[chunk] = step.attn_chunk_form
+            self.attn_rows_form = step.attn_rows_form
             fn = jax.jit(step, donate_argnums=(1, 2))
             self.programs[chunk] = fn.lower(
                 self.assets.params, self._kv, self._last_tok, plan).compile()
@@ -348,8 +364,11 @@ class LmEngine:
                 if done["pool_wait_rows"]:
                     self.pool_wait_steps += 1
                     self.pool_wait_rows += done["pool_wait_rows"]
-                if done["attn_chunk_form"]:
-                    self.attn_steps[done["attn_chunk_form"]] += 1
+                form = done["attn_chunk_form"]
+                if form:
+                    self.attn_steps[form] = self.attn_steps.get(form, 0) + 1
+                self.hc_defect_max = max(self.hc_defect_max,
+                                         done.get("hc_defect", 0.0))
             self._observe(done)
         if self._flight is None:
             self._hold.yield_full_mesh()
@@ -466,6 +485,9 @@ class LmEngine:
                   "row_pos": plan["row_pos"][plan["row_active"]].tolist(),
                   "context": p0 if pre is not None else None,
                   "attn_chunk_form": self.attn_forms[bucket],
+                  "attn_rows_form": self.attn_rows_form,
+                  "rows_context": int(plan["row_pos"][
+                      plan["row_active"]].sum() + len(deco)),
                   "chunk_tag": pre.tag if pre is not None else None,
                   "emitted": [req.tag for req, _ in emitted],
                   "pages_in_use": self._cache.in_use(),
@@ -505,6 +527,8 @@ class LmEngine:
         record["expert_load"] = ints["expert_load"].tolist()
         if "keys" in ints:
             record["sparse_keys"] = ints["keys"].tolist()
+        elif "hc_defect" in ints:
+            record["hc_defect"] = ints["hc_defect"]
         else:
             record["window_pages"] = ints["pages"].tolist()
         with trace.span("lm.step.deliver"):
@@ -579,9 +603,10 @@ def default_geometry(cfg) -> Geometry:
     """``Geometry``'s defaults (32 rows, chunks of 2048, pages of 256,
     a context cap of 40,960) with the pools sized so that every row can
     hold the context cap, where that stays under ``POOL_BYTES`` a class;
-    a model whose every layer is of the full class (13 KB a position
-    over six layers) gets the pages that fit, and its requests wait for
-    pages with rows to spare. No window layers, no window pool."""
+    a model whose every layer is of the full class (``KeyeVL2``: 13 KB a
+    position over six layers; ``xing4_0``'s latents: 6.9 KB) gets the
+    pages that fit, and its requests wait for pages with rows to spare.
+    No window layers, no window pool."""
     base = Geometry()
     window_b, full_b = cfg.position_bytes()
 

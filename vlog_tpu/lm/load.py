@@ -1,6 +1,6 @@
 """A transcript model on disk: ``config.json`` (the published keys of a
-family ``LmConfig.from_hf`` builds: ``afmoe``, or ``KeyeVL2``'s language
-model; ``model_type`` says which), the weights as safetensors (one ``model.safetensors``, or the
+family ``LmConfig.from_hf`` builds: ``afmoe``, ``KeyeVL2``'s language
+model, or ``xing4_0``; ``model_type`` says which), the weights as safetensors (one ``model.safetensors``, or the
 shards that ``model.safetensors.index.json`` names; the published names,
 torch layouts) and ``tokenizer.json``. Nothing is fetched: the operator
 points ``VLOG_DIGEST_DIR`` at a local directory, as ``VLOG_WHISPER_DIR``.
@@ -16,13 +16,22 @@ except ``bias`` (float32). A ``KeyeVL2`` layer has ``n1`` ``n2``, ``wq``
 ``wk`` ``wv`` ``wo``, ``qn`` ``kn``, the indexer's ``iq`` (H, heads x
 dim), ``ik`` (H, dim), ``ikn`` ``ikb`` (its key's LayerNorm) and ``iw``
 (H, heads), then ``router`` and the three expert stacks: no gate, no
-bias, no dense layer, no shared expert.
+bias, no dense layer, no shared expert. A ``xing4_0`` layer has ``n1``
+``n2``, the latent attention's ``wqa`` (H, q rank), ``qan``, ``wqb`` (q
+rank, heads x (nope + rope)), ``wkva`` (H, kv rank + rope), ``kvn``,
+``wkvb`` (kv rank, heads x (nope + v)), ``wo``, one hyper-connection a
+sublayer, ``hca_*`` (attention) and ``hcm_*`` (MLP): ``_w`` (streams x H,
+2 streams + streams^2) bfloat16, ``_b`` (the same width) and ``_a`` (3,)
+float32, then the dense or the expert leaves of ``afmoe``.
 
-No published checkpoint's index has been met for either family (this
+No published checkpoint's index has been met for any family (this
 machine has no network): the names below are the published modelling
-code's for ``afmoe`` and, for ``KeyeVL2``, those of the family its
-config descends from with the indexer's as the published sparse
-attention names them; a checkpoint that names a tensor otherwise is
+code's for ``afmoe``; for ``KeyeVL2``, those of the family its config
+descends from with the indexer's as the published sparse attention
+names them; for ``xing4_0``, those of the latent-attention family its
+config descends from, and names of this repository's choosing for the
+hyper-connections' tensors (``self_attn_hc.*``, ``mlp_hc.*``), which no
+catalog row names; a checkpoint that names a tensor otherwise is
 refused by that name (``LmLoadError``), never half loaded.
 """
 
@@ -35,7 +44,7 @@ from typing import Any
 
 import jax.numpy as jnp
 
-from vlog_tpu.lm.model import BF16, F32, LmConfig
+from vlog_tpu.lm.model import BF16, F32, XING, LmConfig
 
 
 class LmLoadError(RuntimeError):
@@ -66,9 +75,28 @@ class LmAssets:
 
 def layer_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
     """``(our key, shape, kind)`` of one layer's leaves; ``kind`` is
-    ``normal``, ``ones``, ``zeros`` or ``bias``."""
+    ``normal``, ``ones``, ``zeros``, ``bias`` (float32, as every kind
+    that begins so: ``bias_zeros``, ``bias_ones``) or ``hc`` (a
+    hyper-connection's projection)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q, kv = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
+    if cfg.latent_width:
+        nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        maps = 2 * cfg.hc_mult + cfg.hc_mult ** 2
+        hc = [(f"hc{s}_{part}", shape, kind) for s in "am"
+              for part, shape, kind in (
+                  ("w", (cfg.hc_mult * h, maps), "hc"),
+                  ("b", (maps,), "bias_zeros"), ("a", (3,), "bias_ones"))]
+        return [("n1", (h,), "ones"), ("n2", (h,), "ones"),
+                ("wqa", (h, cfg.q_lora_rank), "normal"),
+                ("qan", (cfg.q_lora_rank,), "ones"),
+                ("wqb", (cfg.q_lora_rank, q), "normal"),
+                ("wkva", (h, cfg.latent_width), "normal"),
+                ("kvn", (rank,), "ones"),
+                ("wkvb", (rank, nh * (cfg.qk_nope_head_dim
+                                      + cfg.v_head_dim)), "normal"),
+                ("wo", (nh * cfg.v_head_dim, h), "normal"),
+                *hc, *_mlp_leaves(cfg, li)]
     if cfg.index_topk:
         e, i = cfg.num_experts, cfg.moe_intermediate_size
         ih, idim = cfg.index_heads, cfg.index_head_dim
@@ -86,19 +114,25 @@ def layer_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
            ("wk", (h, kv), "normal"), ("wv", (h, kv), "normal"),
            ("wg", (h, q), "normal"), ("wo", (q, h), "normal"),
            ("qn", (hd,), "ones"), ("kn", (hd,), "ones")]
+    return out + _mlp_leaves(cfg, li)
+
+
+def _mlp_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
+    """A layer's dense SwiGLU, or its router, selection bias, routed
+    experts and shared expert (``afmoe`` and ``xing4_0``)."""
+    h = cfg.hidden_size
     if li < cfg.num_dense_layers:
         i = cfg.intermediate_size
-        out += [("w_gate", (h, i), "normal"), ("w_up", (h, i), "normal"),
+        return [("w_gate", (h, i), "normal"), ("w_up", (h, i), "normal"),
                 ("w_down", (i, h), "normal")]
-    else:
-        e, i = cfg.num_experts, cfg.moe_intermediate_size
-        out += [("router", (h, e), "normal"), ("bias", (e,), "bias"),
-                ("e_gate", (e, h, i), "normal"), ("e_up", (e, h, i), "normal"),
-                ("e_down", (e, i, h), "normal")]
-        if cfg.num_shared_experts:
-            s = i * cfg.num_shared_experts
-            out += [("s_gate", (h, s), "normal"), ("s_up", (h, s), "normal"),
-                    ("s_down", (s, h), "normal")]
+    e, i = cfg.num_experts, cfg.moe_intermediate_size
+    out = [("router", (h, e), "normal"), ("bias", (e,), "bias"),
+           ("e_gate", (e, h, i), "normal"), ("e_up", (e, h, i), "normal"),
+           ("e_down", (e, i, h), "normal")]
+    if cfg.num_shared_experts:
+        s = i * cfg.num_shared_experts
+        out += [("s_gate", (h, s), "normal"), ("s_up", (h, s), "normal"),
+                ("s_down", (s, h), "normal")]
     return out
 
 
@@ -126,13 +160,32 @@ KEYE_NAMES = {
     "ikb": "self_attn.indexer.k_norm.bias",
     "iw": "self_attn.indexer.weights_proj.weight",
     "router": "mlp.gate.weight"}
+XING_NAMES = {
+    "n1": "input_layernorm.weight", "n2": "post_attention_layernorm.weight",
+    "wqa": "self_attn.q_a_proj.weight",
+    "qan": "self_attn.q_a_layernorm.weight",
+    "wqb": "self_attn.q_b_proj.weight",
+    "wkva": "self_attn.kv_a_proj_with_mqa.weight",
+    "kvn": "self_attn.kv_a_layernorm.weight",
+    "wkvb": "self_attn.kv_b_proj.weight", "wo": "self_attn.o_proj.weight",
+    # the hyper-connections: names of this repository's choosing
+    "hca_w": "self_attn_hc.proj.weight", "hca_b": "self_attn_hc.bias",
+    "hca_a": "self_attn_hc.alpha", "hcm_w": "mlp_hc.proj.weight",
+    "hcm_b": "mlp_hc.bias", "hcm_a": "mlp_hc.alpha",
+    "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+    "w_down": "mlp.down_proj.weight", "router": "mlp.gate.weight",
+    "bias": "mlp.gate.e_score_correction_bias",
+    "s_gate": "mlp.shared_experts.gate_proj.weight",
+    "s_up": "mlp.shared_experts.up_proj.weight",
+    "s_down": "mlp.shared_experts.down_proj.weight"}
 EXPERT_NAMES = {"e_gate": "gate_proj", "e_up": "up_proj",
                 "e_down": "down_proj"}
 
 
 def layer_names(cfg: LmConfig) -> dict:
     """Our key -> the family's published name under its layer."""
-    return KEYE_NAMES if cfg.model_type == "KeyeVL2" else HF_NAMES
+    return {"KeyeVL2": KEYE_NAMES, XING: XING_NAMES}.get(cfg.model_type,
+                                                         HF_NAMES)
 
 
 def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
@@ -156,7 +209,8 @@ def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
             else:
                 leaf = get(base + names[name])
                 leaf = leaf.T if leaf.ndim == 2 else leaf
-                lp[name] = leaf.astype(F32 if kind == "bias" else BF16)
+                lp[name] = leaf.astype(F32 if kind.startswith("bias")
+                                       else BF16)
         layers.append(lp)
     return {"embed": get("model.embed_tokens.weight").astype(BF16),
             "head": get("lm_head.weight").T.astype(BF16),

@@ -1,7 +1,7 @@
 """The transcript model: configuration, layer mathematics, step program.
 
-Two published families share the step program's frame, the paged cache
-and the expert layer; ``LmConfig.model_type`` chooses the block.
+Three published families share the step program's frame, the paged
+cache and the expert layer; ``LmConfig.model_type`` chooses the block.
 
 **``afmoe``.** Every layer is
 ``h = h + N2(Attn(N1(h)))`` then ``h = h + N4(Mlp(N3(h)))`` (RMSNorm
@@ -34,9 +34,32 @@ they read) and :func:`gathered_attention` over the chosen keys alone (a
 decoding row: ``index_topk`` keys of K and V in place of its whole
 context).
 
-Precision as stated: weights, K/V and indexer keys bfloat16, products
-accumulate in float32, the residual stream, norms, router, index
-scores, softmax and logits float32.
+**``xing4_0``.** Latent attention (MLA as DeepSeek-V2/V3 publish it)
+under a four-stream residual. ``x`` the sublayer's normed input:
+``c_q = RMSNorm(x Wqa)``, ``q = c_q Wqb`` as heads of ``[nope | rope]``;
+``[c_kv | k_r] = x Wkva``, ``c = RMSNorm(c_kv)``, ``k_rope = rot(k_r)``
+ONE head for all; ``[k_nope_h | v_h] = c Wkvb[h]``; ``s = (q_nope .
+k_nope + q_rope . k_rope) * scale``. The cache holds ``(c, k_rope)``,
+ONE array a layer of ``kv_lora_rank + qk_rope_head_dim`` numbers a
+position on the full class's page numbers. Two forms of that sum,
+chosen by shape as :func:`attention_form` chooses: the ROWS (one query a
+sequence) run it **absorbed** (``q_lat = q_nope Wuk^T``, scores against
+the latent itself, ``o = (softmax(s) c) Wuv``: the 32 heads read ONE key
+head of 576 whose first 512 lanes are also its value, so a latent is
+read once); a prefill CHUNK runs it **expanded** (a block of the
+context's latents through ``Wkvb`` once for all the chunk's queries,
+then heads of 192 / 128). Rotary is YaRN (:func:`yarn_inv_freq`). The
+residual state of a token is ``X`` (streams, hidden), the embedding
+copied ``hc_mult`` times; around EACH sublayer ``F`` the
+manifold-constrained hyper-connection (:func:`hc_maps`): ``u = H_pre X``,
+``X' = H_res X + H_post^T F(u)`` with ``H_res`` made doubly stochastic by
+``hc_sinkhorn_iters`` Sinkhorn iterations; the streams are summed before
+the final norm. Experts are ``afmoe``'s (sigmoid, a selection bias, a
+shared expert). The family's next-token-prediction module is not built.
+
+Precision as stated: weights, K/V, indexer keys and latents bfloat16,
+products accumulate in float32, the residual stream(s), norms, router,
+index scores, hyper-connection mappings, softmax and logits float32.
 
 **The step program** (:func:`build_step`) serves one engine step: at
 most one prefill chunk of ONE request (``chunk`` tokens, a static
@@ -75,13 +98,14 @@ F32 = jnp.float32
 BF16 = jnp.bfloat16
 SLIDING = "sliding_attention"
 FULL = "full_attention"
+XING = "xing4_0"
 MASKED = -1e30
 
 
 @dataclass(frozen=True)
 class LmConfig:
     """The model's shape, from a published ``config.json`` (HF keys).
-    What only one family has is zero, empty or ``False`` for the other."""
+    What only one family has is zero, empty or ``False`` for the others."""
 
     hidden_size: int
     num_attention_heads: int
@@ -106,10 +130,23 @@ class LmConfig:
     index_heads: int = 0                # the sparse attention's indexer
     index_head_dim: int = 0
     index_topk: int = 0                 # keys a query attends (0: all)
+    q_lora_rank: int = 0                # latent attention (0: K and V)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: factor, original positions, beta_fast, beta_slow, mscale,
+    # mscale_all_dim (empty: plain rotary)
+    rope_yarn: tuple[float, ...] = ()
+    hc_mult: int = 0                    # residual streams (0: one, plain)
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: tuple[float, float] = (0.0, 0.0)
 
     @classmethod
     def from_hf(cls, d: dict) -> "LmConfig":
-        families = {"afmoe": cls._from_afmoe, "KeyeVL2": cls._from_keye}
+        families = {"afmoe": cls._from_afmoe, "KeyeVL2": cls._from_keye,
+                    XING: cls._from_xing}
         family = d.get("model_type", "afmoe")
         if family not in families:
             raise ValueError(f"model_type {family!r} is not built (built: "
@@ -187,9 +224,73 @@ class LmConfig:
             index_head_dim=int(sa["indexer_head_dim"]),
             index_topk=int(sa["topk"]))
 
+    @classmethod
+    def _from_xing(cls, d: dict) -> "LmConfig":
+        """``xing4_0``: latent attention under hyper-connections, two
+        dense layers then sigmoid-routed experts beside a shared one.
+        ``num_nextn_predict_layers`` is read past: the module that
+        drafts the next token sits after the last layer and the main
+        model serves without it."""
+        scaling = d.get("rope_scaling") or {}
+        refused = {
+            "n_group != 1": int(d.get("n_group", 1)) != 1,
+            "topk_group != 1": int(d.get("topk_group", 1)) != 1,
+            "scoring_func other than sigmoid":
+                d.get("scoring_func", "sigmoid") != "sigmoid",
+            "topk_method other than noaux_tc":
+                d.get("topk_method", "noaux_tc") != "noaux_tc",
+            "moe_layer_freq != 1": int(d.get("moe_layer_freq", 1)) != 1,
+            "attention_bias": bool(d.get("attention_bias")),
+            "tie_word_embeddings": bool(d.get("tie_word_embeddings")),
+            "rope_scaling.type other than yarn":
+                scaling.get("type", scaling.get("rope_type")) != "yarn",
+            "hc_mult < 2": int(d.get("hc_mult", 0)) < 2}
+        bad = [name for name, hit in refused.items() if hit]
+        if bad:
+            raise ValueError(f"{XING}: not built: {', '.join(bad)}")
+        n = int(d["num_hidden_layers"])
+        nope, rope_dim = int(d["qk_nope_head_dim"]), int(d["qk_rope_head_dim"])
+        return cls(
+            hidden_size=int(d["hidden_size"]),
+            num_attention_heads=int(d["num_attention_heads"]),
+            # what the cache holds: one latent key head for every query
+            num_key_value_heads=1, head_dim=nope + rope_dim,
+            layer_types=(FULL,) * n,
+            num_dense_layers=min(int(d["first_k_dense_replace"]), n),
+            intermediate_size=int(d["intermediate_size"]),
+            moe_intermediate_size=int(d["moe_intermediate_size"]),
+            num_experts=int(d["n_routed_experts"]),
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            num_shared_experts=int(d.get("n_shared_experts") or 0),
+            route_norm=bool(d.get("norm_topk_prob", True)),
+            route_scale=float(d.get("routed_scaling_factor", 1.0)),
+            sliding_window=0, vocab_size=int(d["vocab_size"]),
+            rms_norm_eps=float(d["rms_norm_eps"]),
+            rope_theta=float(d["rope_theta"]), mup_enabled=False,
+            model_type=XING, q_lora_rank=int(d["q_lora_rank"]),
+            kv_lora_rank=int(d["kv_lora_rank"]), qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope_dim, v_head_dim=int(d["v_head_dim"]),
+            rope_yarn=(float(scaling["factor"]),
+                       float(scaling["original_max_position_embeddings"]),
+                       float(scaling.get("beta_fast", 32)),
+                       float(scaling.get("beta_slow", 1)),
+                       float(scaling.get("mscale", 1)),
+                       float(scaling.get("mscale_all_dim", 0))),
+            hc_mult=int(d["hc_mult"]),
+            hc_sinkhorn_iters=int(d["hc_sinkhorn_iters"]),
+            hc_eps=float(d["hc_eps"]),
+            hc_clamp=(float(d["mhc_h_res_clamp_min"]),
+                      float(d["mhc_h_res_clamp_max"])))
+
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a position's cached latent holds (0: K and V)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
 
     @property
     def window_layers(self) -> int:
@@ -201,7 +302,12 @@ class LmConfig:
 
     def position_bytes(self) -> tuple[int, int]:
         """Cache bytes one position costs over the layers held here, by
-        class ``(window, full)``: K and V, and the indexer's key."""
+        class ``(window, full)``: K and V in bfloat16 (``afmoe``), with
+        the indexer's key beside them (``KeyeVL2``), or the one latent
+        ``(c, k_rope)`` in their place (``xing4_0``: 1,152 B a layer
+        against the others' 2,048)."""
+        if self.latent_width:
+            return (0, self.full_layers * self.latent_width * 2)
         kv = 2 * self.num_key_value_heads * self.head_dim * 2
         index = self.index_head_dim * 2 if self.index_topk else 0
         return (self.window_layers * kv, self.full_layers * (kv + index))
@@ -269,16 +375,126 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
         * w.astype(F32)
 
 
-def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, pos: jax.Array, theta: float,
+         inv: jax.Array | None = None) -> jax.Array:
     """Rotate-half rotary embedding over the whole head: ``x`` (T, heads,
-    hd) float32, ``pos`` (T,) absolute positions."""
+    hd) float32, ``pos`` (T,) absolute positions; ``inv`` (hd / 2,) the
+    frequencies where they are not ``theta``'s plain ones."""
     hd = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, hd, 2, dtype=F32) / hd)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, hd, 2, dtype=F32) / hd)
     ang = pos.astype(F32)[:, None] * inv[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) \
         * jnp.sin(ang)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: float,
+                  beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN's ``dim / 2`` frequencies: the plain ones ``f`` where a
+    dimension turns more than ``beta_fast`` times over the ``original``
+    positions, ``f / factor`` where it turns fewer than ``beta_slow``
+    times, a linear ramp between (the published
+    ``yarn_find_correction_range`` / ``yarn_linear_ramp_mask``)."""
+    def turns_at(beta: float) -> float:
+        return dim * math.log(original / (beta * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    f = theta ** (-jnp.arange(0, dim, 2, dtype=F32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=F32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    m = 1.0 - ramp
+    return f / factor * (1.0 - m) + f * m
+
+
+def yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def latent_scale(cfg: LmConfig) -> float:
+    """The softmax scale of latent attention: ``head_dim ** -0.5`` times
+    YaRN's ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor, _orig, _fast, _slow, _m, m_all = cfg.rope_yarn
+    s = yarn_mscale(factor, m_all) if m_all else 1.0
+    return cfg.head_dim ** -0.5 * s * s
+
+
+def latent_rope(cfg: LmConfig, x: jax.Array, pos: jax.Array) -> jax.Array:
+    """YaRN rotary over the rope dims of a latent-attention head."""
+    factor, orig, fast, slow, m, m_all = cfg.rope_yarn
+    inv = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, factor, orig,
+                        fast, slow)
+    out = rope(x, pos, cfg.rope_theta, inv)
+    # cos and sin carry mscale / mscale_all_dim (1 where they are equal)
+    return out * (yarn_mscale(factor, m) / yarn_mscale(factor, m_all)) \
+        if m_all and m != m_all else out
+
+
+def sinkhorn(logits: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``exp(logits)`` (n, n, T) made doubly stochastic: ``iters`` times
+    each row over its sum, then each column over its sum. Every
+    iteration is run, converged or not. The leading two axes are the
+    matrix and the tokens lie in the lanes; the sums over an axis of
+    ``n`` are written out as adds of slices, so an iteration is
+    elementwise and one pass (a reduction would end the fusion)."""
+    n = logits.shape[0]
+
+    def body(_, m):
+        m = m / (sum(m[:, j] for j in range(n))[:, None] + eps)
+        return m / (sum(m[i] for i in range(n))[None] + eps)
+
+    return lax.fori_loop(0, iters, body, jnp.exp(logits))
+
+
+def hc_maps(cfg: LmConfig, xs: jax.Array, proj: jax.Array, bias: jax.Array,
+            alpha: jax.Array):
+    """The three mappings of one hyper-connection from the residual
+    state ``xs`` (T, n, H) float32: ``z = vec(X) / sqrt(mean(vec(X)^2) +
+    hc_eps)``, ``[Hp | Ho | Hr] = alpha * (z proj) + bias`` (``proj`` (n H,
+    2n + n^2), ``Hr`` row-major), ``H_pre = sigmoid(Hp)`` (n, T), ``H_post
+    = 2 sigmoid(Ho)`` (n, T), ``H_res = sinkhorn(clamp(Hr))`` (n, n, T).
+    All float32; also returns the largest distance of a row or column
+    sum of each token's ``H_res`` from 1 (T,)."""
+    t, n, h = xs.shape
+    flat = xs.reshape(t, n * h)
+    # the norm is one number a token: applied to the 24 products, not to
+    # the 14,336 inputs
+    z = jnp.dot(flat, proj.astype(F32), precision=lax.Precision.HIGHEST,
+                preferred_element_type=F32) * lax.rsqrt(
+                    jnp.mean(flat * flat, axis=-1, keepdims=True)
+                    + cfg.hc_eps)
+    z = z.T                                 # (24, T): tokens in the lanes
+    pre = jax.nn.sigmoid(alpha[0] * z[:n] + bias[:n, None])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * z[n:2 * n] + bias[n:2 * n, None])
+    logits = (alpha[2] * z[2 * n:] + bias[2 * n:, None]).reshape(n, n, t)
+    res = sinkhorn(jnp.clip(logits, *cfg.hc_clamp), cfg.hc_sinkhorn_iters,
+                   cfg.hc_eps)
+    defect = jnp.maximum(jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0), axis=0),
+                         jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0), axis=0))
+    return pre, post, res, defect
+
+
+def hyper_connect(cfg: LmConfig, xs: jax.Array, hc: tuple, fn):
+    """One sublayer ``fn`` under its hyper-connection: ``xs`` (T, n, H)
+    -> ``(H_res X + H_post^T fn(H_pre X), defect (T,))``; ``hc`` the
+    connection's ``(proj, bias, alpha)``."""
+    with jax.named_scope("lm.hc.map"):
+        pre, post, res, defect = hc_maps(cfg, xs, *hc)
+    # sums over the n streams written out: one elementwise pass each,
+    # where a contraction over 4 would be handed to the matrix unit
+    n = xs.shape[1]
+    streams = [xs[:, j, :] for j in range(n)]
+    with jax.named_scope("lm.hc.pre"):
+        u = sum(pre[j][:, None] * streams[j] for j in range(n))
+    y = fn(u)
+    with jax.named_scope("lm.hc.post"):
+        xs = jnp.stack([
+            sum(res[i, j][:, None] * streams[j] for j in range(n))
+            + post[i][:, None] * y for i in range(n)], axis=1)
+    return xs, defect
 
 
 def mm(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -304,7 +520,9 @@ def attention_form(seqs: int, nq: int, nkv: int, hd: int, page: int) -> str:
 def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
                     pool_k: jax.Array, pool_v: jax.Array, table: jax.Array,
                     base: jax.Array, *, window: int | None, page: int,
-                    block_pages: int, chosen: jax.Array | None = None
+                    block_pages: int, chosen: jax.Array | None = None,
+                    expand=None, value_width: int | None = None,
+                    keys_minor: bool = False
                     ) -> tuple[jax.Array, jax.Array]:
     """Online-softmax attention of a batch of sequences over their pages.
 
@@ -316,6 +534,20 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     position from ``base`` on; whole blocks wide). Returns
     ``(out (S, Q, nkv, g, hd) float32, pages visited (S,))``.
 
+    Where the pool holds something other than K beside V (a latent),
+    ``pool_v`` is ``None`` and ``expand`` turns a gathered block of
+    ``pool_k`` ``(S, block_pages, ...)`` into that block's ``(k (S, keys, nkv,
+    hd), v (S, keys, nkv, value_width))``: the value may be a slice of
+    the key (read once) or both a product of the block; ``out`` is then
+    ``value_width`` wide. With ``keys_minor`` the pool holds ONE K/V
+    head with the positions in the lanes, ``(pages, hd, page)``, and
+    ``expand`` returns ``(k (S, block_pages, hd, page), v (S,
+    block_pages, value_width, page))``: a gathered block is then used
+    as it lies, page by page (a TPU lays a ``(pages, page, 576)`` array
+    out with the 256 in the lanes whatever the program says, and every
+    use of it the other way round relays 880 MB out: 10 ms a layer, my
+    chip run, PR 35). Such a call runs the loop.
+
     Two forms of the one algorithm, chosen by :func:`attention_form`
     from the call's shapes and the backend: rows of one query each run
     the loop below; a prefill chunk (one sequence of ``Q`` consecutive
@@ -326,7 +558,8 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     width = table.shape[1]
     keys = block_pages * page
     n_pages = jnp.where(last_pos >= 0, (last_pos - base) // page + 1, 0)
-    if attention_form(s, nq, nkv, hd, page) == "kernel":
+    vd = hd if expand is None else value_width
+    if expand is None and attention_form(s, nq, nkv, hd, page) == "kernel":
         out = attention_kernel.chunk_attention(
             q[0], qpos[0, 0], n_pages[0], pool_k, pool_v, table[0], base[0],
             window=window, page=page, block_pages=block_pages,
@@ -341,8 +574,11 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
         live = slots[None, :] < n_pages[:, None]              # (S, bp)
         phys = jnp.where(live, jnp.take(table, jnp.minimum(slots, width - 1),
                                         axis=1), 0)
-        k = pool_k[phys].reshape(s, keys, nkv, hd)
-        v = pool_v[phys].reshape(s, keys, nkv, hd)
+        if expand is None:
+            k = pool_k[phys].reshape(s, keys, nkv, hd)
+            v = pool_v[phys].reshape(s, keys, nkv, hd)
+        else:                           # the block as the pool holds it
+            k, v = expand(pool_k[phys])
         kpos = base[:, None] + i * keys + lane[None, :]       # (S, K)
         ok = kpos[:, None, :] <= qpos[:, :, None]             # (S, Q, K)
         if window is not None:
@@ -350,22 +586,34 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
         ok &= jnp.repeat(live, page, axis=1)[:, None, :]
         if chosen is not None:
             ok &= lax.dynamic_slice_in_dim(chosen, i * keys, keys, axis=2)
-        sc = jnp.einsum("sqngd,sknd->sngqk", q, k,
-                        preferred_element_type=F32)
+        if keys_minor:
+            # one K/V head: k (S, bp, hd, page) enters the product as the
+            # gather left it
+            sc = jnp.einsum("sqgd,sbdk->sgqbk", q[:, :, 0], k,
+                            preferred_element_type=F32).reshape(
+                                s, 1, g, nq, keys)
+        else:
+            sc = jnp.einsum("sqngd,sknd->sngqk", q, k,
+                            preferred_element_type=F32)
         sc = jnp.where(ok[:, None, None, :, :], sc, MASKED)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
         p = jnp.where(ok[:, None, None, :, :],
                       jnp.exp(sc - m_new[..., None]), 0.0)
         scale = jnp.exp(m - m_new)
         l = l * scale + jnp.sum(p, axis=-1)
-        acc = acc * scale[..., None] + jnp.einsum(
-            "sngqk,sknd->sngqd", p.astype(BF16), v,
-            preferred_element_type=F32)
-        return m_new, l, acc
+        if keys_minor:
+            pv = jnp.einsum(
+                "sgqbk,sbdk->sgqd", p[:, 0].astype(BF16).reshape(
+                    s, g, nq, block_pages, page), v,
+                preferred_element_type=F32)[:, None]
+        else:
+            pv = jnp.einsum("sngqk,sknd->sngqd", p.astype(BF16), v,
+                            preferred_element_type=F32)
+        return m_new, l, acc * scale[..., None] + pv
 
     m0 = jnp.full((s, nkv, g, nq), MASKED, F32)
     l0 = jnp.zeros((s, nkv, g, nq), F32)
-    a0 = jnp.zeros((s, nkv, g, nq, hd), F32)
+    a0 = jnp.zeros((s, nkv, g, nq, vd), F32)
     _, l, acc = lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4), n_pages
@@ -524,13 +772,20 @@ def empty_cache(cfg: LmConfig, geo: Geometry) -> dict:
     """Per layer one K and one V pool ``(pages, page, nkv, hd)`` and,
     where the model has an indexer, one pool of its keys ``(pages, page,
     index_head_dim)``; the layers of a class share page numbers
-    (``cache.py``), and a layer's three pools share them too."""
+    (``cache.py``), and a layer's three pools share them too. A model
+    with latent attention has ONE pool a layer in their place, ``lat``
+    ``(pages, latent_width, page)`` (positions in the lanes: 576 does
+    not fill them and 256 does, so this is how the chip would lay the
+    array out anyway), on the full class's page numbers."""
     sizes = [geo.window_pages if k == SLIDING else geo.full_pages
              for k in cfg.layer_types]
 
     def pools(*tail):
         return [jnp.zeros((n, geo.page) + tail, BF16) for n in sizes]
 
+    if cfg.latent_width:
+        return {"lat": [jnp.zeros((n, cfg.latent_width, geo.page), BF16)
+                        for n in sizes]}
     out = {"k": pools(cfg.num_key_value_heads, cfg.head_dim),
            "v": pools(cfg.num_key_value_heads, cfg.head_dim)}
     if cfg.index_topk:
@@ -541,12 +796,20 @@ def empty_cache(cfg: LmConfig, geo: Geometry) -> dict:
 def unpack_ints(cfg: LmConfig, geo: Geometry, ints) -> dict:
     """A step's ``out["ints"]`` (on the host) by name. The last two are
     ``pages`` (a model with window layers) or ``keys`` (one with an
-    indexer): what one layer read over what a causal-dense one would."""
+    indexer): what one layer read over what a causal-dense one would. A
+    model with hyper-connections ends on ONE, ``hc_defect``: the float32
+    bits of the largest distance of a row or column sum of any ``H_res``
+    of the step from 1."""
     r = geo.rows + 1
     n_moe = cfg.num_layers - cfg.num_dense_layers
-    return {"tokens": ints[:r],
-            "expert_load": ints[r:r + 3 * n_moe].reshape(n_moe, 3),
-            "keys" if cfg.index_topk else "pages": ints[r + 3 * n_moe:]}
+    out = {"tokens": ints[:r],
+           "expert_load": ints[r:r + 3 * n_moe].reshape(n_moe, 3)}
+    tail = ints[r + 3 * n_moe:]
+    if cfg.hc_mult:
+        out["hc_defect"] = float(tail.view("float32")[0])
+    else:
+        out["keys" if cfg.index_topk else "pages"] = tail
+    return out
 
 
 def plan_shapes(cfg: LmConfig, geo: Geometry, chunk: int) -> dict:
@@ -610,6 +873,34 @@ def _write_pages(st: _Step, geo: Geometry, pools: list, new: list,
         row_tab, slot[:, None], axis=1)[:, 0], 0)
     return [pool.at[phys, st.row_pos % page].set(x[chunk:])
             for pool, x in zip(pools, new)]
+
+
+def _write_latents(st: _Step, geo: Geometry, pool: jax.Array,
+                   new: jax.Array, ctab, row_tab) -> jax.Array:
+    """:func:`_write_pages` for a pool with the positions in the lanes,
+    ``(pages, width, page)``: ``new`` (T, width)."""
+    page, chunk = geo.page, st.chunk
+    if chunk:
+        first = st.p0 // page
+        for j in range(chunk // page):
+            slot = jnp.minimum(first + j, ctab.shape[0] - 1)
+            phys = jnp.where(j * page < st.n, ctab[slot], 0)
+            pool = lax.dynamic_update_slice(
+                pool, new[j * page:(j + 1) * page].T[None], (phys, 0, 0))
+    slot = jnp.clip(st.row_pos // page, 0, row_tab.shape[1] - 1)
+    phys = jnp.where(st.row_on, jnp.take_along_axis(
+        row_tab, slot[:, None], axis=1)[:, 0], 0)
+    rows = new[chunk:].T[None]                              # (1, width, R)
+
+    def one(r, pool):
+        # a column a row, in place: a scatter would ask for the width
+        # in the lanes and relay the whole pool out and back (880 MB a
+        # layer each way in the compiled step)
+        return lax.dynamic_update_slice(
+            pool, lax.dynamic_slice_in_dim(rows, r, 1, axis=2),
+            (phys[r], 0, st.row_pos[r] % page))
+
+    return lax.fori_loop(0, rows.shape[2], one, pool)
 
 
 def _experts(cfg: LmConfig, lp: dict, x: jax.Array, valid: jax.Array):
@@ -761,6 +1052,169 @@ def _sparse_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int, lp: dict,
     return h + y, load, keys
 
 
+def latent_rows_form(nh: int, latent: int, page: int) -> str:
+    """``"kernel"`` or ``"loop"``: how :func:`absorbed_attention` reads
+    the rows' pages (shapes and backend, as :func:`attention_form`)."""
+    kernel = attention_kernel.latent_rows_supported(nh, latent, page)
+    return "kernel" if kernel and jax.default_backend() == "tpu" else "loop"
+
+
+def absorbed_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
+                       pool: jax.Array, table: jax.Array, w_kvb: jax.Array,
+                       *, nope: int, scale: float, page: int,
+                       block_pages: int) -> jax.Array:
+    """Latent attention of ONE query a sequence in the absorbed form:
+    ``q`` (S, heads, nope + rope) float32 (rope part rotated), ``qpos``
+    (S,), ``last_pos`` (S,), ``pool`` (pages, rank + rope, page) bfloat16,
+    ``table`` (S, W) from position 0 on, ``w_kvb`` (rank, heads, nope +
+    v). ``q_lat = q_nope Wuk^T``, scores against the latent itself, whose
+    first ``rank`` lanes are also the value (read once, for every head
+    and both products), ``o = (softmax(s) c) Wuv``. On a TPU the pages
+    are read by one kernel whose work is the sum of the rows' contexts
+    (``attention_kernel.py::latent_rows_attention``); elsewhere by the
+    loop of :func:`paged_attention`, every row as long as the longest.
+    Returns (S, heads, v) float32."""
+    rank = w_kvb.shape[0]
+    q_lat = jnp.einsum("thd,chd->thc", q[..., :nope].astype(BF16),
+                       w_kvb[..., :nope], preferred_element_type=F32)
+    qa = (jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+          * scale).astype(BF16)
+    if latent_rows_form(q.shape[1], pool.shape[1], page) == "kernel":
+        o_lat = attention_kernel.latent_rows_attention(
+            qa, last_pos, pool, table, page=page, block_pages=block_pages)
+    else:
+        # the value IS the key's array: the product runs over all of the
+        # latent's lanes and the rope's 64 are dropped after, which costs
+        # an eighth more operations of a pass that waits on memory and
+        # saves a copy of every block's first 512
+        o_lat, _ = paged_attention(
+            qa[:, None, None], qpos[:, None], last_pos, pool, None, table,
+            jnp.zeros_like(qpos), window=None, page=page,
+            block_pages=block_pages, value_width=pool.shape[1],
+            keys_minor=True, expand=lambda blk: (blk, blk))
+        o_lat = o_lat[:, 0, 0]
+    return jnp.einsum("thc,chd->thd", o_lat[..., :rank].astype(BF16),
+                      w_kvb[..., nope:], preferred_element_type=F32)
+
+
+def latent_chunk_form(nq: int, nope: int, rope_dim: int, rank: int, vd: int,
+                      page: int) -> str:
+    """``"latent_expanded_kernel"`` or ``"latent_expanded_loop"``: the
+    form :func:`expanded_attention` takes for a chunk of ``nq`` queries
+    (shapes and backend, as :func:`attention_form`; no knob)."""
+    kernel = attention_kernel.latent_supported(nq, nope, rope_dim, rank, vd,
+                                               page)
+    return "latent_expanded_kernel" \
+        if kernel and jax.default_backend() == "tpu" \
+        else "latent_expanded_loop"
+
+
+def expanded_attention(q: jax.Array, p0: jax.Array, last_pos: jax.Array,
+                       pool: jax.Array, table: jax.Array, w_kvb: jax.Array,
+                       *, nope: int, scale: float, page: int,
+                       block_pages: int) -> jax.Array:
+    """Latent attention of a prefill chunk in the expanded form: ``q``
+    (Q, heads, nope + rope) float32 at positions ``p0 + arange(Q)`` of
+    one sequence, ``pool`` (pages, rank + rope, page), ``table`` (W,). A
+    block of the context's latents goes through ``Wkvb`` ONCE for all of
+    the chunk's queries and is attended as ``heads`` K/V heads of ``nope
+    + rope`` / ``v``, the one rope key copied to each. Two forms (:func:`latent_chunk_form`): on a TPU one
+    kernel that expands a block for a head in VMEM
+    (``attention_kernel.py::latent_chunk_attention``); elsewhere the
+    loop below (scope ``lm.attn.latent.expand`` for its expansion).
+    Returns (Q, heads, v) float32."""
+    rank, nh, wide = w_kvb.shape
+    if latent_chunk_form(q.shape[0], nope, q.shape[-1] - nope, rank,
+                         wide - nope, page) == "latent_expanded_kernel":
+        return attention_kernel.latent_chunk_attention(
+            (q * scale).astype(BF16), p0,
+            jnp.where(last_pos >= 0, last_pos // page + 1, 0), pool, table,
+            w_kvb, nope=nope, page=page, block_pages=block_pages)
+
+    def expand(blk):
+        with jax.named_scope("lm.attn.latent.expand"):
+            lat = blk[0].transpose(0, 2, 1).reshape(
+                -1, blk.shape[2])                           # (keys, 576)
+            full = jnp.einsum("kc,chd->khd", lat[:, :rank], w_kvb,
+                              preferred_element_type=F32).astype(BF16)
+            k = jnp.concatenate([full[..., :nope], jnp.broadcast_to(
+                lat[:, None, rank:], (lat.shape[0], nh, lat.shape[1] - rank)
+            )], axis=-1)
+            return k[None], full[None, ..., nope:]
+
+    out, _ = paged_attention(
+        (q * scale).astype(BF16)[None, :, :, None],
+        (p0 + jnp.arange(q.shape[0], dtype=jnp.int32))[None], last_pos[None],
+        pool, None, table[None], jnp.zeros((1,), jnp.int32),
+        window=None, page=page, block_pages=block_pages,
+        value_width=wide - nope, expand=expand)
+    return out[0, :, :, 0]
+
+
+def _latent_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int, lp: dict,
+                  xs: jax.Array, kv: dict):
+    """One ``xing4_0`` layer on the residual state ``xs`` (T, streams,
+    H); returns ``(xs, expert load or None, the largest defect of its
+    two ``H_res`` over the valid tokens (1,))``."""
+    chunk, plan = st.chunk, st.plan
+    nh, eps = cfg.num_attention_heads, cfg.rms_norm_eps
+    rank, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    how = dict(nope=nope, scale=latent_scale(cfg), page=geo.page,
+               block_pages=geo.kv_block_pages)
+    w_kvb = lp["wkvb"].reshape(rank, nh, nope + vd)
+
+    def attention(u):
+        x = rms_norm(u, lp["n1"], eps)
+        with jax.named_scope("lm.attn.latent.project"):
+            q = mm(rms_norm(mm(x, lp["wqa"]), lp["qan"], eps),
+                   lp["wqb"]).reshape(-1, nh, cfg.head_dim)
+            q = jnp.concatenate(
+                [q[..., :nope], latent_rope(cfg, q[..., nope:], st.pos)],
+                axis=-1)
+            kva = mm(x, lp["wkva"])
+            latent = jnp.concatenate(
+                [rms_norm(kva[:, :rank], lp["kvn"], eps),
+                 latent_rope(cfg, kva[:, None, rank:], st.pos)[:, 0]],
+                axis=-1).astype(BF16)
+        row_tab = plan["row_ftab"]
+        ctab = plan["chunk_ftab"] if chunk else None
+        with jax.named_scope("lm.cache.write"):
+            pool = _write_latents(st, geo, kv["lat"][li], latent, ctab,
+                                  row_tab)
+        kv["lat"][li] = pool
+        with jax.named_scope("lm.attn.latent.rows"):
+            o = absorbed_attention(q[chunk:], st.row_pos, st.row_last, pool,
+                                   row_tab, w_kvb, **how)
+        if chunk:
+            with jax.named_scope("lm.attn.latent.chunk"):
+                o = jnp.concatenate([expanded_attention(
+                    q[:chunk], st.p0, st.chunk_last, pool, ctab, w_kvb,
+                    **how), o])
+        with jax.named_scope("lm.attn.latent.project"):
+            return mm(o.reshape(-1, nh * vd), lp["wo"])
+
+    load = []
+
+    def mlp(u):
+        x = rms_norm(u, lp["n2"], eps)
+        if li < cfg.num_dense_layers:
+            with jax.named_scope("lm.mlp.dense"):
+                return moe.swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+        y, counted = _experts(cfg, lp, x, st.valid)
+        load.append(counted)
+        if cfg.num_shared_experts:
+            with jax.named_scope("lm.moe.shared"):
+                y = y + moe.swiglu(x, lp["s_gate"], lp["s_up"], lp["s_down"])
+        return y
+
+    xs, d_attn = hyper_connect(
+        cfg, xs, (lp["hca_w"], lp["hca_b"], lp["hca_a"]), attention)
+    xs, d_mlp = hyper_connect(
+        cfg, xs, (lp["hcm_w"], lp["hcm_b"], lp["hcm_a"]), mlp)
+    defect = jnp.max(jnp.where(st.valid, jnp.maximum(d_attn, d_mlp), 0.0))
+    return xs, load[0] if load else None, defect[None]
+
+
 def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     """``step(params, kv, last_tok, plan) -> (kv, last_tok, out)`` for
     one bucket: ``chunk`` prefill tokens (0: none) beside ``geo.rows``
@@ -772,11 +1226,16 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     ``pages`` (2,) pages one window layer visited and pages a
     causal-full layer would have or, for a model with an indexer,
     ``keys`` (2,) keys one layer attended and keys a causal-dense layer
-    would have."""
+    would have or, for a model with hyper-connections, ``hc_defect``
+    (1,) (:func:`unpack_ints`). Between the embedding and the head such
+    a model's activation is its residual state ``(tokens, hc_mult,
+    hidden)``: the embedding copied into every stream, the streams
+    summed before the final norm."""
     geo.check(cfg)
     r = geo.rows
     eps = cfg.rms_norm_eps
-    layer = _sparse_layer if cfg.index_topk else _afmoe_layer
+    layer = _latent_layer if cfg.latent_width else \
+        _sparse_layer if cfg.index_topk else _afmoe_layer
 
     def step(params, kv, last_tok, plan):
         row_pos = plan["row_pos"]
@@ -796,16 +1255,22 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
             h = params["embed"][ids].astype(F32)
             if cfg.mup_enabled:
                 h = h * math.sqrt(cfg.hidden_size)
+            if cfg.hc_mult:
+                h = jnp.repeat(h[:, None, :], cfg.hc_mult, axis=1)
 
         new = {name: list(pools) for name, pools in kv.items()}
         loads = []
-        read = jnp.zeros((2,), jnp.int32)
+        read = jnp.zeros((1,), F32) if cfg.hc_mult \
+            else jnp.zeros((2,), jnp.int32)
         for li, lp in enumerate(params["layers"]):
             h, load, counted = layer(cfg, geo, st, li, lp, h, new)
             if load is not None:
                 loads.append(load)
             if counted is not None:
-                read = counted
+                # the worst H_res of every layer; one layer's count
+                read = jnp.maximum(read, counted) if cfg.hc_mult else counted
+        if cfg.hc_mult:
+            read = lax.bitcast_convert_type(read, jnp.int32)
 
         with jax.named_scope("lm.head"):
             if chunk:
@@ -813,6 +1278,8 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
                                        h[jnp.maximum(st.n - 1, 0)][None]])
             else:
                 top = jnp.concatenate([h, jnp.zeros_like(h[:1])])
+            if cfg.hc_mult:
+                top = jnp.sum(top, axis=1)
             logits = mm(rms_norm(top, params["final_norm"], eps),
                         params["head"])
             tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -826,8 +1293,16 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
 
     step.__name__ = f"lm_step_c{chunk}"
     step.__qualname__ = step.__name__
-    # the form the chunk's attention takes in this program (None: no chunk)
-    step.attn_chunk_form = attention_form(
-        1, chunk, cfg.num_key_value_heads, cfg.head_dim, geo.page) \
-        if chunk else None
+    # the forms the rows' and the chunk's attention take in this program
+    # (the chunk's None where the bucket has none)
+    if cfg.latent_width:
+        step.attn_rows_form = "latent_absorbed"
+        step.attn_chunk_form = latent_chunk_form(
+            chunk, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.kv_lora_rank, cfg.v_head_dim, geo.page) if chunk else None
+    else:
+        step.attn_rows_form = "gathered" if cfg.index_topk else "loop"
+        step.attn_chunk_form = attention_form(
+            1, chunk, cfg.num_key_value_heads, cfg.head_dim, geo.page) \
+            if chunk else None
     return step
