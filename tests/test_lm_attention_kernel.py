@@ -1,13 +1,16 @@
-"""The chunk form of ``paged_attention`` (lm/attention_kernel.py).
+"""The kernel forms of ``paged_attention`` (lm/attention_kernel.py).
 
-On the CPU the kernel runs in Pallas's interpreter at tiny widths and is
+On the CPU a kernel runs in Pallas's interpreter at tiny widths and is
 held to the loop form, which tests/test_lm_model.py holds to the plain
 references: same blocks, same online softmax, so the two agree to
 float32 rounding. One test compiles both families' ``lm_step_c2048`` at
 the cells' shapes for a described v5e (no chip attached, nothing runs)
 and reads the optimized HLO: the chunk's attention is a Mosaic custom
 call under its layer's scope, the pools reach it without a copy, and no
-float32 array of chunk x block keys x heads is left in the step.
+float32 array of chunk x block keys x heads is left in the step. A
+second reads Trinity's ``lm_step_c0`` and ``lm_step_c2048`` for the
+rows' kernel: one custom call a layer, no ``while`` left under the
+attention's scopes.
 
 The topology is described inside a fixture (the TPU's library belongs
 to one process at a time; tests/test_beam_cache_layout.py and
@@ -60,10 +63,10 @@ def _case(seed, nq, p0, n, window, base_pages, masked):
 def _both_forms(monkeypatch, args, kw, q_tile):
     """``paged_attention`` in the loop form, then in the kernel form
     (the interpreter in the kernel's place, ``q_tile`` queries a tile)."""
-    assert lm_model.attention_form(1, args[0].shape[1], NKV, HD,
-                                   PAGE) == "loop"
+    assert lm_model.attention_form(1, args[0].shape[1], NKV, G, HD, PAGE,
+                                   chosen=kw["chosen"] is not None) == "loop"
     loop = lm_model.paged_attention(*args, **kw)
-    monkeypatch.setattr(lm_model, "attention_form", lambda *_: "kernel")
+    monkeypatch.setattr(lm_model, "attention_form", lambda *_, **__: "kernel")
     monkeypatch.setattr(
         attention_kernel, "chunk_attention", functools.partial(
             attention_kernel.chunk_attention, q_tile=q_tile, interpret=True))
@@ -119,20 +122,144 @@ def test_a_query_no_key_is_chosen_for_reads_zeros(monkeypatch):
     assert not got[0, 3].any() and not got[0, 11].any() and got[0, 4].any()
 
 
+# --------------------------------------------------------------------------
+# the rows: one query a sequence, each over its own pages
+# --------------------------------------------------------------------------
+
+def _kv_rows_case(seed, lasts, nkv, g, window, ahead=0):
+    """The arguments of one ``paged_attention`` call for one query a
+    sequence at the positions ``lasts`` (-1: the sequence is absent). A
+    window row's table is a ring: it starts at the page its band starts
+    in, less ``ahead`` pages the ring has not yet given back."""
+    rng = np.random.default_rng(seed)
+    pk, pv = (jnp.asarray(rng.normal(size=(POOL, PAGE, nkv, HD)),
+                          jnp.bfloat16) for _ in range(2))
+    rows = len(lasts)
+    q = jnp.asarray(rng.normal(size=(rows, 1, nkv, g, HD)) * 0.4,
+                    jnp.bfloat16)
+    table = np.zeros((rows, WIDTH), np.int32)
+    base = np.zeros(rows, np.int32)
+    for i, last in enumerate(lasts):
+        if last < 0:
+            continue
+        if window is not None:
+            base[i] = max((last - window + 1) // PAGE - ahead, 0) * PAGE
+        live = (last - base[i]) // PAGE + 1
+        table[i, :live] = rng.permutation(np.arange(1, POOL))[:live]
+    last = jnp.asarray(lasts, jnp.int32)
+    return (q, jnp.maximum(last, 0)[:, None], last, pk, pv,
+            jnp.asarray(table), jnp.asarray(base)), dict(
+                window=window, page=PAGE, block_pages=BP)
+
+
+# one key; a context that ends mid-page (13) and one that ends mid-block
+# (21: the third block's first page); the longest; an absent row
+RAGGED = (0, 13, -1, 21, 47, 30)
+KV_ROWS = {
+    "full": (RAGGED, 2, 2, None, 0),
+    "full_nkv4": (RAGGED, 4, 2, None, 0),
+    "full_g4": (RAGGED, 2, 4, None, 0),
+    "full_longest": ((47, 47, 47), 2, 2, None, 0),
+    "full_absent": ((-1, -1), 2, 2, None, 0),
+    "full_page_edges": ((3, 4, 7, 8, 15, 16), 2, 2, None, 0),
+    # window 10 over pages of 4: the band starts mid-page; rows still
+    # inside their first window (base 0) beside rows whose ring has moved
+    "window": ((0, 5, 9, 13, -1, 30, 47, 95), 2, 2, 10, 0),
+    "window_nkv4": ((0, 5, 9, 13, -1, 30, 47, 95), 4, 2, 10, 0),
+    # the ring still holds pages behind the band: the row's first blocks
+    # are out of sight and are skipped
+    "window_pages_behind": ((30, 47, 95, 22, 41), 2, 2, 10, 5),
+    "window_whole_pages": ((7, 8, 11, 12, 40), 2, 2, 8, 1),
+}
+
+
+@pytest.mark.parametrize("case", KV_ROWS)
+def test_the_kv_rows_kernel_equals_the_loop(monkeypatch, case):
+    """Rows of ragged length in one call: each reads its own pages, in
+    the loop's blocks, and comes out as the loop gives it."""
+    lasts, nkv, g, window, ahead = KV_ROWS[case]
+    args, kw = _kv_rows_case(19, lasts, nkv, g, window, ahead)
+    assert lm_model.attention_form(len(lasts), 1, nkv, g, HD,
+                                   PAGE) == "loop"
+    loop = lm_model.paged_attention(*args, **kw)
+    monkeypatch.setattr(lm_model, "attention_form",
+                        lambda *_, **__: "rows_kernel")
+    monkeypatch.setattr(
+        attention_kernel, "rows_attention", functools.partial(
+            attention_kernel.rows_attention, interpret=True))
+    got = _same(loop, lm_model.paged_attention(*args, **kw))
+    assert got.shape == (len(lasts), 1, nkv, g, HD)
+    for i, last in enumerate(lasts):
+        assert got[i].any() == (last >= 0)          # absent: zeros
+        if last >= 0:
+            assert np.abs(got[i]).max() > 0.05      # it attended something
+
+
+def test_the_grid_is_the_blocks_the_rows_read_and_no_more():
+    """One grid step a block that some row reads: a step of the engine
+    costs the SUM of its rows' visible blocks, not rows x the longest."""
+    plan = functools.partial(attention_kernel._rows_plan, width=WIDTH,
+                             page=PAGE, bp=BP)
+    # a full row of 48 keys, one of 14, an absent one, one of 2
+    last = jnp.asarray([47, 13, -1, 1], jnp.int32)
+    steps = plan(jnp.maximum(last, 0), last, jnp.zeros(4, jnp.int32),
+                 window=None)
+    t = 4 * WIDTH // BP
+    row, block, top, total = (steps[:t], steps[t:2 * t], steps[2 * t:3 * t],
+                              int(steps[3 * t]))
+    # 6 blocks, 2 blocks, the absent row's one step (zeros), 1 block
+    assert total == 6 + 2 + 1 + 1
+    assert row[:total].tolist() == [0] * 6 + [1] * 2 + [2] + [3]
+    assert block[:total].tolist() == [0, 1, 2, 3, 4, 5, 0, 1, 0, 0]
+    # 14 keys end in the fourth page, 2 in the first: slots stop there
+    assert top[:total].tolist() == [11] * 6 + [3] * 2 + [0] + [0]
+    assert set(row[total:].tolist()) == {3}         # the rest repeat
+    # a window row (10) at 95 whose ring starts at page 20: its band
+    # starts in slot 1 and ends in slot 3, so it reads blocks 0 and 1; one
+    # whose ring still holds five pages behind the band (slots 5 to 7 in
+    # sight) reads blocks 2 and 3 and never the two before them
+    steps = plan(jnp.asarray([95, 95]), jnp.asarray([95, 95]),
+                 jnp.asarray([80, 64]), window=10)
+    t = 2 * WIDTH // BP
+    assert int(steps[3 * t]) == 2 + 2
+    assert steps[t:t + 4].tolist() == [0, 1, 2, 3]
+    assert steps[2 * t:2 * t + 4].tolist() == [3, 3, 7, 7]
+
+
 def test_the_form_is_read_from_the_call(monkeypatch):
     """A chunk on a TPU takes the kernel where Mosaic tiles its shapes;
-    rows of one query, several sequences, any other backend and shapes
+    several sequences of several queries, any other backend and shapes
     the kernel does not tile take the loop."""
-    form = lm_model.attention_form      # (sequences, queries, nkv, hd, page)
-    assert form(1, 2048, 4, 128, 256) == "loop"                # the CPU
+    # (sequences, queries, nkv, g, hd, page)
+    form = lm_model.attention_form
+    assert form(1, 2048, 4, 8, 128, 256) == "loop"             # the CPU
     monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
-    assert form(1, 2048, 4, 128, 256) == "kernel"
-    assert form(1, 256, 4, 128, 256) == "kernel"
-    assert form(32, 1, 4, 128, 256) == "loop"
-    assert form(1, 1, 4, 128, 256) == "loop"
-    assert form(2, 2048, 4, 128, 256) == "loop"
-    assert form(1, 16, 2, 16, 4) == "loop"
-    assert form(1, 2048, 3, 128, 256) == "loop"
+    assert form(1, 2048, 4, 8, 128, 256) == "kernel"
+    assert form(1, 256, 4, 8, 128, 256) == "kernel"
+    assert form(1, 2048, 4, 8, 128, 256, chosen=True) == "kernel"
+    assert form(1, 2048, 4, 8, 128, 256, expand=True) == "loop"
+    assert form(2, 2048, 4, 8, 128, 256) == "loop"
+    assert form(1, 16, 2, 2, 16, 4) == "loop"
+    assert form(1, 2048, 3, 8, 128, 256) == "loop"
+
+
+def test_the_rows_form_of_kv_pages_is_read_from_the_call(monkeypatch):
+    """Rows of one query each take the rows' kernel on a TPU, without a
+    choice of keys and over K/V pages alone: a pool that ``expand``
+    reads (every ``keys_minor`` caller) and any other backend take the
+    loop, as do shapes Mosaic does not tile."""
+    form = lm_model.attention_form
+    assert form(32, 1, 4, 8, 128, 256) == "loop"               # the CPU
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    assert form(32, 1, 4, 8, 128, 256) == "rows_kernel"
+    assert form(16, 1, 4, 8, 128, 256) == "rows_kernel"
+    assert form(1, 1, 4, 8, 128, 256) == "rows_kernel"
+    assert form(32, 1, 4, 8, 128, 256, chosen=True) == "loop"
+    assert form(32, 1, 1, 32, 576, 256, expand=True) == "loop"  # latents
+    assert form(32, 1, 4, 8, 64, 256) == "loop"
+    assert form(32, 1, 4, 8, 128, 64) == "loop"
+    assert form(32, 1, 2, 2, 128, 256) == "loop"
+    assert form(5, 1, NKV, G, HD, PAGE) == "loop"
 
 
 # --------------------------------------------------------------------------
@@ -165,10 +292,9 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("config_name,weights", [
-    ("trinity_mini_6l", "afmoe_weights"), ("keye_vl2_lm_6l", "keye_weights")])
-def test_the_cells_chunk_step_compiles_to_the_kernel(
-        monkeypatch, one_chip, no_compile_cache, config_name, weights):
+def _compile_step(monkeypatch, one_chip, config_name, weights, chunk):
+    """``(cfg, geo, step, compiled)``: the cell's ``lm_step_c<chunk>``
+    compiled at its own shapes for the described chip."""
     if str(ROOT / "benchmark") not in sys.path:
         sys.path.insert(0, str(ROOT / "benchmark"))
     make_params = __import__(f"models.{weights}",
@@ -179,6 +305,7 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     geo = lm_model.Geometry(**{k: int(dep[k]) for k in (
         "rows", "chunk", "page", "context_cap", "kv_block_pages",
         "window_pages", "full_pages")})
+    chunk = geo.chunk if chunk is None else chunk
 
     def described(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -187,19 +314,43 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     # the described chip is not the process's backend: steer the one
     # question the program asks of it
     monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
-    step = lm_model.build_step(cfg, geo, geo.chunk)
-    assert step.attn_chunk_form == "kernel"
+    step = lm_model.build_step(cfg, geo, chunk)
     plan = {k: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for k, (s, d) in lm_model.plan_shapes(cfg, geo,
-                                                  geo.chunk).items()}
-    text = jax.jit(step, donate_argnums=(1, 2)).lower(
+            for k, (s, d) in lm_model.plan_shapes(cfg, geo, chunk).items()}
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
         described(jax.eval_shape(lambda: make_params(cfgd, 7))),
         described(jax.eval_shape(lambda: lm_model.empty_cache(cfg, geo))),
         jax.ShapeDtypeStruct((geo.rows,), jnp.int32, sharding=one_chip),
-        plan).compile().as_text()
+        plan).compile()
+    return cfg, geo, step, compiled
 
-    calls = [ln for ln in text.splitlines()
-             if " custom-call(" in ln and "lm_chunk_attention" in ln]
+
+def _custom_calls(text, name):
+    return [ln for ln in text.splitlines()
+            if " custom-call(" in ln and name in ln]
+
+
+def _operands(text, call):
+    """The HLO lines that define a custom call's operands, in order."""
+    names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
+             ln for ln in text.splitlines() if " = " in ln}
+    inside = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+    return [names[o.strip().lstrip("%")] for o in
+            re.sub(r"/\*[^*]*\*/", "", inside).split(",")]
+
+
+@pytest.mark.parametrize("config_name,weights", [
+    ("trinity_mini_6l", "afmoe_weights"), ("keye_vl2_lm_6l", "keye_weights")])
+def test_the_cells_chunk_step_compiles_to_the_kernel(
+        monkeypatch, one_chip, no_compile_cache, config_name, weights):
+    cfg, geo, step, compiled = _compile_step(monkeypatch, one_chip,
+                                             config_name, weights, None)
+    assert step.attn_chunk_form == "kernel"
+    assert step.attn_rows_form == (
+        "gathered" if cfg.index_topk else "rows_kernel")
+    text = compiled.as_text()
+
+    calls = _custom_calls(text, "lm_chunk_attention")
     assert len(calls) == cfg.num_layers
     assert all('custom_call_target="tpu_custom_call"' in ln for ln in calls)
     scopes = {"lm.attn.sparse": cfg.num_layers} if cfg.index_topk else {
@@ -207,13 +358,9 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     assert {s: sum(f"/{s}/lm_chunk_attention" in ln for ln in calls)
             for s in scopes} == scopes
     # the pools reach the kernel as they lie: a bitcast, never a copy
-    names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
-             ln for ln in text.splitlines() if " = " in ln}
     for ln in calls:
-        operands = re.search(r"custom-call\(([^)]*)\)", ln).group(1)
-        pools = [o.strip().lstrip("%") for o in
-                 re.sub(r"/\*[^*]*\*/", "", operands).split(",")][4:12]
-        assert all(" bitcast(" in names[o] for o in pools), pools
+        pools = _operands(text, ln)[4:12]
+        assert all(" bitcast(" in o for o in pools), pools
     # a block's scores (chunk x block keys x heads, float32) stay on the
     # chip: the loop form leaves f32[1,4,8,2048,1024] in the step
     scores = geo.chunk * geo.kv_block_pages * geo.page \
@@ -221,6 +368,38 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     left = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]", text)
             if math.prod(int(d) for d in m.group(1).split(",")) == scores}
     assert not left
+
+
+@pytest.mark.parametrize("chunk", [0, 2048])
+def test_trinitys_rows_compile_to_the_kernel(
+        monkeypatch, one_chip, no_compile_cache, chunk):
+    """Every bucket's program (the decode-only one and the fullest
+    shown) holds one Mosaic custom call a layer for the rows, fed by the
+    pools as they lie, and no loop is left under the attention."""
+    cfg, geo, step, compiled = _compile_step(
+        monkeypatch, one_chip, "trinity_mini_6l", "afmoe_weights", chunk)
+    assert step.attn_rows_form == "rows_kernel"
+    assert step.attn_chunk_form == ("kernel" if chunk else None)
+    text = compiled.as_text()
+    calls = _custom_calls(text, "lm_rows_attention")
+    assert len(calls) == cfg.num_layers == 6
+    assert all('custom_call_target="tpu_custom_call"' in ln for ln in calls)
+    assert {s: sum(f"/{s}/lm_rows_attention" in ln for ln in calls)
+            for s in ("lm.attn.window", "lm.attn.full")} == {
+                "lm.attn.window": cfg.window_layers,
+                "lm.attn.full": cfg.full_layers}
+    for ln in calls:
+        # last, after the grid's plan and q: a block's pages of K and V
+        pools = _operands(text, ln)[-2 * geo.kv_block_pages:]
+        assert all(" bitcast(" in o for o in pools), pools
+    # the rows' fori_loop is gone: no while under the attention's scopes,
+    # and no block of gathered K/V (rows x block keys x kv heads x 128)
+    assert not [ln for ln in text.splitlines() if " while(" in ln
+                and re.search(r"lm\.attn\.(window|full)", ln)]
+    gathered = "bf16[%d,%d,%d,%d]" % (
+        geo.rows, geo.kv_block_pages * geo.page, cfg.num_key_value_heads,
+        cfg.head_dim)
+    assert gathered not in text
 
 
 # --------------------------------------------------------------------------
@@ -352,55 +531,26 @@ def test_the_rows_form_is_read_from_the_call(monkeypatch):
 
 def test_the_chapters_cells_chunk_step_compiles_to_the_latent_kernel(
         monkeypatch, one_chip, no_compile_cache):
-    if str(ROOT / "benchmark") not in sys.path:
-        sys.path.insert(0, str(ROOT / "benchmark"))
-    from models.xing_weights import make_params
-    cfgd = json.loads((ROOT / "benchmark" / "configs" / "xing4_29b_6l.json"
-                       ).read_text())
-    cfg, dep = lm_model.LmConfig.from_hf(cfgd), cfgd["deployment"]
-    geo = lm_model.Geometry(**{k: int(dep[k]) for k in (
-        "rows", "chunk", "page", "context_cap", "kv_block_pages",
-        "window_pages", "full_pages")})
-
-    def described(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
-    step = lm_model.build_step(cfg, geo, geo.chunk)
+    cfg, geo, step, compiled = _compile_step(
+        monkeypatch, one_chip, "xing4_29b_6l", "xing_weights", None)
     assert step.attn_chunk_form == "latent_expanded_kernel"
     assert step.attn_rows_form == "latent_absorbed"
-    plan = {k: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for k, (s, d) in lm_model.plan_shapes(cfg, geo,
-                                                  geo.chunk).items()}
-    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
-        described(jax.eval_shape(lambda: make_params(cfgd, 7))),
-        described(jax.eval_shape(lambda: lm_model.empty_cache(cfg, geo))),
-        jax.ShapeDtypeStruct((geo.rows,), jnp.int32, sharding=one_chip),
-        plan).compile()
     text = compiled.as_text()
-    calls = [ln for ln in text.splitlines()
-             if " custom-call(" in ln and "lm_latent_chunk_attention" in ln]
+    calls = _custom_calls(text, "lm_latent_chunk_attention")
     assert len(calls) == cfg.num_layers == 6
     assert all('custom_call_target="tpu_custom_call"' in ln for ln in calls)
     assert all("/lm.attn.latent.chunk/lm_latent_chunk_attention" in ln
                for ln in calls)
-    rows = [ln for ln in text.splitlines()
-            if " custom-call(" in ln and "lm_latent_rows_attention" in ln]
+    rows = _custom_calls(text, "lm_latent_rows_attention")
     assert len(rows) == cfg.num_layers
     assert all("/lm.attn.latent.rows/lm_latent_rows_attention" in ln
                for ln in rows)
     calls += rows
     # the pool reaches the kernel as it lies: a bitcast, never a copy,
     # and nowhere in the step is a whole pool relaid (880 MB a layer)
-    names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
-             ln for ln in text.splitlines() if " = " in ln}
     for ln in calls:
-        operands = re.search(r"custom-call\(([^)]*)\)", ln).group(1)
-        pools = [o.strip().lstrip("%") for o in
-                 re.sub(r"/\*[^*]*\*/", "", operands).split(",")][
-                     -geo.kv_block_pages:]
-        assert all(" bitcast(" in names[o] for o in pools), pools
+        pools = _operands(text, ln)[-geo.kv_block_pages:]
+        assert all(" bitcast(" in o for o in pools), pools
     pages = geo.full_pages
     assert not [ln for ln in text.splitlines()
                 if re.search(rf"= bf16\[{pages},[0-9,]+\][^ ]* (copy|transpose)"

@@ -130,6 +130,54 @@ def test_the_step_record_says_which_form_the_chunk_attended_in(model):
                              "loop_steps": len(with_chunk)}
 
 
+@pytest.mark.parametrize("form", ["loop", "rows_kernel"])
+def test_the_step_record_says_which_form_the_rows_attended_in(
+        monkeypatch, model, form):
+    """``attn_rows_form`` is what ``build_step`` chose for the rows
+    (``model.py::attention_form``): the loop on this backend. With the
+    choice steered to the rows' kernel (Pallas's interpreter in the
+    chip's place) every record and ``stats()`` say so, and the engine
+    serves the same tokens: window and full layers, rows of ragged
+    length, rows that come and go."""
+    import functools
+
+    from vlog_tpu.lm import attention_kernel
+    from vlog_tpu.lm import model as lm_model
+
+    _hf, cfg, params = model
+    prompts = [np.arange(n) % 512 for n in (21, 5, 40)]
+
+    def served():
+        eng = engine(cfg, params, rows=4)
+        try:
+            tokens = [r.wait(300) for r in [
+                eng.submit(p, max_new=6) for p in prompts]]
+            return tokens, list(eng.step_log), eng.stats()
+        finally:
+            eng.close()
+
+    want, log, stats = served()
+    assert {rec["attn_rows_form"] for rec in log} == {"loop"}
+    assert stats["attn_rows_form"] == "loop"
+    if form == "loop":
+        return
+    choose = lm_model.attention_form
+
+    def steered(seqs, nq, *shape, chosen=False, expand=False):
+        if nq == 1 and not chosen and not expand:
+            return "rows_kernel"
+        return choose(seqs, nq, *shape, chosen=chosen, expand=expand)
+
+    monkeypatch.setattr(lm_model, "attention_form", steered)
+    monkeypatch.setattr(
+        attention_kernel, "rows_attention", functools.partial(
+            attention_kernel.rows_attention, interpret=True))
+    got, log, stats = served()
+    assert {rec["attn_rows_form"] for rec in log} == {"rows_kernel"}
+    assert stats["attn_rows_form"] == "rows_kernel"
+    assert got == want
+
+
 def test_eos_ends_a_request_early(model):
     _hf, cfg, params = model
     eng = engine(cfg, params)

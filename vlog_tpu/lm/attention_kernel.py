@@ -1,6 +1,8 @@
-"""The chunk form of ``model.py::paged_attention`` as one Mosaic kernel.
+"""The kernel forms of ``model.py``'s attention over paged pools: four
+Mosaic kernels, each the loop's mathematics at the loop's precision.
 
-One sequence, ``Q`` consecutive query positions (a prefill chunk), keys
+**The chunk** (:func:`chunk_attention`, ``paged_attention``'s form for a
+prefill chunk). One sequence, ``Q`` consecutive query positions, keys
 read straight from the paged pools through the page table. A tile's
 scores, its running maximum, sum and accumulator live in VMEM: nothing
 of ``queries x keys`` size crosses HBM. The mathematics and the
@@ -26,6 +28,32 @@ the tile sees whole skips the positional mask.
 Sized on the chip (PERF.md section 6, PR 34): ``Q_TILE`` 128 with the
 geometry's block of 4 pages reads 4.7 ms for 2,048 queries over 18k keys
 (64: 5.1 ms; 256 spills: 7.9 ms; the loop: 23.0 ms).
+
+**The rows** (:func:`rows_attention`, ``paged_attention``'s form for
+``S`` sequences of one query each over the same K/V pools). The grid is
+ONE axis with one step a block that some row reads (a dynamic bound):
+the rows in order, each row's blocks of ``block_pages`` pages from its
+band's first (the table of a window layer is a ring from ``base`` on) to
+its last live one. The plan of it (:func:`_rows_plan`: each step's row
+and block) is made beside the call from the rows' positions and is
+scalar-prefetched with the tables, so an index map is three reads and
+every page of the next step, the next ROW's first among them, is
+fetched while this one computes. A step reads its block of K and of V
+and takes one softmax step over it, as the loop does; a row's first
+step clears the running maximum, sum and accumulator, its last writes
+the row out. So a step of the engine costs the SUM of its rows' visible
+blocks and not ``rows`` times the longest (the loop's), and no grid
+step is spent on a block that is not read (a grid of ``rows x the
+longest row's blocks`` with the unread ones skipped cost 1.0 us a
+skipped step in index arithmetic, 0.8 of a full layer's 1.77 ms:
+PERF.md section 6, PR 36). An absent row keeps one step, reads
+nothing and comes out zeros. The kv heads are NOT split: a page goes to
+the matrix unit as the pool holds it, ``(position x kv head, 128)``,
+every query head is run against every row of it and keeps its own kv
+head's by the mask. That is ``nkv`` times the score and ``exp`` work of
+a split, on a kernel that waits on HBM (a key byte meets 32 operations
+where the chip has 240 to spend), and nothing of K or V is moved inside
+VMEM.
 
 **Latent attention's chunk** (:func:`latent_chunk_attention`, the
 expanded form of ``model.py::expanded_attention``) is a second kernel
@@ -231,6 +259,183 @@ def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
         name="lm_chunk_attention",
     )(table.astype(jnp.int32), meta, *args)
     return out.transpose(1, 0, 2).reshape(nq, nkv, g, hd)
+
+
+# --------------------------------------------------------------------------
+# K/V pages: the rows, each over its own pages
+# --------------------------------------------------------------------------
+
+FAR = 1 << 30       # a key position no query reaches
+
+
+def rows_supported(nkv: int, g: int, hd: int, page: int) -> bool:
+    """Shapes Mosaic tiles: a head is one 128-lane block and the kv
+    heads pair up in 32-bit words (a page is then read as the pool holds
+    it, as :func:`supported` asks), the query heads whole bfloat16
+    sublane tiles of the score product's rows, a page's rows whole lane
+    blocks of the scores."""
+    return hd == 128 and nkv % 2 == 0 and (nkv * g) % 16 == 0 \
+        and page % 128 == 0
+
+
+def _divmod(x: jax.Array, n: int) -> tuple[jax.Array, jax.Array]:
+    """``divmod`` of non-negative int32 by a static ``n``: shifts where
+    ``n`` is a power of two (the vector unit has no divider)."""
+    if n & (n - 1) == 0:
+        return x >> (n.bit_length() - 1), x & (n - 1)
+    return lax.div(x, jnp.int32(n)), lax.rem(x, jnp.int32(n))
+
+
+def _row_pages(qpos, last_pos, base, *, page: int, window: int | None):
+    """``[lo, hi)``: the table slots that hold a key the query at
+    ``qpos`` attends (causal, the band, live pages), for one row or for
+    all of them."""
+    n_pages = jnp.where(last_pos >= 0, (last_pos - base) // page + 1, 0)
+    hi = jnp.clip((qpos - base) // page + 1, 0, n_pages)
+    lo = jnp.zeros_like(hi) if window is None else \
+        jnp.maximum(qpos - window + 1 - base, 0) // page
+    return lo, hi
+
+
+def _rows_plan(qpos, last_pos, base, *, width: int, page: int, bp: int,
+               window: int | None):
+    """The kernel's grid, one step a block that some row reads: ``(row |
+    block | top | total)`` int32, the first three ``T = rows x blocks a
+    table of ``width`` slots holds`` long. Grid step ``t`` serves table
+    block ``block[t]`` of row ``row[t]``, whose last slot in sight is
+    ``top[t]`` (a slot of the block past it reads that page again): the
+    rows in order, each row's blocks from its band's first to its last
+    live one. ``total`` steps are real, the rest repeat the last. A row
+    with nothing to read keeps ONE step, in which it writes its zeros."""
+    rows = qpos.shape[0]
+    lo, hi = _row_pages(qpos, last_pos, base, page=page, window=window)
+    first = lo // bp
+    n = jnp.maximum(jnp.where(hi > 0, (hi - 1) // bp - first + 1, 0), 1)
+    ends = jnp.cumsum(n)
+    t = jnp.arange(rows * -(-width // bp), dtype=jnp.int32)
+    # the row of step t: how many rows end at or before it (a few
+    # compares a step; a binary search would be a loop on the device)
+    row = jnp.minimum(jnp.sum(t[:, None] >= ends[None, :], axis=1), rows - 1)
+    first, start, n, top = jnp.stack(
+        [first, ends - n, n, jnp.maximum(hi, 1) - 1], axis=1)[row].T
+    block = first + jnp.minimum(t - start, n - 1)
+    return jnp.concatenate([row, block, top, ends[-1:]]).astype(jnp.int32)
+
+
+def _paged_rows_kernel(steps, table, meta, q_ref, *refs, n_steps: int,
+                       rows: int, nkv: int, g: int, page: int, bp: int,
+                       window: int | None):
+    del table
+    k_refs, v_refs = refs[:bp], refs[bp:2 * bp]
+    o_ref, m_s, l_s, acc_s = refs[2 * bp:]
+    t = pl.program_id(0)
+    r, kb, total = steps[t], steps[n_steps + t], steps[3 * n_steps]
+    qpos, base = meta[r], meta[2 * rows + r]
+    _, hi = _row_pages(qpos, meta[rows + r], base, page=page, window=window)
+
+    @pl.when((t == 0) | (steps[jnp.maximum(t - 1, 0)] != r))
+    def _():
+        m_s[...] = jnp.full(m_s.shape, MASKED, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+
+    # every real step reads a block in its row's sight, but for the one
+    # step of a row with nothing to read
+    @pl.when((t < total) & (hi > 0))
+    def _():
+        # a page as the pool holds it: rows by position then kv head.
+        # Every query head is run against every row and keeps its own kv
+        # head's (the others' masked), so no key or value is moved
+        # inside VMEM
+        shape = (nkv * g, page * nkv)
+        rel, head = _divmod(lax.broadcasted_iota(jnp.int32, shape, 1), nkv)
+        mine, _ = _divmod(lax.broadcasted_iota(jnp.int32, shape, 0), g)
+        rel = jnp.where(head == mine, rel, FAR)     # position in the page
+        q = q_ref[...]
+        scores = []
+        for j, k_ref in enumerate(k_refs):
+            slot = kb * bp + j
+            start = base + slot * page
+            ok = rel <= jnp.where(slot < hi, qpos - start, -1)
+            if window is not None:
+                ok &= rel > qpos - window - start
+            s = lax.dot_general(q, k_ref[...], (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32)
+            scores.append(jnp.where(ok, s, MASKED))
+        # ONE softmax step a block, as the loop takes it
+        m_prev = m_s[...]
+        m_new = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=1, keepdims=True) for s in scores], m_prev)
+        scale = jnp.exp(m_prev - m_new)
+        l, acc = l_s[...] * scale, acc_s[...] * scale
+        for s, v_ref in zip(scores, v_refs):
+            # a masked score underflows to 0 against the block's maximum,
+            # which is real: every block of a row's steps holds a key in
+            # its sight
+            p = jnp.exp(s - m_new)
+            l += jnp.sum(p, axis=1, keepdims=True)
+            acc += jnp.dot(p.astype(BF16), v_ref[...],
+                           preferred_element_type=F32)
+        m_s[...], l_s[...], acc_s[...] = m_new, l, acc
+
+    @pl.when((t == total - 1) | (
+        (t < total) & (steps[jnp.minimum(t + 1, n_steps - 1)] != r)))
+    def _():
+        o_ref[...] = jnp.where(
+            m_s[...] > 0.5 * MASKED,
+            acc_s[...] / jnp.maximum(l_s[...], 1e-30), 0.0)
+
+
+def rows_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
+                   pool_k: jax.Array, pool_v: jax.Array, table: jax.Array,
+                   base: jax.Array, *, window: int | None, page: int,
+                   block_pages: int, interpret: bool = False) -> jax.Array:
+    """``q`` (S, nkv, g, hd) bfloat16, scaled, the one query of sequence
+    ``s`` at position ``qpos[s]``; ``last_pos`` (S,) the last position
+    that holds a key (-1: absent, reads zeros); the pools ``(pages, page,
+    nkv, hd)``; ``table`` (S, W) physical pages from position ``base``
+    (S,) on. Returns (S, nkv, g, hd) float32."""
+    rows, nkv, g, hd = q.shape
+    bp, width = block_pages, table.shape[1]
+    steps = _rows_plan(qpos, last_pos, base, width=width, page=page, bp=bp,
+                       window=window)
+    meta = jnp.concatenate([qpos, last_pos, base]).astype(jnp.int32)
+    # as many grid steps as the rows read blocks between them; the
+    # interpreter takes no dynamic bound: there the steps past them are
+    # skipped one by one
+    n_steps = steps.shape[0] // 3
+    by_row = pl.BlockSpec((None, nkv * g, hd),
+                          lambda t, steps, *_: (steps[t], 0, 0))
+
+    def page_spec(j):
+        def index(t, steps, table, meta):
+            slot = jnp.minimum(steps[n_steps + t] * bp + j,
+                               steps[2 * n_steps + t])
+            return table[steps[t] * width + slot], 0
+        return pl.BlockSpec((page * nkv, hd), index)
+
+    out = pl.pallas_call(
+        functools.partial(_paged_rows_kernel, n_steps=n_steps, rows=rows,
+                          nkv=nkv, g=g, page=page, bp=bp, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_steps if interpret else steps[-1],),
+            in_specs=[by_row] + [page_spec(j) for _ in "kv"
+                                 for j in range(bp)],
+            out_specs=by_row,
+            scratch_shapes=[pltpu.VMEM((nkv * g, 1), F32),
+                            pltpu.VMEM((nkv * g, 1), F32),
+                            pltpu.VMEM((nkv * g, hd), F32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, nkv * g, hd), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="lm_rows_attention",
+    )(steps, table.reshape(-1).astype(jnp.int32), meta,
+      q.reshape(rows, nkv * g, hd),
+      *([pool_k.reshape(-1, hd)] * bp + [pool_v.reshape(-1, hd)] * bp))
+    return out.reshape(rows, nkv, g, hd)
 
 
 # --------------------------------------------------------------------------

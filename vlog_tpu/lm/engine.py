@@ -38,8 +38,10 @@ and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
 attention took in this bucket's program, ``"kernel"`` or ``"loop"``:
 ``model.py::attention_form``, or for latent attention
 ``"latent_expanded_kernel"`` / ``"latent_expanded_loop"``; ``None`` for
-a step without a chunk), ``attn_rows_form`` (the rows': ``"loop"``,
-``"gathered"`` or ``"latent_absorbed"``), ``rows_context`` (positions
+a step without a chunk), ``attn_rows_form`` (the rows', fixed with the
+program too: ``"rows_kernel"`` or ``"loop"`` from the same function,
+``"gathered"`` for a model with an indexer, ``"latent_absorbed"`` for
+latent attention), ``rows_context`` (positions
 the rows' attention read a layer: each row's position plus one),
 ``chunk_tag``, ``emitted`` (tags of the requests that got a token),
 ``pages_in_use`` by class, ``pages_freed``, ``pool_wait_rows`` (rows that

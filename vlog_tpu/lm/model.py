@@ -68,10 +68,11 @@ step's tokens pass the projections, the dense MLPs and the experts
 together; attention runs per sequence over the paged cache: the chunk's
 and the rows' new K/V are written into their pages first, then each
 query block reads its sequence's pages ``kv_block_pages`` at a time
-under an online softmax (never more than queries x block scores; for a
-chunk on a TPU inside one kernel, ``attention_kernel.py``, where they
-never leave the chip), from the table's first page to the page of its
-last query. A table is a
+under an online softmax (never more than queries x block scores; on a
+TPU inside one kernel for the chunk and one for the rows,
+``attention_kernel.py``, where they never leave the chip and a row
+reads its own pages and no other's), from the table's first page to
+the page of its last query. A table is a
 list of physical pages from logical page ``base // page`` on: for a
 full layer ``base`` is 0, for a window layer the host hands in only the
 pages the band touches (``cache.py``), so a window layer visits no page
@@ -509,12 +510,22 @@ def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float
     return (x - mu) * lax.rsqrt(var + eps) * w.astype(F32) + b.astype(F32)
 
 
-def attention_form(seqs: int, nq: int, nkv: int, hd: int, page: int) -> str:
-    """``"kernel"`` or ``"loop"``: the form :func:`paged_attention` takes
-    for ``seqs`` sequences of ``nq`` queries each."""
-    chunk = seqs == 1 and nq > 1 and attention_kernel.supported(
-        nq, nkv, hd, page)
-    return "kernel" if chunk and jax.default_backend() == "tpu" else "loop"
+def attention_form(seqs: int, nq: int, nkv: int, g: int, hd: int, page: int,
+                   *, chosen: bool = False, expand: bool = False) -> str:
+    """The form :func:`paged_attention` takes for ``seqs`` sequences of
+    ``nq`` queries each, ``g`` query heads a kv head: ``"kernel"`` (a
+    prefill chunk: one sequence, with or without a choice of keys),
+    ``"rows_kernel"`` (rows of one query each over K/V pages, no choice)
+    or ``"loop"`` (any other backend than a TPU, a pool that ``expand``
+    reads, shapes Mosaic does not tile)."""
+    if expand or jax.default_backend() != "tpu":
+        return "loop"
+    if seqs == 1 and nq > 1 and attention_kernel.supported(nq, nkv, hd, page):
+        return "kernel"
+    if nq == 1 and not chosen and attention_kernel.rows_supported(
+            nkv, g, hd, page):
+        return "rows_kernel"
+    return "loop"
 
 
 def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
@@ -548,23 +559,34 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     use of it the other way round relays 880 MB out: 10 ms a layer, my
     chip run, PR 35). Such a call runs the loop.
 
-    Two forms of the one algorithm, chosen by :func:`attention_form`
-    from the call's shapes and the backend: rows of one query each run
-    the loop below; a prefill chunk (one sequence of ``Q`` consecutive
-    positions from ``qpos[0, 0]`` on) runs on a TPU as one kernel that
-    keeps a block's scores on the chip (``attention_kernel.py``).
+    Three forms of the one algorithm, chosen by :func:`attention_form`
+    from the call's shapes and the backend. On a TPU a prefill chunk
+    (one sequence of ``Q`` consecutive positions from ``qpos[0, 0]`` on)
+    runs as one kernel that keeps a block's scores on the chip, and rows
+    of one query each as one kernel in which a row reads its OWN pages,
+    so a step's work is the sum of the rows' contexts (both in
+    ``attention_kernel.py``). Everything else runs the loop below, every
+    row as long as the longest: any other backend, ``expand`` and
+    ``keys_minor`` callers, shapes Mosaic does not tile.
     """
     s, nq, nkv, g, hd = q.shape
     width = table.shape[1]
     keys = block_pages * page
     n_pages = jnp.where(last_pos >= 0, (last_pos - base) // page + 1, 0)
     vd = hd if expand is None else value_width
-    if expand is None and attention_form(s, nq, nkv, hd, page) == "kernel":
+    form = attention_form(s, nq, nkv, g, hd, page, chosen=chosen is not None,
+                          expand=expand is not None)
+    if form == "kernel":
         out = attention_kernel.chunk_attention(
             q[0], qpos[0, 0], n_pages[0], pool_k, pool_v, table[0], base[0],
             window=window, page=page, block_pages=block_pages,
             chosen=None if chosen is None else chosen[0])
         return out[None], n_pages
+    if form == "rows_kernel":
+        out = attention_kernel.rows_attention(
+            q[:, 0], qpos[:, 0], last_pos, pool_k, pool_v, table, base,
+            window=window, page=page, block_pages=block_pages)
+        return out[:, None], n_pages
     n_blocks = (jnp.max(n_pages) + block_pages - 1) // block_pages
     lane = jnp.arange(keys, dtype=jnp.int32)
 
@@ -1301,8 +1323,11 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
             chunk, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
             cfg.kv_lora_rank, cfg.v_head_dim, geo.page) if chunk else None
     else:
-        step.attn_rows_form = "gathered" if cfg.index_topk else "loop"
+        shape = (cfg.num_key_value_heads,
+                 cfg.num_attention_heads // cfg.num_key_value_heads,
+                 cfg.head_dim, geo.page)
+        step.attn_rows_form = "gathered" if cfg.index_topk \
+            else attention_form(r, 1, *shape)
         step.attn_chunk_form = attention_form(
-            1, chunk, cfg.num_key_value_heads, cfg.head_dim, geo.page) \
-            if chunk else None
+            1, chunk, *shape, chosen=bool(cfg.index_topk)) if chunk else None
     return step
