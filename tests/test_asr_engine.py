@@ -253,6 +253,8 @@ def test_engine_survives_a_failed_batch(assets):
 OLD_KEYS = {"rows", "n", "occupancy", "jobs", "elapsed_s"}
 NEW_KEYS = {"seq", "t_start", "t_dispatch", "t_ready", "t_end", "phase_s",
             "gap_s", "windows", "wait_s", "build_s", "first_of_shape"}
+# PR 37: who kept the chip waiting (obs/hostwait.py)
+HOST_KEYS = {"wait", "gc_s", "stall"}
 PHASES = ("coalesce", "lease", "take", "stack", "mel", "dispatch",
           "device_wait", "parse", "deliver")
 
@@ -279,7 +281,7 @@ def test_tick_record_accounts_for_every_cycle(assets):
     log = engine.batch_log
     assert [b["n"] for b in log] == [2, 2, 2, 2]
     for k, b in enumerate(log):
-        assert set(b) == OLD_KEYS | NEW_KEYS
+        assert set(b) == OLD_KEYS | NEW_KEYS | HOST_KEYS
         # rows: the bucket, rounded up to the (virtual) mesh's width
         assert b["seq"] == k and b["rows"] % 2 == 0
         assert b["occupancy"] == 2 / b["rows"]
@@ -313,6 +315,72 @@ def test_tick_record_accounts_for_every_cycle(assets):
     # 5 ms each, but a loaded machine may take the thread away between
     # two spans: hold the best cycle to 5 ms and every one to 250
     assert min(slack) < 0.005 and max(slack) < 0.25
+
+
+def test_the_tick_record_says_who_kept_the_chip_waiting(assets):
+    """PR 37: every tick carries the token pull's wait record (taken from
+    the ``asr.generate.device_wait`` span, where ``generate_batch`` puts
+    it), the collection seconds of its own stretch and its stall cause;
+    ``stats()["waits"]`` counts every pull and is still there after
+    ``close()``, where the benchmark's driver reads it."""
+    engine = AsrEngine(assets, batch_windows=2, tick_s=0.02)
+    try:
+        h = engine.begin_job("who", language="en", max_new=8, beam=1)
+        for i in range(6):
+            h.submit(i, 25.0 * i, _tone(4.0))
+        assert len(list(h.results())) == 6
+        h.close()
+    finally:
+        engine.close()
+    log = engine.batch_log
+    stats = engine.stats()
+    assert len(log) == 3
+    for b in log:
+        wait = b["wait"]
+        assert set(wait) == {"polls", "gap_max_s", "cpu_s", "gc_s", "wait_s",
+                         "ready_max_s", "copy_s"}
+        assert wait["polls"] >= 2                   # tokens, then no-speech
+        assert 0.0 <= wait["gap_max_s"] <= wait["wait_s"]
+        assert 0.0 <= wait["gc_s"] <= wait["wait_s"]
+        # the pull is the span's: the phase holds it and a little more
+        assert wait["wait_s"] <= b["phase_s"]["device_wait"] + 1e-6
+        assert b["phase_s"]["device_wait"] - wait["wait_s"] < 0.05
+        assert 0.0 <= b["gc_s"] <= b["t_end"] - b["t_start"]
+        assert b["stall"] in (None, "gc", "host", "runtime")
+    waits = stats["waits"]
+    assert waits["count"] == 3 and len(waits["longest"]) == 3
+    assert sum(waits["stalls"].values()) == sum(
+        b["stall"] is not None for b in log)
+    longest = waits["longest"][0]
+    assert longest["wait_s"] == max(b["wait"]["wait_s"] for b in log)
+    assert longest["key"] == log[0]["rows"]
+    assert longest["seq"] in {b["seq"] for b in log}
+
+
+def test_the_tick_thread_starting_installs_the_gc_recorder(assets,
+                                                            monkeypatch):
+    """The process's GC recorder goes on with the engine's thread, once a
+    process (the LM engine's twin is in ``test_lm_engine.py``)."""
+    import gc
+
+    from vlog_tpu.obs import hostwait, trace
+
+    monkeypatch.setattr(trace, "start_thread", lambda *a, **k: None)
+    was = hostwait.GC._installed
+    hostwait.GC.reset()
+    engines = [AsrEngine(assets, batch_windows=2, tick_s=0.02)
+               for _ in range(2)]
+    try:
+        assert hostwait.GC._callback not in gc.callbacks
+        for k, engine in enumerate(engines):
+            engine.begin_job(f"gc{k}", language="en").close()
+        assert gc.callbacks.count(hostwait.GC._callback) == 1
+    finally:
+        for engine in engines:
+            engine.close()
+        hostwait.GC.reset()
+        if was:
+            hostwait.GC.install()
 
 
 def test_gap_is_not_counted_across_an_idle_wait(assets):

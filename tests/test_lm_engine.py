@@ -4,6 +4,7 @@ evicting, the step record adds up, and the engine swaps residency with
 the ASR plane at a job boundary."""
 
 # slowlane-ok(module): the tiny model's step programs build in seconds
+import gc
 import threading
 
 import numpy as np
@@ -104,6 +105,109 @@ def test_step_record_adds_up(model):
     assert any(rec["decode_rows"] >= 2 for rec in log)
     assert log[0]["gap_s"] is None and all(
         rec["gap_s"] >= 0 for rec in log[1:] if rec["gap_s"] is not None)
+
+
+def test_the_step_record_says_who_kept_the_chip_waiting(model):
+    """PR 37: every step carries the pull's wait record, the collection
+    seconds of its own stretch, its stall cause and ``idle_before_s``
+    (None with no step in flight, else from the first of the plan's
+    checks that found it done to the dispatch); ``stats()["waits"]``
+    counts every pull and is still there after ``close()``."""
+    _hf, cfg, params = model
+    eng = engine(cfg, params, rows=4)
+    try:
+        for r in [eng.submit(np.arange(n) % 512, max_new=5)
+                  for n in (30, 9, 17)]:
+            r.wait(300)
+        log = list(eng.step_log)
+    finally:
+        eng.close()
+    stats = eng.stats()
+    for rec in log:
+        wait = rec["wait"]
+        assert set(wait) == {"polls", "gap_max_s", "cpu_s", "gc_s", "wait_s",
+                         "ready_max_s", "copy_s"}
+        assert wait["polls"] >= 1
+        assert 0.0 <= wait["gap_max_s"] <= wait["wait_s"]
+        assert wait["wait_s"] <= rec["phase_s"]["device_wait"] + 1e-6
+        assert 0.0 <= rec["gc_s"] <= rec["t_end"] - rec["t_start"]
+        assert rec["stall"] in (None, "gc", "host", "runtime")
+        idle = rec["idle_before_s"]
+        assert idle is None or 0.0 <= idle <= rec["t_dispatch"] \
+            - rec["t_start"]
+    assert log[0]["idle_before_s"] is None      # nothing was in flight
+    assert any(rec["idle_before_s"] is not None for rec in log)
+    waits = stats["waits"]
+    assert waits["count"] == len(log)
+    assert len(waits["longest"]) == min(5, len(log))
+    assert waits["longest"][0]["wait_s"] == max(
+        rec["wait"]["wait_s"] for rec in log)
+    assert {w["key"] for w in waits["longest"]} <= set(
+        eng.geo.chunk_buckets())
+    assert sum(waits["stalls"].values()) == sum(
+        rec["stall"] is not None for rec in log)
+
+
+def test_idle_before_is_zero_while_the_step_in_flight_runs(model):
+    """The plan's checks (``_probe``) ask the step in flight; one that
+    never reads ready leaves ``idle_before_s`` at 0.0, one that reads
+    ready at the first check makes it run from there."""
+    from vlog_tpu.lm.engine import LmEngine
+    from vlog_tpu.lm.load import LmAssets
+
+    _hf, cfg, params = model
+    # not started: no thread plans beside the test
+    eng = LmEngine(LmAssets(cfg, params, None, "tiny"),
+                   geometry=geometry(cfg, rows=4))
+    try:
+        class Busy:
+            def __init__(self, ready):
+                self.ready = ready
+                self.calls = 0
+
+            def is_ready(self):
+                self.calls += 1
+                return self.ready
+
+        busy = Busy(False)
+        eng._flight = ({}, {"ints": busy})
+        for _ in range(4):
+            eng._probe()
+        assert eng._idle_from is None and busy.calls == 4
+        done = Busy(True)
+        eng._flight = ({}, {"ints": done})
+        eng._probe()
+        eng._probe()
+        assert eng._idle_from is not None and done.calls == 1
+    finally:
+        eng.close()
+
+
+def test_an_engine_thread_starting_installs_the_recorder(model, monkeypatch):
+    """The process's GC recorder goes on when an engine's thread starts,
+    once a process, also for an engine built without the registry (as
+    the benchmark builds them)."""
+    from vlog_tpu.lm.engine import LmEngine
+    from vlog_tpu.lm.load import LmAssets
+    from vlog_tpu.obs import hostwait, trace
+
+    _hf, cfg, params = model
+    monkeypatch.setattr(trace, "start_thread", lambda *a, **k: None)
+    was = hostwait.GC._installed
+    hostwait.GC.reset()
+    engines = [LmEngine(LmAssets(cfg, params, None, "tiny"),
+                        geometry=geometry(cfg, rows=4)) for _ in range(2)]
+    try:
+        assert hostwait.GC._callback not in gc.callbacks
+        for eng in engines:
+            eng._start_locked()
+        assert gc.callbacks.count(hostwait.GC._callback) == 1
+    finally:
+        for eng in engines:
+            eng.close()
+        hostwait.GC.reset()
+        if was:
+            hostwait.GC.install()
 
 
 def test_the_step_record_says_which_form_the_chunk_attended_in(model):

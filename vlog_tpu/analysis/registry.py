@@ -11,7 +11,8 @@ the real registries from source —
 - **failpoint sites**: the literal keys of ``SITES`` in
   ``utils/failpoints.py``;
 - **metric families**: first-arg names of ``Counter``/``Gauge``/
-  ``Histogram``/``Summary`` constructors in ``obs/metrics.py``
+  ``Histogram``/``Summary`` constructors in ``obs/metrics.py`` (and of
+  ``CounterMetricFamily``, a counter a collector renders at scrape time)
   (counters documented with their ``_total`` suffix), plus the
   hand-rendered ``# HELP``/``# TYPE`` families in the same file;
 - **span names**: literal first args of ``span()``/``event()`` calls
@@ -48,7 +49,8 @@ RULE = "registry"
 
 _ENV_PARSERS = frozenset({"_env_str", "_env_int", "_env_float", "_env_bool",
                           "_env_path"})
-_METRIC_CTORS = frozenset({"Counter", "Gauge", "Histogram", "Summary"})
+_METRIC_CTORS = frozenset({"Counter", "Gauge", "Histogram", "Summary",
+                           "CounterMetricFamily"})
 _KNOB_RE = re.compile(r"VLOG_[A-Z][A-Z0-9_]*")
 _HELP_RE = re.compile(r"#\s*(?:HELP|TYPE)\s+(vlog_\w+)")
 _DOC_SITE_RE = re.compile(r"`([a-z]+\.[a-z_]+)`")
@@ -187,7 +189,8 @@ def metric_families(modules: list[Module]) -> set[str]:
                         # prometheus renders counters with a _total
                         # suffix whether or not the declared name
                         # carries one — normalize, don't double-append
-                        if seg == "Counter" and not name.endswith("_total"):
+                        if seg in ("Counter", "CounterMetricFamily") \
+                                and not name.endswith("_total"):
                             name += "_total"
                         fams.add(name)
         fams.update(_HELP_RE.findall(mod.source))

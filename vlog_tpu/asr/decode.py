@@ -38,10 +38,11 @@ from vlog_tpu.asr.model import (
     decoder_step,
     encode,
 )
-from vlog_tpu.obs import trace
+from vlog_tpu.obs import hostwait, trace
 
 TIME_PRECISION = 0.02       # seconds per timestamp token step
 MAX_INITIAL_TIMESTAMP_INDEX = 50   # first cue within 1.0 s
+POLL_S = 1e-3       # the token pull's poll: ~10^3 polls in a 1.3 s tick
 
 
 # --------------------------------------------------------------------------
@@ -356,13 +357,17 @@ def generate_batch(assets: WhisperAssets, mel: jnp.ndarray, *,
     Two spans split the call for whoever listens (the engine's tick
     record): ``asr.generate.dispatch`` ends when the jitted call has
     returned, ``asr.generate.device_wait`` is the two pulls, that is the
-    host's wait for the device program."""
+    host's wait for the device program, polled (``obs/hostwait.py::pull``)
+    and not blocking; its wait record rides on the span as the attribute
+    ``wait``, where the engine's tick record takes it from."""
     with trace.span("asr.generate.dispatch"):
         toks, nsp = _dispatch(assets, mel, language=language, task=task,
                               max_new=max_new, timestamps=timestamps,
                               beam=beam)
-    with trace.span("asr.generate.device_wait"):
-        return np.asarray(toks), np.asarray(nsp)
+    with trace.span("asr.generate.device_wait") as waiting:
+        (toks, nsp), waiting.attrs["wait"] = hostwait.pull(
+            (toks, nsp), poll_s=POLL_S)
+        return toks, nsp
 
 
 def _dispatch(assets: WhisperAssets, mel: jnp.ndarray, *, language: str,

@@ -54,7 +54,17 @@ after a failed batch), ``windows``, ``wait_s``, ``build_s`` and
 ``first_of_shape`` (``parallel/compile_cache.py::build_seconds``), all
 on ``time.monotonic()``. The entry is appended before the results are
 delivered; ``phase_s["deliver"]`` and ``t_end`` are filled in place
-afterwards. Every span is also a ``vlog:<name>`` annotation in a
+afterwards. Who kept the chip waiting (``obs/hostwait.py``) is on the
+same record: ``wait`` is the record of the token pull (``polls``,
+``gap_max_s``, ``cpu_s``, ``gc_s``, ``wait_s``, ``ready_max_s``,
+``copy_s``; ``None`` for a stand-in
+of ``generate_batch``), ``gc_s`` the process's collection seconds
+between ``t_start`` and ``t_end``, ``stall`` the cause of a stalled pull
+(``gc``, ``host``, ``runtime``, judged against the recent pulls of the
+same rows) or ``None``; the tick is not pipelined, so its idle gap is
+``gap_s``. ``stats()`` ``waits`` keeps the stalls by cause and the five
+longest pulls, and survives ``close()``. Every span is also a
+``vlog:<name>`` annotation in a
 profiler capture (``obs/trace.py``), so device idle gaps can be named by
 phase (``obs/profiler.py::summarize``). Jobs keep their own spans: the
 daemon wraps an attempt in ``worker.transcribe`` and
@@ -74,7 +84,7 @@ from vlog_tpu.asr import mel as melmod
 from vlog_tpu.asr.load import WhisperAssets, load_whisper
 from vlog_tpu.asr.queue import BatchKey, WindowQueue, WorkItem
 from vlog_tpu.asr.vtt import Cue
-from vlog_tpu.obs import trace
+from vlog_tpu.obs import hostwait, trace
 from vlog_tpu.parallel import compile_cache
 from vlog_tpu.parallel.engine_host import HOST, HeldLease
 from vlog_tpu.utils import failpoints
@@ -196,6 +206,7 @@ class AsrEngine:
         # for tests/stats, timing for whoever asks where a tick went.
         self.batch_log: list[dict] = []         # guarded-by: _lock
         self.windows_decoded = 0                # guarded-by: _lock
+        self.waits = hostwait.WaitBook()        # guarded-by: _lock
         self._trace = trace.TraceContext(trace.new_id(), None,
                                          trace.TraceBuffer())
         # tick thread only
@@ -220,6 +231,7 @@ class AsrEngine:
             self._jobs[job] = handle
             if not self._started:
                 self._started = True
+                hostwait.GC.install()       # once a process
                 self._thread = trace.start_thread(
                     self._trace, self._run, name="vlog-asr-engine")
         return handle
@@ -251,7 +263,8 @@ class AsrEngine:
             return {"batches": batches, "windows": self.windows_decoded,
                     "mean_occupancy": occ,
                     "pending": self._queue.pending(),
-                    "kv_pool": kv_pool.stats()}
+                    "kv_pool": kv_pool.stats(),
+                    "waits": self.waits.stats()}
 
     def close(self) -> None:
         from vlog_tpu.asr.decode import kv_pool
@@ -413,7 +426,8 @@ class AsrEngine:
             # normal job-failure handling and the tick loop keeps serving.
             self._fail_items(items, exc)
             self._observe_batch_metrics(key, items, rows=0, elapsed=0.0,
-                                        device_wait=0.0, failed=True)
+                                        device_wait=0.0, stall=None,
+                                        failed=True)
             return None
         entry = {
             "rows": rows, "n": n, "occupancy": n / rows,
@@ -427,6 +441,8 @@ class AsrEngine:
         self._seq += 1
         with self._lock:
             self._fold(entry, tick, self._trace.buffer.snapshot())
+            entry["stall"] = None if entry["wait"] is None else \
+                self.waits.add(entry["wait"], key=rows, seq=entry["seq"])
             self.windows_decoded += n
             self.batch_log.append(entry)
             handles = {it.job: self._jobs.get(it.job) for it in items}
@@ -437,7 +453,8 @@ class AsrEngine:
                     h._deliver(it.index, cues, wait_s)
             self._observe_batch_metrics(
                 key, items, rows=rows, elapsed=elapsed,
-                device_wait=entry["phase_s"]["device_wait"], failed=False)
+                device_wait=entry["phase_s"]["device_wait"],
+                stall=entry["stall"], failed=False)
         return entry
 
     def _fold(self, entry: dict, tick: trace.Span,
@@ -447,7 +464,7 @@ class AsrEngine:
         ends. Called under ``_lock`` when the entry is appended (before
         delivery) and again when the tick has closed."""
         phase_s = trace.add_phase_seconds(dict(self._carry_s), spans)
-        generating = dispatched = ready = None
+        generating = dispatched = ready = wait = None
         for sp in spans:
             if sp.name == "asr.tick.generate":
                 generating = sp
@@ -455,6 +472,7 @@ class AsrEngine:
                 dispatched = sp.ended_mono
             elif sp.name == "asr.generate.device_wait":
                 ready = sp.ended_mono
+                wait = sp.attrs.get("wait")
         # a stand-in for decode.generate_batch opens no spans of its own
         entry["t_start"] = tick.started_mono
         entry["t_dispatch"] = (dispatched if dispatched is not None
@@ -462,6 +480,9 @@ class AsrEngine:
         entry["t_ready"] = (ready if ready is not None
                             else generating.ended_mono)
         entry["t_end"] = tick.ended_mono
+        entry["wait"] = wait
+        entry["gc_s"] = None if tick.ended_mono is None else \
+            hostwait.GC.seconds_between(tick.started_mono, tick.ended_mono)
         entry["phase_s"] = phase_s
         entry["gap_s"] = (None if self._prev_ready is None
                           else entry["t_dispatch"] - self._prev_ready)
@@ -476,7 +497,8 @@ class AsrEngine:
 
     def _observe_batch_metrics(self, key: BatchKey, items: list[WorkItem],
                                *, rows: int, elapsed: float,
-                               device_wait: float, failed: bool) -> None:
+                               device_wait: float, stall: str | None,
+                               failed: bool) -> None:
         try:
             from vlog_tpu.obs.metrics import runtime
 
@@ -496,6 +518,8 @@ class AsrEngine:
             # pull), not the host's whole tick: stacking, mel dispatch
             # and a first shape's compile are no device seconds
             m.device_seconds.labels("asr", "forward").inc(device_wait)
+            if stall is not None:
+                m.engine_stalls.labels("asr", stall).inc()
             now = time.monotonic()
             for it in items:
                 m.asr_queue_wait.observe(max(0.0, now - it.enqueued_at))

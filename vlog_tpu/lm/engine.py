@@ -53,6 +53,21 @@ layer would have; from the device, read out with the tokens) or, for a
 model with hyper-connections, ``hc_defect`` (the largest distance of a
 row or column sum of any ``H_res`` of the step from 1; from the device),
 ``build_s``; all instants on ``time.monotonic()``.
+
+Who kept the chip waiting (``obs/hostwait.py``) is on the same record:
+``wait`` is the record of the pull of the step's tokens (``polls``,
+``gap_max_s``, ``cpu_s``, ``gc_s``, ``wait_s``, ``ready_max_s``,
+``copy_s``), ``gc_s`` the process's
+collection seconds between ``t_start`` and ``t_end``, ``stall`` the
+cause of a stalled pull (``gc``, ``host``, ``runtime``) or ``None``, and
+``idle_before_s`` how long the device stood idle before this step
+because the host had not dispatched it: the plan asks the step in flight
+``is_ready()`` after admit, after pages, after stack and before the
+dispatch, and this runs from the first check that found it done to
+``t_dispatch`` (0.0 when it was still running; ``None`` when no step was
+in flight). It is a lower bound of the idle the host caused. ``stats()``
+``waits`` keeps the stalls by cause and the five longest pulls of the
+engine's life, and survives ``close()``.
 """
 
 from __future__ import annotations
@@ -67,12 +82,13 @@ from vlog_tpu.lm.cache import PagedCache, SeqPages
 from vlog_tpu.lm.load import LmAssets
 from vlog_tpu.lm.model import (Geometry, build_step, empty_cache,
                                plan_shapes, unpack_ints)
-from vlog_tpu.obs import trace
+from vlog_tpu.obs import hostwait, trace
 from vlog_tpu.parallel import compile_cache
 from vlog_tpu.parallel.engine_host import HOST, HeldLease
 
 PHASES = ("admit", "pages", "stack", "dispatch", "device_wait", "deliver")
 THREAD = "vlog-lm-engine"
+POLL_S = 2e-4           # the pull's poll of the step's tokens
 
 
 class LmJobError(RuntimeError):
@@ -141,6 +157,7 @@ class LmEngine:
         self.attn_rows_form: str | None = None
         self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
         self.hc_defect_max = 0.0                    # guarded-by: _lock
+        self.waits = hostwait.WaitBook()            # guarded-by: _lock
         # engine thread only
         self._cache: PagedCache | None = None
         self._kv = None
@@ -152,6 +169,7 @@ class LmEngine:
         self._seq = 0
         self._prev_end: float | None = None
         self._prev_ready: float | None = None
+        self._idle_from: float | None = None    # step in flight seen done
 
     # callers ----------------------------------------------------------
 
@@ -221,7 +239,8 @@ class LmEngine:
         whose chunk attended in each form, ``attn_rows_form`` the form of
         the rows, and
         ``hc_defect_max`` the largest ``hc_defect`` of any step (0.0 for
-        a model with one residual stream)."""
+        a model with one residual stream), ``waits`` the pulls' stalls by
+        cause and the five longest (``obs/hostwait.py::WaitBook``)."""
         cache = self._cache
         with self._lock:
             return {"steps": len(self.step_log),
@@ -235,7 +254,8 @@ class LmEngine:
                                   "rows": self.pool_wait_rows},
                     "attn": {f"{form}_steps": n
                              for form, n in self.attn_steps.items()},
-                    "attn_rows_form": self.attn_rows_form}
+                    "attn_rows_form": self.attn_rows_form,
+                    "waits": self.waits.stats()}
 
     def close(self) -> None:
         self._stop.set()
@@ -251,6 +271,7 @@ class LmEngine:
         if self._started:
             return
         self._started = True
+        hostwait.GC.install()           # once a process
         self._thread = trace.start_thread(self._trace, self._run,
                                           name=THREAD)
 
@@ -310,6 +331,7 @@ class LmEngine:
         deliver step ``n - 1``."""
         built0 = compile_cache.thread_built_s()
         record = None
+        self._idle_from = None
         with trace.span("lm.step") as top:
             with trace.span("lm.step.admit"):
                 with self._lock:
@@ -324,12 +346,14 @@ class LmEngine:
                     return
                 if not self._hold.acquire(self._stop):
                     return
+            self._probe()
             try:
                 step = self._plan()
                 if step is not None:
                     record, plan = step
                     with trace.span("lm.step.dispatch"):
                         program = self.programs[record["chunk"]]
+                        self._probe()
                         self._kv, self._last_tok, out = program(
                             self.assets.params, self._kv, self._last_tok,
                             plan)
@@ -337,6 +361,10 @@ class LmEngine:
                     record["gap_s"] = (
                         None if self._prev_end is None
                         else record["t_dispatch"] - self._prev_end)
+                    record["idle_before_s"] = (
+                        None if self._flight is None
+                        else 0.0 if self._idle_from is None
+                        else record["t_dispatch"] - self._idle_from)
                 prev, self._flight = self._flight, (
                     None if step is None else (record, out))
                 if prev is not None:
@@ -355,6 +383,7 @@ class LmEngine:
         if prev is not None:
             done = prev[0]
             done["t_end"] = now
+            done["gc_s"] = hostwait.GC.seconds_between(done["t_start"], now)
             # the pull and the delivery of a step happen one iteration
             # after its plan: fold them into the step they belong to
             mine = self._phases(spans)
@@ -371,9 +400,20 @@ class LmEngine:
                     self.attn_steps[form] = self.attn_steps.get(form, 0) + 1
                 self.hc_defect_max = max(self.hc_defect_max,
                                          done.get("hc_defect", 0.0))
+                done["stall"] = self.waits.add(done["wait"],
+                                               key=done["chunk"],
+                                               seq=done["seq"])
             self._observe(done)
         if self._flight is None:
             self._hold.yield_full_mesh()
+
+    def _probe(self) -> None:
+        """A phase boundary of the plan: note when the step in flight
+        is first seen done (``idle_before_s``; at most four calls a
+        step)."""
+        if self._idle_from is None and self._flight is not None \
+                and self._flight[1]["ints"].is_ready():
+            self._idle_from = time.monotonic()
 
     @staticmethod
     def _phases(spans) -> dict:
@@ -444,6 +484,7 @@ class LmEngine:
                 pos = req.prompt.size + req.planned - 1
                 freed += req.pages.trim(pos)
                 req.pages.extend(pos + 1)
+        self._probe()
         with trace.span("lm.step.stack"):
             shapes = plan_shapes(self.cfg, geo, bucket)
             plan = {k: np.zeros(s, d) for k, (s, d) in shapes.items()}
@@ -482,6 +523,7 @@ class LmEngine:
                     emitted.append((pre, r))
                     pre.planned = 1
                     self._prefilling = None
+        self._probe()
         record = {"seq": self._seq, "chunk": bucket,
                   "decode_rows": len(deco), "prefill_tokens": n,
                   "row_pos": plan["row_pos"][plan["row_active"]].tolist(),
@@ -515,11 +557,11 @@ class LmEngine:
             # in seven lost 2.2 to 2.5 s in ONE blocking pull of these
             # counters while the device had long finished this step and
             # the next (3 of 19 runs; none of 8 polled; my chip runs,
-            # PR 33: not proof, PERF.md section 7)
-            pending = out["ints"]
-            while not pending.is_ready():
-                time.sleep(2e-4)
-            ints = unpack_ints(self.cfg, self.geo, np.asarray(pending))
+            # PR 33: not proof, PERF.md section 7); the wait record says
+            # which of the thread, a collection or the runtime held it
+            (ints,), record["wait"] = hostwait.pull((out["ints"],),
+                                                    poll_s=POLL_S)
+            ints = unpack_ints(self.cfg, self.geo, ints)
         ready = time.monotonic()
         record["t_ready"] = ready
         begun = record["t_dispatch"] if self._prev_ready is None \
@@ -592,8 +634,11 @@ class LmEngine:
         try:
             from vlog_tpu.obs.metrics import runtime
 
-            runtime().device_seconds.labels("lm", "step").inc(
+            m = runtime()
+            m.device_seconds.labels("lm", "step").inc(
                 record["phase_s"]["device_wait"])
+            if record["stall"] is not None:
+                m.engine_stalls.labels("lm", record["stall"]).inc()
         except Exception:  # noqa: BLE001 — metrics never break serving
             pass
 

@@ -33,6 +33,7 @@ from typing import Any
 try:
     from prometheus_client import (CollectorRegistry, Counter, Gauge,
                                    Histogram, generate_latest)
+    from prometheus_client.core import CounterMetricFamily
     HAVE_PROMETHEUS = True
 except ImportError:  # pragma: no cover — exercised only in minimal envs
     # This module is imported by the whole job plane (claims, workers,
@@ -65,6 +66,8 @@ except ImportError:  # pragma: no cover — exercised only in minimal envs
 
     def generate_latest(_registry) -> bytes:       # type: ignore[no-redef]
         return b""
+
+    CounterMetricFamily = None                     # type: ignore[misc]
 
 from vlog_tpu import config
 from vlog_tpu.obs.trace import STAGE_KEYS
@@ -393,6 +396,20 @@ class RuntimeMetrics:
             "next to host_busy_s/host_occupancy for the d2h-vs-compute "
             "split",
             ["plane", "rung"], registry=self.registry)
+        # Who kept the chip waiting (obs/hostwait.py): a model engine's
+        # pull that exceeded its recent median by 0.2 s or more, by the
+        # cause its wait record names, and the process's collections
+        self.engine_stalls = Counter(
+            "vlog_engine_stalls_total",
+            "Model-engine pulls of a step's or tick's results that waited "
+            "0.2 s or more past the median of the engine's recent pulls, "
+            "by plane (lm, asr) and cause (gc: collections covered half "
+            "the excess; runtime: one is_ready() call or the copy took "
+            "half, or the device said not ready; host: the waiting "
+            "thread was away that long outside those calls)",
+            ["plane", "cause"], registry=self.registry)
+        if CounterMetricFamily is not None:
+            self.registry.register(_GcPauses())
         self.slo_error_ratio = Gauge(
             "vlog_slo_error_ratio",
             "Fraction of an objective's events outside its threshold "
@@ -446,6 +463,25 @@ class RuntimeMetrics:
 
     def render_text(self) -> str:
         return generate_latest(self.registry).decode()
+
+
+class _GcPauses:
+    """``vlog_gc_pause_seconds_total{generation}``, read at scrape time
+    from ``obs/hostwait.py::GC``: the recorder's callback runs inside a
+    collection and must not take a counter's lock."""
+
+    def collect(self):
+        from vlog_tpu.obs.hostwait import GC
+
+        fam = CounterMetricFamily(
+            "vlog_gc_pause_seconds",
+            "Seconds the process spent in garbage collection (every "
+            "thread stopped), by generation; recorded once a model "
+            "engine has started",
+            labels=["generation"])
+        for gen, seconds in enumerate(GC.seconds):
+            fam.add_metric([str(gen)], seconds)
+        yield fam
 
 
 _runtime: RuntimeMetrics | None = None

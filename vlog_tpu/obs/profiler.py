@@ -58,6 +58,10 @@ GAP_NS = 50_000             # shorter device gaps are launch spacing
 EDGE_NS = 1_000_000         # a program run this close to an end may be cut
 TOP_PROGRAMS = 8
 ANNOTATION = "vlog:"
+# the root span of a model engine's cycle: the line (thread) that holds
+# one is the engine's (a capture names every Python thread by the
+# process, "python3", not by the thread's own name)
+ENGINE_ROOTS = frozenset({"lm.step", "asr.tick"})
 # the program's named scopes in an op's framework name, outermost first:
 # "jit(f)/jit(main)/asr.decoder_step/asr.decoder_step.mlp/dot_general",
 # under a transform "jit(f)/while/body/vmap(ladder.mc)/gather"
@@ -224,9 +228,11 @@ def summarize(xplane_path: str | Path) -> dict:
       profiler's default; without them every op is ``unscoped``). A
       fusion carries one name, so its seconds go to the scope of the op
       XLA named it after;
-    - ``idle_by_span``: device gaps over 50 us summed under the
-      innermost (shortest) ``vlog:`` annotation of any host thread that
-      covers their middle, else ``no_program_span``;
+    - ``idle_by_span``: device gaps over 50 us summed under the ``vlog:``
+      annotation that covers their middle (:func:`idle_owner`: a
+      collection's ``gc.gen<N>`` first, then the innermost span of a
+      model engine's thread, then the innermost of any thread), else
+      ``no_program_span``;
     - ``programs``: for the eight compiled programs ("XLA Modules"
       line) that took most device time, their runs that lie wholly
       inside the capture, those runs' seconds and the same by-scope
@@ -236,14 +242,23 @@ def summarize(xplane_path: str | Path) -> dict:
 
     The arithmetic is ``benchmark/harness/trace.py``'s, kept apart from
     it: the yardstick does not import the program, nor the program it.
-    Reads the file with nothing but jax."""
+    Reads the file with nothing but jax; :func:`summarize_planes` is the
+    reduction of what it read."""
+    return summarize_planes(_load_planes(xplane_path))
+
+
+def _load_planes(xplane_path: str | Path) -> list[dict]:
+    """A capture as plain planes, ``[{"name", "lines": [{"name",
+    "events": [(start_ns, end_ns, name)]}]}]``: a device plane's "XLA
+    Ops" events named by their scope (the program that ran them, from
+    its "XLA Modules" line, and its HLO protos) and its "XLA Modules"
+    events; a host plane's lines with their ``vlog:`` annotations alone,
+    the prefix dropped."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(str(xplane_path))
     by_program = _program_scopes(Path(xplane_path).read_bytes())
-    device_lines: list[list[tuple]] = []    # leaf candidates per device
-    module_lines: list[list[tuple]] = []    # program runs, beside them
-    spans: list[tuple[int, int, str]] = []
+    planes = []
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             lines = {line.name: line for line in plane.lines}
@@ -268,31 +283,68 @@ def summarize(xplane_path: str | Path) -> dict:
                     scope = scopes[(program, ev.name)] = by_program.get(
                         program, {}).get(instruction, "unscoped")
                 events.append((start, start + int(ev.duration_ns), scope))
-            if not events:
-                continue
-            device_lines.append(events)
-            module_lines.append([(s, e, name.split("(", 1)[0])
-                                 for s, e, name in runs])
+            planes.append({"name": plane.name, "lines": [
+                {"name": "XLA Ops", "events": events},
+                {"name": "XLA Modules", "events": runs}]})
         elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(ANNOTATION):
-                        start = int(ev.start_ns)
-                        spans.append((start, start + int(ev.duration_ns),
-                                      ev.name[len(ANNOTATION):]))
+            planes.append({"name": plane.name, "lines": [
+                {"name": line.name, "events": [
+                    (int(ev.start_ns),
+                     int(ev.start_ns) + int(ev.duration_ns),
+                     ev.name[len(ANNOTATION):])
+                    for ev in line.events if ev.name.startswith(ANNOTATION)]}
+                for line in plane.lines]})
+    return planes
+
+
+def idle_owner(at: int, spans: list[tuple[int, int, str, bool]]) -> str:
+    """The span a device gap whose middle is ``at`` is booked to.
+    ``spans``: ``(start_ns, end_ns, name, of_an_engine_thread)`` sorted by
+    start. A collection (``gc.gen<N>``) stops every thread, so it wins
+    wherever it covers ``at``; else the innermost span of a model
+    engine's thread, which is what the device waits for, over a shorter
+    span of a bystander thread; else the innermost of any thread."""
+    best: dict[str, tuple[int, str]] = {}
+    for s, e, name, engine in spans:
+        if s > at:
+            break
+        if e < at:
+            continue
+        for kind, hit in (("gc", name.startswith("gc.")), ("engine", engine),
+                          ("any", True)):
+            if hit and (kind not in best or e - s < best[kind][0]):
+                best[kind] = (e - s, name)
+    for kind in ("gc", "engine", "any"):
+        if kind in best:
+            return best[kind][1]
+    return "no_program_span"
+
+
+def summarize_planes(planes: list[dict]) -> dict:
+    """:func:`summarize` of planes as :func:`_load_planes` returns them
+    (device op events already named by scope)."""
+    device_lines: list[list[tuple]] = []    # leaf candidates per device
+    module_lines: list[list[tuple]] = []    # program runs, beside them
+    spans: list[tuple[int, int, str, bool]] = []
+    for plane in planes:
+        lines = {line["name"]: line["events"] for line in plane["lines"]}
+        if plane["name"].startswith("/device:"):
+            if not lines.get("XLA Ops"):
+                continue
+            device_lines.append(list(lines["XLA Ops"]))
+            module_lines.append([(s, e, name.split("(", 1)[0])
+                                 for s, e, name in lines.get(
+                                     "XLA Modules", ())])
+        elif plane["name"].startswith("/host:"):
+            for line in plane["lines"]:
+                engine = any(name in ENGINE_ROOTS
+                             for _s, _e, name in line["events"])
+                spans.extend((s, e, name, engine)
+                             for s, e, name in line["events"])
     spans.sort()
     edges = [t for events in device_lines for s, e, _ in events
-             for t in (s, e)] + [t for s, e, _ in spans for t in (s, e)]
+             for t in (s, e)] + [t for s, e, _, _ in spans for t in (s, e)]
     lo, hi = (min(edges), max(edges)) if edges else (0, 0)
-
-    def covering(at: int) -> str:
-        best = None
-        for s, e, name in spans:
-            if s > at:
-                break
-            if e >= at and (best is None or e - s < best[0]):
-                best = (e - s, name)
-        return best[1] if best else "no_program_span"
 
     busy_ns = 0
     scope_ns: dict[str, int] = {}
@@ -300,7 +352,7 @@ def summarize(xplane_path: str | Path) -> dict:
 
     def gap(a: int, b: int) -> None:
         if b - a > GAP_NS:
-            name = covering((a + b) // 2)
+            name = idle_owner((a + b) // 2, spans)
             gap_ns[name] = gap_ns.get(name, 0) + (b - a)
 
     programs: dict[str, dict] = {}
