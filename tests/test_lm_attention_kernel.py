@@ -7,10 +7,13 @@ float32 rounding. One test compiles both families' ``lm_step_c2048`` at
 the cells' shapes for a described v5e (no chip attached, nothing runs)
 and reads the optimized HLO: the chunk's attention is a Mosaic custom
 call under its layer's scope, the pools reach it without a copy, and no
-float32 array of chunk x block keys x heads is left in the step. A
-second reads Trinity's ``lm_step_c0`` and ``lm_step_c2048`` for the
-rows' kernel: one custom call a layer, no ``while`` left under the
-attention's scopes.
+float32 array of chunk x block keys x heads is left in the step; in
+Keye's, the chunk's choice of keys is one custom call a layer between
+the index scores and the attention's kernel, with no array of ordered
+bits or boolean mask left. A second reads Trinity's ``lm_step_c0`` and
+``lm_step_c2048`` for the rows' kernel: one custom call a layer, no
+``while`` left under the attention's scopes. The choice's kernel is
+held to ``model.py::select_keys`` bit for bit, ties included.
 
 The topology is described inside a fixture (the TPU's library belongs
 to one process at a time; tests/test_beam_cache_layout.py and
@@ -263,6 +266,130 @@ def test_the_rows_form_of_kv_pages_is_read_from_the_call(monkeypatch):
 
 
 # --------------------------------------------------------------------------
+# the chunk's choice of keys: the kernel against select_keys, bit for bit
+# --------------------------------------------------------------------------
+
+def _choice_case(seed, nq, width, p0, n, *, ties=False, tie_rows=None):
+    """Index scores of a chunk of ``nq`` queries from ``p0`` of which
+    ``n`` are real (``n_keys = p0 + n``), as ``index_scores`` leaves them:
+    ``-inf`` past a query's position and past ``n_keys``, never -0.0.
+    With ``ties`` the scores take a few values, in ``tie_rows`` alone
+    where given."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(nq, width)).astype(np.float32)
+    if ties:
+        rows = slice(None) if tie_rows is None else tie_rows
+        x[rows] = np.round(x[rows] * 2) / 2
+        x[x == 0.0] = 0.0
+    qpos = p0 + np.arange(nq)
+    col = np.arange(width)[None, :]
+    x[(col > qpos[:, None]) | (col >= p0 + n)] = -np.inf
+    return x, p0 + n
+
+
+def _straddles(x, top):
+    """Per query: do ties at its ``top``-th best score straddle the cut?"""
+    out = []
+    for row in x:
+        keys = row[np.isfinite(row)]
+        if keys.size <= top:
+            out.append(False)
+            continue
+        kth = np.sort(keys)[::-1][top - 1]
+        out.append((keys > kth).sum() < top < (keys >= kth).sum())
+    return np.asarray(out)
+
+
+def _both_choices(x, top, p0, n_keys, *, block, q_tile):
+    """``select_keys`` (the loop) and the kernel in the interpreter."""
+    want = np.asarray(lm_model.select_keys(
+        jnp.asarray(x)[None], top, jnp.int32(n_keys), block=block))[0]
+    got = np.asarray(attention_kernel.select_keys_kernel(
+        jnp.asarray(x), top, jnp.int32(p0), jnp.int32(n_keys), block=block,
+        q_tile=q_tile, interpret=True))
+    assert got.dtype == np.int8 and set(np.unique(got)) <= {0, 1}
+    assert (got.astype(bool) == want).all()
+    return got.astype(bool)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("top", [1, 4, 16, 41, 64])
+def test_the_choice_kernel_equals_the_loop(top, ties):
+    """Every ``top`` of ``test_the_chosen_set_is_lax_top_ks``, with and
+    without ties: 64 queries from position 20 over 80 keys, so the first
+    rows hold fewer keys than the larger ``top`` (all of them chosen)
+    and the last more; ``n_keys`` ends inside the first block."""
+    x, n_keys = _choice_case(top, 64, 512, 20, 60, ties=ties)
+    got = _both_choices(x, top, 20, n_keys, block=128, q_tile=32)
+    assert (got.sum(1) == np.minimum(top, np.isfinite(x).sum(1))).all()
+    if ties and top > 1:
+        assert _straddles(x, top).any()
+
+
+CHOICES = {
+    # (seed, nq, width, p0, n, q_tile, ties, tie rows, top)
+    # a row of nothing but equal scores beside rows of ties
+    "all_ties": (1, 64, 512, 150, 64, 32, True, None, 16),
+    # eight tiles of 32 whose causal ends fall in the first, second and
+    # third block of 128; n_keys (308) not a multiple of the block, the
+    # last tile's last queries padding, the fourth block past every tile
+    "tiles_in_blocks": (2, 256, 512, 60, 248, 32, False, None, 24),
+    # a short chunk: queries past n are padding, n_keys ends mid-block
+    "short_chunk": (3, 64, 1024, 300, 37, 32, True, None, 50),
+    # two tiles of 64 (two row groups each): rows of a chunk's very start
+    # (fewer keys than top) beside rows that hold more
+    "rows_under_top": (4, 128, 512, 0, 128, 64, False, None, 96),
+}
+
+
+@pytest.mark.parametrize("case", CHOICES)
+def test_the_choice_kernel_over_tiles_and_blocks(case):
+    """The kernel's tiles each read their own causal blocks: what lies
+    past a tile's last block, past a query's position and past
+    ``n_keys`` reads 0, and what is chosen is ``select_keys``' set."""
+    seed, nq, width, p0, n, tq, ties, tie_rows, top = CHOICES[case]
+    x, n_keys = _choice_case(seed, nq, width, p0, n, ties=ties,
+                             tie_rows=tie_rows)
+    if case == "all_ties":
+        x[0, np.isfinite(x[0])] = 0.5
+    got = _both_choices(x, top, p0, n_keys, block=128, q_tile=tq)
+    qpos = p0 + np.arange(nq)
+    col = np.arange(width)[None, :]
+    assert not got[(col > qpos[:, None]) | (col >= n_keys)].any()
+    assert (got.sum(1) == np.minimum(top, np.isfinite(x).sum(1))).all()
+    if case == "all_ties":          # the first ``top`` positions win
+        assert got[0].nonzero()[0].tolist() == list(range(top))
+
+
+def test_a_tie_straddles_the_cut_in_one_tile_and_not_the_next():
+    """Ties in the first tile's rows alone: the bisection over positions
+    runs there and not in the second tile, and both come out as the
+    loop's."""
+    x, n_keys = _choice_case(5, 64, 512, 200, 64, ties=True,
+                             tie_rows=slice(0, 32))
+    straddles = _straddles(x, 40)
+    assert straddles[:32].any() and not straddles[32:].any()
+    _both_choices(x, 40, 200, n_keys, block=128, q_tile=32)
+
+
+def test_the_choice_form_is_read_from_the_call(monkeypatch):
+    """A chunk on a TPU chooses in the kernel where Mosaic tiles its
+    shapes and a tile's row fits VMEM; any other backend, and shapes
+    the kernel does not tile, take ``select_keys``."""
+    form = lm_model.select_form
+    assert form(2048, 40960, 1024) == "loop"                   # the CPU
+    monkeypatch.setattr(lm_model.jax, "default_backend", lambda: "tpu")
+    assert form(2048, 40960, 1024) == "kernel"
+    assert form(256, 40960, 1024) == "kernel"
+    assert form(2048, 4096, 512) == "kernel"
+    assert form(16, 64, 8) == "loop"                # the tests' tiny model
+    assert form(48, 40960, 1024) == "loop"          # no whole int8 tile
+    assert form(2048, 40960 + 512, 1024) == "loop"  # not whole blocks
+    assert form(2048, 40960, 1000) == "loop"        # not whole lane blocks
+    assert form(2048, 1 << 20, 1024) == "loop"      # a row past VMEM
+
+
+# --------------------------------------------------------------------------
 # what the TPU compiler makes of the cells' chunk step
 # --------------------------------------------------------------------------
 
@@ -368,6 +495,24 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     left = {m.group(0) for m in re.finditer(r"f32\[([0-9,]+)\]", text)
             if math.prod(int(d) for d in m.group(1).split(",")) == scores}
     assert not left
+
+    # the chunk's choice of keys: one kernel a layer, fed the index
+    # scores as they lie and feeding the attention's kernel its mask; no
+    # array of ordered bits and no boolean mask is left to relay
+    assert step.attn_select_form == ("kernel" if cfg.index_topk else None)
+    choices = [ln for ln in _custom_calls(text, "lm_select_keys")
+               if "/lm.attn.select/lm_select_keys" in ln]
+    assert len(choices) == (cfg.num_layers if cfg.index_topk else 0)
+    for ln in choices:
+        assert 'custom_call_target="tpu_custom_call"' in ln
+        assert " bitcast(" in _operands(text, ln)[-1]
+    for ln in calls if cfg.index_topk else ():
+        mask = _operands(text, ln)[-1]
+        assert "= s8[" in mask and " custom-call(" in mask \
+            and "/lm_select_keys" in mask
+    row = f"{geo.chunk},{geo.key_width}]"
+    assert not [ln for ln in text.splitlines()
+                if f"u32[1,{row}" in ln or f"pred[{row}" in ln]
 
 
 @pytest.mark.parametrize("chunk", [0, 2048])

@@ -210,28 +210,87 @@ def test_an_engine_thread_starting_installs_the_recorder(model, monkeypatch):
             hostwait.GC.install()
 
 
-def test_the_step_record_says_which_form_the_chunk_attended_in(model):
-    """``attn_chunk_form`` is fixed when the bucket's program is built
-    (``model.py::attention_form``): on this backend every chunk attends
-    in the loop; a step without a chunk has none."""
-    _hf, cfg, params = model
+@pytest.mark.parametrize("family", ["afmoe", "keye", "xing"])
+def test_the_step_record_says_which_form_the_chunk_attended_in(model,
+                                                               family):
+    """``attn_chunk_form`` and ``attn_select_form`` are fixed when the
+    bucket's program is built (``model.py::attention_form``,
+    ``select_form``): on this backend every chunk attends and a model
+    with an indexer chooses its keys in the loop; a step without a chunk
+    has neither, nor has a model without an indexer a choice."""
+    from lm_helpers import tiny_keye, tiny_xing
+
+    _hf, cfg, params = {"afmoe": lambda: model, "keye": tiny_keye,
+                        "xing": tiny_xing}[family]()
     eng = engine(cfg, params, rows=4)
     try:
         for r in [eng.submit(np.arange(n) % 512, max_new=4)
                   for n in (21, 5)]:
             r.wait(300)
         log, stats = list(eng.step_log), eng.stats()
-        assert eng.attn_forms == {b: "loop" if b else None
+        chunk_form = "latent_expanded_loop" if family == "xing" else "loop"
+        assert eng.attn_forms == {b: chunk_form if b else None
                                   for b in eng.geo.chunk_buckets()}
+        choice = "loop" if family == "keye" else None
+        assert eng.select_forms == {b: choice if b else None
+                                    for b in eng.geo.chunk_buckets()}
     finally:
         eng.close()
     with_chunk = [rec for rec in log if rec["chunk"]]
     assert with_chunk and len(with_chunk) < len(log)
-    assert {rec["attn_chunk_form"] for rec in with_chunk} == {"loop"}
-    assert all(rec["attn_chunk_form"] is None for rec in log
-               if not rec["chunk"])
-    assert stats["attn"] == {"kernel_steps": 0,
-                             "loop_steps": len(with_chunk)}
+    assert {rec["attn_chunk_form"] for rec in with_chunk} == {chunk_form}
+    assert {rec["attn_select_form"] for rec in with_chunk} == {choice}
+    assert all(rec["attn_chunk_form"] is None
+               and rec["attn_select_form"] is None
+               for rec in log if not rec["chunk"])
+    counts = {"kernel_steps": 0, "loop_steps": 0,
+              f"{chunk_form}_steps": len(with_chunk)}
+    if choice:      # the choices add up to the chunk steps
+        counts["select_loop_steps"] = len(with_chunk)
+    assert stats["attn"] == counts
+
+
+def test_keyes_engine_serves_the_same_tokens_with_the_choice_kernel(
+        monkeypatch):
+    """With ``select_form`` steered to the kernel wherever Mosaic would
+    tile the bucket (Pallas's interpreter in the chip's place; pages of
+    32 in blocks of 128 keys, so buckets of 32 and 64 queries take it),
+    every chunk step records it and the engine serves the tokens the
+    loop serves: prompts over the 16 keys kept, across blocks."""
+    import functools
+
+    from lm_helpers import tiny_keye
+
+    from vlog_tpu.lm import attention_kernel
+    from vlog_tpu.lm import model as lm_model
+
+    _hf, cfg, params = tiny_keye()
+    prompts = [np.arange(n) % 512 for n in (150, 40, 300)]
+
+    def served():
+        eng = engine(cfg, params, rows=4, chunk=64, page=32, block=4,
+                     cap=512)
+        try:
+            tokens = [r.wait(600) for r in [
+                eng.submit(p, max_new=6) for p in prompts]]
+            return tokens, list(eng.step_log), eng.stats()
+        finally:
+            eng.close()
+
+    want, log, stats = served()
+    chunks = sum(1 for rec in log if rec["chunk"])
+    assert stats["attn"]["select_loop_steps"] == chunks
+    monkeypatch.setattr(lm_model, "select_form", lambda nq, width, block: (
+        "kernel" if attention_kernel.select_supported(nq, width, block)
+        else "loop"))
+    monkeypatch.setattr(
+        attention_kernel, "select_keys_kernel", functools.partial(
+            attention_kernel.select_keys_kernel, interpret=True))
+    got, log, stats = served()
+    assert {rec["attn_select_form"] for rec in log if rec["chunk"]} \
+        == {"kernel"}
+    assert stats["attn"]["select_kernel_steps"] == chunks
+    assert got == want
 
 
 @pytest.mark.parametrize("form", ["loop", "rows_kernel"])

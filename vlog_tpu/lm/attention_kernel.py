@@ -1,5 +1,6 @@
-"""The kernel forms of ``model.py``'s attention over paged pools: four
-Mosaic kernels, each the loop's mathematics at the loop's precision.
+"""The kernel forms of ``model.py``'s attention over paged pools and of
+its sparse attention's choice of keys: five Mosaic kernels, each the
+loop's mathematics at the loop's precision.
 
 **The chunk** (:func:`chunk_attention`, ``paged_attention``'s form for a
 prefill chunk). One sequence, ``Q`` consecutive query positions, keys
@@ -77,6 +78,33 @@ through that row's table and runs the row's 32 heads against it (scores
 and the value product against the same bytes); a row's blocks past its
 last page neither compute nor fetch, so a step's work is the sum of the
 rows' contexts and not 32 times the longest (the loop's).
+
+**The choice of keys** (:func:`select_keys_kernel`, ``model.py::
+select_keys`` for a prefill chunk): each query keeps its ``top`` best
+index scores, exactly, ties to the lower position. The grid is ``(query
+tiles, key blocks)``, the key blocks innermost and as many as the
+chunk's last query reads (a dynamic bound); past a tile's last causal
+block its index map stays on that block, so it fetches nothing. A step
+turns its ``(SELECT_Q_TILE, block)`` float32 scores into int32 keys
+whose signed order is the scores' order and keeps them in a ``(blocks,
+SELECT_Q_TILE, block)`` VMEM scratch: the scores cross HBM once, and
+nothing else does but the mask. At the tile's last step the search runs
+over the scratch for all of the tile's queries together (a pass ends
+in a reduction the next one waits for, so the tile runs one chain of
+passes, not one a group of 32 queries): the k-th key one bit a pass, 32
+passes (a pass compares every live key of the tile against its query's
+candidate and counts, lane-wise partial sums reduced once a pass),
+then, only in a tile where ties at the k-th straddle the cut, a
+bisection over the position (16 passes at 40,960 keys) that keeps the
+lower-positioned ties; then the tile's mask is written as the chunk's
+kernel reads it, int8 ``(queries, keys)`` keys-minor, zeros past the
+tile's live blocks.
+
+Sized on the chip (PERF.md, section 6): four groups of 32 queries
+searched one after another read 2.1 ms a layer at 18k keys (the loop:
+19.8), 1.1 ms of it fixed, the chains of dependent passes; two bits a
+pass (three compares a key) read slower past 8k keys, where the vector
+unit and not the loads bounds a pass.
 """
 
 from __future__ import annotations
@@ -667,3 +695,172 @@ def latent_rows_attention(q: jax.Array, last_pos: jax.Array, pool: jax.Array,
         name="lm_latent_rows_attention",
     )(table.reshape(-1).astype(jnp.int32), last_pos.astype(jnp.int32), q,
       *([pool.reshape(-1, page)] * bp))
+
+
+# --------------------------------------------------------------------------
+# the choice of keys: a chunk's top scores, searched in VMEM
+# --------------------------------------------------------------------------
+
+SELECT_Q_TILE = 128     # queries a tile of the choice, searched together
+LANES = 128
+SIGN = -(1 << 31)       # int32 with the sign bit alone
+
+
+def _ordered(bits: jax.Array) -> jax.Array:
+    """float32 bits as int32 whose signed order is the floats' order."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+NO_KEY = -(1 << 31) + 0x7FFFFF  # ``_ordered`` of -inf's bits (0xFF800000)
+
+
+def select_supported(nq: int, width: int, block: int) -> bool:
+    """Shapes Mosaic tiles, in the VMEM a tile may hold: the chunk whole
+    query tiles of whole int8 mask tiles, a block whole lane blocks, the
+    score row whole blocks; a tile's row of keys as int32 and its mask,
+    twice, under three quarters of ``VMEM_LIMIT``."""
+    tq = min(nq, SELECT_Q_TILE)
+    held = tq * width * (4 + 2) + 2 * tq * block * 4
+    return nq % tq == 0 and tq % 32 == 0 and block % LANES == 0 \
+        and width % block == 0 and held <= VMEM_LIMIT * 3 // 4
+
+
+def _select_blocks(meta, qi, *, tq: int, block: int):
+    """The blocks of the score row that hold a key some query of tile
+    ``qi`` may choose (causal, the first ``n_keys`` columns)."""
+    p0, n_keys = meta[0], meta[1]
+    end = jnp.minimum(n_keys, p0 + (qi + 1) * tq)
+    return jnp.maximum(end + block - 1, 0) // block
+
+
+def _select_kernel(meta, s_ref, o_ref, key_s, *, tq: int, block: int,
+                   top: int, width: int):
+    qi, kb = pl.program_id(0), pl.program_id(1)
+    nb = _select_blocks(meta, qi, tq=tq, block=block)
+
+    @pl.when(kb < nb)
+    def _():
+        key_s[kb] = _ordered(lax.bitcast_convert_type(s_ref[...], jnp.int32))
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        _choose(key_s, o_ref, nb, tq=tq, block=block, top=top, width=width)
+
+        def clear(c, carry):
+            # nothing past the tile's last block is a key it may choose
+            o_ref[:, pl.ds(pl.multiple_of(c * block, block), block)] = \
+                jnp.zeros((tq, block), jnp.int8)
+            return carry
+
+        lax.fori_loop(nb, width // block, clear, None)
+
+
+def _choose(key_s, o_ref, nb, *, tq: int, block: int, top: int,
+            width: int):
+    """Find each of the tile's queries' ``top``-th largest key over its
+    ``nb`` live blocks, one bit a pass, then, where ties at it straddle
+    the cut, the position that splits them; write the tile's mask."""
+    shape = (tq, LANES)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def count(pred):
+        """Per query ``(tq, 1)``, the live columns where ``pred(keys,
+        first column)`` holds: one read of the keys, lane-wise partial
+        sums reduced once."""
+        def body(c, acc):
+            for j in range(block // LANES):
+                keys = key_s[c, :, pl.ds(j * LANES, LANES)]
+                acc = acc + jnp.where(pred(keys, c * block + j * LANES), 1, 0)
+            return acc
+        acc = lax.fori_loop(0, nb, body, jnp.zeros(shape, jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def settle(p, carry):
+        """Bit ``31 - p`` of the k-th key (its bits as unsigned; a
+        candidate is compared in the signed order with its sign bit
+        flipped): set where ``top`` keys still reach it; and how many
+        reach the k-th as it stands."""
+        kth, reach = carry
+        cand = kth | (1 << (31 - p))
+        cand_b = jnp.broadcast_to(cand ^ SIGN, shape)
+        n = count(lambda k, _: k >= cand_b)
+        return jnp.where(n >= top, cand, kth), jnp.where(n >= top, n, reach)
+
+    zero = jnp.zeros((tq, 1), jnp.int32)
+    kth, reach = lax.fori_loop(0, 32, settle, (zero, zero))
+    kth = kth ^ SIGN
+    # fewer than ``top`` keys: the k-th is no key, and every key is chosen
+    thr = jnp.maximum(kth, NO_KEY + 1)
+    split = (kth > NO_KEY) & (reach > top)
+    thr_b = jnp.broadcast_to(thr, shape)
+
+    def ties():
+        """The largest position ``P`` at which the keys above the k-th and
+        the ties before ``P`` are still fewer than ``top``: the cut's
+        tie is at ``P``, the ties after it are left out."""
+        def bisect(p, cut):
+            cand = cut | (1 << (bits - 1 - p))
+            cand_b = jnp.broadcast_to(cand, shape)
+            n = count(lambda k, col: (k > thr_b) | (
+                (k == thr_b) & (lane < cand_b - col)))
+            return jnp.where(n < top, cand, cut)
+        bits = max(width - 1, 1).bit_length()
+        return jnp.where(split, lax.fori_loop(0, bits, bisect, zero),
+                         jnp.int32(width))
+
+    cut = lax.cond(jnp.max(split.astype(jnp.int32)) > 0, ties,
+                   lambda: jnp.full((tq, 1), width, jnp.int32))
+    cut_b = jnp.broadcast_to(cut, shape)
+
+    def write(c, carry):
+        for j in range(block // LANES):
+            col = c * block + j * LANES
+            keys = key_s[c, :, pl.ds(j * LANES, LANES)]
+            chosen = (keys > thr_b) | ((keys == thr_b) & (lane <= cut_b - col))
+            o_ref[:, pl.ds(pl.multiple_of(col, LANES), LANES)] = \
+                jnp.where(chosen, 1, 0).astype(jnp.int8)
+        return carry
+
+    lax.fori_loop(0, nb, write, None)
+
+
+def select_keys_kernel(scores: jax.Array, top: int, p0: jax.Array,
+                       n_keys: jax.Array, *, block: int,
+                       q_tile: int = SELECT_Q_TILE,
+                       interpret: bool = False) -> jax.Array:
+    """``model.py::select_keys`` for ONE chunk: ``scores`` (Q, W) float32,
+    query ``i`` at position ``p0 + i``, ``-inf`` at what is no key (past
+    a query's position and past ``n_keys``: ``index_scores``). Returns
+    (Q, W) int8, 1 where chosen: every key of a query that has at most
+    ``top``, else its ``top`` largest, ties to the lower position; the
+    same set as ``select_keys``, bit for bit."""
+    nq, width = scores.shape
+    tq = min(nq, q_tile)
+    meta = jnp.stack([p0, n_keys]).astype(jnp.int32)
+    # as many key blocks as the chunk's last query reads; the interpreter
+    # takes no dynamic bound: there the steps past them do nothing
+    n_blocks = width // block if interpret else \
+        jnp.maximum((n_keys + block - 1) // block, 1)
+
+    def score_block(qi, kb, meta):
+        """Tile ``qi``'s block at step ``kb``: past its last live one it
+        stays on that one (no fetch)."""
+        last = _select_blocks(meta, qi, tq=tq, block=block) - 1
+        return qi, jnp.clip(kb, 0, jnp.maximum(last, 0))
+
+    return pl.pallas_call(
+        functools.partial(_select_kernel, tq=tq, block=block, top=top,
+                          width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nq // tq, n_blocks),
+            in_specs=[pl.BlockSpec((tq, block), score_block)],
+            out_specs=pl.BlockSpec((tq, width), lambda qi, kb, meta: (qi, 0)),
+            scratch_shapes=[pltpu.VMEM((width // block, tq, block),
+                                       jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((nq, width), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="lm_select_keys",
+    )(meta, scores)

@@ -38,7 +38,10 @@ and this step's ``t_dispatch``), ``decode_rows``, ``prefill_tokens``,
 attention took in this bucket's program, ``"kernel"`` or ``"loop"``:
 ``model.py::attention_form``, or for latent attention
 ``"latent_expanded_kernel"`` / ``"latent_expanded_loop"``; ``None`` for
-a step without a chunk), ``attn_rows_form`` (the rows', fixed with the
+a step without a chunk), ``attn_select_form`` (the form in which the
+chunk chose its keys, fixed with the program: ``"kernel"`` or ``"loop"``,
+``model.py::select_form``; ``None`` without a chunk or for a model with
+no indexer), ``attn_rows_form`` (the rows', fixed with the
 program too: ``"rows_kernel"`` or ``"loop"`` from the same function,
 ``"gathered"`` for a model with an indexer, ``"latent_absorbed"`` for
 latent attention), ``rows_context`` (positions
@@ -154,6 +157,7 @@ class LmEngine:
                                          trace.TraceBuffer())
         self.programs: dict[int, object] = {}       # bucket -> compiled
         self.attn_forms: dict[int, str | None] = {}  # bucket -> chunk form
+        self.select_forms: dict[int, str | None] = {}  # bucket -> choice
         self.attn_rows_form: str | None = None
         self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
         self.hc_defect_max = 0.0                    # guarded-by: _lock
@@ -236,7 +240,10 @@ class LmEngine:
         that can be handed out, are handed out and are spoken for,
         ``pool_wait`` the steps in which, and the rows that, stood empty
         for want of pages (summed over those steps), ``attn`` the steps
-        whose chunk attended in each form, ``attn_rows_form`` the form of
+        whose chunk attended in each form (``kernel_steps``, ...) and,
+        for a model with an indexer, chose its keys in each
+        (``select_kernel_steps``, ``select_loop_steps``),
+        ``attn_rows_form`` the form of
         the rows, and
         ``hc_defect_max`` the largest ``hc_defect`` of any step (0.0 for
         a model with one residual stream), ``waits`` the pulls' stalls by
@@ -308,6 +315,7 @@ class LmEngine:
                     for k, (s, d) in plan_shapes(cfg, geo, chunk).items()}
             step = build_step(cfg, geo, chunk)
             self.attn_forms[chunk] = step.attn_chunk_form
+            self.select_forms[chunk] = step.attn_select_form
             self.attn_rows_form = step.attn_rows_form
             fn = jax.jit(step, donate_argnums=(1, 2))
             self.programs[chunk] = fn.lower(
@@ -395,8 +403,10 @@ class LmEngine:
                 if done["pool_wait_rows"]:
                     self.pool_wait_steps += 1
                     self.pool_wait_rows += done["pool_wait_rows"]
-                form = done["attn_chunk_form"]
-                if form:
+                forms = [done["attn_chunk_form"]]
+                if done["attn_select_form"]:
+                    forms.append(f"select_{done['attn_select_form']}")
+                for form in filter(None, forms):
                     self.attn_steps[form] = self.attn_steps.get(form, 0) + 1
                 self.hc_defect_max = max(self.hc_defect_max,
                                          done.get("hc_defect", 0.0))
@@ -529,6 +539,7 @@ class LmEngine:
                   "row_pos": plan["row_pos"][plan["row_active"]].tolist(),
                   "context": p0 if pre is not None else None,
                   "attn_chunk_form": self.attn_forms[bucket],
+                  "attn_select_form": self.select_forms[bucket],
                   "attn_rows_form": self.attn_rows_form,
                   "rows_context": int(plan["row_pos"][
                       plan["row_active"]].sum() + len(deco)),

@@ -26,7 +26,10 @@ over those alone, one choice for all heads. The indexer's keys live in
 a second pool beside K and V on the full class's page numbers.
 :func:`index_scores` computes the scores over a sequence's pages,
 :func:`select_keys` finds each query's k-th best score digit by digit
-over the float32 bits (exact; the scores are read, never sorted) and
+over the float32 bits (exact; the scores are read, never sorted; on a
+TPU a chunk's choice is one kernel that reads a tile's scores once into
+VMEM and searches them there, ``attention_kernel.py::
+select_keys_kernel``, chosen by :func:`select_form`) and
 :func:`top_positions` is ``lax.top_k`` for the few decoding rows. Two
 forms of the attention compute the same thing: :func:`paged_attention`
 masked by the choice (a prefill chunk: 2,048 queries share the pages
@@ -541,8 +544,9 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     absolute positions of the queries; ``last_pos`` (S,) the last
     position that holds a key (-1: the sequence is absent); ``table``
     (S, W) physical pages from position ``base`` (S,) on; ``chosen``
-    (S, Q, keys) bool, where given, the keys each query attends (by
-    position from ``base`` on; whole blocks wide). Returns
+    (S, Q, keys) bool or int8 (non-zero: chosen), where given, the keys
+    each query attends (by position from ``base`` on; whole blocks
+    wide). Returns
     ``(out (S, Q, nkv, g, hd) float32, pages visited (S,))``.
 
     Where the pool holds something other than K beside V (a latent),
@@ -607,7 +611,8 @@ def paged_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
             ok &= kpos[:, None, :] > qpos[:, :, None] - window
         ok &= jnp.repeat(live, page, axis=1)[:, None, :]
         if chosen is not None:
-            ok &= lax.dynamic_slice_in_dim(chosen, i * keys, keys, axis=2)
+            ok &= lax.dynamic_slice_in_dim(chosen, i * keys, keys,
+                                           axis=2).astype(bool)
         if keys_minor:
             # one K/V head: k (S, bp, hd, page) enters the product as the
             # gather left it
@@ -757,6 +762,16 @@ def select_keys(scores: jax.Array, top: int, n_keys: jax.Array, *,
         return valid & (bits >= kth[..., None])
 
     return lax.cond(jnp.any(at > room), split, whole, None)
+
+
+def select_form(nq: int, width: int, block: int) -> str:
+    """``"kernel"`` or ``"loop"``: how a chunk of ``nq`` queries chooses
+    its keys over a score row of ``width`` columns read in blocks of
+    ``block``: ``attention_kernel.py::select_keys_kernel`` on a TPU where
+    Mosaic tiles the shapes, :func:`select_keys` elsewhere (shapes and
+    backend, as :func:`attention_form`; no knob)."""
+    kernel = attention_kernel.select_supported(nq, width, block)
+    return "kernel" if kernel and jax.default_backend() == "tpu" else "loop"
 
 
 def top_positions(scores: jax.Array, top: int) -> tuple[jax.Array, jax.Array]:
@@ -1054,9 +1069,13 @@ def _sparse_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int, lp: dict,
                                   (st.p0 + st.offs)[None],
                                   st.chunk_last[None], pki, ctab[None],
                                   width=geo.key_width, **blocks)
+        block = geo.kv_block_pages * page
         with jax.named_scope("lm.attn.select"):
-            chosen = select_keys(scores, top, st.p0 + st.n,
-                                 block=geo.kv_block_pages * page)
+            if select_form(chunk, geo.key_width, block) == "kernel":
+                chosen = attention_kernel.select_keys_kernel(
+                    scores[0], top, st.p0, st.p0 + st.n, block=block)[None]
+            else:
+                chosen = select_keys(scores, top, st.p0 + st.n, block=block)
         with jax.named_scope("lm.attn.sparse"):
             o_chunk, _ = paged_attention(
                 q[None, :chunk], (st.p0 + st.offs)[None],
@@ -1315,8 +1334,12 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
 
     step.__name__ = f"lm_step_c{chunk}"
     step.__qualname__ = step.__name__
-    # the forms the rows' and the chunk's attention take in this program
-    # (the chunk's None where the bucket has none)
+    # the forms the rows' and the chunk's attention and the chunk's choice
+    # of keys take in this program (the chunk's None where the bucket has
+    # none, the choice's also where the model has no indexer)
+    step.attn_select_form = select_form(
+        chunk, geo.key_width, geo.kv_block_pages * geo.page) \
+        if chunk and cfg.index_topk else None
     if cfg.latent_width:
         step.attn_rows_form = "latent_absorbed"
         step.attn_chunk_form = latent_chunk_form(
