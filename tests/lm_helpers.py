@@ -1,6 +1,6 @@
-"""Shared by the transcript model's tests: the tiny afmoe, KeyeVL2 and
-xing4_0 models, their engine and the comparison with the plain
-references."""
+"""Shared by the transcript model's tests: the tiny afmoe, KeyeVL2,
+xing4_0 and qwen3_next models, their engine and the comparison with the
+plain references."""
 
 import json
 import sys
@@ -324,4 +324,57 @@ def xing_rows(req, hf, params, **how):
     full = np.concatenate([req.prompt, req.tokens[:-1]]).astype(np.int32)
     steps = sorted(req.logits)
     return steps, xing_ref.forward(
+        params, hf, full, [req.prompt.size - 1 + i for i in steps], **how)
+
+
+# ---- qwen3_next at tiny widths --------------------------------------------
+
+def tiny_qwen_hf_config(**over) -> dict:
+    """A CPU-sized config of the published ``qwen3_next`` form: three
+    Gated DeltaNet layers (2 key heads of 16 repeated to 4 value heads of
+    16) then gated attention (4 heads of 32 over 2 K/V heads, rotary on
+    8 of 32 dims); 8 experts held of a router's 16, top 3, beside a gated
+    shared expert."""
+    cfg = {"model_type": "qwen3_next", "hidden_size": 64, "head_dim": 32,
+           "num_attention_heads": 4, "num_key_value_heads": 2,
+           "num_hidden_layers": 4, "full_attention_interval": 4,
+           "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+           "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+           "linear_conv_kernel_dim": 4, "partial_rotary_factor": 0.25,
+           "intermediate_size": 96, "moe_intermediate_size": 32,
+           "shared_expert_intermediate_size": 32, "num_experts": 8,
+           "published_num_experts": 16, "first_held_expert": 0,
+           "num_experts_per_tok": 3, "norm_topk_prob": True,
+           "decoder_sparse_step": 1, "mlp_only_layers": [],
+           "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 1e7,
+           "hidden_act": "silu", "tie_word_embeddings": False,
+           "use_sliding_window": False, "rope_scaling": None,
+           "max_position_embeddings": 2048}
+    cfg.update(over)
+    return cfg
+
+
+def tiny_qwen(seed=13, init_std=0.125, **over):
+    """Matrices N(0, 0.125^2) (``tiny_xing`` says why); ``A_log`` spread
+    so that the value heads' decays run from a few positions' memory to
+    a few hundred, as the benchmark's draw does at its widths."""
+    hf = tiny_qwen_hf_config(**over)
+    cfg = LmConfig.from_hf(hf)
+    params = random_params(cfg, seed, init_std)
+    nv = cfg.linear_value_heads
+    for lp in params["layers"]:
+        if "a_log" in lp:
+            lp["a_log"] = jnp.log(jnp.geomspace(1e-2, 1.0, nv)).astype(F32)
+            lp["dt_bias"] = jnp.zeros((nv,), F32)
+    return hf, cfg, params
+
+
+def qwen_rows(req, hf, params, **how):
+    """The reference's full forward pass over a finished request's
+    prompt plus served tokens, at its captured steps."""
+    from reference import qwen3next_ref
+
+    full = np.concatenate([req.prompt, req.tokens[:-1]]).astype(np.int32)
+    steps = sorted(req.logits)
+    return steps, qwen3next_ref.forward(
         params, hf, full, [req.prompt.size - 1 + i for i in steps], **how)

@@ -24,7 +24,10 @@ heads is read with a sublane stride and split in VMEM. A key block that
 no query of a tile can see (above the diagonal, outside the band, past
 the last live page) does no work, and its index map stays on the block
 before it, so it fetches nothing either. A block that every query of
-the tile sees whole skips the positional mask.
+the tile sees whole skips the positional mask. A head of 256 lies in
+the pool as two 128-lane halves (``model.py::kv_tail``), so a page is
+still ``(position x kv head x half, 128)`` as it lies; a word then pairs
+a head's two halves, which are split and set side by side in VMEM.
 
 Sized on the chip (PERF.md section 6, PR 34): ``Q_TILE`` 128 with the
 geometry's block of 4 pages reads 4.7 ms for 2,048 queries over 18k keys
@@ -125,12 +128,15 @@ LATENT_Q_TILE = 256             # queries a sub-tile of the latent kernel
 VMEM_LIMIT = 64 * 1024 * 1024
 
 
+HEAD_DIMS = (128, 256)           # a head one or two 128-lane blocks
+
+
 def supported(nq: int, nkv: int, hd: int, page: int) -> bool:
-    """Shapes Mosaic tiles: a head is one 128-lane block (a page is then
-    read as the pool holds it, two kv heads a 32-bit word), a page whole
-    int8 mask tiles, the chunk whole query tiles."""
+    """Shapes Mosaic tiles: a head is one or two 128-lane blocks (a page
+    is then read as the pool holds it, two kv heads a 32-bit word), a
+    page whole int8 mask tiles, the chunk whole query tiles."""
     tq = min(nq, Q_TILE)
-    return hd == 128 and nkv % 2 == 0 and page % 32 == 0 \
+    return hd in HEAD_DIMS and nkv % 2 == 0 and page % 32 == 0 \
         and nq % tq == 0 and tq % 32 == 0
 
 
@@ -145,8 +151,16 @@ def _pages_seen(meta, qi, *, tq: int, page: int, window: int | None):
     return lo, hi
 
 
+def _halves(words: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The two bfloat16 rows a 32-bit word of a page holds: the even row
+    in the low half, the odd in the high."""
+    return tuple(pltpu.bitcast(w, F32).astype(BF16)
+                 for w in (words << 16, words & jnp.uint32(0xFFFF0000)))
+
+
 def _kernel(table, meta, q_ref, *refs, tq: int, nkv: int, g: int, hd: int,
-            page: int, bp: int, window: int | None, masked: bool):
+            page: int, bp: int, window: int | None, masked: bool,
+            split: int):
     del table
     k_refs, v_refs = refs[:bp], refs[bp:2 * bp]
     refs = refs[2 * bp:]
@@ -167,16 +181,20 @@ def _kernel(table, meta, q_ref, *refs, tq: int, nkv: int, g: int, hd: int,
     k_lo = base + kb * keys
 
     def heads(page_refs):
-        """A block's pages ``(page * nkv, hd)``, rows by position then kv
-        head, as one ``(keys, hd)`` array a kv head. Two heads share a
-        32-bit word, so a pair is read with a sublane stride and split."""
+        """A block's pages ``(page * rows a position, lanes)``, rows by
+        position then kv head (then half), as one ``(keys, hd)`` array a
+        kv head. Two rows share a 32-bit word, so a pair is read with a
+        sublane stride and split: two kv heads or, for a head of two
+        halves, one head's halves, set side by side."""
+        stride = nkv * split // 2
         out = []
-        for pair in range(nkv // 2):
+        for pair in range(stride):
             words = jnp.concatenate(
-                [r.bitcast(jnp.uint32)[pl.ds(pair, page, stride=nkv // 2), :]
+                [r.bitcast(jnp.uint32)[pl.ds(pair, page, stride=stride), :]
                  for r in page_refs], axis=0)
-            out += [pltpu.bitcast(w, F32).astype(BF16)
-                    for w in (words << 16, words & jnp.uint32(0xFFFF0000))]
+            low, high = _halves(words)
+            out += [jnp.concatenate([low, high], axis=1)] if split == 2 \
+                else [low, high]
         return out
 
     def tile(edge: bool):
@@ -231,13 +249,16 @@ def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
                     q_tile: int = Q_TILE, interpret: bool = False
                     ) -> jax.Array:
     """``q`` (Q, nkv, g, hd) bfloat16, scaled, at positions ``p0 +
-    arange(Q)``; the pools ``(pages, page, nkv, hd)``; ``table`` (W,)
+    arange(Q)``; the pools ``(pages, page, nkv, hd)`` (or, a head in
+    128-lane halves, ``(pages, page, 2 nkv, 128)``); ``table`` (W,)
     physical pages from position ``base`` () on, the first ``n_pages`` ()
     of them live; ``chosen`` (Q, keys) bool where given
     (``paged_attention``). Returns (Q, nkv, g, hd) float32."""
     nq, nkv, g, hd = q.shape
     tq, bp, width = min(nq, q_tile), block_pages, table.shape[0]
     keys = bp * page
+    lanes = pool_k.shape[-1]        # hd, or 128 for a head of two halves
+    rows_a_position = nkv * hd // lanes
     meta = jnp.stack([p0, n_pages, base]).astype(jnp.int32)
     # as many key blocks as the last query reads; the interpreter takes
     # no dynamic bound: there the steps past them are skipped one by one
@@ -256,14 +277,15 @@ def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
             slot = jnp.clip(block(kb, qi, meta) * bp + j, 0,
                             jnp.minimum(jnp.maximum(meta[1], 1), width) - 1)
             return table[slot], 0
-        return pl.BlockSpec((page * nkv, hd), index)
+        return pl.BlockSpec((page * rows_a_position, lanes), index)
 
     # the heads lead, so a tile's rows are head-major; a page's rows are
     # (position, kv head): the pool's own bytes, no relayout
     by_head = pl.BlockSpec((nkv * g, tq, hd), lambda qi, kb, *_: (0, qi, 0))
     in_specs = [by_head] + [kv_spec(j) for _ in "kv" for j in range(bp)]
     args = [q.reshape(nq, nkv * g, hd).transpose(1, 0, 2)]
-    args += [pool_k.reshape(-1, hd)] * bp + [pool_v.reshape(-1, hd)] * bp
+    args += [pool_k.reshape(-1, lanes)] * bp \
+        + [pool_v.reshape(-1, lanes)] * bp
     if chosen is not None:
         in_specs.append(pl.BlockSpec(
             (tq, keys), lambda qi, kb, table, meta:
@@ -272,7 +294,8 @@ def chunk_attention(q: jax.Array, p0: jax.Array, n_pages: jax.Array,
     rows = nkv * g * tq
     out = pl.pallas_call(
         functools.partial(_kernel, tq=tq, nkv=nkv, g=g, hd=hd, page=page,
-                          bp=bp, window=window, masked=chosen is not None),
+                          bp=bp, window=window, masked=chosen is not None,
+                          split=hd // lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(nq // tq, n_blocks),
             in_specs=in_specs, out_specs=by_head,
@@ -297,12 +320,12 @@ FAR = 1 << 30       # a key position no query reaches
 
 
 def rows_supported(nkv: int, g: int, hd: int, page: int) -> bool:
-    """Shapes Mosaic tiles: a head is one 128-lane block and the kv
-    heads pair up in 32-bit words (a page is then read as the pool holds
-    it, as :func:`supported` asks), the query heads whole bfloat16
+    """Shapes Mosaic tiles: a head is one or two 128-lane blocks and the
+    kv heads pair up in 32-bit words (a page is then read as the pool
+    holds it, as :func:`supported` asks), the query heads whole bfloat16
     sublane tiles of the score product's rows, a page's rows whole lane
     blocks of the scores."""
-    return hd == 128 and nkv % 2 == 0 and (nkv * g) % 16 == 0 \
+    return hd in HEAD_DIMS and nkv % 2 == 0 and (nkv * g) % 16 == 0 \
         and page % 128 == 0
 
 
@@ -352,7 +375,7 @@ def _rows_plan(qpos, last_pos, base, *, width: int, page: int, bp: int,
 
 def _paged_rows_kernel(steps, table, meta, q_ref, *refs, n_steps: int,
                        rows: int, nkv: int, g: int, page: int, bp: int,
-                       window: int | None):
+                       window: int | None, split: int):
     del table
     k_refs, v_refs = refs[:bp], refs[bp:2 * bp]
     o_ref, m_s, l_s, acc_s = refs[2 * bp:]
@@ -380,6 +403,15 @@ def _paged_rows_kernel(steps, table, meta, q_ref, *refs, n_steps: int,
         mine, _ = _divmod(lax.broadcasted_iota(jnp.int32, shape, 0), g)
         rel = jnp.where(head == mine, rel, FAR)     # position in the page
         q = q_ref[...]
+
+        def page_rows(ref):
+            """``(page * nkv, hd)``: a head of two halves has them in
+            one word, set side by side."""
+            if split == 1:
+                return ref[...]
+            return jnp.concatenate(_halves(ref.bitcast(jnp.uint32)[...]),
+                                   axis=1)
+
         scores = []
         for j, k_ref in enumerate(k_refs):
             slot = kb * bp + j
@@ -387,7 +419,7 @@ def _paged_rows_kernel(steps, table, meta, q_ref, *refs, n_steps: int,
             ok = rel <= jnp.where(slot < hi, qpos - start, -1)
             if window is not None:
                 ok &= rel > qpos - window - start
-            s = lax.dot_general(q, k_ref[...], (((1,), (1,)), ((), ())),
+            s = lax.dot_general(q, page_rows(k_ref), (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32)
             scores.append(jnp.where(ok, s, MASKED))
         # ONE softmax step a block, as the loop takes it
@@ -402,7 +434,7 @@ def _paged_rows_kernel(steps, table, meta, q_ref, *refs, n_steps: int,
             # its sight
             p = jnp.exp(s - m_new)
             l += jnp.sum(p, axis=1, keepdims=True)
-            acc += jnp.dot(p.astype(BF16), v_ref[...],
+            acc += jnp.dot(p.astype(BF16), page_rows(v_ref),
                            preferred_element_type=F32)
         m_s[...], l_s[...], acc_s[...] = m_new, l, acc
 
@@ -421,10 +453,12 @@ def rows_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
     """``q`` (S, nkv, g, hd) bfloat16, scaled, the one query of sequence
     ``s`` at position ``qpos[s]``; ``last_pos`` (S,) the last position
     that holds a key (-1: absent, reads zeros); the pools ``(pages, page,
-    nkv, hd)``; ``table`` (S, W) physical pages from position ``base``
-    (S,) on. Returns (S, nkv, g, hd) float32."""
+    nkv, hd)`` or, a head in 128-lane halves, ``(pages, page, 2 nkv,
+    128)``; ``table`` (S, W) physical pages from position ``base`` (S,)
+    on. Returns (S, nkv, g, hd) float32."""
     rows, nkv, g, hd = q.shape
     bp, width = block_pages, table.shape[1]
+    lanes = pool_k.shape[-1]
     steps = _rows_plan(qpos, last_pos, base, width=width, page=page, bp=bp,
                        window=window)
     meta = jnp.concatenate([qpos, last_pos, base]).astype(jnp.int32)
@@ -440,11 +474,12 @@ def rows_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
             slot = jnp.minimum(steps[n_steps + t] * bp + j,
                                steps[2 * n_steps + t])
             return table[steps[t] * width + slot], 0
-        return pl.BlockSpec((page * nkv, hd), index)
+        return pl.BlockSpec((page * nkv * hd // lanes, lanes), index)
 
     out = pl.pallas_call(
         functools.partial(_paged_rows_kernel, n_steps=n_steps, rows=rows,
-                          nkv=nkv, g=g, page=page, bp=bp, window=window),
+                          nkv=nkv, g=g, page=page, bp=bp, window=window,
+                          split=hd // lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_steps if interpret else steps[-1],),
@@ -462,7 +497,7 @@ def rows_attention(q: jax.Array, qpos: jax.Array, last_pos: jax.Array,
         name="lm_rows_attention",
     )(steps, table.reshape(-1).astype(jnp.int32), meta,
       q.reshape(rows, nkv * g, hd),
-      *([pool_k.reshape(-1, hd)] * bp + [pool_v.reshape(-1, hd)] * bp))
+      *([pool_k.reshape(-1, lanes)] * bp + [pool_v.reshape(-1, lanes)] * bp))
     return out.reshape(rows, nkv, g, hd)
 
 

@@ -33,6 +33,15 @@ indexer keys for ``KeyeVL2``, 6.9 KB of latents for ``xing4_0``;
 ``afmoe``'s full class holds only its full layers, 2 KB each), it is the
 pool and not the rows that bounds what is resident, and rows stand empty
 while the next request waits for pages (the engine counts them).
+A model with Gated DeltaNet layers (``qwen3_next``) has a class of
+state besides the pages: a request's recurrent state and conv tail have
+no positions, so it gets ONE slot (:class:`StateSlots`) from admission
+to release, which the step program zeroes at the request's first chunk
+and overwrites at every step (``model.py::empty_cache``). A request
+holds a row from admission on (the engine reserves it before its first
+chunk), so a request mid-prefill is among the rows and ``rows`` slots
+serve them all; a request's slot is its row's. Its DeltaNet layers have
+no pool and no table.
 Used from the engine's thread alone; no lock.
 """
 
@@ -65,12 +74,37 @@ class PagePool:
         self._free.append(page)
 
 
-class SeqPages:
-    """One admitted sequence's tables (see the module docstring)."""
+class StateSlots:
+    """The recurrent-state class: ``slots`` slots (0: the model keeps no
+    such state), one a request from admission to release."""
 
-    def __init__(self, cache: "PagedCache", need_w: int, need_f: int):
+    def __init__(self, slots: int):
+        self.capacity = slots
+        self._used: set[int] = set()
+
+    @property
+    def in_use(self) -> int:
+        return len(self._used)
+
+    def take(self, slot: int) -> int:
+        if not 0 <= slot < self.capacity or slot in self._used:
+            raise ValueError(f"state slot {slot} is not free")
+        self._used.add(slot)
+        return slot
+
+    def give(self, slot: int) -> None:
+        self._used.discard(slot)
+
+
+class SeqPages:
+    """One admitted sequence's tables (see the module docstring) and,
+    for a model with recurrent state, its slot."""
+
+    def __init__(self, cache: "PagedCache", need_w: int, need_f: int,
+                 slot: int | None = None):
         self._cache = cache
         self._need = (need_w, need_f)
+        self.slot = slot
         self.full: list[int] = []
         self.win: deque[int] = deque()
         self.win_first = 0          # logical page of win[0]
@@ -120,6 +154,9 @@ class SeqPages:
         c.window.reserved -= self._need[0]
         c.full.reserved -= self._need[1]
         self._need = (0, 0)
+        if self.slot is not None:
+            c.slots.give(self.slot)
+            self.slot = None
         return freed
 
 
@@ -133,6 +170,7 @@ class PagedCache:
         self.context_cap = geo.context_cap
         self.window = PagePool(geo.window_pages)
         self.full = PagePool(geo.full_pages)
+        self.slots = StateSlots(geo.rows if cfg.linear_layers else 0)
 
     def need(self, total_len: int) -> tuple[int, int]:
         pages = -(-total_len // self.page)
@@ -144,16 +182,21 @@ class PagedCache:
         return (total_len <= self.context_cap and w <= self.window.capacity
                 and f <= self.full.capacity)
 
-    def admit(self, total_len: int) -> SeqPages | None:
+    def admit(self, total_len: int, row: int = 0) -> SeqPages | None:
         """Reserve both pools for a request of ``total_len`` positions
-        (prompt plus output); ``None`` while they cannot hold it."""
+        (prompt plus output) that will hold ``row``, and its state slot
+        where the model keeps one; ``None`` while they cannot hold it."""
         w, f = self.need(total_len)
         if (self.window.reserved + w > self.window.capacity
                 or self.full.reserved + f > self.full.capacity):
             return None
         self.window.reserved += w
         self.full.reserved += f
-        return SeqPages(self, w, f)
+        return SeqPages(self, w, f, self.slots.take(row)
+                        if self.slots.capacity else None)
+
+    def state(self) -> dict:
+        return {"slots": self.slots.capacity, "in_use": self.slots.in_use}
 
     def in_use(self) -> dict:
         return {"window": self.window.in_use, "full": self.full.in_use}

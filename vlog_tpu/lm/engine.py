@@ -54,8 +54,13 @@ resident), ``expert_load`` and ``window_pages`` or, for a model with an
 indexer, ``sparse_keys`` (keys one layer attended, keys a causal-dense
 layer would have; from the device, read out with the tokens) or, for a
 model with hyper-connections, ``hc_defect`` (the largest distance of a
-row or column sum of any ``H_res`` of the step from 1; from the device),
-``build_s``; all instants on ``time.monotonic()``.
+row or column sum of any ``H_res`` of the step from 1; from the device)
+or, for a model with DeltaNet layers, ``held_choices`` (valid
+token-choice pairs routed to the experts held here, all valid pairs;
+from the device), ``gdn_chunk_form`` (``"chunkwise"``, ``None`` without a
+chunk) and ``gdn_rows_form`` (``"recurrent"``), both ``None`` for other
+models, ``state_slots`` (state slots in use), ``build_s``; all instants
+on ``time.monotonic()``.
 
 Who kept the chip waiting (``obs/hostwait.py``) is on the same record:
 ``wait`` is the record of the pull of the step's tokens (``polls``,
@@ -159,6 +164,8 @@ class LmEngine:
         self.attn_forms: dict[int, str | None] = {}  # bucket -> chunk form
         self.select_forms: dict[int, str | None] = {}  # bucket -> choice
         self.attn_rows_form: str | None = None
+        self.gdn_forms: dict[int, str | None] = {}  # bucket -> DeltaNet's
+        self.gdn_rows_form: str | None = None
         self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
         self.hc_defect_max = 0.0                    # guarded-by: _lock
         self.waits = hostwait.WaitBook()            # guarded-by: _lock
@@ -238,6 +245,8 @@ class LmEngine:
     def stats(self) -> dict:
         """Counts since the engine began; ``pool`` per class the pages
         that can be handed out, are handed out and are spoken for,
+        ``state`` the recurrent-state slots (``slots``, ``in_use``; 0
+        for a model without DeltaNet layers),
         ``pool_wait`` the steps in which, and the rows that, stood empty
         for want of pages (summed over those steps), ``attn`` the steps
         whose chunk attended in each form (``kernel_steps``, ...) and,
@@ -257,6 +266,7 @@ class LmEngine:
                     "pages_in_use": cache.in_use()
                     if cache else {"window": 0, "full": 0},
                     "pool": cache.pools() if cache else {},
+                    "state": cache.state() if cache else {},
                     "pool_wait": {"steps": self.pool_wait_steps,
                                   "rows": self.pool_wait_rows},
                     "attn": {f"{form}_steps": n
@@ -317,6 +327,8 @@ class LmEngine:
             self.attn_forms[chunk] = step.attn_chunk_form
             self.select_forms[chunk] = step.attn_select_form
             self.attn_rows_form = step.attn_rows_form
+            self.gdn_forms[chunk] = step.gdn_chunk_form
+            self.gdn_rows_form = step.gdn_rows_form
             fn = jax.jit(step, donate_argnums=(1, 2))
             self.programs[chunk] = fn.lower(
                 self.assets.params, self._kv, self._last_tok, plan).compile()
@@ -449,12 +461,13 @@ class LmEngine:
                 continue
             if None not in self._rows:
                 return None
-            pages = self._cache.admit(total)
+            row = self._rows.index(None)
+            pages = self._cache.admit(total, row)
             if pages is None:
                 return None
             self._waiting.popleft()
             req.pages = pages
-            req.row = self._rows.index(None)
+            req.row = row
             self._rows[req.row] = req           # reserved; decodes later
             req.stats["t_admit"] = time.monotonic()
             self._prefilling = req
@@ -523,6 +536,8 @@ class LmEngine:
                 plan["chunk_ftab"] = ftab
                 if windowed:
                     plan["chunk_wtab"] = wtab
+                if "chunk_slot" in plan:
+                    plan["chunk_slot"][0] = pre.pages.slot
                 pre.prefilled += n
                 pre.stats.setdefault("t_first_chunk", time.monotonic())
                 pre.stats["prefill_steps"] = pre.stats.get(
@@ -541,6 +556,9 @@ class LmEngine:
                   "attn_chunk_form": self.attn_forms[bucket],
                   "attn_select_form": self.select_forms[bucket],
                   "attn_rows_form": self.attn_rows_form,
+                  "gdn_chunk_form": self.gdn_forms[bucket],
+                  "gdn_rows_form": self.gdn_rows_form,
+                  "state_slots": self._cache.slots.in_use,
                   "rows_context": int(plan["row_pos"][
                       plan["row_active"]].sum() + len(deco)),
                   "chunk_tag": pre.tag if pre is not None else None,
@@ -584,6 +602,8 @@ class LmEngine:
             record["sparse_keys"] = ints["keys"].tolist()
         elif "hc_defect" in ints:
             record["hc_defect"] = ints["hc_defect"]
+        elif "held_choices" in ints:
+            record["held_choices"] = ints["held_choices"].tolist()
         else:
             record["window_pages"] = ints["pages"].tolist()
         with trace.span("lm.step.deliver"):

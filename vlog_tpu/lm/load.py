@@ -1,6 +1,6 @@
 """A transcript model on disk: ``config.json`` (the published keys of a
 family ``LmConfig.from_hf`` builds: ``afmoe``, ``KeyeVL2``'s language
-model, or ``xing4_0``; ``model_type`` says which), the weights as safetensors (one ``model.safetensors``, or the
+model, ``xing4_0`` or ``qwen3_next``; ``model_type`` says which), the weights as safetensors (one ``model.safetensors``, or the
 shards that ``model.safetensors.index.json`` names; the published names,
 torch layouts) and ``tokenizer.json``. Nothing is fetched: the operator
 points ``VLOG_DIGEST_DIR`` at a local directory, as ``VLOG_WHISPER_DIR``.
@@ -22,7 +22,15 @@ rank, heads x (nope + rope)), ``wkva`` (H, kv rank + rope), ``kvn``,
 ``wkvb`` (kv rank, heads x (nope + v)), ``wo``, one hyper-connection a
 sublayer, ``hca_*`` (attention) and ``hcm_*`` (MLP): ``_w`` (streams x H,
 2 streams + streams^2) bfloat16, ``_b`` (the same width) and ``_a`` (3,)
-float32, then the dense or the expert leaves of ``afmoe``.
+float32, then the dense or the expert leaves of ``afmoe``. A
+``qwen3_next`` layer has ``n1`` ``n2`` (zero-centred: the factor is ``1 +
+w``), then a DeltaNet's ``w_qkvz`` (H, key heads x (2 dk + 2 r dv)),
+``w_ba`` (H, 2 value heads), ``conv`` (taps, conv channels), ``a_log``
+``dt_bias`` (value heads,) float32, ``norm`` (dv,) and ``w_out``, or a
+gated attention's ``wq`` (H, heads x 2 hd: ``[q | gate]`` a head),
+``wk`` ``wv`` ``wo`` ``qn`` ``kn``; then ``router`` (H, the router's
+width), the held experts' stacks, the shared expert ``s_*`` and its gate
+``sg`` (H, 1).
 
 No published checkpoint's index has been met for any family (this
 machine has no network): the names below are the published modelling
@@ -31,7 +39,10 @@ descends from with the indexer's as the published sparse attention
 names them; for ``xing4_0``, those of the latent-attention family its
 config descends from, and names of this repository's choosing for the
 hyper-connections' tensors (``self_attn_hc.*``, ``mlp_hc.*``), which no
-catalog row names; a checkpoint that names a tensor otherwise is
+catalog row names; for ``qwen3_next``, the published modelling code's
+(``linear_attn.*``, ``mlp.shared_expert_gate``; the conv's weight is
+stored ``(channels, 1, taps)``) with the experts held here read by their
+published index; a checkpoint that names a tensor otherwise is
 refused by that name (``LmLoadError``), never half loaded.
 """
 
@@ -44,7 +55,7 @@ from typing import Any
 
 import jax.numpy as jnp
 
-from vlog_tpu.lm.model import BF16, F32, XING, LmConfig
+from vlog_tpu.lm.model import BF16, F32, LINEAR, QWEN3_NEXT, XING, LmConfig
 
 
 class LmLoadError(RuntimeError):
@@ -80,6 +91,28 @@ def layer_leaves(cfg: LmConfig, li: int) -> list[tuple[str, tuple, str]]:
     hyper-connection's projection)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q, kv = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
+    if cfg.linear_layers:
+        norms = [("n1", (h,), "zeros"), ("n2", (h,), "zeros")]
+        if cfg.layer_types[li] == LINEAR:
+            nk, nv = cfg.linear_key_heads, cfg.linear_value_heads
+            dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+            mixer = [("w_qkvz", (h, 2 * nk * dk + 2 * nv * dv), "normal"),
+                     ("w_ba", (h, 2 * nv), "normal"),
+                     ("conv", (cfg.linear_conv, cfg.conv_dim), "normal"),
+                     ("a_log", (nv,), "bias"), ("dt_bias", (nv,), "bias_ones"),
+                     ("norm", (dv,), "ones"), ("w_out", (nv * dv, h), "normal")]
+        else:
+            mixer = [("wq", (h, 2 * q), "normal"), ("wk", (h, kv), "normal"),
+                     ("wv", (h, kv), "normal"), ("wo", (q, h), "normal"),
+                     ("qn", (hd,), "zeros"), ("kn", (hd,), "zeros")]
+        e, i = cfg.num_experts, cfg.moe_intermediate_size
+        s = i * cfg.num_shared_experts
+        return norms + mixer + [
+            ("router", (h, cfg.router_experts), "normal"),
+            ("e_gate", (e, h, i), "normal"), ("e_up", (e, h, i), "normal"),
+            ("e_down", (e, i, h), "normal"), ("s_gate", (h, s), "normal"),
+            ("s_up", (h, s), "normal"), ("s_down", (s, h), "normal"),
+            ("sg", (h, 1), "normal")]
     if cfg.latent_width:
         nh, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         maps = 2 * cfg.hc_mult + cfg.hc_mult ** 2
@@ -178,14 +211,29 @@ XING_NAMES = {
     "s_gate": "mlp.shared_experts.gate_proj.weight",
     "s_up": "mlp.shared_experts.up_proj.weight",
     "s_down": "mlp.shared_experts.down_proj.weight"}
+QWEN3_NEXT_NAMES = {
+    "n1": "input_layernorm.weight", "n2": "post_attention_layernorm.weight",
+    "w_qkvz": "linear_attn.in_proj_qkvz.weight",
+    "w_ba": "linear_attn.in_proj_ba.weight",
+    "conv": "linear_attn.conv1d.weight", "a_log": "linear_attn.A_log",
+    "dt_bias": "linear_attn.dt_bias", "norm": "linear_attn.norm.weight",
+    "w_out": "linear_attn.out_proj.weight",
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "qn": "self_attn.q_norm.weight", "kn": "self_attn.k_norm.weight",
+    "router": "mlp.gate.weight",
+    "s_gate": "mlp.shared_expert.gate_proj.weight",
+    "s_up": "mlp.shared_expert.up_proj.weight",
+    "s_down": "mlp.shared_expert.down_proj.weight",
+    "sg": "mlp.shared_expert_gate.weight"}
 EXPERT_NAMES = {"e_gate": "gate_proj", "e_up": "up_proj",
                 "e_down": "down_proj"}
 
 
 def layer_names(cfg: LmConfig) -> dict:
     """Our key -> the family's published name under its layer."""
-    return {"KeyeVL2": KEYE_NAMES, XING: XING_NAMES}.get(cfg.model_type,
-                                                         HF_NAMES)
+    return {"KeyeVL2": KEYE_NAMES, XING: XING_NAMES,
+            QWEN3_NEXT: QWEN3_NEXT_NAMES}.get(cfg.model_type, HF_NAMES)
 
 
 def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
@@ -203,11 +251,15 @@ def from_state_dict(cfg: LmConfig, sd: dict) -> dict:
         for name, _shape, kind in layer_leaves(cfg, li):
             if name in EXPERT_NAMES:
                 proj = EXPERT_NAMES[name]
+                first = cfg.expert_first
                 lp[name] = jnp.stack([
                     get(f"{base}mlp.experts.{e}.{proj}.weight").T
-                    for e in range(cfg.num_experts)]).astype(BF16)
+                    for e in range(first, first + cfg.num_experts)]
+                ).astype(BF16)
             else:
                 leaf = get(base + names[name])
+                if name == "conv":          # (channels, 1, taps)
+                    leaf = leaf.reshape(leaf.shape[0], -1)
                 leaf = leaf.T if leaf.ndim == 2 else leaf
                 lp[name] = leaf.astype(F32 if kind.startswith("bias")
                                        else BF16)

@@ -60,9 +60,38 @@ manifold-constrained hyper-connection (:func:`hc_maps`): ``u = H_pre X``,
 the final norm. Experts are ``afmoe``'s (sigmoid, a selection bias, a
 shared expert). The family's next-token-prediction module is not built.
 
+**``qwen3_next``.** Three Gated DeltaNet layers to every gated full
+attention layer (``layer_types`` ``linear_attention`` /
+``full_attention``); every layer ``h = h + Mix(N1(h))`` then ``h = h +
+Moe(N2(h))``, every norm but the DeltaNet's output norm zero-centred
+(``x / rms(x) * (1 + w)``). A DeltaNet layer (:func:`_gdn_mixer`):
+``[q k v z] = x Wqkvz`` grouped by key head, ``[b a] = x Wba``, a causal
+depthwise conv of ``linear_conv`` taps then SiLU over ``[q k v]``, q and
+k L2-normalised (q times ``dk^-0.5``), the key heads repeated to the
+value heads, ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
+dt_bias)``, and per value head the gated delta rule on a float32 state
+``S`` (dk x dv): ``S <- e^g S``, ``S <- S + k ((v - S^T k) beta)^T``,
+``o = S^T q``; then ``RMSNorm(o) w SiLU(z)`` and ``Wout``. A request's
+``S`` and its conv tail (the last ``linear_conv - 1`` inputs) live in a
+slot of their own (``cache.py``), not in pages. Two forms of the one
+recurrence: a prefill chunk runs it chunkwise (:func:`gdn_chunk`, the
+WY form over sub-chunks of ``GDN_SUB``, the state carried across them),
+the rows one token each (:func:`gdn_rows`); both are XLA. A full layer
+(:func:`_gated_attention`) is grouped-query attention over K/V pages
+with heads of 256: ``[q | gate]`` a head from ``Wq``, zero-centred
+``q_norm`` / ``k_norm``, rotate-half rotary over the first
+``rotary_dim`` dims, ``softmax(q k^T / sqrt(hd)) v * sigmoid(gate)``,
+``Wo``. Experts: softmax over ALL ``router_experts`` router outputs,
+the top k renormalised; the layer holds ``num_experts`` of them (from
+``expert_first`` on: one chip's share of an expert-parallel
+deployment), computes only the pairs routed to those and adds
+``sigmoid(x Wsg) * Shared(x)``.
+
 Precision as stated: weights, K/V, indexer keys and latents bfloat16,
 products accumulate in float32, the residual stream(s), norms, router,
-index scores, hyper-connection mappings, softmax and logits float32.
+index scores, hyper-connection mappings, softmax and logits float32;
+the DeltaNet's state and its recurrence float32 (products at
+``HIGHEST``), its conv tail bfloat16.
 
 **The step program** (:func:`build_step`) serves one engine step: at
 most one prefill chunk of ONE request (``chunk`` tokens, a static
@@ -102,8 +131,11 @@ F32 = jnp.float32
 BF16 = jnp.bfloat16
 SLIDING = "sliding_attention"
 FULL = "full_attention"
+LINEAR = "linear_attention"
 XING = "xing4_0"
+QWEN3_NEXT = "qwen3_next"
 MASKED = -1e30
+GDN_SUB = 64        # positions a sub-chunk of the chunkwise DeltaNet
 
 
 @dataclass(frozen=True)
@@ -146,11 +178,19 @@ class LmConfig:
     hc_sinkhorn_iters: int = 0
     hc_eps: float = 0.0
     hc_clamp: tuple[float, float] = (0.0, 0.0)
+    rotary_dim: int = 0                 # rotated dims of a head (0: all)
+    linear_key_heads: int = 0           # Gated DeltaNet (0: none)
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 0                # the causal conv's taps
+    router_experts: int = 0             # the router's width (0: num_experts)
+    expert_first: int = 0               # the first expert held here
 
     @classmethod
     def from_hf(cls, d: dict) -> "LmConfig":
         families = {"afmoe": cls._from_afmoe, "KeyeVL2": cls._from_keye,
-                    XING: cls._from_xing}
+                    XING: cls._from_xing, QWEN3_NEXT: cls._from_qwen3_next}
         family = d.get("model_type", "afmoe")
         if family not in families:
             raise ValueError(f"model_type {family!r} is not built (built: "
@@ -286,9 +326,84 @@ class LmConfig:
             hc_clamp=(float(d["mhc_h_res_clamp_min"]),
                       float(d["mhc_h_res_clamp_max"])))
 
+    @classmethod
+    def _from_qwen3_next(cls, d: dict) -> "LmConfig":
+        """``qwen3_next``: Gated DeltaNet and gated full attention (the
+        layer kinds from ``layer_types``, else every
+        ``full_attention_interval``-th layer full), softmax-routed experts
+        beside a gated shared expert on every layer. Where this holds a
+        share of the experts, ``num_experts`` counts the experts held and
+        ``published_num_experts`` the router's outputs, the first held
+        being ``first_held_expert``. ``num_nextn_predict_layers`` is read
+        past, as for ``xing4_0``."""
+        n = int(d["num_hidden_layers"])
+        every = int(d.get("full_attention_interval", 4))
+        kinds = tuple(d.get("layer_types") or [
+            FULL if (i + 1) % every == 0 else LINEAR for i in range(n)])[:n]
+        refused = {
+            "decoder_sparse_step != 1":
+                int(d.get("decoder_sparse_step", 1)) != 1,
+            "mlp_only_layers": bool(d.get("mlp_only_layers")),
+            "use_sliding_window": bool(d.get("use_sliding_window")),
+            "attention_bias": bool(d.get("attention_bias")),
+            "tie_word_embeddings": bool(d.get("tie_word_embeddings")),
+            "rope_scaling": bool(d.get("rope_scaling")),
+            "hidden_act other than silu": d.get("hidden_act", "silu")
+            != "silu",
+            "layer kinds other than linear_attention and full_attention":
+                len(kinds) != n or not set(kinds) <= {LINEAR, FULL}}
+        bad = [name for name, hit in refused.items() if hit]
+        if bad:
+            raise ValueError(f"{QWEN3_NEXT}: not built: {', '.join(bad)}")
+        hd = int(d["head_dim"])
+        held = int(d["num_experts"])
+        return cls(
+            hidden_size=int(d["hidden_size"]),
+            num_attention_heads=int(d["num_attention_heads"]),
+            num_key_value_heads=int(d["num_key_value_heads"]),
+            head_dim=hd, layer_types=kinds, num_dense_layers=0,
+            intermediate_size=int(d["intermediate_size"]),
+            moe_intermediate_size=int(d["moe_intermediate_size"]),
+            num_experts=held,
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            num_shared_experts=int(d["shared_expert_intermediate_size"])
+            // int(d["moe_intermediate_size"]),
+            route_norm=bool(d.get("norm_topk_prob", True)),
+            route_scale=1.0, sliding_window=0,
+            vocab_size=int(d["vocab_size"]),
+            rms_norm_eps=float(d["rms_norm_eps"]),
+            rope_theta=float(d["rope_theta"]), mup_enabled=False,
+            model_type=QWEN3_NEXT, score_func="softmax",
+            rotary_dim=int(hd * float(d.get("partial_rotary_factor", 1.0))),
+            linear_key_heads=int(d["linear_num_key_heads"]),
+            linear_value_heads=int(d["linear_num_value_heads"]),
+            linear_key_dim=int(d["linear_key_head_dim"]),
+            linear_value_dim=int(d["linear_value_head_dim"]),
+            linear_conv=int(d["linear_conv_kernel_dim"]),
+            router_experts=int(d.get("published_num_experts", held)),
+            expert_first=int(d.get("first_held_expert", 0)))
+
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def linear_layers(self) -> int:
+        return sum(k == LINEAR for k in self.layer_types)
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of a DeltaNet layer's conv: ``[q k v]``."""
+        return 2 * self.linear_key_heads * self.linear_key_dim \
+            + self.linear_value_heads * self.linear_value_dim
+
+    @property
+    def held(self) -> tuple[int, int] | None:
+        """The router outputs whose experts this holds, ``(first,
+        end)``; ``None`` where it holds every one."""
+        if not self.router_experts or self.router_experts == self.num_experts:
+            return None
+        return (self.expert_first, self.expert_first + self.num_experts)
 
     @property
     def latent_width(self) -> int:
@@ -302,7 +417,7 @@ class LmConfig:
 
     @property
     def full_layers(self) -> int:
-        return self.num_layers - self.window_layers
+        return sum(k == FULL for k in self.layer_types)
 
     def position_bytes(self) -> tuple[int, int]:
         """Cache bytes one position costs over the layers held here, by
@@ -805,17 +920,35 @@ def gathered_attention(q: jax.Array, positions: jax.Array, live: jax.Array,
 # the step program
 # --------------------------------------------------------------------------
 
+LANES = 128
+
+
+def kv_tail(cfg: LmConfig) -> tuple[int, int]:
+    """How a position's K (or V) lies in a page: ``(kv heads, head_dim)``
+    or, a head wider than a lane block, ``(kv heads x blocks, 128)``: a
+    head of 256 as its two halves, so that a page is rows of 128 lanes
+    as the kernels read it without a copy (``attention_kernel.py``); the
+    same bytes in the same order as ``(kv heads, 256)``."""
+    nkv, hd = cfg.num_key_value_heads, cfg.head_dim
+    if hd > LANES and hd % LANES == 0:
+        return nkv * hd // LANES, LANES
+    return nkv, hd
+
+
 def empty_cache(cfg: LmConfig, geo: Geometry) -> dict:
-    """Per layer one K and one V pool ``(pages, page, nkv, hd)`` and,
+    """Per layer one K and one V pool ``(pages, page, *kv_tail)`` and,
     where the model has an indexer, one pool of its keys ``(pages, page,
     index_head_dim)``; the layers of a class share page numbers
     (``cache.py``), and a layer's three pools share them too. A model
     with latent attention has ONE pool a layer in their place, ``lat``
     ``(pages, latent_width, page)`` (positions in the lanes: 576 does
     not fill them and 256 does, so this is how the chip would lay the
-    array out anyway), on the full class's page numbers."""
+    array out anyway), on the full class's page numbers. A model with
+    DeltaNet layers has pools for its full layers alone and, for each
+    DeltaNet layer, ``state`` ``(rows, value heads, dk, dv)`` float32 and
+    ``conv`` ``(rows, taps - 1, conv channels)`` bfloat16: a slot a row."""
     sizes = [geo.window_pages if k == SLIDING else geo.full_pages
-             for k in cfg.layer_types]
+             for k in cfg.layer_types if k != LINEAR]
 
     def pools(*tail):
         return [jnp.zeros((n, geo.page) + tail, BF16) for n in sizes]
@@ -823,10 +956,17 @@ def empty_cache(cfg: LmConfig, geo: Geometry) -> dict:
     if cfg.latent_width:
         return {"lat": [jnp.zeros((n, cfg.latent_width, geo.page), BF16)
                         for n in sizes]}
-    out = {"k": pools(cfg.num_key_value_heads, cfg.head_dim),
-           "v": pools(cfg.num_key_value_heads, cfg.head_dim)}
+    out = {"k": pools(*kv_tail(cfg)), "v": pools(*kv_tail(cfg))}
     if cfg.index_topk:
         out["ki"] = pools(cfg.index_head_dim)
+    if cfg.linear_layers:
+        # a slot a row (cache.py): the float32 state and the conv tail
+        out["state"] = [jnp.zeros(
+            (geo.rows, cfg.linear_value_heads, cfg.linear_key_dim,
+             cfg.linear_value_dim), F32) for _ in range(cfg.linear_layers)]
+        out["conv"] = [jnp.zeros((geo.rows, cfg.linear_conv - 1,
+                                  cfg.conv_dim), BF16)
+                       for _ in range(cfg.linear_layers)]
     return out
 
 
@@ -836,7 +976,9 @@ def unpack_ints(cfg: LmConfig, geo: Geometry, ints) -> dict:
     indexer): what one layer read over what a causal-dense one would. A
     model with hyper-connections ends on ONE, ``hc_defect``: the float32
     bits of the largest distance of a row or column sum of any ``H_res``
-    of the step from 1."""
+    of the step from 1. A model with DeltaNet layers ends on
+    ``held_choices``: valid token-choice pairs routed to the experts held
+    here and all valid pairs, over its expert layers."""
     r = geo.rows + 1
     n_moe = cfg.num_layers - cfg.num_dense_layers
     out = {"tokens": ints[:r],
@@ -844,6 +986,8 @@ def unpack_ints(cfg: LmConfig, geo: Geometry, ints) -> dict:
     tail = ints[r + 3 * n_moe:]
     if cfg.hc_mult:
         out["hc_defect"] = float(tail.view("float32")[0])
+    elif cfg.linear_layers:
+        out["held_choices"] = tail
     else:
         out["keys" if cfg.index_topk else "pages"] = tail
     return out
@@ -866,6 +1010,8 @@ def plan_shapes(cfg: LmConfig, geo: Geometry, chunk: int) -> dict:
                     "chunk_ftab": ((geo.max_pages,), jnp.int32)})
         if cfg.window_layers:
             out["chunk_wtab"] = ((ring,), jnp.int32)
+        if cfg.linear_layers:       # the chunk's request's state slot
+            out["chunk_slot"] = ((1,), jnp.int32)
     return out
 
 
@@ -896,6 +1042,8 @@ def _write_pages(st: _Step, geo: Geometry, pools: list, new: list,
     """The chunk's and the rows' new entries into each pool of one layer
     (``new`` (T, ...) a pool: K, V, the indexer's key)."""
     page, chunk = geo.page, st.chunk
+    new = [x.reshape(x.shape[:1] + pool.shape[2:])
+           for pool, x in zip(pools, new)]
     if chunk:
         first = (st.p0 - cbase) // page
         for j in range(chunk // page):
@@ -946,9 +1094,10 @@ def _experts(cfg: LmConfig, lp: dict, x: jax.Array, valid: jax.Array):
         x, lp["router"], lp.get("bias"), top_k=cfg.num_experts_per_tok,
         route_norm=cfg.route_norm, route_scale=cfg.route_scale,
         score_func=cfg.score_func)
-    y, counted, held = moe.experts(x, chosen, weights, lp["e_gate"],
-                                   lp["e_up"], lp["e_down"], valid)
-    return y, jnp.stack([jnp.max(counted), jnp.sum(counted), held])
+    y, counted, busy = moe.experts(x, chosen, weights, lp["e_gate"],
+                                   lp["e_up"], lp["e_down"], valid,
+                                   held=cfg.held)
+    return y, jnp.stack([jnp.max(counted), jnp.sum(counted), busy])
 
 
 def _afmoe_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int, lp: dict,
@@ -1256,6 +1405,241 @@ def _latent_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int, lp: dict,
     return xs, load[0] if load else None, defect[None]
 
 
+# --------------------------------------------------------------------------
+# Gated DeltaNet and gated attention (qwen3_next)
+# --------------------------------------------------------------------------
+
+def _hi(spec: str, *xs) -> jax.Array:
+    return jnp.einsum(spec, *xs, precision=lax.Precision.HIGHEST,
+                      preferred_element_type=F32)
+
+
+def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def gdn_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+              beta: jax.Array, state: jax.Array, *, sub: int
+              ) -> tuple[jax.Array, jax.Array]:
+    """The gated delta rule over ``T`` consecutive positions of one
+    sequence, chunkwise: ``q`` ``k`` (T, H, dk) (q scaled), ``v`` (T, H,
+    dv), ``g`` ``beta`` (T, H), ``state`` (H, dk, dv), all float32; a
+    position with ``beta`` and ``g`` 0 leaves the state as it was.
+    Returns ``(o (T, H, dv), the state after the last position)``.
+
+    Within a sub-chunk of ``sub`` positions the recurrence is the WY
+    form: with ``G`` the cumulative gates and ``D[i, j] = e^(G_i - G_j)``
+    (i >= j), ``A = strict_lower((beta k) k^T * D)``, ``T = (I + A)^-1``
+    (the product ``(I - A)(I + A^2)(I + A^4)...``: A is nilpotent),
+    ``U = T (beta v)``, ``W = T (beta k e^G)``; then, the state carried
+    from one sub-chunk to the next, ``V = U - W S``, ``o = (q e^G) S +
+    lower(q k^T * D) V`` and ``S <- e^(G_last) S + (k e^(G_last - G))^T
+    V``. Every product at ``HIGHEST``."""
+    t, h, dk = k.shape
+    n = t // sub
+
+    def split(x):
+        return x.reshape((n, sub, h) + x.shape[2:]).swapaxes(1, 2)
+
+    q, k, v, g, beta = map(split, (q, k, v, g, beta))   # (n, H, sub, ...)
+    gc = jnp.cumsum(g, axis=-1)
+    i = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    j = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    decay = jnp.where(i >= j, jnp.exp(jnp.where(
+        i >= j, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    kb = k * beta[..., None]
+    a = jnp.where(i > j, _hi("nhid,nhjd->nhij", kb, k) * decay, 0.0)
+    # (I + A)^-1 = (I - A)(I + A^2)(I + A^4)... while a power is nonzero
+    inv = jnp.eye(sub, dtype=F32) - a
+    power = a
+    for _ in range(max(sub - 1, 1).bit_length() - 1):
+        power = _hi("nhij,nhjk->nhik", power, power)
+        inv = inv + _hi("nhij,nhjk->nhik", inv, power)
+    u = _hi("nhij,nhjd->nhid", inv, v * beta[..., None])
+    w = _hi("nhij,nhjd->nhid", inv, kb * jnp.exp(gc)[..., None])
+    qk = jnp.where(i >= j, _hi("nhid,nhjd->nhij", q, k) * decay, 0.0)
+
+    def body(s, xs):
+        qi, ki, ui, wi, gi, qki = xs
+        v_new = ui - _hi("hid,hde->hie", wi, s)
+        o = _hi("hid,hde->hie", qi * jnp.exp(gi)[..., None], s) \
+            + _hi("hij,hje->hie", qki, v_new)
+        last = gi[:, -1]
+        s = s * jnp.exp(last)[:, None, None] + _hi(
+            "hid,hie->hde", ki * jnp.exp(last[:, None] - gi)[..., None],
+            v_new)
+        return s, o
+
+    state, o = lax.scan(body, state, (q, k, u, w, gc, qk))
+    return o.swapaxes(1, 2).reshape(t, h, -1), state
+
+
+def gdn_rows(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+             beta: jax.Array, state: jax.Array
+             ) -> tuple[jax.Array, jax.Array]:
+    """One step of the gated delta rule for each of ``R`` sequences:
+    ``q`` ``k`` (R, H, dk), ``v`` (R, H, dv), ``g`` ``beta`` (R, H),
+    ``state`` (R, H, dk, dv), float32. ``S <- e^g S``, ``S <- S + k ((v -
+    S^T k) beta)^T``, ``o = S^T q``: elementwise products and sums, a
+    read and a write of every state. Returns ``(o (R, H, dv), state)``."""
+    s = state * jnp.exp(g)[..., None, None]
+    remembered = jnp.sum(s * k[..., :, None], axis=-2)
+    s = s + k[..., :, None] * ((v - remembered) * beta[..., None])[
+        ..., None, :]
+    return jnp.sum(s * q[..., :, None], axis=-2), s
+
+
+def _conv_tail(p0: jax.Array, tail: jax.Array, x: jax.Array,
+               w: jax.Array, n: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """A chunk's causal depthwise conv: ``tail`` (K - 1, C) the inputs
+    before position ``p0`` (ignored at 0), ``x`` (chunk, C), ``w`` (K,
+    C), ``n`` live positions. Returns ``(out (chunk, C), the new tail)``."""
+    taps = w.shape[0]
+    xp = jnp.concatenate([jnp.where(p0 == 0, 0.0, tail.astype(F32)), x])
+    out = sum(w[j].astype(F32) * xp[j:j + x.shape[0]] for j in range(taps))
+    return out, lax.dynamic_slice_in_dim(xp, n, taps - 1)
+
+
+def _gdn_mixer(cfg: LmConfig, st: _Step, di: int, lp: dict, x: jax.Array,
+               kv: dict) -> jax.Array:
+    """One Gated DeltaNet mixer (the module docstring) over a step's
+    tokens: the chunk's through :func:`gdn_chunk` from its slot's state
+    (zeros where the chunk starts at position 0), the rows' through
+    :func:`gdn_rows` from theirs (a row's slot is its row); both written
+    back in place, the rows' where a row is live. ``di``: the layer's
+    place among the DeltaNet layers."""
+    chunk = st.chunk
+    nk, nv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv, r = cfg.linear_key_dim, cfg.linear_value_dim, nv // nk
+    t = x.shape[0]
+    with jax.named_scope("lm.gdn.project"):
+        qkvz = mm(x, lp["w_qkvz"]).reshape(t, nk, 2 * dk + 2 * r * dv)
+        q, k = qkvz[..., :dk], qkvz[..., dk:2 * dk]
+        v = qkvz[..., 2 * dk:2 * dk + r * dv]
+        z = qkvz[..., 2 * dk + r * dv:].reshape(t, nv, dv)
+        ba = mm(x, lp["w_ba"]).reshape(t, nk, 2 * r)
+        b, a = ba[..., :r].reshape(t, nv), ba[..., r:].reshape(t, nv)
+        mixed = jnp.concatenate([q.reshape(t, -1), k.reshape(t, -1),
+                                 v.reshape(t, -1)], axis=-1)
+    state, tails = kv["state"][di], kv["conv"][di]
+    w = lp["conv"]
+    with jax.named_scope("lm.gdn.conv"):
+        xp = jnp.concatenate([tails.astype(F32), mixed[chunk:, None]], 1)
+        y_rows = sum(w[j].astype(F32) * xp[:, j] for j in range(w.shape[0]))
+        tails = jnp.where(st.row_on[:, None, None], xp[:, 1:].astype(BF16),
+                          tails)
+        ys = [y_rows]
+        if chunk:
+            slot = st.plan["chunk_slot"][0]
+            y_chunk, tail = _conv_tail(st.p0, tails[slot], mixed[:chunk],
+                                       w, st.n)
+            tails = lax.dynamic_update_index_in_dim(
+                tails, tail.astype(BF16), slot, 0)
+            ys = [y_chunk] + ys
+        y = jax.nn.silu(jnp.concatenate(ys))
+        kd = nk * dk
+        q = jnp.repeat(l2norm(y[:, :kd].reshape(t, nk, dk)) * dk ** -0.5, r,
+                       axis=1)
+        k = jnp.repeat(l2norm(y[:, kd:2 * kd].reshape(t, nk, dk)), r, axis=1)
+        v = y[:, 2 * kd:].reshape(t, nv, dv)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(lp["a_log"].astype(F32)) * jax.nn.softplus(
+            a + lp["dt_bias"].astype(F32))
+    with jax.named_scope("lm.gdn.rows"):
+        o_rows, s_rows = gdn_rows(q[chunk:], k[chunk:], v[chunk:],
+                                  g[chunk:], beta[chunk:], state)
+        state = jnp.where(st.row_on[:, None, None, None], s_rows, state)
+        outs = [o_rows]
+    if chunk:
+        with jax.named_scope("lm.gdn.chunk"):
+            live = (st.offs < st.n)[:, None]
+            s0 = jnp.where(st.p0 == 0, 0.0, state[slot])
+            o_chunk, s_chunk = gdn_chunk(
+                q[:chunk], k[:chunk], v[:chunk],
+                jnp.where(live, g[:chunk], 0.0),
+                jnp.where(live, beta[:chunk], 0.0), s0,
+                sub=min(GDN_SUB, chunk))
+            state = lax.dynamic_update_index_in_dim(state, s_chunk, slot, 0)
+            outs = [o_chunk] + outs
+    kv["state"][di], kv["conv"][di] = state, tails
+    with jax.named_scope("lm.gdn.out"):
+        o = jnp.concatenate(outs)
+        o = rms_norm(o, lp["norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
+        return mm(o.reshape(t, nv * dv), lp["w_out"])
+
+
+def partial_rope(x: jax.Array, pos: jax.Array, theta: float, dims: int
+                 ) -> jax.Array:
+    """Rotate-half rotary over the first ``dims`` of each head's dims."""
+    return jnp.concatenate([rope(x[..., :dims], pos, theta),
+                            x[..., dims:]], axis=-1)
+
+
+def _gated_attention(cfg: LmConfig, geo: Geometry, st: _Step, fi: int,
+                     lp: dict, x: jax.Array, kv: dict) -> jax.Array:
+    """One gated full attention mixer over K/V pages (the module
+    docstring); ``fi``: the layer's place among the full layers, whose
+    pools alone exist."""
+    chunk, page, plan = st.chunk, geo.page, st.plan
+    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    g, eps = nh // nkv, cfg.rms_norm_eps
+    qg = mm(x, lp["wq"]).reshape(-1, nh, 2 * hd)
+    q = rms_norm(qg[..., :hd], 1.0 + lp["qn"].astype(F32), eps)
+    k = rms_norm(mm(x, lp["wk"]).reshape(-1, nkv, hd),
+                 1.0 + lp["kn"].astype(F32), eps)
+    v = mm(x, lp["wv"]).reshape(-1, nkv, hd)
+    q = partial_rope(q, st.pos, cfg.rope_theta, cfg.rotary_dim)
+    k = partial_rope(k, st.pos, cfg.rope_theta, cfg.rotary_dim)
+    q = (q * hd ** -0.5).astype(BF16).reshape(-1, nkv, g, hd)
+    k, v = k.astype(BF16), v.astype(BF16)
+    row_tab, zero = plan["row_ftab"], jnp.zeros_like(st.row_pos)
+    ctab = plan["chunk_ftab"] if chunk else None
+    with jax.named_scope("lm.cache.write"):
+        pk, pv = _write_pages(st, geo, [kv["k"][fi], kv["v"][fi]], [k, v],
+                              ctab, jnp.int32(0), row_tab, zero)
+    kv["k"][fi], kv["v"][fi] = pk, pv
+    with jax.named_scope("lm.attn.full"):
+        o, _ = paged_attention(
+            q[chunk:, None], st.row_pos[:, None], st.row_last, pk, pv,
+            row_tab, zero, window=None, page=page,
+            block_pages=geo.kv_block_pages)
+        o = o[:, 0]
+        if chunk:
+            o_chunk, _ = paged_attention(
+                q[None, :chunk], (st.p0 + st.offs)[None],
+                st.chunk_last[None], pk, pv, ctab[None],
+                jnp.zeros((1,), jnp.int32), window=None, page=page,
+                block_pages=geo.kv_block_pages)
+            o = jnp.concatenate([o_chunk[0], o])
+        o = o.reshape(-1, nh * hd) * jax.nn.sigmoid(
+            qg[..., hd:].reshape(-1, nh * hd))
+    return mm(o, lp["wo"])
+
+
+def _hybrid_layer(cfg: LmConfig, geo: Geometry, st: _Step, li: int,
+                  lp: dict, h: jax.Array, kv: dict):
+    """One ``qwen3_next`` layer; returns ``(h, expert load, (valid
+    token-choice pairs on the experts held here, all valid pairs))``."""
+    eps = cfg.rms_norm_eps
+    kind = cfg.layer_types[li]
+    x = rms_norm(h, 1.0 + lp["n1"].astype(F32), eps)
+    if kind == LINEAR:
+        h = h + _gdn_mixer(cfg, st, cfg.layer_types[:li].count(LINEAR), lp,
+                           x, kv)
+    else:
+        h = h + _gated_attention(cfg, geo, st,
+                                 cfg.layer_types[:li].count(FULL), lp, x, kv)
+    x = rms_norm(h, 1.0 + lp["n2"].astype(F32), eps)
+    y, load = _experts(cfg, lp, x, st.valid)
+    with jax.named_scope("lm.moe.shared"):
+        y = y + jax.nn.sigmoid(mm(x, lp["sg"])) * moe.swiglu(
+            x, lp["s_gate"], lp["s_up"], lp["s_down"])
+    pairs = jnp.stack([load[1], jnp.sum(st.valid.astype(jnp.int32))
+                       * cfg.num_experts_per_tok])
+    return h + y, load, pairs
+
+
 def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     """``step(params, kv, last_tok, plan) -> (kv, last_tok, out)`` for
     one bucket: ``chunk`` prefill tokens (0: none) beside ``geo.rows``
@@ -1268,7 +1652,8 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     causal-full layer would have or, for a model with an indexer,
     ``keys`` (2,) keys one layer attended and keys a causal-dense layer
     would have or, for a model with hyper-connections, ``hc_defect``
-    (1,) (:func:`unpack_ints`). Between the embedding and the head such
+    (1,) or, for a model with DeltaNet layers, ``held_choices`` (2,)
+    (:func:`unpack_ints`). Between the embedding and the head such
     a model's activation is its residual state ``(tokens, hc_mult,
     hidden)``: the embedding copied into every stream, the streams
     summed before the final norm."""
@@ -1276,7 +1661,8 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     r = geo.rows
     eps = cfg.rms_norm_eps
     layer = _latent_layer if cfg.latent_width else \
-        _sparse_layer if cfg.index_topk else _afmoe_layer
+        _sparse_layer if cfg.index_topk else \
+        _hybrid_layer if cfg.linear_layers else _afmoe_layer
 
     def step(params, kv, last_tok, plan):
         row_pos = plan["row_pos"]
@@ -1308,8 +1694,10 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
             if load is not None:
                 loads.append(load)
             if counted is not None:
-                # the worst H_res of every layer; one layer's count
-                read = jnp.maximum(read, counted) if cfg.hc_mult else counted
+                # the worst H_res of every layer; the held choices of
+                # every layer; else one layer's count
+                read = jnp.maximum(read, counted) if cfg.hc_mult \
+                    else read + counted if cfg.linear_layers else counted
         if cfg.hc_mult:
             read = lax.bitcast_convert_type(read, jnp.int32)
 
@@ -1321,8 +1709,10 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
                 top = jnp.concatenate([h, jnp.zeros_like(h[:1])])
             if cfg.hc_mult:
                 top = jnp.sum(top, axis=1)
-            logits = mm(rms_norm(top, params["final_norm"], eps),
-                        params["head"])
+            norm = params["final_norm"].astype(F32)
+            if cfg.linear_layers:           # zero-centred, as every norm
+                norm = 1.0 + norm
+            logits = mm(rms_norm(top, norm, eps), params["head"])
             tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(row_on, tokens[:r], last_tok)
         if chunk:
@@ -1340,6 +1730,10 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     step.attn_select_form = select_form(
         chunk, geo.key_width, geo.kv_block_pages * geo.page) \
         if chunk and cfg.index_topk else None
+    # the DeltaNet's two forms: XLA on every backend (no kernel yet)
+    step.gdn_rows_form = "recurrent" if cfg.linear_layers else None
+    step.gdn_chunk_form = "chunkwise" if cfg.linear_layers and chunk \
+        else None
     if cfg.latent_width:
         step.attn_rows_form = "latent_absorbed"
         step.attn_chunk_form = latent_chunk_form(

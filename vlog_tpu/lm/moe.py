@@ -8,7 +8,9 @@ expert and runs ONE grouped product per projection over every expert
 (``jax.lax.ragged_dot``: rows of the sorted activations against the
 expert their group names), so no token is dropped and no capacity is
 set; a token's ``k`` results are weighted and summed back in token
-order. bfloat16 operands, float32 accumulation.
+order. bfloat16 operands, float32 accumulation. A layer that holds a
+share of the experts (one chip's of an expert-parallel deployment)
+routes over all of them and computes the part its own give.
 """
 
 from __future__ import annotations
@@ -58,17 +60,30 @@ def swiglu(x: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array
 
 def experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
             gate: jax.Array, up: jax.Array, down: jax.Array,
-            valid: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+            valid: jax.Array, held: tuple[int, int] | None = None
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The routed part. ``x`` (T, H), ``chosen``/``weights`` (T, k),
     ``gate``/``up`` (E, H, I), ``down`` (E, I, H), ``valid`` (T,) bool.
     Returns ``(out (T, H) float32, tokens per expert (E,) int32, experts
     that hold any row () int32)``; the count is of valid tokens only
     (padding is computed, not counted), the experts held are of every
-    row (their weights are read)."""
+    row (their weights are read).
+
+    ``held`` ``(first, end)``: the router outputs whose experts the
+    stacks hold (``E`` of them; default all, ``chosen`` then indexes the
+    stacks). A pair routed outside them is sorted past every group, so
+    the grouped products neither compute it nor weight it: the part of
+    the result that this share of the experts gives."""
     t, k = chosen.shape
     n_experts = gate.shape[0]
     with jax.named_scope("lm.moe.dispatch"):
         flat = chosen.reshape(-1)
+        if held is not None:
+            local = flat - held[0]
+            mine = (local >= 0) & (local < n_experts)
+            # past the last group: no size counts it, bincount drops it
+            flat = jnp.where(mine, local, n_experts)
+            weights = jnp.where(mine.reshape(t, k), weights, 0.0)
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
         counted = jnp.bincount(
