@@ -10,7 +10,11 @@ call under its layer's scope, the pools reach it without a copy, and no
 float32 array of chunk x block keys x heads is left in the step; in
 Keye's, the chunk's choice of keys is one custom call a layer between
 the index scores and the attention's kernel, with no array of ordered
-bits or boolean mask left. A second reads Trinity's ``lm_step_c0`` and
+bits or boolean mask left; in every family's, the experts' rows are
+summed back by one Mosaic custom call an expert layer, fed the down
+product as it lies, with no second sort (``moe_kernel.py``), and that
+kernel's module is one size at every bucket and ``k``. A second reads
+Trinity's ``lm_step_c0`` and
 ``lm_step_c2048`` for the rows' kernel: one custom call a layer, no
 ``while`` left under the attention's scopes. The choice's kernel is
 held to ``model.py::select_keys`` bit for bit, ties included.
@@ -457,6 +461,20 @@ def _custom_calls(text, name):
             if " custom-call(" in ln and name in ln]
 
 
+def _combines_in_the_kernel(cfg, step, text):
+    """The experts' rows are summed back by one Mosaic custom call an
+    expert layer under ``lm.moe.combine``, reading the down product as
+    it lies (a bitcast, never a copy), with no second sort there."""
+    assert step.moe_combine_form == "kernel"
+    calls = _custom_calls(text, "lm_moe_combine")
+    assert len(calls) == cfg.num_layers - cfg.num_dense_layers
+    assert all('custom_call_target="tpu_custom_call"' in ln
+               and "/lm.moe.combine/lm_moe_combine" in ln for ln in calls)
+    assert all(" bitcast(" in _operands(text, ln)[-1] for ln in calls)
+    assert not [ln for ln in text.splitlines()
+                if " sort(" in ln and "lm.moe.combine" in ln]
+
+
 def _operands(text, call):
     """The HLO lines that define a custom call's operands, in order."""
     names = {ln.strip().split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%"):
@@ -513,6 +531,7 @@ def test_the_cells_chunk_step_compiles_to_the_kernel(
     row = f"{geo.chunk},{geo.key_width}]"
     assert not [ln for ln in text.splitlines()
                 if f"u32[1,{row}" in ln or f"pred[{row}" in ln]
+    _combines_in_the_kernel(cfg, step, text)
 
 
 @pytest.mark.parametrize("chunk", [0, 2048])
@@ -709,3 +728,30 @@ def test_the_chapters_cells_chunk_step_compiles_to_the_latent_kernel(
     # weights, pool and a chunk step's temporaries fit the chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    _combines_in_the_kernel(cfg, step, text)
+
+
+def test_the_combine_kernel_lowers_to_one_size_at_every_bucket(
+        one_chip, no_compile_cache):
+    """The combine's Mosaic module (the custom call's serialized body)
+    is the same size at 256 tokens as at 2,080 and at ``k`` 4, 8 or 10:
+    every loop of its body over tokens, pairs or ``k`` is a loop in the
+    module, not unrolled by Python. Tracing and lowering it then cost the
+    same at every bucket, once a shape (``moe.combine`` is one jit)."""
+    from vlog_tpu.lm import moe
+
+    def body(t, k):
+        def shape(s, d):
+            return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        text = moe.combine.lower(
+            shape((t * k, 2048), jnp.float32), shape((t * k,), jnp.int32),
+            shape((t, k), jnp.float32), None, form="kernel").as_text()
+        calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+        assert len(calls) == 1
+        return len(re.search(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)",
+                             calls[0]).group(1))
+
+    # the shapes' own digits and types move it a few hundred bytes; one
+    # DMA start written out a pair at a time adds some 2 KB a pair
+    sizes = [body(t, k) for t in (256, 2080) for k in (4, 8, 10)]
+    assert max(sizes) <= 1.1 * min(sizes) and max(sizes) < 32 << 10, sizes

@@ -341,6 +341,51 @@ def test_the_step_record_says_which_form_the_rows_attended_in(
     assert got == want
 
 
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_step_record_says_which_form_the_experts_combined_in(
+        monkeypatch, form):
+    """``moe_combine_form`` is what ``build_step`` chose for the
+    experts' combine (``moe.py::combine_form``): XLA on this backend.
+    With the choice steered to the kernel (Pallas's interpreter in the
+    chip's place) every record and ``stats()`` say so, and the engine
+    serves the same tokens: a model that holds half its router's
+    experts, so that about half the pairs are neither fetched nor
+    added, in every bucket."""
+    import functools
+
+    from lm_helpers import tiny_qwen
+
+    from vlog_tpu.lm import moe, moe_kernel
+
+    _hf, cfg, params = tiny_qwen(hidden_size=128)
+    prompts = [np.arange(n) % 512 for n in (21, 5, 40)]
+
+    def served():
+        eng = engine(cfg, params, rows=8, chunk=16, page=8, cap=128)
+        try:
+            tokens = [r.wait(300) for r in [
+                eng.submit(p, max_new=6) for p in prompts]]
+            return tokens, list(eng.step_log), eng.stats()
+        finally:
+            eng.close()
+
+    want, log, stats = served()
+    assert {rec["moe_combine_form"] for rec in log} == {"xla"}
+    assert (stats["combine_xla_steps"], stats["combine_kernel_steps"]) \
+        == (len(log), 0)
+    if form == "xla":
+        return
+    monkeypatch.setattr(moe, "combine_form", lambda t, k, h: (
+        "kernel" if moe_kernel.supported(t, k, h) else "xla"))
+    monkeypatch.setattr(moe, "combine", functools.partial(
+        moe.combine, interpret=True))
+    got, log, stats = served()
+    assert {rec["moe_combine_form"] for rec in log} == {"kernel"}
+    assert (stats["combine_xla_steps"], stats["combine_kernel_steps"]) \
+        == (0, len(log))
+    assert got == want
+
+
 def test_eos_ends_a_request_early(model):
     _hf, cfg, params = model
     eng = engine(cfg, params)

@@ -9,7 +9,7 @@ it); ``python -m vlog_tpu.analysis`` is the CLI. Pass registry:
 - ``tracehop``        thread hand-offs in traced modules carry context
 - ``registry``        knob/metric/failpoint/span registries vs docs
 - ``meshshim``        shard_map call sites go through parallel/mesh
-- ``pallasshim``      Pallas kernel code stays in its two kernel modules
+- ``pallasshim``      Pallas kernel code stays in its kernel modules
 - ``lockorder``       lock-order ranks: no rank inversions or cycles
 - ``holdblock``       no blocking calls while an annotated lock is held
 - ``slowlane``        compile-path tests carry the ``slow`` marker

@@ -16,7 +16,12 @@ the same terms: the transcript model's chunk attention, which
 backend (the CPU keeps the XLA loop; tests run the kernel interpreted),
 so ``model.py`` never sees ``pallas_call`` either.
 
-Rule: outside those two modules, no module may
+``lm/moe_kernel.py`` is the third, under the same terms: the
+experts' combine, which ``lm/moe.py::combine_form`` selects from the
+call's shapes and the backend (the CPU keeps the XLA combine; tests run
+the kernel interpreted), so ``moe.py`` never sees ``pallas_call``.
+
+Rule: outside those modules, no module may
 
 - ``from jax.experimental import pallas`` (or ``pallas as pl``)
 - ``import jax.experimental.pallas`` / any ``jax.experimental.pallas.*``
@@ -39,12 +44,13 @@ from vlog_tpu.analysis.core import Finding, Module, dotted_name
 
 RULE = "pallasshim"
 
-_SHIM = ("ops/pallas_ladder.py or lm/attention_kernel.py (the sanctioned "
-         "Pallas surfaces)")
+_SHIM = ("ops/pallas_ladder.py, lm/attention_kernel.py or lm/moe_kernel.py "
+         "(the sanctioned Pallas surfaces)")
 _PALLAS_ROOT = "jax.experimental.pallas"
 
 
-_KERNEL_MODULES = (("ops", "pallas_ladder.py"), ("lm", "attention_kernel.py"))
+_KERNEL_MODULES = (("ops", "pallas_ladder.py"), ("lm", "attention_kernel.py"),
+                   ("lm", "moe_kernel.py"))
 
 
 def _exempt(mod: Module) -> bool:

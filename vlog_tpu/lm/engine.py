@@ -44,7 +44,10 @@ chunk chose its keys, fixed with the program: ``"kernel"`` or ``"loop"``,
 no indexer), ``attn_rows_form`` (the rows', fixed with the
 program too: ``"rows_kernel"`` or ``"loop"`` from the same function,
 ``"gathered"`` for a model with an indexer, ``"latent_absorbed"`` for
-latent attention), ``rows_context`` (positions
+latent attention), ``moe_combine_form`` (the form in which the
+experts' rows were summed back, fixed with the program: ``"kernel"`` or
+``"xla"``, ``moe.py::combine_form``; ``None`` for a model without expert
+layers), ``rows_context`` (positions
 the rows' attention read a layer: each row's position plus one),
 ``chunk_tag``, ``emitted`` (tags of the requests that got a token),
 ``pages_in_use`` by class, ``pages_freed``, ``pool_wait_rows`` (rows that
@@ -166,7 +169,9 @@ class LmEngine:
         self.attn_rows_form: str | None = None
         self.gdn_forms: dict[int, str | None] = {}  # bucket -> DeltaNet's
         self.gdn_rows_form: str | None = None
+        self.combine_forms: dict[int, str | None] = {}  # bucket -> combine
         self.attn_steps = {"kernel": 0, "loop": 0}  # guarded-by: _lock
+        self.combine_steps = {"kernel": 0, "xla": 0}  # guarded-by: _lock
         self.hc_defect_max = 0.0                    # guarded-by: _lock
         self.waits = hostwait.WaitBook()            # guarded-by: _lock
         # engine thread only
@@ -253,7 +258,8 @@ class LmEngine:
         for a model with an indexer, chose its keys in each
         (``select_kernel_steps``, ``select_loop_steps``),
         ``attn_rows_form`` the form of
-        the rows, and
+        the rows, ``combine_kernel_steps`` / ``combine_xla_steps`` the
+        steps whose experts' rows were summed back in each form, and
         ``hc_defect_max`` the largest ``hc_defect`` of any step (0.0 for
         a model with one residual stream), ``waits`` the pulls' stalls by
         cause and the five longest (``obs/hostwait.py::WaitBook``)."""
@@ -272,6 +278,8 @@ class LmEngine:
                     "attn": {f"{form}_steps": n
                              for form, n in self.attn_steps.items()},
                     "attn_rows_form": self.attn_rows_form,
+                    **{f"combine_{form}_steps": n
+                       for form, n in self.combine_steps.items()},
                     "waits": self.waits.stats()}
 
     def close(self) -> None:
@@ -329,6 +337,7 @@ class LmEngine:
             self.attn_rows_form = step.attn_rows_form
             self.gdn_forms[chunk] = step.gdn_chunk_form
             self.gdn_rows_form = step.gdn_rows_form
+            self.combine_forms[chunk] = step.moe_combine_form
             fn = jax.jit(step, donate_argnums=(1, 2))
             self.programs[chunk] = fn.lower(
                 self.assets.params, self._kv, self._last_tok, plan).compile()
@@ -420,6 +429,8 @@ class LmEngine:
                     forms.append(f"select_{done['attn_select_form']}")
                 for form in filter(None, forms):
                     self.attn_steps[form] = self.attn_steps.get(form, 0) + 1
+                if done["moe_combine_form"]:
+                    self.combine_steps[done["moe_combine_form"]] += 1
                 self.hc_defect_max = max(self.hc_defect_max,
                                          done.get("hc_defect", 0.0))
                 done["stall"] = self.waits.add(done["wait"],
@@ -558,6 +569,7 @@ class LmEngine:
                   "attn_rows_form": self.attn_rows_form,
                   "gdn_chunk_form": self.gdn_forms[bucket],
                   "gdn_rows_form": self.gdn_rows_form,
+                  "moe_combine_form": self.combine_forms[bucket],
                   "state_slots": self._cache.slots.in_use,
                   "rows_context": int(plan["row_pos"][
                       plan["row_active"]].sum() + len(deco)),

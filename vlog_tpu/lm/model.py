@@ -1730,6 +1730,10 @@ def build_step(cfg: LmConfig, geo: Geometry, chunk: int):
     step.attn_select_form = select_form(
         chunk, geo.key_width, geo.kv_block_pages * geo.page) \
         if chunk and cfg.index_topk else None
+    # the experts' combine (None where every layer is dense)
+    step.moe_combine_form = moe.combine_form(
+        chunk + r, cfg.num_experts_per_tok, cfg.hidden_size) \
+        if cfg.num_layers > cfg.num_dense_layers else None
     # the DeltaNet's two forms: XLA on every backend (no kernel yet)
     step.gdn_rows_form = "recurrent" if cfg.linear_layers else None
     step.gdn_chunk_form = "chunkwise" if cfg.linear_layers and chunk \

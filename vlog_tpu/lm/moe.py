@@ -8,16 +8,28 @@ expert and runs ONE grouped product per projection over every expert
 (``jax.lax.ragged_dot``: rows of the sorted activations against the
 expert their group names), so no token is dropped and no capacity is
 set; a token's ``k`` results are weighted and summed back in token
-order. bfloat16 operands, float32 accumulation. A layer that holds a
-share of the experts (one chip's of an expert-parallel deployment)
-routes over all of them and computes the part its own give.
+order (:func:`combine`). bfloat16 operands, float32 accumulation. A
+layer that holds a share of the experts (one chip's of an
+expert-parallel deployment) routes over all of them and computes the
+part its own give.
+
+The combine takes one of two forms, chosen by :func:`combine_form` from
+the backend and the shapes (no knob): on a TPU one Mosaic kernel that
+fetches each token's ``k`` rows by position and weights and sums them in
+VMEM (``moe_kernel.py``); elsewhere XLA weights every row, gathers them
+back into token order and sums. Both find a pair's row by the inverse of
+the dispatch's sort, made by a scatter, not a second sort.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from vlog_tpu.lm import moe_kernel
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -95,8 +107,42 @@ def experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
         u = lax.ragged_dot(xs, up, sizes, preferred_element_type=F32)
         ys = lax.ragged_dot((jax.nn.silu(g) * u).astype(BF16), down, sizes,
                             preferred_element_type=F32)
-    with jax.named_scope("lm.moe.combine"):
-        ys = ys * weights.reshape(-1)[order][:, None]
-        back = jnp.argsort(order)
-        out = ys[back].reshape(t, k, -1).sum(axis=1)
+    out = combine(ys, order, weights,
+                  None if held is None else mine.reshape(t, k),
+                  form=combine_form(t, k, ys.shape[-1]))
     return out, counted, jnp.sum(sizes > 0).astype(jnp.int32)
+
+
+def combine_form(t: int, k: int, h: int) -> str:
+    """``"kernel"`` or ``"xla"``: how the ``k`` rows of each of ``t``
+    tokens, ``h`` wide, are summed back (:func:`combine`): the kernel on a
+    TPU where Mosaic tiles the shapes, XLA elsewhere (shapes and backend,
+    as ``model.py::attention_form``; no knob)."""
+    kernel = moe_kernel.supported(t, k, h)
+    return "kernel" if kernel and jax.default_backend() == "tpu" else "xla"
+
+
+# one trace and one lowered callee a shape: every expert layer of a
+# program calls the same
+@functools.partial(jax.jit, static_argnames=("form", "interpret"))
+def combine(ys: jax.Array, order: jax.Array, weights: jax.Array,
+            mine: jax.Array | None, *, form: str,
+            interpret: bool = False) -> jax.Array:
+    """``ys`` (T*k, H) float32, the pairs' rows in the dispatch's
+    ``order``; ``weights`` (T, k) float32 in token order (0 for a pair
+    off this chip's experts); ``mine`` (T, k) bool, the pairs this chip's
+    experts computed (``None``: every pair). Returns (T, H) float32, each
+    token's rows times their weights, summed: by the kernel in the order
+    ``j = 0 .. k-1``, as XLA sums them on a CPU (bit for bit); XLA's TPU
+    reduce adds the ``k`` terms in another order (the last bits)."""
+    t, k = weights.shape
+    n = t * k
+    with jax.named_scope("lm.moe.combine"):
+        # each pair's row in expert order: the inverse of ``order``
+        back = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+        if form == "kernel":
+            return moe_kernel.combine(ys, back.reshape(t, k), weights, mine,
+                                      interpret=interpret)
+        ys = ys * weights.reshape(-1)[order][:, None]
+        return ys[back].reshape(t, k, -1).sum(axis=1)
